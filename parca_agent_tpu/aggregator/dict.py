@@ -66,6 +66,32 @@ _PROBES = 16
 _VEC_MISS_MIN = 512
 
 
+# Block length of the two-level prefix sum below.
+_SCAN_BLOCK = 1024
+
+
+def prefix_sum(x):
+    """Inclusive prefix sum of a 1-D integer array: the values of
+    ``jnp.cumsum(x)``, in a shape XLA:TPU can compile.
+
+    XLA:TPU's compile time for a long 1-D cumsum grows much faster than
+    its length: 0.2 s at 4,096 lanes, 1.1 s at 65,536, 33.7 s at
+    1,048,576 (TPU v5 lite, jax 0.9.0) — and every feed/close program
+    here compacts with one, so at the flagship size each new shape
+    bucket cost ~30 s of compiler inside a 60 s device watchdog. Summing
+    1024-lane blocks along a second axis and adding the blocks' own
+    (recursive) prefix compiles in 0.8 s at 1,048,576 lanes and gives
+    the same integers."""
+    import jax.numpy as jnp
+
+    n = x.shape[0]
+    if n <= 4 * _SCAN_BLOCK or n % _SCAN_BLOCK:
+        return jnp.cumsum(x)
+    inner = jnp.cumsum(x.reshape(n // _SCAN_BLOCK, _SCAN_BLOCK), axis=1)
+    totals = inner[:, -1]
+    return (inner + (prefix_sum(totals) - totals)[:, None]).reshape(n)
+
+
 def make_feed(cap: int, id_cap: int, n_pad: int, n_blocks: int = 0,
               blk: int = 0, probe=None):
     """Pure (unjitted) streaming-window accumulate: batched linear-probe
@@ -128,7 +154,7 @@ def make_feed(cap: int, id_cap: int, n_pad: int, n_blocks: int = 0,
             touch = touch.at[jnp.where(hit, found_id // blk,
                                        n_blocks)].set(1, mode="drop")
         miss = live & ~hit
-        mtgt = jnp.where(miss, jnp.cumsum(miss.astype(jnp.int32)) - 1,
+        mtgt = jnp.where(miss, prefix_sum(miss.astype(jnp.int32)) - 1,
                          jnp.int32(n_pad))
         miss_rows = jnp.full((n_pad,), -1, jnp.int32).at[mtgt].set(
             jnp.arange(h1.shape[0], dtype=jnp.int32), mode="drop")
@@ -194,7 +220,7 @@ def make_close(id_cap: int, n_fetch: int, width: int,
         shifts = (jnp.arange(per32, dtype=jnp.uint32) * width)[None, :]
         lanes = (vals.reshape(-1, per32) << shifts).sum(
             axis=1, dtype=jnp.uint32)
-        tgt = jnp.where(over, jnp.cumsum(over.astype(jnp.int32)) - 1,
+        tgt = jnp.where(over, prefix_sum(over.astype(jnp.int32)) - 1,
                         jnp.int32(n_over_buf))
         ids = jnp.arange(n_fetch, dtype=jnp.uint32)
         over_id = jnp.full((n_over_buf,), jnp.uint32(n_fetch)).at[tgt].set(
@@ -259,7 +285,7 @@ def make_close_delta(id_cap: int, n_fetch: int, width: int,
     def close(acc, touch):
         t = touch[:nb_prefix] > 0
         n_touched = t.astype(jnp.uint32).sum()
-        tgt = jnp.where(t, jnp.cumsum(t.astype(jnp.int32)) - 1,
+        tgt = jnp.where(t, prefix_sum(t.astype(jnp.int32)) - 1,
                         jnp.int32(n_blk_buf))
         blk_ids = jnp.full((n_blk_buf,), jnp.uint32(nb_prefix)).at[tgt].set(
             jnp.arange(nb_prefix, dtype=jnp.uint32), mode="drop")
@@ -273,7 +299,7 @@ def make_close_delta(id_cap: int, n_fetch: int, width: int,
         lanes = (pk.reshape(-1, per32) << shifts).sum(axis=1,
                                                       dtype=jnp.uint32)
         gid = gidx.reshape(-1).astype(jnp.uint32)
-        otgt = jnp.where(over, jnp.cumsum(over.astype(jnp.int32)) - 1,
+        otgt = jnp.where(over, prefix_sum(over.astype(jnp.int32)) - 1,
                          jnp.int32(n_over_buf))
         over_id = jnp.full((n_over_buf,), jnp.uint32(n_fetch)).at[otgt].set(
             gid, mode="drop")
@@ -411,7 +437,8 @@ class DictAggregator:
         self._overflow = overflow
         # Probe implementation for the feed program: "lax" (default — the
         # proven hot path), "pallas" (aggregator/pallas_probe.py), or
-        # "auto" (pallas when available, else lax). Resolved lazily at
+        # "auto" (pallas where it runs interpreted, lax on a TPU, where
+        # Mosaic refuses the kernel). Resolved lazily at
         # the first dispatch; the resolution can only downgrade pallas ->
         # lax (never upgrade mid-run: the jit cache keys on it).
         self._probe_backend = probe_backend
@@ -587,7 +614,7 @@ class DictAggregator:
         (length == number of stacks known after this window).
 
         One-shot semantics over the SAME feed/close programs the streaming
-        protocol uses (a separate lookup program would be one more tunnel
+        protocol uses (a separate lookup program would be one more
         compile for an 8 MB unpacked fetch; feed + packed close ships the
         window once and fetches ~0.6 MB). Any partially-fed open window is
         discarded first — callers don't mix the two protocols mid-window.
@@ -1205,33 +1232,32 @@ class DictAggregator:
 
     def _probe_backend_name(self) -> str:
         if self._probe_resolved is None:
+            from parca_agent_tpu.aggregator import pallas_probe
+
             want = self._probe_backend
-            if want in ("auto", "pallas"):
-                from parca_agent_tpu.aggregator import pallas_probe
+            if want == "auto":
+                # Chosen from the platform, not a fallback: Mosaic
+                # refuses the kernel on a TPU (pallas_probe module docs).
+                want = "pallas" if pallas_probe.auto_uses_pallas() \
+                    else "lax"
+            elif want == "pallas" and not pallas_probe.pallas_available():
+                from parca_agent_tpu.utils.log import get_logger
 
-                if pallas_probe.pallas_available():
-                    want = "pallas"
-                else:
-                    if self._probe_backend == "pallas":
-                        from parca_agent_tpu.utils.log import get_logger
-
-                        get_logger("aggregator.dict").warn(
-                            "pallas probe requested but unavailable; "
-                            "using the lax probe loop")
-                    want = "lax"
+                get_logger("aggregator.dict").warn(
+                    "pallas probe requested but unavailable; "
+                    "using the lax probe loop")
+                want = "lax"
             self._probe_resolved = want
             interp = None
             if want == "pallas":
-                from parca_agent_tpu.aggregator import pallas_probe
-
                 interp = pallas_probe.default_interpret()
-            # A non-lax request resolving to lax IS the silent fallback
-            # the one-hot gauge exists to surface (docs/observability.md
-            # "device flight recorder").
+            # An explicit pallas request resolving to lax IS the silent
+            # fallback the one-hot gauge exists to surface
+            # (docs/observability.md "device flight recorder").
             dtel.note_backend(
                 "feed_probe", requested=self._probe_backend, resolved=want,
                 interpret=interp,
-                fallback=(want == "lax" and self._probe_backend != "lax"))
+                fallback=(want == "lax" and self._probe_backend == "pallas"))
         return self._probe_resolved
 
     def _feed_dispatch_async(self, packed: np.ndarray, n_pad: int,

@@ -1,9 +1,29 @@
-"""Pallas TPU re-expression of the hash-table probe loops.
+"""Pallas re-expression of the hash-table probe loops.
 
-Two kernels, both with an ``interpret=True`` CPU path (exercised by
-tier-1 tests on every CPU-only run) and automatic fallback to the
-existing lax implementations when Pallas is unavailable or fails to
-build (docs/perf.md "sub-RTT close"):
+Two kernels, both with an ``interpret=True`` path (exercised by tier-1
+tests on every CPU-only run) and a fallback to the lax implementations
+when Pallas is unavailable or fails to build (docs/perf.md "sub-RTT
+close").
+
+The chip's verdict (TPU v5 lite, jax 0.9.0, libtpu 0.0.34; compiled
+non-interpret, outside the callers' try/except, at a toy shape and at
+BASELINE config #4 shapes): Mosaic REFUSES both, at lowering, whatever
+the size —
+
+  * batch probe (cap 4096 x 1024 rows; cap 4,194,304 x 131,072 and
+    x 1,048,576 rows): ``RuntimeError: `broadcast_to` is a
+    Triton-specific primitive. Please consider using `jnp.broadcast_to`
+    instead.`` (the vector-indexed ``table_ref[idx, c]`` loads);
+  * loc-table builder (f_cap 1024 / cap_l 2048; f_cap 2^25 / cap_l
+    2^26): ``NotImplementedError: Only 2D gather is supported`` (the
+    1-D ``tpid[pos]`` gathers inside the while_loop).
+
+So on a TPU these kernels do not exist, and ``"auto"`` does not select
+them there (:func:`auto_uses_pallas`): a default that pays a failed
+Mosaic compile and then quietly runs the reference is a hidden
+fallback. Asking for them by name (``probe_backend="pallas"``,
+``dedup="hash"``) still tries, fails loudly and latches lax. Making them
+lower, or deleting them, is ROADMAP A6/C3.
 
   * :func:`make_batch_probe` — the stack dictionary's bounded linear
     probe (``aggregator/dict.py`` ``make_feed``'s inner ``fori_loop``):
@@ -29,10 +49,7 @@ output order — byte-identical pprof, enforced by tests and the bench's
 ``close_overlap`` phase.
 
 Both kernels run whole-array (grid=1) with the operands in
-compiler-chosen memory; on a real TPU backend Mosaic fuses the probe
-loop into one kernel, and any lowering failure (old jaxlib, unsupported
-gather shape) is caught by the callers' fallback — never a wrong
-answer, at worst the lax speed.
+compiler-chosen memory.
 """
 
 from __future__ import annotations
@@ -61,6 +78,15 @@ def pallas_available() -> bool:
         return int(got[0]) == 4
     except Exception:  # noqa: BLE001 - any failure means "not available"
         return False
+
+
+def auto_uses_pallas() -> bool:
+    """What ``"auto"`` (``DictAggregator.probe_backend``,
+    ``TPUAggregator.dedup``) resolves to: the Pallas kernels wherever
+    they run interpreted, the lax programs on a TPU — where Mosaic
+    refuses both kernels (module docs), so selecting them would only
+    buy a failed compile followed by the reference path."""
+    return default_interpret() and pallas_available()
 
 
 def default_interpret() -> bool:

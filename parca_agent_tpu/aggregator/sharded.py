@@ -39,6 +39,7 @@ from parca_agent_tpu.aggregator.dict import (
     _PROBES,
     DictAggregator,
     make_close,
+    prefix_sum,
 )
 from parca_agent_tpu.parallel.mesh import FLEET_AXIS, fleet_mesh
 from parca_agent_tpu.runtime import device_telemetry as dtel
@@ -114,7 +115,7 @@ def _sharded_feed_program(mesh, n_shards: int, cap_s: int, id_cap: int,
         a = a.at[jnp.where(hit, found_id, id_cap)].add(
             jnp.where(live, cnt, 0), mode="drop")
         miss = live & ~hit
-        mtgt = jnp.where(miss, jnp.cumsum(miss.astype(jnp.int32)) - 1,
+        mtgt = jnp.where(miss, prefix_sum(miss.astype(jnp.int32)) - 1,
                          jnp.int32(n_pad_s))
         # Report ORIGINAL packed-buffer positions (the host partitioned
         # the rows, so local lane indices would be meaningless to it).

@@ -479,10 +479,12 @@ class TPUAggregator:
     # f_cap-lane sort as a hash-table build+probe (the Pallas kernel,
     # aggregator/pallas_probe.py — the full-rebuild/backfill fix, docs/
     # perf.md "sub-RTT close"); "sort" is the proven lax pipeline;
-    # "auto" (default) uses hash when Pallas is available and falls back
-    # to sort automatically — including at runtime if the hash kernel
-    # fails to build/lower on this backend. Output bytes are identical
-    # either way (enforced by tests and the bench's close_overlap phase).
+    # "auto" (default) uses hash where the Pallas kernel runs
+    # interpreted and sort on a TPU, where Mosaic refuses the kernel
+    # (pallas_probe module docs); an explicit "hash" still falls back to
+    # sort at runtime, loudly, if the kernel fails to build. Output
+    # bytes are identical either way (enforced by tests and the bench's
+    # close_overlap phase).
     dedup: str = "auto"
 
     # Unique-location count beyond which the one-shot kernel is the wrong
@@ -500,20 +502,27 @@ class TPUAggregator:
                               resolved="lax",
                               fallback=self._hash_disabled)
             return False
-        from parca_agent_tpu.aggregator.pallas_probe import pallas_available
+        from parca_agent_tpu.aggregator import pallas_probe
 
-        if pallas_available():
+        if self.dedup == "auto":
+            # Chosen from the platform, not a fallback: Mosaic refuses
+            # the builder on a TPU (pallas_probe module docs).
+            use = pallas_probe.auto_uses_pallas()
+            dtel.note_backend("loc_dedup", requested="auto",
+                              resolved="pallas" if use else "lax",
+                              fallback=False)
+            return use
+        if pallas_probe.pallas_available():
             dtel.note_backend("loc_dedup", requested=self.dedup,
                               resolved="pallas", fallback=False)
             return True
-        if self.dedup == "hash":
-            from parca_agent_tpu.utils.log import get_logger
+        from parca_agent_tpu.utils.log import get_logger
 
-            get_logger("aggregator.tpu").warn(
-                "hash dedup requested but Pallas is unavailable; using "
-                "the lax sort kernel")
+        get_logger("aggregator.tpu").warn(
+            "hash dedup requested but Pallas is unavailable; using "
+            "the lax sort kernel")
         self._hash_disabled = True
-        # Pallas wanted (auto/hash) but unavailable: the latched
+        # Pallas asked for by name but unavailable: the latched
         # fallback the one-hot gauge surfaces.
         dtel.note_backend("loc_dedup", requested=self.dedup,
                           resolved="lax", fallback=True)

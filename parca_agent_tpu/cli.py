@@ -493,6 +493,13 @@ def build_parser() -> argparse.ArgumentParser:
     return p
 
 
+# Watchdog budget for the process's FIRST guarded device call, which is
+# XLA compiles plus the host-side insert of the whole stack population
+# rather than a device wait (profiler/cpu.py). Every later call runs
+# under the profiler's steady-state bound.
+_FIRST_DEVICE_CALL_TIMEOUT_S = 600.0
+
+
 def _parse_external_labels(text: str) -> dict[str, str]:
     out = {}
     for part in filter(None, text.split(",")):
@@ -618,6 +625,93 @@ def run(argv=None) -> int:
         log.info("running inside a container; host procfs must be mounted "
                  "for whole-machine profiling")
 
+    # -- device flight recorder (docs/observability.md "device flight
+    # recorder") -------------------------------------------------------------
+    # The host recorder's device-side twin: per-kernel compile/execute
+    # histograms with recompile-storm detection, transfer-byte
+    # accounting, latched backend identity, and the window-SLO budget
+    # layer keyed to the configured profiling period. Installed
+    # process-globally so the kernel dispatch sites in
+    # aggregator/{dict,tpu,sharded}.py report without plumbing; storms
+    # route through the window recorder's incident machinery below.
+    from parca_agent_tpu.runtime import device_telemetry as dtel_mod
+
+    device_telemetry = None
+    if not args.no_device_telemetry:
+        device_telemetry = dtel_mod.DeviceTelemetry(
+            period_s=args.profiling_duration,
+            ring=args.telemetry_ring,
+            incident_interval_s=args.trace_incident_interval)
+        dtel_mod.install(device_telemetry)
+
+    # -- device bring-up (docs/robustness.md "device & fleet health") --------
+    # Any config with a device backend gets the demote/promote registry,
+    # and its bring-up runs HERE, before the capture source starts and
+    # before anything else can touch JAX — one process per chip:
+    #   1. the persistent compile cache is placed (runtime/
+    #      compile_cache.py; config only, no backend);
+    #   2. the bring-up probe runs in a KILLED-on-deadline child (a
+    #      wedged backend init hangs inside a C call, and only a child
+    #      process can be killed) while this process stays off JAX;
+    #   3. once the child has exited — and released the chip — this
+    #      process claims the backend, deliberately: it initialises JAX,
+    #      learns the platform it landed on and says so.
+    # Startup waits for 2 and 3, bounded by the probe's own kill
+    # deadline, so the first window is a device window rather than a
+    # fallback window that merely raced a healthy probe. A probe that
+    # fails leaves the registry degraded: windows ship from the CPU
+    # fallback (counted) while capped-backoff re-probes run — in a child
+    # again as long as this process holds no backend, in-process
+    # (utils/bounded.py) once it does. A mid-run hang demotes the same
+    # way and promotion passes a shadow-window correctness gate.
+    device_health = None
+    device_count = 0
+    if args.aggregator != "cpu":
+        from parca_agent_tpu.runtime import compile_cache
+        from parca_agent_tpu.runtime.device_health import (
+            DeviceHealthRegistry,
+            inprocess_probe,
+            subprocess_probe,
+        )
+        from parca_agent_tpu.utils.bounded import bounded_call
+
+        log.info("jax compile cache", directory=compile_cache.configure())
+        dtel_mod.watch_xla_compiles()
+
+        def claim() -> dict:
+            ident = dtel_mod.collect_identity()
+            if device_telemetry is not None:
+                device_telemetry.set_identity(ident)
+            return ident
+
+        probe = None
+        if args.device_probe_timeout > 0:
+            def probe(t=args.device_probe_timeout):
+                if device_health.platform is not None \
+                        or dtel_mod.backend_initialized():
+                    return inprocess_probe(t)
+                return subprocess_probe(t)
+
+        device_health = DeviceHealthRegistry(
+            probe=probe, claim=claim,
+            probe_timeout_s=args.device_probe_timeout,
+            promote_after=args.device_promote_after,
+            window_s=args.profiling_duration)
+        device_health.start()
+        bound = args.device_probe_timeout or 60.0
+        if not device_health.wait_bringup(bound + 10.0):
+            log.warn("device bring-up probe unresolved; starting on the "
+                     "CPU fallback")
+        elif device_health.state == "healthy":
+            status, out, _done, _box = bounded_call(
+                device_health.claim_backend, bound,
+                thread_name="device-claim")
+            if status == "ok" and out is not None:
+                device_count = int(out["device_count"])
+            elif status != "ok":
+                device_health.record_claim_failure(
+                    "backend init hung" if status == "hang" else repr(out))
+
     # -- capture source ------------------------------------------------------
     if args.capture == "replay":
         from parca_agent_tpu.capture.replay import ReplaySource
@@ -679,15 +773,22 @@ def run(argv=None) -> int:
         aggregator = TPUAggregator()
         fallback = CPUAggregator()
     elif args.aggregator == "sharded":
-        import jax
-
+        if device_health.platform is None:
+            # The mesh is built from the devices this process owns; with
+            # no backend claimed (bring-up failed, see above) asking JAX
+            # for them here would be the unbounded init the probe
+            # exists to avoid.
+            log.error("--aggregator sharded needs a working device "
+                      "backend at startup",
+                      device=device_health.snapshot()["last_error"])
+            return 1
         from parca_agent_tpu.aggregator.sharded import ShardedDictAggregator
         from parca_agent_tpu.parallel.mesh import fleet_mesh
 
         # Largest power-of-two device count: sub-tables must be
         # power-of-two sized, and a 6-device host should shard 4 ways
         # rather than die at startup.
-        n_dev = len(jax.devices())
+        n_dev = device_count
         n_shards = 1 << (n_dev.bit_length() - 1)
         if n_shards < n_dev:
             log.warn("sharded aggregator uses a power-of-two shard count",
@@ -717,33 +818,6 @@ def run(argv=None) -> int:
         fallback = CPUAggregator()
     else:
         aggregator = CPUAggregator()
-
-    # -- device-runtime health (docs/robustness.md "device & fleet
-    # health") ---------------------------------------------------------------
-    # Any config with a device backend (fallback != None) gets the
-    # demote/promote registry: bring-up is a KILLED-on-deadline
-    # subprocess probe (a wedged backend init hangs inside a C call —
-    # BENCH_r05 measured >420 s of it — and only a child process can be
-    # killed), the capture loop runs on the CPU fallback until the probe
-    # lands, and a mid-run hang demotes with capped-backoff re-probes +
-    # a shadow-window correctness gate before promotion.
-    device_health = None
-    if fallback is not None:
-        from parca_agent_tpu.runtime.device_health import (
-            DeviceHealthRegistry,
-            subprocess_probe,
-        )
-
-        probe = None
-        if args.device_probe_timeout > 0:
-            probe = (lambda t=args.device_probe_timeout:
-                     subprocess_probe(t))
-        device_health = DeviceHealthRegistry(
-            probe=probe,
-            probe_timeout_s=args.device_probe_timeout,
-            promote_after=args.device_promote_after,
-            window_s=args.profiling_duration)
-        device_health.start()
 
     # -- multi-tenant admission (docs/robustness.md) -------------------------
     # Per-tenant (cgroup-derived) window quotas riding the quarantine
@@ -1058,25 +1132,6 @@ def run(argv=None) -> int:
             incident_interval_s=args.trace_incident_interval)
         trace_mod.install(recorder)
 
-    # -- device flight recorder (docs/observability.md "device flight
-    # recorder") -------------------------------------------------------------
-    # The host recorder's device-side twin: per-kernel compile/execute
-    # histograms with recompile-storm detection, transfer-byte
-    # accounting, latched backend identity, and the window-SLO budget
-    # layer keyed to the configured profiling period. Installed
-    # process-globally so the kernel dispatch sites in
-    # aggregator/{dict,tpu,sharded}.py report without plumbing; storms
-    # route through the window recorder's incident machinery above.
-    device_telemetry = None
-    if not args.no_device_telemetry:
-        from parca_agent_tpu.runtime import device_telemetry as dtel_mod
-
-        device_telemetry = dtel_mod.DeviceTelemetry(
-            period_s=args.profiling_duration,
-            ring=args.telemetry_ring,
-            incident_interval_s=args.trace_incident_interval)
-        dtel_mod.install(device_telemetry)
-
     # -- warm statics snapshot (docs/perf.md "the statics wall") -------------
     statics_store = None
     if args.statics_snapshot_path:
@@ -1262,6 +1317,7 @@ def run(argv=None) -> int:
         admission=admission,
         identity=identity,
         device_health=device_health,
+        first_device_timeout_s=_FIRST_DEVICE_CALL_TIMEOUT_S,
         statics_store=statics_store,
         statics_snapshot_every=args.statics_snapshot_interval,
         statics_cache_bytes=args.statics_cache_bytes,

@@ -6,7 +6,7 @@ registry and the sampling period, and that registry is itself stable
 across an agent restart: the profiled processes did not move, so the
 same mappings and the same addresses re-register. Yet a restart used to
 pay the full cold build — 930–2230 ms of `statics_build_ms` plus a
-240–300 ms first encode at 10 k-pid reduced scale (BENCH_r04/r05) —
+240–300 ms first encode at 10 k-pid reduced scale (host clock, CPU host) —
 because all of that state lived only in process memory.
 
 This module persists it. On the encode-pipeline worker's window clock

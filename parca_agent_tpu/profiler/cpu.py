@@ -78,6 +78,7 @@ class CPUProfiler:
         fallback_aggregator: Aggregator | None = None,
         on_iteration: Callable[[int], None] | None = None,
         device_timeout_s: float = 60.0,
+        first_device_timeout_s: float | None = None,
         device_retry_windows: int = 30,
         manage_gc: bool = False,
         window_sink: Callable[[WindowSnapshot], None] | None = None,
@@ -272,14 +273,33 @@ class CPUProfiler:
             # reads; gate the feeder's polling-thread touches on it.
             # Likewise an inline encode abandoned at its soft deadline
             # still owns the encoder's mirrors until it returns.
+            # And while the device is not trusted (bring-up probe in
+            # flight, demoted, shadow pending) a drain must not feed at
+            # all: the feed would be the process's first JAX touch, and
+            # a probe child may be holding the chip at that very moment.
             streaming_feeder.external_blocked = (
                 lambda: (self._device_inflight is not None
                          and not self._device_inflight.is_set())
                 or (self._encode_inflight is not None
-                    and not self._encode_inflight.is_set()))
+                    and not self._encode_inflight.is_set())
+                or (self._health is not None
+                    and self._health.window_mode() != "device"))
         self._feeder = streaming_feeder
         self._fallback = fallback_aggregator
         self._device_timeout = device_timeout_s
+        # The process's FIRST guarded device call is not a device wait:
+        # it is XLA compiling the feed/close programs plus the host
+        # inserting the whole stack population (aggregator/dict.py
+        # _resolve_misses) — at the flagship size that alone outlasts
+        # the steady-state bound, and tripping it demotes a healthy
+        # device on every cold start. Same repair as the streaming
+        # feeder's first feed (profiler/streaming.py): the long budget
+        # applies exactly once; if that attempt still overruns, every
+        # later call runs under the steady-state bound, which is never
+        # raised. None (embedders, tests) = no separate first budget.
+        self._next_device_timeout = max(
+            device_timeout_s, first_device_timeout_s or device_timeout_s)
+        self._device_timeout_used = device_timeout_s  # for the hang logs
         # Device lifecycle state lives in ONE place: the health registry
         # (runtime/device_health.py) owns wedge accounting, cooldowns,
         # the probing/healthy/degraded/dead machine, and the shadow-
@@ -397,10 +417,15 @@ class CPUProfiler:
 
         def site():
             faults.inject("device.dispatch")
+            # First device touch after a bring-up that had failed: learn
+            # the backend here, inside the guard (no-op once claimed).
+            self._health.claim_backend()
             return thunk()
 
+        self._device_timeout_used = self._next_device_timeout
+        self._next_device_timeout = self._device_timeout
         status, out, done, box = bounded_call(
-            site, self._device_timeout, thread_name="aggregate-device")
+            site, self._device_timeout_used, thread_name="aggregate-device")
         if status == "hang":
             self._device_inflight = done
             self._device_abandoned = box
@@ -459,7 +484,8 @@ class CPUProfiler:
             cpu_out = fallback_thunk()
             if status == "hang":
                 _log.error("device hung during its shadow window; "
-                           "re-demoting", timeout_s=self._device_timeout)
+                           "re-demoting",
+                           timeout_s=self._device_timeout_used)
                 self._health.record_hang()
             else:
                 matched = status == "ok" \
@@ -481,8 +507,11 @@ class CPUProfiler:
                 "device aggregation hung; abandoning call and using the "
                 "CPU fallback",
                 aggregator=type(self._aggregator).__name__,
-                timeout_s=self._device_timeout)
+                timeout_s=self._device_timeout_used)
             self._health.record_hang()
+        # Counted like a planned fallback window: the window ships from
+        # the CPU either way, and "0 fallback windows" has to mean it.
+        self._health.record_fallback_window()
         return fallback_thunk()
 
     def run_iteration(self) -> bool:
@@ -841,6 +870,10 @@ class CPUProfiler:
         with tr.span("close") as sp_close:
             kind, out = self._guarded(fast, fallback)
         self.metrics.last_aggregate_duration_s = sp_close.duration_s
+        # Why a window left the fast path, for the trace: "device" (the
+        # registry planned a fallback window, or the device call failed
+        # or hung) vs "encode" (the device answered; the encoder did not).
+        fallback_reason = "device" if kind == "prof" else None
         if self._feeder is not None and kind == "counts":
             # Streamed windows: the mid-window feed work and the packed
             # close fetch are tracked by the feeder — record them as
@@ -909,9 +942,11 @@ class CPUProfiler:
                 _log.warn("fast encode failed; scalar fallback for this "
                           "window", error=repr(e))
                 kind, out = fallback()
+                fallback_reason = "encode"
         self.metrics.samples_aggregated += snapshot.total_samples()
         if kind == "prof":
-            tr.annotate(path="scalar-fallback")
+            tr.annotate(path="scalar-fallback",
+                        fallback_reason=fallback_reason)
             with tr.span("ship"):
                 for prof in out:
                     self._write_profile(prof)

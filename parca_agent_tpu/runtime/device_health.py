@@ -1,15 +1,28 @@
 """Device-runtime health: bounded bring-up and demote/promote supervision.
 
 ``runtime/quarantine.py`` owns per-pid trust; this module owns the
-ACCELERATOR BACKEND's lifecycle. The failure mode it exists for is the
-one the bench trajectory recorded twice (BENCH_r05: "device probe:
-attempt hung >420s"): a wedged device runtime blocks *inside a C call*
-— backend init, a dispatch, a fetch — that no exception ever leaves and
-no thread can cancel. An always-on profiler must therefore (a) never
+ACCELERATOR BACKEND's lifecycle. The failure mode it exists for: a
+wedged device runtime blocks *inside a C call* — backend init, a
+dispatch, a fetch — that no exception ever leaves and no thread can
+cancel. An always-on profiler must therefore (a) never
 touch the backend from its capture loop without an abandonable guard,
 and (b) never pay an unbounded backend *init*: bring-up probes run in a
 THROWAWAY SUBPROCESS with a hard deadline and a kill, so a wedged init
 costs one dead child, not a hung agent.
+
+One process per chip. An accelerator belongs to one process at a time,
+so the child probe and the agent can never both hold it: the child runs
+only while the agent has NOT initialised a backend (bring-up, and
+re-probes after a bring-up that failed), and nothing in the agent may
+touch JAX while a child is alive. Once the agent has claimed its
+backend (:meth:`DeviceHealthRegistry.claim_backend`) a child could
+never get the chip, and the wedged-init argument no longer applies —
+the backend IS initialised — so re-probes after a demotion run
+IN-PROCESS under the abandonable guard (:func:`inprocess_probe`).
+Every probe reports the platform it ran on, and the registry refuses a
+result from any platform but the agent's own: JAX falls back to XLA:CPU
+quietly when an accelerator fails to initialise, and a probe that
+"passed" there proved nothing about the device.
 
 State machine (all transitions on the profiler's window clock — a
 stalled agent must not silently serve out cooldowns):
@@ -41,6 +54,7 @@ accept the duration-bearing ``hang`` kind (utils/faults.py).
 
 from __future__ import annotations
 
+import os
 import subprocess
 import sys
 import threading
@@ -64,50 +78,94 @@ STATE_DEAD = "dead"
 
 STATES = (STATE_PROBING, STATE_HEALTHY, STATE_DEGRADED, STATE_DEAD)
 
-# One tiny device round trip: backend init + put + jit + fetch — the same
-# aha-moment op bench.py's liveness probe runs. Printing "1" proves the
-# whole path, not just that the import survived.
+# One tiny device round trip: backend init + put + jit + fetch. Printing
+# "1 <platform>" proves the whole path AND names where it ran — JAX
+# lands on XLA:CPU without a word when an accelerator fails to
+# initialise, so a bare "1" would pass vacuously there.
 _PROBE_CODE = (
     "import numpy as np, jax\n"
     "x = jax.device_put(np.zeros(8, np.int32))\n"
-    "print(int(np.asarray(jax.jit(lambda a: a + 1)(x))[0]))\n"
+    "y = int(np.asarray(jax.jit(lambda a: a + 1)(x))[0])\n"
+    "print(y, jax.devices()[0].platform)\n"
 )
 
 
 def subprocess_probe(timeout_s: float, code: str = _PROBE_CODE
-                     ) -> tuple[bool, str]:
+                     ) -> tuple[bool, str, str | None]:
     """One backend bring-up probe in a throwaway subprocess, killed at
     ``timeout_s``. A wedged backend init cannot be cancelled from a
     thread (it hangs inside a C call), but a child process CAN be
-    killed — this is the only hang-proof shape for the probe. Returns
-    (ok, detail)."""
+    killed — this is the only hang-proof shape for a probe while the
+    agent itself has no backend. Only valid then: once the agent holds
+    the chip a child can never get it (:func:`inprocess_probe`).
+    Returns (ok, detail, platform)."""
     try:
         r = subprocess.run([sys.executable, "-c", code],
                            capture_output=True, text=True,
                            timeout=timeout_s)
     except subprocess.TimeoutExpired:
-        return False, f"probe hung >{timeout_s:.0f}s (child killed)"
+        return False, f"probe hung >{timeout_s:.0f}s (child killed)", None
     except OSError as e:  # pragma: no cover - spawn failure is exotic
-        return False, f"probe spawn failed: {e!r}"
+        return False, f"probe spawn failed: {e!r}", None
     if r.returncode != 0:
         tail = (r.stderr or "").strip().splitlines()
         last = tail[-1][-200:] if tail else "no output"
-        return False, f"probe rc={r.returncode}: {last}"
-    if (r.stdout or "").strip().splitlines()[-1:] != ["1"]:
-        return False, f"probe wrong output: {(r.stdout or '')[:80]!r}"
-    return True, "ok"
+        return False, f"probe rc={r.returncode}: {last}", None
+    words = ((r.stdout or "").strip().splitlines() or [""])[-1].split()
+    if len(words) != 2 or words[0] != "1":
+        return False, f"probe wrong output: {(r.stdout or '')[:80]!r}", None
+    return True, f"ok on {words[1]}", words[1]
+
+
+def inprocess_probe(timeout_s: float) -> tuple[bool, str, str | None]:
+    """The same round trip on the backend THIS process already holds,
+    under the abandonable guard (utils/bounded.py). The re-probe shape
+    after a demotion: the agent owns the chip for good, so a child
+    would fail forever (or, on a quiet XLA:CPU landing, pass
+    vacuously); a hang here costs one abandoned daemon thread.
+    Returns (ok, detail, platform)."""
+    from parca_agent_tpu.utils.bounded import bounded_call
+
+    def round_trip():
+        import jax
+        import numpy as np
+
+        x = jax.device_put(np.zeros(8, np.int32))
+        y = int(np.asarray(jax.jit(lambda a: a + 1)(x))[0])
+        return y, str(jax.devices()[0].platform)
+
+    status, out, _done, _box = bounded_call(
+        round_trip, timeout_s, thread_name="device-probe-inprocess")
+    if status == "hang":
+        return False, f"in-process probe hung >{timeout_s:.0f}s", None
+    if status == "err":
+        return False, f"in-process probe failed: {out!r}"[:200], None
+    y, platform = out
+    if y != 1:
+        return False, f"in-process probe wrong output: {y!r}", platform
+    return True, f"ok on {platform}", platform
 
 
 class DeviceHealthRegistry:
     """The device-backend trust state machine (module docs above).
 
-    ``probe`` is a zero-arg callable returning ``(ok, detail)`` — the
-    CLI passes :func:`subprocess_probe`; ``None`` disables the probe
-    phase entirely (cooldown expiry goes straight to the shadow window,
-    the pre-registry retry semantics the profiler's embedded default
-    keeps). Probes run on a daemon thread so the window loop never waits
-    on one; a probe that outlives ``probe_deadline_s`` is counted as a
-    hang and its eventual (stale) result ignored.
+    ``probe`` is a zero-arg callable returning ``(ok, detail)`` or
+    ``(ok, detail, platform)`` — the CLI passes :func:`subprocess_probe`
+    until the agent has claimed its backend and :func:`inprocess_probe`
+    after; ``None`` disables the probe phase entirely (cooldown expiry
+    goes straight to the shadow window, the pre-registry retry semantics
+    the profiler's embedded default keeps). Probes run on a daemon
+    thread so the window loop never waits on one; a probe that outlives
+    ``probe_deadline_s`` is counted as a hang and its eventual (stale)
+    result ignored. A probe that names a platform other than the
+    agent's own (:attr:`platform`, once claimed) is refused.
+
+    ``claim`` is a zero-arg callable returning the backend-identity
+    record (``runtime/device_telemetry.collect_identity``): calling it
+    is what initialises JAX in this process. :meth:`claim_backend` runs
+    it exactly once, deliberately — after the bring-up probe child has
+    exited, or inside the first guarded device call when bring-up
+    failed and a later re-probe passed — never on the HTTP thread.
 
     All mutation is lock-protected: the profiler thread reports faults
     and ticks windows, probe threads deliver results, the HTTP thread
@@ -115,6 +173,7 @@ class DeviceHealthRegistry:
     """
 
     def __init__(self, probe=None, probe_timeout_s: float = 60.0,
+                 claim=None,
                  probe_deadline_s: float | None = None,
                  promote_after: int = 2,
                  cooldown_windows: int = 3,
@@ -125,6 +184,7 @@ class DeviceHealthRegistry:
                  clock=time.monotonic,
                  window_s: float = REFERENCE_WINDOW_S):
         self._probe = probe
+        self._claim = claim
         self._probe_timeout = probe_timeout_s
         # Grace over the probe's own (subprocess) timeout: the in-process
         # deadline only exists for probes wedged BEFORE their own bound
@@ -165,6 +225,13 @@ class DeviceHealthRegistry:
         self.last_demote_window: int | None = None
         self.last_promote_window: int | None = None
         self.last_error: str = ""
+        # The platform the AGENT's backend runs on (None until
+        # claim_backend lands) and the one the last passing probe
+        # reported. They must agree: see _on_probe_result.
+        self.platform: str | None = None         # guarded-by: _lock
+        self.probe_platform: str | None = None   # guarded-by: _lock
+        self._bringup_done = threading.Event()
+        self._claim_mu = threading.Lock()
         self._consec_failures = 0                    # guarded-by: _lock
         self._probe_gen = 0                          # guarded-by: _lock
         self._probe_started_at: float | None = None  # guarded-by: _lock
@@ -173,7 +240,9 @@ class DeviceHealthRegistry:
             "probes_ok": 0,
             "probes_failed": 0,   # == probes_total - probes_ok (invariant)
             "probes_hung": 0,     # the probes_failed that were deadline
-            #                       overruns (BENCH_r05's failure mode)
+            #                       overruns (a wedged backend init)
+            "probes_refused": 0,  # the probes_failed that passed on a
+            #                       platform other than the agent's own
             "hangs_total": 0,
             "dispatch_errors_total": 0,
             "demotions_total": 0,
@@ -193,11 +262,60 @@ class DeviceHealthRegistry:
         a wedged init costs a killed child, never a hung agent."""
         with self._lock:
             if self.state != STATE_PROBING:
+                self._bringup_done.set()
                 return
             if self._probe is None:
                 self.state = STATE_HEALTHY
+                self._bringup_done.set()
                 return
             self._launch_probe_locked()
+
+    def wait_bringup(self, timeout_s: float) -> bool:
+        """Block until the bring-up probe has resolved (either way) or
+        ``timeout_s`` passes; True when the state left ``probing``. The
+        CLI holds the capture loop behind this — bounded by the probe's
+        own kill deadline — so the first window is never a fallback
+        window merely because it raced a healthy probe, and nothing
+        touches JAX while the probe child is alive."""
+        return self._bringup_done.wait(timeout_s)
+
+    def claim_backend(self) -> dict | None:
+        """Learn — once, deliberately — which backend this process runs
+        on: run the ``claim`` callable (which initialises JAX here and,
+        on an accelerator host, takes the chip), latch the platform, and
+        say where the agent landed. From here on probes run in-process.
+        A landing on ``cpu`` that ``JAX_PLATFORMS`` did not ask for is
+        an ERROR line: JAX itself only logs it at INFO and carries on.
+        No-op without a ``claim`` callable or once claimed; raises what
+        the callable raises (callers run it under their own guard)."""
+        with self._claim_mu:  # one claim at a time; _lock stays free
+            with self._lock:
+                if self._claim is None or self.platform is not None:
+                    return None
+                probed = self.probe_platform
+            ident = self._claim()
+            platform = str(ident.get("platform"))
+            with self._lock:
+                self.platform = platform
+        _log.info("device backend claimed", platform=platform,
+                  device_kind=ident.get("device_kind"),
+                  device_count=ident.get("device_count"))
+        wanted = os.environ.get("JAX_PLATFORMS", "")
+        if platform == "cpu" and "cpu" not in wanted.split(","):
+            _log.error("a device aggregator was chosen but JAX landed on "
+                       "the CPU backend: no accelerator was found, or it "
+                       "failed to initialise or is held by another "
+                       "process (set JAX_PLATFORMS to make that fatal)",
+                       jax_platforms=wanted or "(unset)")
+        if probed is not None and probed != platform:
+            with self._lock:
+                self.last_error = (f"agent landed on {platform}, its "
+                                   f"bring-up probe ran on {probed}")
+                _log.error("device backend is not the one the bring-up "
+                           "probe proved out", error=self.last_error)
+                if self.state == STATE_HEALTHY:
+                    self._demote_locked("platform mismatch")
+        return ident
 
     # -- profiler-facing decisions -------------------------------------------
 
@@ -241,6 +359,16 @@ class DeviceHealthRegistry:
             self.last_error = "device call hung (abandoned)"
             self.shadow_pending = False  # a shadow that hung failed too
             self._demote_locked("dispatch hang")
+
+    def record_claim_failure(self, detail: str) -> None:
+        """The in-process backend init behind :meth:`claim_backend`
+        raised or hung: no device to run on. Demotes (windows ship from
+        the CPU fallback) with the usual capped-backoff re-probes."""
+        with self._lock:
+            self.last_error = f"backend claim failed: {detail}"[:200]
+            _log.error("device backend claim failed",
+                       error=self.last_error)
+            self._demote_locked("claim failure")
 
     def record_fallback_window(self) -> None:
         with self._lock:
@@ -314,12 +442,14 @@ class DeviceHealthRegistry:
                          name="device-probe", daemon=True).start()
 
     def _run_probe(self, gen: int) -> None:
+        platform = None
         try:
             faults.inject("device.probe")
-            ok, detail = self._probe()
+            ok, detail, *rest = self._probe()
+            platform = rest[0] if rest else None
         except BaseException as e:  # noqa: BLE001 - a broken probe = failed
             ok, detail = False, repr(e)[:200]
-        self._on_probe_result(gen, bool(ok), str(detail))
+        self._on_probe_result(gen, bool(ok), str(detail), platform)
 
     def _check_probe_deadline_locked(self) -> None:  # palint: holds=_lock
         """A probe that outlived its deadline is a HANG: count it failed
@@ -337,18 +467,31 @@ class DeviceHealthRegistry:
         self._note_probe_failed_locked(
             f"probe overran its deadline ({self._probe_deadline:.0f}s)")
 
-    def _on_probe_result(self, gen: int, ok: bool, detail: str) -> None:
+    def _on_probe_result(self, gen: int, ok: bool, detail: str,
+                         platform: str | None = None) -> None:
         with self._lock:
             if gen != self._probe_gen or self.state == STATE_DEAD:
                 return  # stale (deadline already charged it) or moot
             self._probe_started_at = None
+            if ok and platform is not None and self.platform is not None \
+                    and platform != self.platform:
+                # A pass on somebody else's platform proves nothing
+                # about ours (the quiet XLA:CPU landing of a child that
+                # could not get the chip): refused, counted, failed.
+                self.stats["probes_refused"] += 1
+                ok = False
+                detail = (f"probe ran on {platform}, the agent owns "
+                          f"{self.platform}: refused")
             if ok:
+                if platform is not None:
+                    self.probe_platform = platform
                 self.stats["probes_ok"] += 1
                 self.consecutive_ok_probes += 1
                 if self.state == STATE_PROBING:
                     # Bring-up: the backend proved out; no shadow needed,
                     # there is nothing demoted to distrust yet.
                     self.state = STATE_HEALTHY
+                    self._bringup_done.set()
                     _log.info("device backend probe ok; starting on the "
                               "device", detail=detail)
                 elif self.state == STATE_DEGRADED \
@@ -366,6 +509,7 @@ class DeviceHealthRegistry:
         _log.warn("device probe failed", error=self.last_error,
                   trips=self.trips)
         self._demote_locked("probe failure")
+        self._bringup_done.set()
 
     # -- transitions ---------------------------------------------------------
 
@@ -416,5 +560,7 @@ class DeviceHealthRegistry:
                 "last_demote_window": self.last_demote_window,
                 "last_promote_window": self.last_promote_window,
                 "last_error": self.last_error,
+                "platform": self.platform,
+                "probe_platform": self.probe_platform,
                 "stats": dict(self.stats),
             }

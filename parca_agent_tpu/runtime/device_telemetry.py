@@ -22,10 +22,14 @@ registry that every kernel dispatch site reports into —
     be an incident, not a vibe;
   * H2D/D2H transfer-byte accounting per kernel, derived from the
     packed buffer sizes the sites already compute — no extra syncs;
-  * a latched backend-identity record (platform, device_kind, jax /
-    jaxlib versions, per-kernel pallas/lax resolution and interpret
-    flag) exported once as info-style gauges so a node that silently
-    fell back to lax is visible from /metrics, not just logs;
+  * a latched backend-identity record (platform, device_kind, device
+    count, jax / jaxlib / libtpu versions) that the device's OWNER
+    learns once and hands in (:func:`collect_identity`,
+    :meth:`DeviceTelemetry.set_identity`) — reading it never
+    initialises a backend — plus per-kernel pallas/lax resolution and
+    interpret flags, exported as info-style gauges so a node that
+    landed on the wrong platform or silently fell back to lax is
+    visible from /metrics, not just logs;
   * a window-SLO layer rolling capture-thread busy time plus off-thread
     kernel seconds into a per-window budget-used ratio and a
     windows-over-budget burn counter keyed to the configured period —
@@ -70,49 +74,53 @@ _log = get_logger("device_telemetry")
 EVENTS = ("compile", "execute")
 
 
-def _collect_identity() -> dict:
-    """The latched backend-identity record: platform, device kind,
-    versions, pallas availability. Any probe failure degrades a field
-    to its unknown default — identity must never cost startup."""
+def _dist_version(name: str) -> str:
+    """Installed distribution version without importing the package."""
+    from importlib import metadata
+
+    try:
+        return metadata.version(name)
+    except metadata.PackageNotFoundError:
+        return "none"
+
+
+def backend_initialized() -> bool:
+    """Whether THIS process already holds an initialised JAX backend.
+    Reads only: jax is never imported here and no backend is created —
+    a process that has not touched JAX answers False."""
+    import sys
+
+    xb = sys.modules.get("jax._src.xla_bridge")
+    return bool(xb is not None and xb.backends_are_initialized())
+
+
+def collect_identity() -> dict:
+    """The backend-identity record: platform, device kind and count as
+    JAX reports them, plus the installed versions. DELIBERATE: calling
+    this initialises the JAX backend if nothing has yet (and on a TPU
+    host takes the chip), so it belongs to whoever owns the device —
+    the device-health claim after bring-up, a bench child — never to a
+    scrape. A backend that fails to initialise raises: an identity of
+    "unknown" would hide exactly the landing this record exists to
+    show."""
     import socket
 
-    ident = {
-        "platform": "unknown",
-        "device_kind": "unknown",
-        "device_count": 0,
-        "jax_version": "unknown",
-        "jaxlib_version": "unknown",
-        "pallas_available": False,
-        "interpret_default": True,
+    import jax
+
+    devs = jax.devices()
+    platform = str(devs[0].platform)
+    return {
+        "platform": platform,
+        "device_kind": str(devs[0].device_kind),
+        "device_count": len(devs),
+        "jax_version": str(jax.__version__),
+        "jaxlib_version": _dist_version("jaxlib"),
+        "libtpu_version": _dist_version("libtpu"),
+        # Pallas kernels compile through Mosaic on a TPU and run in the
+        # interpreter everywhere else (aggregator/pallas_probe.py).
+        "interpret_default": platform != "tpu",
         "hostname": socket.gethostname(),
     }
-    try:
-        import jax
-
-        ident["jax_version"] = str(getattr(jax, "__version__", "unknown"))
-        ident["platform"] = str(jax.default_backend())
-        devs = jax.devices()
-        ident["device_count"] = len(devs)
-        if devs:
-            ident["device_kind"] = str(
-                getattr(devs[0], "device_kind", "unknown"))
-    except Exception:  # noqa: BLE001 - identity is best-effort
-        pass
-    try:
-        import jaxlib
-
-        ident["jaxlib_version"] = str(
-            getattr(jaxlib, "__version__", "unknown"))
-    except Exception:  # noqa: BLE001 - identity is best-effort
-        pass
-    try:
-        from parca_agent_tpu.aggregator import pallas_probe
-
-        ident["pallas_available"] = bool(pallas_probe.pallas_available())
-        ident["interpret_default"] = bool(pallas_probe.default_interpret())
-    except Exception:  # noqa: BLE001 - identity is best-effort
-        pass
-    return ident
 
 
 class DeviceTelemetry:
@@ -149,6 +157,17 @@ class DeviceTelemetry:
             "windows_total": 0,
             "windows_over_budget_total": 0,
             "budget_used_last": 0.0,
+        }
+        # What XLA itself reports (watch_xla_compiles): the host-clock
+        # "compile" events above time a kernel's first call per shape,
+        # which for miss_settle is mostly the host-side insert; these
+        # are the compiler's own seconds and the persistent cache's
+        # hit/miss counts (runtime/compile_cache.py).
+        self.xla = {  # guarded-by: _lock
+            "compile_requests_total": 0,
+            "cache_hits_total": 0,
+            "cache_misses_total": 0,
+            "backend_compile_seconds_total": 0.0,
         }
 
     # -- write side (dispatch sites; capture path) ---------------------------
@@ -285,19 +304,44 @@ class DeviceTelemetry:
             self._record_error(e)
 
     # palint: fail-open
+    def note_xla(self, key: str, amount: float = 1) -> None:
+        """One XLA compile/cache event (watch_xla_compiles). Fail-open."""
+        try:
+            with self._lock:
+                self.xla[key] += amount
+        except Exception as e:  # noqa: BLE001 - telemetry is fail-open
+            self._record_error(e)
+
+    # palint: fail-open
+    def set_identity(self, ident: dict) -> None:
+        """Latch the backend-identity record the device owner learned
+        (:func:`collect_identity`). First write wins. Fail-open."""
+        try:
+            with self._lock:
+                if self._identity is None:
+                    self._identity = dict(ident)
+        except Exception as e:  # noqa: BLE001 - telemetry is fail-open
+            self._record_error(e)
+
+    # palint: fail-open
     def ensure_identity(self) -> dict:
-        """Latch (once) and return the backend-identity record. Safe off
-        the capture path only — the first call may initialize the jax
-        backend. Fail-open: an empty dict on error."""
+        """The latched backend-identity record, or ``{}`` while nobody
+        has learned it. PASSIVE — safe on the HTTP thread: it never
+        initialises a backend (a scrape landing while a bring-up probe
+        child holds the chip must not pin this process to the CPU for
+        life) and never latches a placeholder. A process that already
+        runs on an initialised backend (bench children, tests, library
+        embedders) latches from it on first read, since reading an
+        existing backend creates nothing. Fail-open: ``{}`` on error."""
         try:
             with self._lock:
                 if self._identity is not None:
                     return dict(self._identity)
-            ident = _collect_identity()
+            if not backend_initialized():
+                return {}
+            self.set_identity(collect_identity())
             with self._lock:
-                if self._identity is None:
-                    self._identity = ident
-                return dict(self._identity)
+                return dict(self._identity or {})
         except Exception as e:  # noqa: BLE001 - telemetry is fail-open
             self._record_error(e)
             return {}
@@ -412,12 +456,14 @@ class DeviceTelemetry:
                 "bytes": nbytes, "ops": ops}
         with self._lock:
             stats = dict(self.stats)
+            xla = dict(self.xla)
         return {
             "identity": ident,
             "kernels": kernels,
             "backends": self.backends(),
             "transfers": transfers,
             "window_budget": self.budget_export(),
+            "xla": xla,
             "stats": stats,
         }
 
@@ -473,3 +519,38 @@ def tick_window(used_s: float) -> None:
     """Window-SLO hook, called once per profiler iteration."""
     if _active is not None:
         _active.tick_window(used_s)
+
+
+_XLA_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache":
+        "compile_requests_total",
+    "/jax/compilation_cache/cache_hits": "cache_hits_total",
+    "/jax/compilation_cache/cache_misses": "cache_misses_total",
+}
+_XLA_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_xla_watched = False
+
+
+def watch_xla_compiles() -> None:
+    """Route JAX's own compile and persistent-cache events into whatever
+    telemetry is installed. Imports jax (no backend is initialised), so
+    it is called only by entry points that are about to use a device —
+    a numpy-only agent never loads jax. Idempotent; the listeners live
+    for the process."""
+    global _xla_watched
+    if _xla_watched:
+        return
+    from jax import monitoring
+
+    def on_event(event: str, **_kw) -> None:
+        key = _XLA_EVENTS.get(event)
+        if key is not None and _active is not None:
+            _active.note_xla(key)
+
+    def on_duration(event: str, duration_s: float, **_kw) -> None:
+        if event == _XLA_COMPILE_EVENT and _active is not None:
+            _active.note_xla("backend_compile_seconds_total", duration_s)
+
+    monitoring.register_event_listener(on_event)
+    monitoring.register_event_duration_secs_listener(on_duration)
+    _xla_watched = True

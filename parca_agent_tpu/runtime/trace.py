@@ -2,10 +2,10 @@
 histograms, and slow-window auto-capture.
 
 The agent is a profiler that could not explain its own tail latency:
-`/metrics` exposed only last-value gauges, so the 140 ms median close
-headline hid the distribution, and a stalled window (the two >420 s
-device hangs on record, the 930-2230 ms statics rebuilds) had to be
-reconstructed from logs after the fact. This module is the always-on
+`/metrics` exposed only last-value gauges, so a median close headline
+hid the distribution, and a stalled window (a device runtime wedged for
+minutes, a multi-second statics rebuild) had to be reconstructed from
+logs after the fact. This module is the always-on
 instrumentation substrate (docs/observability.md):
 
   * ``WindowTrace`` — one trace per window, trace id = window seq,
@@ -57,7 +57,7 @@ _log = get_logger("trace")
 
 # Log-spaced bucket upper bounds in seconds: 10 us doubling to ~671 s.
 # 27 finite buckets + the implicit +Inf bucket cover everything from a
-# sub-ms host-side stage to the >420 s device hangs on record.
+# sub-ms host-side stage to a device runtime wedged for minutes.
 BUCKET_BOUNDS = tuple(1e-5 * (2.0 ** i) for i in range(27))
 
 # The spans every complete fast-path (dict aggregator + fast encode)

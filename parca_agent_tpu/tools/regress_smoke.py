@@ -38,11 +38,6 @@ import numpy as np
 
 
 def main() -> int:
-    if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-
     from parca_agent_tpu.aggregator.cpu import CPUAggregator
     from parca_agent_tpu.aggregator.dict import DictAggregator
     from parca_agent_tpu.capture.synthetic import SyntheticSpec, generate

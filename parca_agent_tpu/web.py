@@ -509,6 +509,9 @@ def render_metrics(profilers, batch_client=None, extra: dict | None = None,
              budget["windows_over_budget_total"])
         emit("parca_agent_window_budget_used_last_ratio",
              round(budget["budget_used_last"], 6))
+        for k, v in dict(device_telemetry.xla).items():
+            emit(f"parca_agent_xla_{k}",
+                 round(v, 6) if isinstance(v, float) else v)
         for k, v in dict(device_telemetry.stats).items():
             name = f"parca_agent_device_telemetry_{k}"
             emit(name if name.endswith("_total") else name + "_total", v)

@@ -379,3 +379,22 @@ def test_dict_empty_window():
     empty = generate(SyntheticSpec(n_pids=2, n_unique_stacks=4, n_rows=0,
                                    total_samples=10, seed=1))
     assert d.aggregate(empty) == []
+
+
+def test_prefix_sum_equals_cumsum_at_every_branch():
+    """The blocked two-level prefix sum (which exists for XLA:TPU's
+    compile time, aggregator/dict.py) must give cumsum's integers at
+    the plain branch, the blocked branch, the recursive branch and
+    lengths that do not divide into blocks."""
+    import jax
+    import jax.numpy as jnp
+
+    from parca_agent_tpu.aggregator.dict import _SCAN_BLOCK, prefix_sum
+
+    rng = np.random.default_rng(5)
+    for n in (16, 4 * _SCAN_BLOCK, 8 * _SCAN_BLOCK, 5 * (1 << 18),
+              8 * _SCAN_BLOCK * _SCAN_BLOCK, 8 * _SCAN_BLOCK + 7):
+        x = rng.integers(0, 3, n).astype(np.int32)
+        got = np.asarray(jax.jit(prefix_sum)(jnp.asarray(x)))
+        assert got.dtype == np.int32
+        assert np.array_equal(got, np.cumsum(x, dtype=np.int32)), n

@@ -188,6 +188,8 @@ def test_dict_probe_backend_falls_back_when_pallas_unavailable(
         monkeypatch, device_telemetry):
     from parca_agent_tpu.aggregator import pallas_probe
 
+    from parca_agent_tpu.web import render_metrics
+
     monkeypatch.setattr(pallas_probe, "pallas_available", lambda: False)
     snap = _snap(seed=13, rows=128, pids=4)
     for backend in ("pallas", "auto"):
@@ -197,7 +199,49 @@ def test_dict_probe_backend_falls_back_when_pallas_unavailable(
         c = a.close_window()
         assert a._probe_resolved == "lax"
         assert int(c.sum()) == snap.total_samples()
-    _assert_fallback_gauge(device_telemetry, "feed_probe")
+        if backend == "pallas":
+            # Asked for by name and not delivered: the fallback gauge.
+            _assert_fallback_gauge(device_telemetry, "feed_probe")
+    # "auto" resolving to lax is auto doing its job, not a fallback.
+    assert 'parca_agent_kernel_fallback{kernel="feed_probe"} 0' \
+        in render_metrics([], device_telemetry=device_telemetry)
+
+
+def test_auto_never_selects_a_kernel_mosaic_refused(
+        monkeypatch, device_telemetry):
+    """On a TPU (default_interpret() False) Mosaic refuses both Pallas
+    kernels at lowering — the chip's verdict, recorded in the
+    pallas_probe module docs — so "auto" must resolve to the lax
+    programs there, up front, with no failed compile and no fallback
+    flag: neither probe_backend="auto" nor --aggregator tpu's default
+    dedup="auto" may select them."""
+    from parca_agent_tpu.aggregator import pallas_probe
+    from parca_agent_tpu.aggregator.tpu import TPUAggregator
+    from parca_agent_tpu.web import render_metrics
+
+    def _must_not_build(*a, **kw):
+        raise AssertionError("auto built a Pallas kernel on a TPU")
+
+    monkeypatch.setattr(pallas_probe, "default_interpret", lambda: False)
+    monkeypatch.setattr(pallas_probe, "make_batch_probe", _must_not_build)
+    monkeypatch.setattr(pallas_probe, "make_loc_table_builder",
+                        _must_not_build)
+    assert pallas_probe.auto_uses_pallas() is False
+    snap = _snap(seed=19, rows=128, pids=4)
+    a = DictAggregator(capacity=1 << 10, overflow="raise",
+                       probe_backend="auto")
+    a.feed(snap, a.hash_rows(snap))
+    assert int(a.close_window().sum()) == snap.total_samples()
+    assert a._probe_resolved == "lax"
+    t = TPUAggregator()
+    assert t.dedup == "auto" and t._use_hash() is False
+    assert not t._hash_disabled  # selected, not latched off
+    metrics = render_metrics([], device_telemetry=device_telemetry)
+    for kernel in ("feed_probe", "loc_dedup"):
+        assert f'parca_agent_kernel_fallback{{kernel="{kernel}"}} 0' \
+            in metrics
+        assert f'parca_agent_kernel_backend{{kernel="{kernel}",' \
+            f'backend="lax"}} 1' in metrics
 
 
 def test_dict_probe_runtime_failure_latches_lax(

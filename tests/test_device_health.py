@@ -82,21 +82,99 @@ def test_hang_fault_sleeps_at_the_site():
 
 def test_subprocess_probe_kills_a_hung_probe_within_deadline():
     t0 = time.monotonic()
-    ok, detail = subprocess_probe(0.5, code="import time; time.sleep(60)")
-    assert not ok and "hung" in detail
+    ok, detail, platform = subprocess_probe(
+        0.5, code="import time; time.sleep(60)")
+    assert not ok and "hung" in detail and platform is None
     assert time.monotonic() - t0 < 10  # the child was KILLED, not joined
 
 
 def test_subprocess_probe_reports_a_crashing_probe():
-    ok, detail = subprocess_probe(30, code="raise SystemExit(3)")
+    ok, detail, _ = subprocess_probe(30, code="raise SystemExit(3)")
     assert not ok and "rc=3" in detail
+
+
+def test_subprocess_probe_names_the_platform_it_ran_on():
+    """A bare "1" is no longer a pass: JAX lands on XLA:CPU quietly when
+    an accelerator fails to initialise, so the probe must say where it
+    ran (the real probe code is exercised by the slow test below)."""
+    ok, detail, platform = subprocess_probe(30, code="print(1, 'tpu')")
+    assert ok and platform == "tpu" and "tpu" in detail
+    ok, detail, platform = subprocess_probe(30, code="print(1)")
+    assert not ok and "wrong output" in detail and platform is None
 
 
 @pytest.mark.slow
 def test_subprocess_probe_real_backend_roundtrip():
     # The real probe code: backend init + put + jit + fetch in a child.
-    ok, detail = subprocess_probe(120)
+    ok, detail, platform = subprocess_probe(120)
     assert ok, detail
+    assert platform == "cpu"  # conftest pins the children's platform too
+
+
+def test_inprocess_probe_round_trips_on_the_held_backend():
+    from parca_agent_tpu.runtime.device_health import inprocess_probe
+
+    ok, detail, platform = inprocess_probe(60)
+    assert ok, detail
+    assert platform == "cpu"
+
+
+# -- one owner per chip: claim, foreign platforms, probe shape ----------------
+
+
+def test_registry_refuses_a_probe_from_a_foreign_platform():
+    """The agent owns a TPU; a re-probe that 'passed' on cpu (a child
+    that could not get the chip and fell back quietly) proves nothing:
+    refused, counted failed, no progress toward promotion."""
+    results = iter([(True, "ok on cpu", "cpu"), (True, "ok on tpu", "tpu")])
+    reg = DeviceHealthRegistry(
+        probe=lambda: next(results), probe_timeout_s=5, promote_after=1,
+        cooldown_windows=1, start_state=STATE_HEALTHY,
+        claim=lambda: {"platform": "tpu", "device_kind": "TPU v5 lite",
+                       "device_count": 1})
+    assert reg.claim_backend()["platform"] == "tpu"
+    assert reg.platform == "tpu"
+    assert reg.claim_backend() is None  # once
+    reg.record_hang()
+    reg.tick_window()  # cooldown over -> probe 1 (foreign)
+    assert _wait(lambda: reg.stats["probes_refused"] == 1)
+    assert reg.stats["probes_failed"] == 1 and reg.stats["probes_ok"] == 0
+    assert "refused" in reg.last_error and not reg.shadow_pending
+    for _ in range(6):  # doubled cooldown, then probe 2 (ours)
+        reg.tick_window()
+        _wait(lambda: not reg.snapshot()["probe_in_flight"])
+        if reg.shadow_pending:
+            break
+    assert reg.shadow_pending and reg.stats["probes_ok"] == 1
+    assert reg.snapshot()["platform"] == "tpu"
+
+
+def test_claim_on_a_platform_the_bringup_probe_did_not_prove_demotes():
+    """Bring-up probe child ran on tpu; the agent's own init then landed
+    on cpu (the chip was lost in between): said loudly, and demoted."""
+    reg = DeviceHealthRegistry(
+        probe=lambda: (True, "ok on tpu", "tpu"), probe_timeout_s=5,
+        claim=lambda: {"platform": "cpu", "device_kind": "cpu",
+                       "device_count": 1})
+    reg.start()
+    assert reg.wait_bringup(10) and reg.state == STATE_HEALTHY
+    assert reg.snapshot()["probe_platform"] == "tpu"
+    reg.claim_backend()
+    assert reg.platform == "cpu"
+    assert reg.state == STATE_DEGRADED
+    assert reg.stats["demotions_total"] == 1
+    assert "bring-up probe ran on tpu" in reg.last_error
+
+
+def test_wait_bringup_resolves_on_failure_too():
+    reg = DeviceHealthRegistry(probe=lambda: (False, "no chip"),
+                               probe_timeout_s=5)
+    assert not reg.wait_bringup(0.01)  # not started: still probing
+    reg.start()
+    assert reg.wait_bringup(10) and reg.state == STATE_DEGRADED
+    quiet = DeviceHealthRegistry(probe=None)
+    quiet.start()
+    assert quiet.wait_bringup(0) and quiet.state == STATE_HEALTHY
 
 
 # -- the registry state machine -----------------------------------------------
@@ -268,6 +346,10 @@ def test_profiler_hang_injection_wedge_cooldown_inflight_gated_retry():
     assert p.run_iteration()            # hang -> abandoned -> fallback
     assert p.last_error is None and len(w.profiles) == 5
     assert p._device_wedged_at is not None
+    # The hang-path window shipped from the CPU like any planned
+    # fallback window, and is counted where those are: "0 fallback
+    # windows" on /metrics has to mean no window left the device.
+    assert p._health.stats["fallback_windows_total"] == 1
     inflight = p._device_inflight
     assert inflight is not None and not inflight.is_set()
     assert len(calls) == 0              # wedged in the injected hang
@@ -275,7 +357,7 @@ def test_profiler_hang_injection_wedge_cooldown_inflight_gated_retry():
     # sleeping in the injected hang) gates the retry: fallback again.
     assert p.run_iteration()
     assert p._health.shadow_pending     # gate armed...
-    assert p._health.stats["fallback_windows_total"] == 1  # ...not taken
+    assert p._health.stats["fallback_windows_total"] == 2  # ...not taken
     assert inflight.wait(10)            # the abandoned call returns (ok)
     assert len(calls) == 1
     assert p.run_iteration()            # shadow window: device + fallback
@@ -308,6 +390,43 @@ def test_abandoned_call_late_failure_is_logged_and_counted():
     assert p.metrics.device_abandoned_ok_total == 0
     assert p.last_error is None
     assert len(w.profiles) == 2 * 5     # both windows shipped regardless
+
+
+def test_error_path_fallback_windows_are_counted():
+    """A device call that RAISES ships its window from the CPU too —
+    one counted fallback window per strike, before any demotion."""
+    class Broken(CPUAggregator):
+        def aggregate(self, snapshot):
+            raise RuntimeError("transfer error")
+
+    w = CollectingWriter()
+    p = CPUProfiler(source=ReplaySource([_snap() for _ in range(2)]),
+                    aggregator=Broken(),
+                    fallback_aggregator=CPUAggregator(), profile_writer=w)
+    assert p.run_iteration() and p.run_iteration()
+    st = p._health.stats
+    assert st["dispatch_errors_total"] == 2 and st["demotions_total"] == 0
+    assert st["fallback_windows_total"] == 2
+    assert len(w.profiles) == 2 * 5
+
+
+def test_first_device_call_gets_the_long_budget_exactly_once():
+    """The process's first guarded call is compile + population insert,
+    not a device wait: it runs under first_device_timeout_s; every
+    later call under the steady-state bound, which is not raised."""
+    faults.install(faults.FaultInjector.from_spec(
+        "device.dispatch:hang:ms=300,count=2", seed=42))
+    w = CollectingWriter()
+    p = CPUProfiler(source=ReplaySource([_snap() for _ in range(2)]),
+                    aggregator=CPUAggregator(),
+                    fallback_aggregator=CPUAggregator(), profile_writer=w,
+                    device_timeout_s=0.05, first_device_timeout_s=30.0)
+    assert p.run_iteration()            # 300 ms inside the 30 s budget
+    assert p._health.stats["hangs_total"] == 0
+    assert p._health.state == STATE_HEALTHY
+    assert p.run_iteration()            # 300 ms against 50 ms: a hang
+    assert p._health.stats["hangs_total"] == 1
+    assert p._device_inflight.wait(10)
 
 
 def test_device_failure_strikes_demote_then_shadow_recovers():
@@ -638,14 +757,12 @@ def test_bench_device_outage_phase_scores_zero_loss():
     bench = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(bench)
     r = bench._device_outage()
-    bench._finalize_result(r, device_alive=True,
-                           require_full_scale=False, require_device=False)
+    bench._finalize_result(r, require_full_scale=False, require_device=False)
     assert r["windows_lost"] == 0
     assert r["promoted"]
     assert r["scored"] is True
     # The satellite's uniformity contract: a violated acceptance bar
     # reads scored: false through the same stamp, no bespoke strings.
     bad = {"error": "windows_lost=3"}
-    bench._finalize_result(bad, device_alive=True,
-                           require_full_scale=False, require_device=False)
+    bench._finalize_result(bad, require_full_scale=False, require_device=False)
     assert bad["scored"] is False

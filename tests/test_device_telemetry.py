@@ -163,15 +163,46 @@ def test_note_backend_fields_are_sticky():
 
 
 def test_identity_latches_once_and_names_the_backend():
+    from parca_agent_tpu.runtime.device_telemetry import collect_identity
+
     t = DeviceTelemetry()
+    t.set_identity(collect_identity())  # the device owner's deliberate act
     a = t.ensure_identity()
     assert a["platform"] == "cpu"
+    assert a["device_kind"] == "cpu"
     assert a["jax_version"] != "unknown"
-    assert a["jaxlib_version"] != "unknown"
+    assert a["jaxlib_version"] not in ("unknown", "none")
     assert a["device_count"] >= 1
+    assert a["interpret_default"] is True
     assert a["hostname"]
+    t.set_identity({"platform": "other"})  # first write wins
     assert t.ensure_identity() == a
     assert t.snapshot()["identity"] == a
+
+
+def test_xla_compile_events_are_routed_to_the_installed_telemetry():
+    """watch_xla_compiles: the compiler's own seconds and the persistent
+    cache's request count reach whatever telemetry is installed."""
+    import jax
+    import jax.numpy as jnp
+
+    from parca_agent_tpu.runtime import device_telemetry as dtel_mod
+
+    t = DeviceTelemetry()
+    dtel_mod.install(t)
+    try:
+        dtel_mod.watch_xla_compiles()
+        dtel_mod.watch_xla_compiles()  # idempotent: one listener pair
+        jax.jit(lambda x: x * 3 + 1)(jnp.arange(37)).block_until_ready()
+        assert t.xla["backend_compile_seconds_total"] > 0
+        assert t.snapshot()["xla"] == t.xla
+        from parca_agent_tpu.web import render_metrics
+
+        m = render_metrics([], device_telemetry=t)
+        assert "parca_agent_xla_backend_compile_seconds_total" in m
+        assert "# TYPE parca_agent_xla_cache_hits_total counter" in m
+    finally:
+        dtel_mod.install(None)
 
 
 # -- window-SLO layer ---------------------------------------------------------

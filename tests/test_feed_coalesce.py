@@ -372,7 +372,6 @@ def test_vec_and_scalar_prefix_reuse_mixed_batches():
     _assert_valid_probe_layout(vec)
 
 
-@pytest.mark.requires_shard_map
 def test_sharded_coalesced_counts_identical_to_raw():
     """The mesh-sharded aggregator inherits the fold through the base
     feed: partitioned dispatch rows shrink to uniques per shard and the

@@ -647,21 +647,22 @@ def test_alerts_sink_requeues_on_failed_append(tmp_path):
     assert sent.metrics()["alerts_pending"] == 0
 
 
-def test_walker_sharded_tables_are_not_shard_map_gated():
-    # Guard against the skip marker over-matching: unwind/table.py's
-    # ShardedTable is pure numpy — its "sharded"-named tests must keep
-    # running even where jax has no shard_map (this very environment),
-    # so test_walker must never appear in either conftest rule set.
-    from tests.conftest import (
-        _SHARD_MAP_MIXED_MODULES,
-        _SHARD_MAP_MODULES,
-    )
+def test_no_test_is_gated_on_the_jax_version():
+    # One installation, and pyproject.toml names it as the floor: the
+    # sharded/fleet sets (and test_walker's numpy-only ShardedTable
+    # cases) run unconditionally — the conftest carries no shard_map
+    # switch any more, because the jax it defended against cannot be
+    # installed under that floor.
+    import jax
 
-    assert "test_walker" not in _SHARD_MAP_MODULES
-    assert "test_walker" not in _SHARD_MAP_MIXED_MODULES
-    # And the rule sets cover exactly the failing-at-seed env set.
-    assert _SHARD_MAP_MODULES == {"test_aggregator_sharded",
-                                  "test_fleet", "test_distributed"}
+    import tests.conftest as conftest
+
+    assert hasattr(jax, "shard_map")
+    assert not [n for n in vars(conftest) if "shard_map" in n.lower()]
+    floor = [ln for ln in open(os.path.join(
+        os.path.dirname(conftest.__file__), "..", "pyproject.toml"))
+        if ln.startswith("tpu = ")][0]
+    assert f"jax>={jax.__version__}" in floor
 
 
 def test_alerts_sink_without_sentinel_is_inert(tmp_path):
