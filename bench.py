@@ -553,48 +553,6 @@ def run(emit=None) -> dict:
         _progress(f"cold restart drill done: {phase}")
         _emit_partial()
 
-    # Tracing-tax drill (docs/observability.md): the window flight
-    # recorder is always-on in production, so its cost rides every close
-    # — this phase proves the tax stays within 2% of the untraced close
-    # and stamps the traced arm's per-stage percentiles so the artifact
-    # records DISTRIBUTIONS, not just medians. Host-side only (numpy
-    # aggregator + discard writer): it can neither hang the attempt nor
-    # disturb the headline.
-    if os.environ.get("PARCA_BENCH_TRACE", "1") != "0" \
-            and _budget_left(0.12, "trace_overhead"):
-        try:
-            phase = _trace_overhead()
-        except Exception as e:  # noqa: BLE001 - report, don't fail the bench
-            phase = {"error": repr(e)[:300]}
-        _finalize_result(phase, require_full_scale=False,
-                         require_device=False)
-        extras["trace_overhead"] = phase
-        if "overhead_pct" in phase:
-            # Headline-adjacent copy (the acceptance bar reads this).
-            extras["trace_overhead_pct"] = phase["overhead_pct"]
-        _progress(f"trace overhead drill done: {phase}")
-        _emit_partial()
-
-    # Device-telemetry-tax drill (docs/observability.md "device flight
-    # recorder"): the device flight recorder is always-on in production,
-    # so its hook traffic rides every close — this phase proves the tax
-    # stays within 1% of the untelemetered close. Host-side only, same
-    # isolation argument as the tracing drill above.
-    if os.environ.get("PARCA_BENCH_TELEMETRY", "1") != "0" \
-            and _budget_left(0.12, "telemetry_overhead"):
-        try:
-            phase = _telemetry_overhead()
-        except Exception as e:  # noqa: BLE001 - report, don't fail the bench
-            phase = {"error": repr(e)[:300]}
-        _finalize_result(phase, require_full_scale=False,
-                         require_device=False)
-        extras["telemetry_overhead"] = phase
-        if "overhead_pct" in phase:
-            # Headline-adjacent copy (the acceptance bar reads this).
-            extras["telemetry_overhead_pct"] = phase["overhead_pct"]
-        _progress(f"telemetry overhead drill done: {phase}")
-        _emit_partial()
-
     # Sub-RTT close drill (docs/perf.md "sub-RTT close"): double-buffer
     # overlap, delta-fetch byte accounting, and the Pallas batch-probe
     # kernel, all gated on pprof byte identity. Reduced-scale and
@@ -936,282 +894,6 @@ def _cold_restart(agg, snap, hashes) -> dict:
         result["error"] = (f"warm first encode {warm_first_ms:.0f}ms "
                            f"regressed past cold {cold_first_ms:.0f}ms")
     return result
-
-
-def _trace_overhead() -> dict:
-    """Tracing-tax drill: the 2% acceptance bar on the window flight
-    recorder's always-on cost (docs/observability.md).
-
-    Two measurements, one gate:
-
-      * An order-balanced A/B of identical reduced-scale windows through
-        the REAL profiler iteration loop (recorder off vs on, ABBA
-        interleaved, paired differences). Reported for honesty — but on
-        a busy shared host the per-window scheduler/allocator jitter is
-        +-0.5 ms, an order of magnitude above the true effect, so the
-        A/B alone cannot gate at 2% without flapping.
-      * The recorder's per-window cost measured DIRECTLY (a tight loop
-        of begin + the mandatory spans + complete, ring/histograms/
-        detector all live). The tracing tax is workload-independent by
-        construction, so this measures the same quantity with ~ns
-        precision. The gate: that cost must be within 2% of the
-        untraced steady-state close — and the A/B numbers must not
-        contradict it beyond noise.
-
-    The traced arm's per-stage percentiles ride out in the result so
-    BENCH_r* artifacts record latency DISTRIBUTIONS from here on."""
-    from parca_agent_tpu.aggregator.cpu import CPUAggregator
-    from parca_agent_tpu.capture.synthetic import SyntheticSpec, generate
-    from parca_agent_tpu.profiler.cpu import CPUProfiler
-    from parca_agent_tpu.runtime.trace import FlightRecorder
-
-    import gc
-
-    n_windows = int(os.environ.get("PARCA_BENCH_TRACE_WINDOWS", 24))
-    warm = 4
-    snaps = [generate(SyntheticSpec(
-        n_pids=32, n_unique_stacks=1024, n_rows=1024,
-        total_samples=4096, mean_depth=12, seed=100 + i))
-        for i in range(6)]
-
-    class Sink:
-        def write(self, labels, blob):
-            pass
-
-    class Src:
-        def __init__(self, n):
-            self._left = n
-
-        def poll(self):
-            if self._left <= 0:
-                return None
-            self._left -= 1
-            return snaps[self._left % len(snaps)]
-
-    def make(recorder):
-        return CPUProfiler(
-            source=Src(n_windows), aggregator=CPUAggregator(),
-            profile_writer=Sink(), duration_s=0.0,
-            trace_recorder=recorder)
-
-    rec = FlightRecorder(ring=n_windows)
-    arms = (make(None), make(rec))
-    offs, ons = [], []
-    # Paired measurement: each step runs both arms back to back in
-    # ABBA-alternating order (cancels ordering bias), with a collect at
-    # each boundary so CPython GC pauses land OUTSIDE the measured
-    # region for both arms equally. The estimator is the median of the
-    # PAIRED differences — shared host noise (scheduler, allocator,
-    # cache state) cancels pair-by-pair, which a difference of two
-    # independent medians cannot do at a sub-0.1% true effect.
-    gc_was = gc.isenabled()
-    gc.disable()
-    try:
-        for i in range(n_windows):
-            t = [0.0, 0.0]
-            for k in ((0, 1), (1, 0))[i % 2]:
-                gc.collect()
-                t0 = time.perf_counter()
-                if not arms[k].run_iteration():
-                    raise RuntimeError("trace_overhead source exhausted "
-                                       "early")  # never inside assert:
-                # python -O would strip the iteration itself
-                t[k] = time.perf_counter() - t0
-            offs.append(t[0])
-            ons.append(t[1])
-    finally:
-        if gc_was:
-            gc.enable()
-    off_ms = _median_ms(offs[warm:])
-    on_ms = _median_ms(ons[warm:])
-    # Order-balanced paired differences: consecutive iterations ran the
-    # arms in opposite order (ABBA), so averaging each adjacent pair of
-    # differences cancels the run-second-is-warmer bias that otherwise
-    # swamps a sub-0.1% true effect; the median over those balanced
-    # samples is the overhead estimate.
-    diffs = [a - b for a, b in zip(ons, offs)]
-    balanced = [(diffs[k] + diffs[k + 1]) / 2
-                for k in range(warm, n_windows - 1, 2)]
-    ab_diff_ms = _median_ms(balanced)
-
-    # Direct per-window recorder cost: one trace with the mandatory
-    # spans + meta through the live ring/histogram/detector machinery.
-    reps = 2000
-    mic = FlightRecorder(ring=256)
-    t0 = time.perf_counter()
-    for _ in range(reps):
-        tr = mic.begin()
-        tr.add_span("drain", 1e-4)
-        tr.add_span("close", 1e-2)
-        tr.add_span("prepare", 1e-3)
-        tr.add_span("encode", 5e-3)
-        tr.add_span("ship", 2e-3)
-        tr.annotate(samples=4096, path="pipeline")
-        tr.complete()
-    per_window_ms = (time.perf_counter() - t0) / reps * 1e3
-
-    overhead_pct = per_window_ms / off_ms * 100.0
-    # The A/B must not contradict the direct measure beyond host noise:
-    # a paired estimate several times the budget means the recorder is
-    # costing real close latency the microbench cannot see.
-    ab_slack_ms = max(3 * 0.02 * off_ms, 1.0)
-    phase = {
-        "close_untraced_ms": round(off_ms, 3),
-        "close_traced_ms": round(on_ms, 3),
-        "ab_paired_diff_ms": round(ab_diff_ms, 4),
-        "trace_cost_per_window_ms": round(per_window_ms, 4),
-        "overhead_pct": round(overhead_pct, 3),
-        "budget_pct": 2.0,
-        "windows": n_windows,
-        "traces_completed": rec.stats["traces_completed"],
-        "stage_percentiles_ms": rec.percentiles(),
-    }
-    if rec.stats["traces_completed"] != n_windows:
-        phase["error"] = (f"recorder completed "
-                          f"{rec.stats['traces_completed']} of "
-                          f"{n_windows} windows")
-    elif per_window_ms > 0.02 * off_ms:
-        phase["error"] = (f"tracing costs {per_window_ms:.4f} ms/window "
-                          f"({overhead_pct:.2f}%), over the 2% budget on "
-                          f"a {off_ms:.3f} ms close")
-    elif ab_diff_ms > ab_slack_ms:
-        phase["error"] = (f"A/B paired difference {ab_diff_ms:.3f} ms "
-                          f"contradicts the microbench beyond noise "
-                          f"(bar {ab_slack_ms:.3f} ms)")
-    return phase
-
-
-def _telemetry_overhead() -> dict:
-    """Device-telemetry-tax drill: the 1% acceptance bar on the device
-    flight recorder's always-on cost (docs/observability.md "device
-    flight recorder"). Same two-measurement shape as _trace_overhead —
-    the A/B through the real iteration loop is reported for honesty,
-    the workload-independent direct microbench gates:
-
-      * An order-balanced ABBA A/B of identical reduced-scale windows
-        through the REAL profiler iteration loop, telemetry uninstalled
-        vs installed (the window-SLO tick plus whatever kernel sites the
-        host aggregator exercises), paired differences.
-      * The telemetry's per-window cost measured DIRECTLY: one window's
-        worth of hook traffic — the dispatch-site record() calls with
-        shape latches and transfer bytes, a transfer(), and the
-        tick_window() roll — against the live registry. Budget: within
-        1% of the untelemetered steady-state close, and the A/B must
-        not contradict it beyond noise."""
-    from parca_agent_tpu.aggregator.cpu import CPUAggregator
-    from parca_agent_tpu.capture.synthetic import SyntheticSpec, generate
-    from parca_agent_tpu.profiler.cpu import CPUProfiler
-    from parca_agent_tpu.runtime import device_telemetry as dtel
-
-    import gc
-
-    n_windows = int(os.environ.get("PARCA_BENCH_TRACE_WINDOWS", 24))
-    warm = 4
-    snaps = [generate(SyntheticSpec(
-        n_pids=32, n_unique_stacks=1024, n_rows=1024,
-        total_samples=4096, mean_depth=12, seed=300 + i))
-        for i in range(6)]
-
-    class Sink:
-        def write(self, labels, blob):
-            pass
-
-    class Src:
-        def __init__(self, n):
-            self._left = n
-
-        def poll(self):
-            if self._left <= 0:
-                return None
-            self._left -= 1
-            return snaps[self._left % len(snaps)]
-
-    def make():
-        return CPUProfiler(
-            source=Src(n_windows), aggregator=CPUAggregator(),
-            profile_writer=Sink(), duration_s=0.0)
-
-    prev = dtel.get()
-    tel = dtel.DeviceTelemetry(period_s=0.0, ring=n_windows)
-    arms = (make(), make())  # 0: telemetry off, 1: telemetry on
-    offs, ons = [], []
-    gc_was = gc.isenabled()
-    gc.disable()
-    try:
-        for i in range(n_windows):
-            t = [0.0, 0.0]
-            for k in ((0, 1), (1, 0))[i % 2]:
-                gc.collect()
-                dtel.install(tel if k else None)
-                t0 = time.perf_counter()
-                if not arms[k].run_iteration():
-                    raise RuntimeError("telemetry_overhead source "
-                                       "exhausted early")
-                t[k] = time.perf_counter() - t0
-            offs.append(t[0])
-            ons.append(t[1])
-    finally:
-        dtel.install(prev)
-        if gc_was:
-            gc.enable()
-    off_ms = _median_ms(offs[warm:])
-    on_ms = _median_ms(ons[warm:])
-    diffs = [a - b for a, b in zip(ons, offs)]
-    balanced = [(diffs[k] + diffs[k + 1]) / 2
-                for k in range(warm, n_windows - 1, 2)]
-    ab_diff_ms = _median_ms(balanced)
-
-    # Direct per-window telemetry cost: the hook traffic one window of
-    # the overlapped close path generates (feed dispatch, packed close,
-    # collect, an eager device write, the SLO tick), with the latch,
-    # histogram, and timeline machinery all live. Steady state by
-    # construction: the shapes below latch on the first rep and every
-    # later rep takes the signature-seen path, exactly like a pinned
-    # production geometry.
-    reps = 2000
-    mic = dtel.DeviceTelemetry(period_s=1.0, ring=256)
-    t0 = time.perf_counter()
-    for _ in range(reps):
-        mic.record("feed_probe", 1e-4, shape=(1 << 18, 1 << 17, 4096, 8,
-                                              512, "pallas"),
-                   h2d_bytes=1 << 16)
-        mic.record("close_delta", 1e-3, shape=(1 << 17, 2048, 10, 256,
-                                               64, 512))
-        mic.record("close_fetch", 5e-4, shape=(2048, 10),
-                   d2h_bytes=81920)
-        mic.record_transfer("miss_settle", "h2d", 4096)
-        mic.tick_window(5e-3)
-    per_window_ms = (time.perf_counter() - t0) / reps * 1e3
-
-    overhead_pct = per_window_ms / off_ms * 100.0
-    ab_slack_ms = max(3 * 0.01 * off_ms, 1.0)
-    phase = {
-        "close_untelemetered_ms": round(off_ms, 3),
-        "close_telemetered_ms": round(on_ms, 3),
-        "ab_paired_diff_ms": round(ab_diff_ms, 4),
-        "telemetry_cost_per_window_ms": round(per_window_ms, 4),
-        "overhead_pct": round(overhead_pct, 3),
-        "budget_pct": 1.0,
-        "windows": n_windows,
-        "windows_ticked": tel.window_stats["windows_total"],
-        "record_errors": tel.stats["record_errors"],
-    }
-    if tel.window_stats["windows_total"] != n_windows:
-        phase["error"] = (f"telemetry ticked "
-                          f"{tel.window_stats['windows_total']} of "
-                          f"{n_windows} windows")
-    elif tel.stats["record_errors"]:
-        phase["error"] = (f"{tel.stats['record_errors']} telemetry "
-                          f"record errors during the drill")
-    elif per_window_ms > 0.01 * off_ms:
-        phase["error"] = (f"telemetry costs {per_window_ms:.4f} ms/window "
-                          f"({overhead_pct:.2f}%), over the 1% budget on "
-                          f"a {off_ms:.3f} ms close")
-    elif ab_diff_ms > ab_slack_ms:
-        phase["error"] = (f"A/B paired difference {ab_diff_ms:.3f} ms "
-                          f"contradicts the microbench beyond noise "
-                          f"(bar {ab_slack_ms:.3f} ms)")
-    return phase
 
 
 def _close_overlap() -> dict:
@@ -2914,8 +2596,7 @@ def main() -> int:
     # The device flight recorder rides every bench process that
     # measures, so every phase artifact carries the kernel/compile/
     # transfer truth of the run that produced it (_finalize_result
-    # stamps env + snapshot). The telemetry_overhead drill holds the tax
-    # under 1%.
+    # stamps env + snapshot).
     if os.environ.get("PARCA_BENCH_TELEMETRY", "1") != "0":
         from parca_agent_tpu.runtime import device_telemetry as dtel
 

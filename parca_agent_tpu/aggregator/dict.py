@@ -50,6 +50,7 @@ from parca_agent_tpu.capture.formats import (
 )
 from parca_agent_tpu.ops.hashing import native_hash_available, row_hash_np
 from parca_agent_tpu.runtime import device_telemetry as dtel
+from parca_agent_tpu.runtime import trace
 from parca_agent_tpu.utils import faults
 
 # Linear-probe bound. The capacity guard keeps load factor <= 0.5, and at
@@ -123,42 +124,48 @@ def make_feed(cap: int, id_cap: int, n_pad: int, n_blocks: int = 0,
         h1, h2, h3 = packed[0], packed[1], packed[2]
         cnt = packed[3].astype(jnp.int32)
 
-        if probe is not None:
-            found_id = probe(table, h1, h2, h3)
-        else:
-            mask = jnp.uint32(cap - 1)
+        # The named scopes are what an operator reads in the profiler's
+        # trace (``probe`` where the op list says ``while.5``); the
+        # module's own name (``jit_feed``) is not theirs to change.
+        with jax.named_scope("probe"):
+            if probe is not None:
+                found_id = probe(table, h1, h2, h3)
+            else:
+                mask = jnp.uint32(cap - 1)
 
-            def step(k, state):
-                found_id, done = state
-                idx = ((h1 + jnp.uint32(k)) & mask).astype(jnp.int32)
-                row = table[idx]
-                occ = row[:, 3] > 0
-                hit = occ & (row[:, 0] == h1) & (row[:, 1] == h2) \
-                    & (row[:, 2] == h3)
-                stop = hit | ~occ
-                found_id = jnp.where(hit & ~done,
-                                     row[:, 3].astype(jnp.int32) - 1,
-                                     found_id)
-                return found_id, done | stop
+                def step(k, state):
+                    found_id, done = state
+                    idx = ((h1 + jnp.uint32(k)) & mask).astype(jnp.int32)
+                    row = table[idx]
+                    occ = row[:, 3] > 0
+                    hit = occ & (row[:, 0] == h1) & (row[:, 1] == h2) \
+                        & (row[:, 2] == h3)
+                    stop = hit | ~occ
+                    found_id = jnp.where(hit & ~done,
+                                         row[:, 3].astype(jnp.int32) - 1,
+                                         found_id)
+                    return found_id, done | stop
 
-            found_id = jnp.full(h1.shape, -1, jnp.int32)
-            done = jnp.zeros(h1.shape, bool)
-            found_id, _ = jax.lax.fori_loop(0, _PROBES, step,
-                                            (found_id, done))
+                found_id = jnp.full(h1.shape, -1, jnp.int32)
+                done = jnp.zeros(h1.shape, bool)
+                found_id, _ = jax.lax.fori_loop(0, _PROBES, step,
+                                                (found_id, done))
 
-        live = cnt > 0
-        hit = (found_id >= 0) & live
-        acc = acc.at[jnp.where(hit, found_id, id_cap)].add(
-            cnt, mode="drop")
-        if n_blocks:
-            touch = touch.at[jnp.where(hit, found_id // blk,
-                                       n_blocks)].set(1, mode="drop")
-        miss = live & ~hit
-        mtgt = jnp.where(miss, prefix_sum(miss.astype(jnp.int32)) - 1,
-                         jnp.int32(n_pad))
-        miss_rows = jnp.full((n_pad,), -1, jnp.int32).at[mtgt].set(
-            jnp.arange(h1.shape[0], dtype=jnp.int32), mode="drop")
-        n_miss = miss.astype(jnp.int32).sum()
+        with jax.named_scope("accumulate"):
+            live = cnt > 0
+            hit = (found_id >= 0) & live
+            acc = acc.at[jnp.where(hit, found_id, id_cap)].add(
+                cnt, mode="drop")
+            if n_blocks:
+                touch = touch.at[jnp.where(hit, found_id // blk,
+                                           n_blocks)].set(1, mode="drop")
+        with jax.named_scope("miss_compaction"):
+            miss = live & ~hit
+            mtgt = jnp.where(miss, prefix_sum(miss.astype(jnp.int32)) - 1,
+                             jnp.int32(n_pad))
+            miss_rows = jnp.full((n_pad,), -1, jnp.int32).at[mtgt].set(
+                jnp.arange(h1.shape[0], dtype=jnp.int32), mode="drop")
+            n_miss = miss.astype(jnp.int32).sum()
         return acc, touch, n_miss, miss_rows
 
     return feed
@@ -214,21 +221,24 @@ def make_close(id_cap: int, n_fetch: int, width: int,
     per32 = 32 // width
 
     def close(acc):
-        head = acc[:n_fetch]
-        over = head > (sentinel - 1)
-        vals = jnp.where(over, sentinel, head).astype(jnp.uint32)
-        shifts = (jnp.arange(per32, dtype=jnp.uint32) * width)[None, :]
-        lanes = (vals.reshape(-1, per32) << shifts).sum(
-            axis=1, dtype=jnp.uint32)
-        tgt = jnp.where(over, prefix_sum(over.astype(jnp.int32)) - 1,
-                        jnp.int32(n_over_buf))
-        ids = jnp.arange(n_fetch, dtype=jnp.uint32)
-        over_id = jnp.full((n_over_buf,), jnp.uint32(n_fetch)).at[tgt].set(
-            ids, mode="drop")
-        over_val = jnp.zeros((n_over_buf,), jnp.uint32).at[tgt].set(
-            head.astype(jnp.uint32), mode="drop")
-        n_over = over.astype(jnp.uint32).sum()
-        tail_total = acc[n_fetch:].sum().astype(jnp.uint32)
+        with jax.named_scope("pack"):
+            head = acc[:n_fetch]
+            over = head > (sentinel - 1)
+            vals = jnp.where(over, sentinel, head).astype(jnp.uint32)
+            shifts = (jnp.arange(per32, dtype=jnp.uint32) * width)[None, :]
+            lanes = (vals.reshape(-1, per32) << shifts).sum(
+                axis=1, dtype=jnp.uint32)
+        with jax.named_scope("overflow_sideband"):
+            tgt = jnp.where(over, prefix_sum(over.astype(jnp.int32)) - 1,
+                            jnp.int32(n_over_buf))
+            ids = jnp.arange(n_fetch, dtype=jnp.uint32)
+            over_id = jnp.full((n_over_buf,),
+                               jnp.uint32(n_fetch)).at[tgt].set(
+                ids, mode="drop")
+            over_val = jnp.zeros((n_over_buf,), jnp.uint32).at[tgt].set(
+                head.astype(jnp.uint32), mode="drop")
+            n_over = over.astype(jnp.uint32).sum()
+            tail_total = acc[n_fetch:].sum().astype(jnp.uint32)
         out = jnp.concatenate([
             lanes, over_id, over_val, n_over[None], tail_total[None]])
         return out
@@ -283,29 +293,35 @@ def make_close_delta(id_cap: int, n_fetch: int, width: int,
     nb_prefix = n_fetch // blk
 
     def close(acc, touch):
-        t = touch[:nb_prefix] > 0
-        n_touched = t.astype(jnp.uint32).sum()
-        tgt = jnp.where(t, prefix_sum(t.astype(jnp.int32)) - 1,
-                        jnp.int32(n_blk_buf))
-        blk_ids = jnp.full((n_blk_buf,), jnp.uint32(nb_prefix)).at[tgt].set(
-            jnp.arange(nb_prefix, dtype=jnp.uint32), mode="drop")
-        live_b = blk_ids < nb_prefix
-        safe = jnp.minimum(blk_ids, nb_prefix - 1).astype(jnp.int32)
-        gidx = safe[:, None] * blk + jnp.arange(blk, dtype=jnp.int32)[None, :]
-        vals = jnp.where(live_b[:, None], acc[gidx], 0).reshape(-1)
-        over = vals > (sentinel - 1)
-        pk = jnp.where(over, sentinel, vals).astype(jnp.uint32)
-        shifts = (jnp.arange(per32, dtype=jnp.uint32) * width)[None, :]
-        lanes = (pk.reshape(-1, per32) << shifts).sum(axis=1,
-                                                      dtype=jnp.uint32)
-        gid = gidx.reshape(-1).astype(jnp.uint32)
-        otgt = jnp.where(over, prefix_sum(over.astype(jnp.int32)) - 1,
-                         jnp.int32(n_over_buf))
-        over_id = jnp.full((n_over_buf,), jnp.uint32(n_fetch)).at[otgt].set(
-            gid, mode="drop")
-        over_val = jnp.zeros((n_over_buf,), jnp.uint32).at[otgt].set(
-            vals.astype(jnp.uint32), mode="drop")
-        n_over = over.astype(jnp.uint32).sum()
+        with jax.named_scope("touched_blocks"):
+            t = touch[:nb_prefix] > 0
+            n_touched = t.astype(jnp.uint32).sum()
+            tgt = jnp.where(t, prefix_sum(t.astype(jnp.int32)) - 1,
+                            jnp.int32(n_blk_buf))
+            blk_ids = jnp.full((n_blk_buf,),
+                               jnp.uint32(nb_prefix)).at[tgt].set(
+                jnp.arange(nb_prefix, dtype=jnp.uint32), mode="drop")
+            live_b = blk_ids < nb_prefix
+            safe = jnp.minimum(blk_ids, nb_prefix - 1).astype(jnp.int32)
+            gidx = safe[:, None] * blk \
+                + jnp.arange(blk, dtype=jnp.int32)[None, :]
+            vals = jnp.where(live_b[:, None], acc[gidx], 0).reshape(-1)
+        with jax.named_scope("pack"):
+            over = vals > (sentinel - 1)
+            pk = jnp.where(over, sentinel, vals).astype(jnp.uint32)
+            shifts = (jnp.arange(per32, dtype=jnp.uint32) * width)[None, :]
+            lanes = (pk.reshape(-1, per32) << shifts).sum(axis=1,
+                                                          dtype=jnp.uint32)
+        with jax.named_scope("overflow_sideband"):
+            gid = gidx.reshape(-1).astype(jnp.uint32)
+            otgt = jnp.where(over, prefix_sum(over.astype(jnp.int32)) - 1,
+                             jnp.int32(n_over_buf))
+            over_id = jnp.full((n_over_buf,),
+                               jnp.uint32(n_fetch)).at[otgt].set(
+                gid, mode="drop")
+            over_val = jnp.zeros((n_over_buf,), jnp.uint32).at[otgt].set(
+                vals.astype(jnp.uint32), mode="drop")
+            n_over = over.astype(jnp.uint32).sum()
         # Exactness guards: untouched prefix blocks and the tail beyond
         # n_fetch must both carry zero mass (the acc resets at window
         # open and the feed marks every add). A nonzero guard means the
@@ -783,7 +799,6 @@ class DictAggregator:
         over ALL snapshot rows — the sampler's dedup drain computes it
         once per unique record (docs/perf.md "feed endgame"); None
         self-hashes here."""
-        import time as _time
 
         import jax.numpy as jnp
 
@@ -848,62 +863,62 @@ class DictAggregator:
                 or not native_hash_available())
             rep = None
             if fold_first:
-                t0 = _time.perf_counter()
-                try:
-                    faults.inject("feed.coalesce")
-                    sl = slice(lo, hi)
-                    depth = (np.asarray(snapshot.user_len[sl], np.int64)
-                             + np.asarray(snapshot.kernel_len[sl],
-                                          np.int64))
-                    md = int(depth.max(initial=0))
-                    rec = np.empty((n, 3 + md), np.uint64)
-                    rec[:, 0] = np.asarray(snapshot.pids[sl],
-                                           np.int64).view(np.uint64)
-                    rec[:, 1] = np.asarray(snapshot.user_len[sl],
-                                           np.uint64)
-                    rec[:, 2] = np.asarray(snapshot.kernel_len[sl],
-                                           np.uint64)
-                    if md:
-                        rec[:, 3:] = snapshot.stacks[sl, :md]
-                    folded = fold_rows_first_seen(
-                        rec.view(np.dtype(
-                            (np.void, (3 + md) * 8))).ravel(), w64)
-                    if folded is not None:
-                        rep, _inv, fw = folded
-                        w64 = fw
-                        rows_map = rows_map[rep]
-                    self.stats["coalesce_rows_in"] = \
-                        self.stats.get("coalesce_rows_in", 0) + n
-                    self.stats["coalesce_rows_out"] = \
-                        self.stats.get("coalesce_rows_out", 0) \
-                        + len(rows_map)
-                except Exception as e:  # noqa: BLE001 - counted fallback
-                    # Fail-open to the unfolded batch (locals are only
-                    # rebound on success above, so rows_map/w64 are
-                    # intact); the triple fold is NOT retried — one fold
-                    # attempt per feed, like the hash-then-fold order.
-                    rep = None
-                    self.stats["coalesce_fallbacks"] = \
-                        self.stats.get("coalesce_fallbacks", 0) + 1
-                    from parca_agent_tpu.utils.log import get_logger
+                with trace.child("feed_coalesce") as sp:
+                    try:
+                        faults.inject("feed.coalesce")
+                        sl = slice(lo, hi)
+                        depth = (np.asarray(snapshot.user_len[sl], np.int64)
+                                 + np.asarray(snapshot.kernel_len[sl],
+                                              np.int64))
+                        md = int(depth.max(initial=0))
+                        rec = np.empty((n, 3 + md), np.uint64)
+                        rec[:, 0] = np.asarray(snapshot.pids[sl],
+                                               np.int64).view(np.uint64)
+                        rec[:, 1] = np.asarray(snapshot.user_len[sl],
+                                               np.uint64)
+                        rec[:, 2] = np.asarray(snapshot.kernel_len[sl],
+                                               np.uint64)
+                        if md:
+                            rec[:, 3:] = snapshot.stacks[sl, :md]
+                        folded = fold_rows_first_seen(
+                            rec.view(np.dtype(
+                                (np.void, (3 + md) * 8))).ravel(), w64)
+                        if folded is not None:
+                            rep, _inv, fw = folded
+                            w64 = fw
+                            rows_map = rows_map[rep]
+                        self.stats["coalesce_rows_in"] = \
+                            self.stats.get("coalesce_rows_in", 0) + n
+                        self.stats["coalesce_rows_out"] = \
+                            self.stats.get("coalesce_rows_out", 0) \
+                            + len(rows_map)
+                    except Exception as e:  # noqa: BLE001 - counted fallback
+                        # Fail-open to the unfolded batch (locals are only
+                        # rebound on success above, so rows_map/w64 are
+                        # intact); the triple fold is NOT retried — one fold
+                        # attempt per feed, like the hash-then-fold order.
+                        rep = None
+                        self.stats["coalesce_fallbacks"] = \
+                            self.stats.get("coalesce_fallbacks", 0) + 1
+                        from parca_agent_tpu.utils.log import get_logger
 
-                    get_logger("aggregator.dict").warn(
-                        "feed coalesce failed; dispatching the "
-                        "uncoalesced batch", error=repr(e)[:200])
-                self.timings["feed_coalesce"] = _time.perf_counter() - t0
-            t0 = _time.perf_counter()
-            if rep is None:
-                h1, h2, h3 = self.hash_rows(snapshot)
-                h1c, h2c, h3c = h1[lo:hi], h2[lo:hi], h3[lo:hi]
-            else:
-                h1c, h2c, h3c = row_hash_np(
-                    np.ascontiguousarray(snapshot.stacks[rows_map]),
-                    snapshot.pids[rows_map],
-                    snapshot.user_len[rows_map],
-                    snapshot.kernel_len[rows_map], n_hashes=3)
-                h2c = self._route_hashes(h1c, h2c, h3c,
-                                         snapshot.pids[rows_map])
-            self.timings["feed_hash"] = _time.perf_counter() - t0
+                        get_logger("aggregator.dict").warn(
+                            "feed coalesce failed; dispatching the "
+                            "uncoalesced batch", error=repr(e)[:200])
+                self.timings["feed_coalesce"] = sp.duration_s
+            with trace.child("feed_hash") as sp:
+                if rep is None:
+                    h1, h2, h3 = self.hash_rows(snapshot)
+                    h1c, h2c, h3c = h1[lo:hi], h2[lo:hi], h3[lo:hi]
+                else:
+                    h1c, h2c, h3c = row_hash_np(
+                        np.ascontiguousarray(snapshot.stacks[rows_map]),
+                        snapshot.pids[rows_map],
+                        snapshot.user_len[rows_map],
+                        snapshot.kernel_len[rows_map], n_hashes=3)
+                    h2c = self._route_hashes(h1c, h2c, h3c,
+                                             snapshot.pids[rows_map])
+            self.timings["feed_hash"] = sp.duration_s
             if not fold_first and self._coalesce and n > 1:
                 h1c, h2c, h3c, w64, rows_map = self._coalesce_triples(
                     h1c, h2c, h3c, w64, rows_map)
@@ -917,36 +932,36 @@ class DictAggregator:
             return
         counts_c = w64.astype(np.uint32)
         nd = len(h1c)
-        t0 = _time.perf_counter()
-        counts_c, corrections = self._prefilter_unreachable(
-            h1c, h2c, h3c, counts_c)
-        # (corrections join _pending only after the device call succeeds,
-        # mirroring the miss path: a failed feed must not leave partial
-        # host-side mass that a recovery close would emit as a window.)
-        n_pad = 1 << max(4, (nd - 1).bit_length())
-        # LRU (dict order = recency order via pop/re-insert): an
-        # evict-smallest policy would pin stale large buffers after a
-        # burst while current small sizes churn through one slot.
-        packed = self._feed_bufs.pop(n_pad, None)
-        if packed is None:
-            if len(self._feed_bufs) >= 4:  # bounded cache
-                self._feed_bufs.pop(next(iter(self._feed_bufs)))
-            packed = np.zeros((4, n_pad), np.uint32)
-        else:
-            packed[:, nd:] = 0  # stale tail from a previous, larger chunk
-        self._feed_bufs[n_pad] = packed
-        packed[0, :nd] = h1c
-        packed[1, :nd] = h2c
-        packed[2, :nd] = h3c
-        packed[3, :nd] = counts_c
-        self.timings["feed_pack"] = _time.perf_counter() - t0
+        trace.count(rows_fed=nd)
+        with trace.child("feed_pack") as sp:
+            counts_c, corrections = self._prefilter_unreachable(
+                h1c, h2c, h3c, counts_c)
+            # (corrections join _pending only after the device call succeeds,
+            # mirroring the miss path: a failed feed must not leave partial
+            # host-side mass that a recovery close would emit as a window.)
+            n_pad = 1 << max(4, (nd - 1).bit_length())
+            # LRU (dict order = recency order via pop/re-insert): an
+            # evict-smallest policy would pin stale large buffers after a
+            # burst while current small sizes churn through one slot.
+            packed = self._feed_bufs.pop(n_pad, None)
+            if packed is None:
+                if len(self._feed_bufs) >= 4:  # bounded cache
+                    self._feed_bufs.pop(next(iter(self._feed_bufs)))
+                packed = np.zeros((4, n_pad), np.uint32)
+            else:
+                packed[:, nd:] = 0  # stale tail from a previous, larger chunk
+            self._feed_bufs[n_pad] = packed
+            packed[0, :nd] = h1c
+            packed[1, :nd] = h2c
+            packed[2, :nd] = h3c
+            packed[3, :nd] = counts_c
+        self.timings["feed_pack"] = sp.duration_s
 
         self._ensure_device()
         if self._acc is None:
             self._acc = self._new_acc()
         if self._blk and self._touch is None:
             self._touch = self._new_touch()
-        t0 = _time.perf_counter()
         handle = self._feed_dispatch_async(packed, n_pad,
                                            1 if self._needs_reset else 0)
         self._needs_reset = False
@@ -955,12 +970,12 @@ class DictAggregator:
         # gate and width prediction read it); host-settled corrections
         # and carried mass are not part of it.
         self._fed_total += int(w64.sum()) - sum(c for _, c in corrections)
-        # Dispatch-only cost: the miss sync that used to ride here (and
+        # Dispatch-only cost (timings["feed_dispatch"], set where the
+        # program is called): the miss sync that used to ride here (and
         # block the capture thread for the kernel's full latency) is
         # deferred to the next feed / the close, where the kernel has
         # already completed and the sync is ~free — the feed's device
         # work OVERLAPS capture instead of stalling it.
-        self.timings["feed_dispatch"] = _time.perf_counter() - t0
         self._miss_inflight = (handle, packed, snapshot, rows_map, w64,
                                h1c, h2c, h3c)
 
@@ -980,20 +995,21 @@ class DictAggregator:
         if inflight is None:
             return
         handle, _packed, snapshot, rows_map, w64, h1d, h2d, h3d = inflight
-        t0 = _time.perf_counter()
-        miss_rel = self._settle_dispatch(handle)
-        self.timings["feed_settle"] = _time.perf_counter() - t0
+        with trace.child("feed_settle") as sp:  # the wait for the kernel
+            miss_rel = self._settle_dispatch(handle)
+        self.timings["feed_settle"] = sp.duration_s
+        trace.count(misses=len(miss_rel))
         if len(miss_rel):
-            t0 = _time.perf_counter()
             # Miss indices address dispatch rows: rows_map translates
             # back to representative snapshot rows, and the dispatch-
             # row-aligned hash lanes and FOLDED weights (a
             # representative's own count would drop its duplicates'
             # mass) ride the inflight tuple with them.
-            self._pending.extend(self._resolve_misses(
-                snapshot, rows_map[miss_rel], h1d[miss_rel],
-                h2d[miss_rel], h3d[miss_rel], w64[miss_rel]))
-            self.timings["feed_miss"] = _time.perf_counter() - t0
+            with trace.child("feed_miss") as sp:
+                self._pending.extend(self._resolve_misses(
+                    snapshot, rows_map[miss_rel], h1d[miss_rel],
+                    h2d[miss_rel], h3d[miss_rel], w64[miss_rel]))
+            self.timings["feed_miss"] = sp.duration_s
         if self._carry and not self._carry_disabled:
             t0 = _time.perf_counter()
             self._carry_admit(h1d, h2d, h3d)
@@ -1022,39 +1038,38 @@ class DictAggregator:
         the unfolded stream. A fold failure (chaos site feed.coalesce)
         is counted and degrades to the unfolded batch, never a lost
         feed."""
-        import time as _time
 
         n = len(h1c)
-        t0 = _time.perf_counter()
-        try:
-            faults.inject("feed.coalesce")
-            key = np.empty((n, 3), np.uint32)
-            key[:, 0] = h1c
-            key[:, 1] = h2c
-            key[:, 2] = h3c
-            folded = fold_rows_first_seen(
-                key.view(np.dtype((np.void, 12))).ravel(), w64)
-            if folded is not None:
-                rep, _inv, fw = folded
-                h1c, h2c, h3c = h1c[rep], h2c[rep], h3c[rep]
-                w64 = fw
-                rows_map = rows_map[rep]
-            self.stats["coalesce_rows_in"] = \
-                self.stats.get("coalesce_rows_in", 0) + n
-            self.stats["coalesce_rows_out"] = \
-                self.stats.get("coalesce_rows_out", 0) + len(h1c)
-        except Exception as e:  # noqa: BLE001 - counted fallback
-            # Fail-open to the unfolded batch: the feed must never be
-            # lost to the optimization riding it. Locals are only
-            # rebound on success above, so the input rows are intact.
-            self.stats["coalesce_fallbacks"] = \
-                self.stats.get("coalesce_fallbacks", 0) + 1
-            from parca_agent_tpu.utils.log import get_logger
+        with trace.child("feed_coalesce") as sp:
+            try:
+                faults.inject("feed.coalesce")
+                key = np.empty((n, 3), np.uint32)
+                key[:, 0] = h1c
+                key[:, 1] = h2c
+                key[:, 2] = h3c
+                folded = fold_rows_first_seen(
+                    key.view(np.dtype((np.void, 12))).ravel(), w64)
+                if folded is not None:
+                    rep, _inv, fw = folded
+                    h1c, h2c, h3c = h1c[rep], h2c[rep], h3c[rep]
+                    w64 = fw
+                    rows_map = rows_map[rep]
+                self.stats["coalesce_rows_in"] = \
+                    self.stats.get("coalesce_rows_in", 0) + n
+                self.stats["coalesce_rows_out"] = \
+                    self.stats.get("coalesce_rows_out", 0) + len(h1c)
+            except Exception as e:  # noqa: BLE001 - counted fallback
+                # Fail-open to the unfolded batch: the feed must never be
+                # lost to the optimization riding it. Locals are only
+                # rebound on success above, so the input rows are intact.
+                self.stats["coalesce_fallbacks"] = \
+                    self.stats.get("coalesce_fallbacks", 0) + 1
+                from parca_agent_tpu.utils.log import get_logger
 
-            get_logger("aggregator.dict").warn(
-                "feed coalesce failed; dispatching the uncoalesced "
-                "batch", error=repr(e)[:200])
-        self.timings["feed_coalesce"] = _time.perf_counter() - t0
+                get_logger("aggregator.dict").warn(
+                    "feed coalesce failed; dispatching the uncoalesced "
+                    "batch", error=repr(e)[:200])
+        self.timings["feed_coalesce"] = sp.duration_s
         return h1c, h2c, h3c, w64, rows_map
 
     def _carry_match(self, h1c, h2c, h3c, w64):
@@ -1266,7 +1281,6 @@ class DictAggregator:
         sync; returns an opaque handle for _settle_dispatch. The
         accumulator donation contract: self._acc/_touch are None while
         the dispatch is in flight (invalid if it throws)."""
-        import time as _time
 
         import jax.numpy as jnp
 
@@ -1281,37 +1295,39 @@ class DictAggregator:
         touch = self._touch if self._blk else jnp.zeros(1, jnp.int32)
         self._acc = None    # donated: invalid if the call throws
         self._touch = None
-        t0 = _time.perf_counter()
-        try:
-            acc, touch, n_miss, miss_rows = prog(
-                self._dev, acc, touch, jnp.asarray(packed),
-                jnp.uint32(reset))
-        except Exception as e:  # noqa: BLE001 - pallas path only
-            if self._probe_resolved != "pallas":
-                raise
-            # Automatic fallback, mirroring TPUAggregator.aggregate: a
-            # Pallas build/lowering failure on this backend (the CPU
-            # interpret probe can pass while Mosaic later refuses the
-            # kernel) degrades the probe to the lax loop — never a lost
-            # feed, at worst the old speed. Latched so the per-feed hot
-            # path does not retry a broken lowering. Safe to retry with
-            # the held acc/touch: a lowering failure raises at compile,
-            # before donation consumes the buffers.
-            self._probe_resolved = "lax"
-            dtel.note_backend("feed_probe", resolved="lax", fallback=True)
-            from parca_agent_tpu.utils.log import get_logger
+        # One clock pair for the span, timings[...] and the telemetry.
+        with trace.child("feed_dispatch") as sp:
+            try:
+                acc, touch, n_miss, miss_rows = prog(
+                    self._dev, acc, touch, jnp.asarray(packed),
+                    jnp.uint32(reset))
+            except Exception as e:  # noqa: BLE001 - pallas path only
+                if self._probe_resolved != "pallas":
+                    raise
+                # Automatic fallback, mirroring TPUAggregator.aggregate: a
+                # Pallas build/lowering failure on this backend (the CPU
+                # interpret probe can pass while Mosaic later refuses the
+                # kernel) degrades the probe to the lax loop — never a lost
+                # feed, at worst the old speed. Latched so the per-feed hot
+                # path does not retry a broken lowering. Safe to retry with
+                # the held acc/touch: a lowering failure raises at compile,
+                # before donation consumes the buffers.
+                self._probe_resolved = "lax"
+                dtel.note_backend("feed_probe", resolved="lax", fallback=True)
+                from parca_agent_tpu.utils.log import get_logger
 
-            get_logger("aggregator.dict").warn(
-                "pallas batch probe failed; falling back to the lax "
-                "probe loop", error=repr(e)[:200])
-            prog = _feed_program(self._cap, self._id_cap, n_pad,
-                                 self._n_blocks, self._blk, "lax")
-            sig = (self._cap, self._id_cap, n_pad, self._n_blocks,
-                   self._blk, "lax")
-            acc, touch, n_miss, miss_rows = prog(
-                self._dev, acc, touch, jnp.asarray(packed),
-                jnp.uint32(reset))
-        dtel.record("feed_probe", _time.perf_counter() - t0, shape=sig,
+                get_logger("aggregator.dict").warn(
+                    "pallas batch probe failed; falling back to the lax "
+                    "probe loop", error=repr(e)[:200])
+                prog = _feed_program(self._cap, self._id_cap, n_pad,
+                                     self._n_blocks, self._blk, "lax")
+                sig = (self._cap, self._id_cap, n_pad, self._n_blocks,
+                       self._blk, "lax")
+                acc, touch, n_miss, miss_rows = prog(
+                    self._dev, acc, touch, jnp.asarray(packed),
+                    jnp.uint32(reset))
+        self.timings["feed_dispatch"] = sp.duration_s
+        dtel.record("feed_probe", sp.duration_s, shape=sig,
                     h2d_bytes=packed.nbytes)
         self._acc = acc
         self._touch = touch if self._blk else None
@@ -1331,40 +1347,39 @@ class DictAggregator:
     def _close_pack_dispatch(self, acc, n_fetch: int, width: int,
                              n_over_buf: int):
         """Dispatch the full close pack program (no host sync)."""
-        import time as _time
 
         prog = _close_program(self._id_cap, n_fetch, width, n_over_buf)
-        t0 = _time.perf_counter()
-        out = prog(acc)
-        dtel.record("close_pack", _time.perf_counter() - t0,
+        with trace.child("close_dispatch") as sp:
+            out = prog(acc)
+        self.timings["close_dispatch"] = sp.duration_s
+        dtel.record("close_pack", sp.duration_s,
                     shape=(self._id_cap, n_fetch, width, n_over_buf))
         return out
 
     def _close_pack_collect(self, out_dev) -> np.ndarray:
         """Fetch a dispatched close pack's packed buffer."""
-        import time as _time
 
-        t0 = _time.perf_counter()
-        host = np.asarray(out_dev)
+        with trace.child("close_fetch") as sp:  # the D2H wait
+            host = np.asarray(out_dev)
+        self.timings["close_fetch"] = sp.duration_s
         # Execute-only (shape=None): the fetch is a collect, not a
         # dispatch — its compile truth already lives in the pack/delta
         # signatures above, and latching the output shape here would
         # re-report every legitimate delta<->full geometry switch as a
         # recompile storm.
-        dtel.record("close_fetch", _time.perf_counter() - t0,
-                    d2h_bytes=host.nbytes)
+        dtel.record("close_fetch", sp.duration_s, d2h_bytes=host.nbytes)
         return host
 
     def _close_delta_dispatch(self, acc, touch, n_fetch: int, width: int,
                               n_over_buf: int, n_blk_buf: int):
         """Dispatch the delta close pack program (no host sync)."""
-        import time as _time
 
         prog = _close_program_delta(self._id_cap, n_fetch, width,
                                     n_over_buf, n_blk_buf, self._blk)
-        t0 = _time.perf_counter()
-        out = prog(acc, touch)
-        dtel.record("close_delta", _time.perf_counter() - t0,
+        with trace.child("close_dispatch") as sp:
+            out = prog(acc, touch)
+        self.timings["close_dispatch"] = sp.duration_s
+        dtel.record("close_delta", sp.duration_s,
                     shape=(self._id_cap, n_fetch, width, n_over_buf,
                            n_blk_buf, self._blk))
         return out
@@ -1413,7 +1428,6 @@ class DictAggregator:
         window's pack/fetch proceeds. Returns None for an empty window
         (nothing fed, nothing pending) after counting it, matching the
         old close_window fast path."""
-        import time as _time
 
         if self._close_handle is not None:
             raise RuntimeError("previous close not collected")
@@ -1454,7 +1468,6 @@ class DictAggregator:
                                 h.delta_blks * self._blk)
             h.n_over_buf = min(_CLOSE_OVERS[h.width],
                                1 << (predicted - 1).bit_length())
-            t0 = _time.perf_counter()
             if h.delta_blks:
                 h.out_dev = self._close_delta_dispatch(
                     h.acc, h.touch, h.n_fetch, h.width, h.n_over_buf,
@@ -1462,17 +1475,17 @@ class DictAggregator:
             else:
                 h.out_dev = self._close_pack_dispatch(
                     h.acc, h.n_fetch, h.width, h.n_over_buf)
-            self.timings["close_dispatch"] = _time.perf_counter() - t0
         # The flip: the closed window's buffers stay intact inside the
         # handle (retries re-pack them); the next window's first feed
         # resets the flipped-in twin (stale by two windows) on device.
-        t0 = _time.perf_counter()
-        self._acc, self._acc_spare = self._acc_spare, self._acc
-        self._touch, self._touch_spare = self._touch_spare, self._touch
-        self._fed_total = 0
-        self._needs_reset = True
-        self.stats["buffer_flips"] = self.stats.get("buffer_flips", 0) + 1
-        self.timings["buffer_flip"] = _time.perf_counter() - t0
+        with trace.child("buffer_flip", histogram=True) as sp:
+            self._acc, self._acc_spare = self._acc_spare, self._acc
+            self._touch, self._touch_spare = self._touch_spare, self._touch
+            self._fed_total = 0
+            self._needs_reset = True
+            self.stats["buffer_flips"] = \
+                self.stats.get("buffer_flips", 0) + 1
+        self.timings["buffer_flip"] = sp.duration_s
         self._close_handle = h
         return h
 
@@ -1498,7 +1511,6 @@ class DictAggregator:
         intact (pre-flip) accumulator on any misprediction — touched
         blocks grown first, then the full fetch as the exact fallback,
         then the sideband's grow-then-widen ladder, all lossless."""
-        import time as _time
 
         if handle is None:  # empty window (already counted)
             return np.zeros(self._next_id, np.int64)
@@ -1511,7 +1523,7 @@ class DictAggregator:
             out_dev = h.out_dev
             h.out_dev = None
             nb_prefix = n_fetch // self._blk if self._blk else 0
-            t0 = _time.perf_counter()
+            fetch_s = 0.0  # the D2H waits of this close, retries and all
             while True:
                 per32 = 32 // width
                 if out_dev is None:  # a retry: re-pack the intact acc
@@ -1523,6 +1535,7 @@ class DictAggregator:
                         out_dev = self._close_pack_dispatch(
                             h.acc, n_fetch, width, n_over_buf)
                 host = self._close_pack_collect(out_dev)
+                fetch_s += self.timings["close_fetch"]
                 out_dev = None
                 if int(host[-1]) != 0:
                     raise AssertionError("count mass beyond fetched prefix")
@@ -1569,79 +1582,79 @@ class DictAggregator:
                     width = 8 if width == 4 else 16
                     n_over_buf = _CLOSE_OVERS[width]
             self._prev_n_over = n_over
-            fetch_s = _time.perf_counter() - t0
             self.timings["close_fetch"] = fetch_s
             if n_blk_buf:
                 self.timings["delta_fetch"] = fetch_s
+                trace.note("delta_fetch", fetch_s, histogram=True)
             else:
                 # A full close must not leave the previous DELTA close's
                 # timing behind: the profiler records a delta_fetch trace
                 # span only when the key is present for THIS window.
                 self.timings.pop("delta_fetch", None)
-            t0 = _time.perf_counter()
-            sentinel = (1 << width) - 1
-            shifts = (np.arange(per32, dtype=np.uint32) * width)[None, :]
-            if n_blk_buf:
-                lanes_n = n_blk_buf * self._blk // per32
-                wb_key = (1, n_blk_buf * self._blk, width)
-            else:
-                lanes_n = n_fetch // per32
-                wb_key = (0, n_fetch, width)
-            lanes = host[:lanes_n]
-            wb = self._unpack_bufs.get(wb_key)
-            if wb is None:
-                if len(self._unpack_bufs) >= 4:  # bounded: evict smallest
-                    self._unpack_bufs.pop(
-                        min(self._unpack_bufs,
-                            key=lambda k: self._unpack_bufs[k].nbytes))
-                wb = self._unpack_bufs[wb_key] = np.empty(
-                    (lanes_n, per32), np.uint32)
-            np.right_shift(lanes[:, None], shifts, out=wb)
-            np.bitwise_and(wb, np.uint32(sentinel), out=wb)
-            self._counts_flip ^= 1
-            counts = self._counts_bufs[self._counts_flip]
-            if counts is None or len(counts) != n_fetch:
-                counts = np.empty(n_fetch, np.int64)
-                self._counts_bufs[self._counts_flip] = counts
-            if n_blk_buf:
-                # Delta unpack: zero, then scatter the touched blocks
-                # back to their id ranges (block ids ride the buffer).
-                counts[:] = 0
-                n_t = n_touched
-                bids = host[lanes_n:lanes_n + n_blk_buf][:n_t].astype(
-                    np.int64)
-                idx = (bids[:, None] * self._blk
-                       + np.arange(self._blk, dtype=np.int64)).reshape(-1)
-                counts[idx] = wb.reshape(-1)[: n_t * self._blk]
-                over_off = lanes_n + n_blk_buf
-                self._prev_touched = n_t
-                self.stats["delta_closes"] = \
-                    self.stats.get("delta_closes", 0) + 1
-                self.stats["fetch_rows_last"] = n_t * self._blk
-            else:
-                counts[:] = wb.reshape(-1)
-                over_off = lanes_n
-                self.stats["full_closes"] = \
-                    self.stats.get("full_closes", 0) + 1
-                self.stats["fetch_rows_last"] = n_fetch
-                if self._blk and h.touch is not None:
-                    # Learn the touched population from the flags (one
-                    # small fetch) so the NEXT close can go delta — full
-                    # closes are the cold path, so the extra round trip
-                    # amortizes away in steady state.
-                    try:
-                        self._prev_touched = int(
-                            (np.asarray(h.touch)[:nb_prefix] > 0).sum())
-                    except Exception:  # noqa: BLE001 - advisory only
-                        self._prev_touched = None
-            over_id = host[over_off:over_off + n_over]
-            over_val = host[over_off + n_over_buf:
-                            over_off + n_over_buf + n_over]
-            counts[over_id] = over_val
-            self.stats["fetch_bytes_last"] = int(host.nbytes)
-            self.stats["fetch_bytes_total"] = \
-                self.stats.get("fetch_bytes_total", 0) + int(host.nbytes)
-            self.timings["close_unpack"] = _time.perf_counter() - t0
+            with trace.child("close_unpack") as sp:
+                sentinel = (1 << width) - 1
+                shifts = (np.arange(per32, dtype=np.uint32) * width)[None, :]
+                if n_blk_buf:
+                    lanes_n = n_blk_buf * self._blk // per32
+                    wb_key = (1, n_blk_buf * self._blk, width)
+                else:
+                    lanes_n = n_fetch // per32
+                    wb_key = (0, n_fetch, width)
+                lanes = host[:lanes_n]
+                wb = self._unpack_bufs.get(wb_key)
+                if wb is None:
+                    if len(self._unpack_bufs) >= 4:  # bounded: evict smallest
+                        self._unpack_bufs.pop(
+                            min(self._unpack_bufs,
+                                key=lambda k: self._unpack_bufs[k].nbytes))
+                    wb = self._unpack_bufs[wb_key] = np.empty(
+                        (lanes_n, per32), np.uint32)
+                np.right_shift(lanes[:, None], shifts, out=wb)
+                np.bitwise_and(wb, np.uint32(sentinel), out=wb)
+                self._counts_flip ^= 1
+                counts = self._counts_bufs[self._counts_flip]
+                if counts is None or len(counts) != n_fetch:
+                    counts = np.empty(n_fetch, np.int64)
+                    self._counts_bufs[self._counts_flip] = counts
+                if n_blk_buf:
+                    # Delta unpack: zero, then scatter the touched blocks
+                    # back to their id ranges (block ids ride the buffer).
+                    counts[:] = 0
+                    n_t = n_touched
+                    bids = host[lanes_n:lanes_n + n_blk_buf][:n_t].astype(
+                        np.int64)
+                    idx = (bids[:, None] * self._blk
+                           + np.arange(self._blk, dtype=np.int64)).reshape(-1)
+                    counts[idx] = wb.reshape(-1)[: n_t * self._blk]
+                    over_off = lanes_n + n_blk_buf
+                    self._prev_touched = n_t
+                    self.stats["delta_closes"] = \
+                        self.stats.get("delta_closes", 0) + 1
+                    self.stats["fetch_rows_last"] = n_t * self._blk
+                else:
+                    counts[:] = wb.reshape(-1)
+                    over_off = lanes_n
+                    self.stats["full_closes"] = \
+                        self.stats.get("full_closes", 0) + 1
+                    self.stats["fetch_rows_last"] = n_fetch
+                    if self._blk and h.touch is not None:
+                        # Learn the touched population from the flags (one
+                        # small fetch) so the NEXT close can go delta — full
+                        # closes are the cold path, so the extra round trip
+                        # amortizes away in steady state.
+                        try:
+                            self._prev_touched = int(
+                                (np.asarray(h.touch)[:nb_prefix] > 0).sum())
+                        except Exception:  # noqa: BLE001 - advisory only
+                            self._prev_touched = None
+                over_id = host[over_off:over_off + n_over]
+                over_val = host[over_off + n_over_buf:
+                                over_off + n_over_buf + n_over]
+                counts[over_id] = over_val
+                self.stats["fetch_bytes_last"] = int(host.nbytes)
+                self.stats["fetch_bytes_total"] = \
+                    self.stats.get("fetch_bytes_total", 0) + int(host.nbytes)
+            self.timings["close_unpack"] = sp.duration_s
         else:
             # Pending-only close (nothing fed to the device): no fetch
             # ran, so the previous close's delta timing must not survive

@@ -43,6 +43,7 @@ from parca_agent_tpu.aggregator.dict import (
 )
 from parca_agent_tpu.parallel.mesh import FLEET_AXIS, fleet_mesh
 from parca_agent_tpu.runtime import device_telemetry as dtel
+from parca_agent_tpu.runtime import trace
 
 
 def route_h2(h2: np.ndarray, pids, shard_of_pid, n_shards: int
@@ -434,18 +435,17 @@ class ShardedDictAggregator(DictAggregator):
     # palint: device-state: _dev, _acc, _touch, _acc_spare, _touch_spare
     def _feed_dispatch_async(self, packed: np.ndarray, n_pad: int,
                              reset: int):
-        import time as _time
-
         part = self._partition_packed(packed)
         prog = _sharded_feed_program(self._mesh, self._n_shards, self._cap_s,
                                      self._id_cap, part.shape[2])
         dev_packed = self._device_put_sharded(part)
         acc = self._acc
         self._acc = None  # donated: invalid if the call throws
-        t0 = _time.perf_counter()
-        acc, n_miss, miss_rows = prog(self._dev, acc, dev_packed,
-                                      np.uint32(reset))
-        dtel.record("feed_probe", _time.perf_counter() - t0,
+        with trace.child("feed_dispatch") as sp:
+            acc, n_miss, miss_rows = prog(self._dev, acc, dev_packed,
+                                          np.uint32(reset))
+        self.timings["feed_dispatch"] = sp.duration_s
+        dtel.record("feed_probe", sp.duration_s,
                     shape=("sharded", self._n_shards, self._cap_s,
                            self._id_cap, part.shape[2]))
         self._acc = acc
@@ -466,28 +466,16 @@ class ShardedDictAggregator(DictAggregator):
 
     def _close_pack_dispatch(self, acc, n_fetch: int, width: int,
                              n_over_buf: int):
-        import time as _time
-
         prog = _sharded_close_program(self._mesh, self._n_shards,
                                       self._id_cap, n_fetch, width,
                                       n_over_buf)
-        t0 = _time.perf_counter()
-        out = prog(acc)[0]  # every shard holds the same packed copy
-        dtel.record("close_pack", _time.perf_counter() - t0,
+        with trace.child("close_dispatch") as sp:
+            out = prog(acc)[0]  # every shard holds the same packed copy
+        self.timings["close_dispatch"] = sp.duration_s
+        dtel.record("close_pack", sp.duration_s,
                     shape=("sharded", self._n_shards, self._id_cap,
                            n_fetch, width, n_over_buf))
         return out
-
-    def _close_pack_collect(self, out_dev) -> np.ndarray:
-        import time as _time
-
-        t0 = _time.perf_counter()
-        host = np.asarray(out_dev)
-        # Execute-only, same reasoning as the base collect: the compile
-        # truth lives in the pack signature, not the fetched shape.
-        dtel.record("close_fetch", _time.perf_counter() - t0,
-                    d2h_bytes=host.nbytes)
-        return host
 
     def _dev_scatter(self, slots: np.ndarray, vals: np.ndarray) -> None:
         import jax.numpy as jnp

@@ -78,9 +78,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "(docs/observability.md): per-window lifecycle "
                         "traces on /debug/windows + /debug/trace/<seq>, "
                         "per-stage latency histograms on /metrics, and "
-                        "the slow-window detector. On by default — the "
-                        "bench's trace_overhead phase holds the tax "
-                        "under 2%% of the close")
+                        "the slow-window detector. On by default")
     p.add_argument("--trace-ring", type=int, default=512,
                    help="completed window traces kept in the flight "
                         "recorder's ring buffer")
@@ -105,8 +103,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "latency histograms, recompile-storm detection, "
                         "H2D/D2H transfer accounting, and window-SLO "
                         "budget burn on /metrics + /debug/device. On by "
-                        "default — the bench's telemetry_overhead phase "
-                        "holds the tax under 1%% of the close")
+                        "default")
     p.add_argument("--telemetry-ring", type=int, default=256,
                    help="kernel events and window-SLO entries kept in "
                         "the device flight recorder's timeline rings "

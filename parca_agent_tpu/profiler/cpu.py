@@ -25,6 +25,7 @@ from parca_agent_tpu.capture.formats import WindowSnapshot
 from parca_agent_tpu.pprof.builder import build_pprof
 from parca_agent_tpu.runtime import device_telemetry as dtel
 from parca_agent_tpu.runtime.quarantine import apply_ladder
+from parca_agent_tpu.runtime import trace as trace_mod
 from parca_agent_tpu.runtime.trace import NULL_TRACE
 from parca_agent_tpu.utils import faults
 from parca_agent_tpu.utils.log import get_logger
@@ -415,12 +416,18 @@ class CPUProfiler:
         touched while it may still be executing inside it)."""
         from parca_agent_tpu.utils.bounded import bounded_call
 
+        # The call runs on a thread of its own; the aggregator's child
+        # spans belong under the span open here (the window's close).
+        open_span = trace_mod.current()
+
         def site():
-            faults.inject("device.dispatch")
-            # First device touch after a bring-up that had failed: learn
-            # the backend here, inside the guard (no-op once claimed).
-            self._health.claim_backend()
-            return thunk()
+            with trace_mod.adopt(open_span):
+                faults.inject("device.dispatch")
+                # First device touch after a bring-up that had failed:
+                # learn the backend here, inside the guard (no-op once
+                # claimed).
+                self._health.claim_backend()
+                return thunk()
 
         self._device_timeout_used = self._next_device_timeout
         self._next_device_timeout = self._device_timeout
@@ -544,15 +551,19 @@ class CPUProfiler:
             # registry state must be invalidated before any of the new
             # generation's samples resolve through it (fail-open by the
             # tracker's own contract — see process/identity.py).
-            self._identity.observe_window(snapshot.pids)
+            with tr.span("identity"):
+                self._identity.observe_window(snapshot.pids)
         if self._admission is not None:
             # Per-tenant usage accounting BEFORE the close: the ladder
             # levels this window's profiles ride were set by last tick
             # (admission reacts on the window clock, one window behind —
             # the same cadence as quarantine cooldowns).
-            self._admission.account_window(snapshot.pids, snapshot.counts)
+            with tr.span("admission"):
+                self._admission.account_window(snapshot.pids,
+                                               snapshot.counts)
         tr.annotate(time_ns=snapshot.time_ns,
-                    samples=int(snapshot.total_samples()))
+                    samples=int(snapshot.total_samples()),
+                    rows=len(snapshot))
         t_start = time.perf_counter()
         try:
             if self._encoder is not None:
@@ -580,8 +591,7 @@ class CPUProfiler:
                         sp_sym.duration_s
 
                 with tr.span("ship"):
-                    for prof in profiles:
-                        self._write_profile(prof)
+                    self._write_profiles(profiles)
                 n_pids = len(profiles)
                 tr.annotate(pids=n_pids, path="scalar")
 
@@ -737,10 +747,12 @@ class CPUProfiler:
             if self._regression is not None:
                 self._regression.fold_from_prepared(ctx, prep)
 
-    def _write_one(self, pid: int, payload) -> bool:
+    def _write_one(self, pid: int, payload, tally: list) -> bool:
         """Labels lookup + write + bookkeeping for one profile; False when
         relabeling dropped the target. `payload` is a zero-arg callable so
-        dropped targets never pay the serialization. Called from the
+        dropped targets never pay the serialization. `tally[0]` gathers
+        the seconds of the label lookup with its lock (the ship's
+        ``ship_labels`` child, _write_all). Called from the
         profiler thread (inline/scalar paths) or the pipeline's worker;
         the write lock covers only the shared mutable state (label-cache
         lookup, written counter) — serialization/gzip and writer.write
@@ -749,8 +761,10 @@ class CPUProfiler:
         concurrent write(): FileProfileWriter is one open/write per call,
         RemoteProfileWriter's gzip is pure and its sink buffer locked)."""
         try:
+            t0 = time.monotonic()
             with self._write_mu:
                 labels = self._labels_for(pid)
+            tally[0] += time.monotonic() - t0
             if labels is None:
                 self.process_last_errors[pid] = None
                 return False  # relabeling dropped this target
@@ -764,18 +778,41 @@ class CPUProfiler:
             self.process_last_errors[pid] = e
             raise
 
-    def _write_profile(self, prof: PidProfile) -> None:
+    def _write_profiles(self, profiles) -> int:
+        """Ship PidProfiles through the scalar builder."""
         # compress=False: the writer owns gzip framing (gzipping here too
         # double-compressed every profile).
-        self._write_one(prof.pid,
-                        lambda: build_pprof(prof, compress=False))
+        return self._write_all(
+            (p.pid, lambda p=p: build_pprof(p, compress=False))
+            for p in profiles)
 
     def _write_encoded(self, out) -> int:
         """Ship [(pid, blob)] from the fast encoder through the writer."""
+        return self._write_all((pid, lambda b=blob: b) for pid, blob in out)
+
+    def _write_all(self, items) -> int:
+        """One window's ship: every (pid, payload) through _write_one.
+        What the ship is made of is summed where the work is (the label
+        lookup here, gzip and enqueue in the writer) and recorded once,
+        as children of the ship span open on this thread: 12,500 spans
+        a window would cost more than they tell."""
         n = 0
-        for pid, blob in out:
-            if self._write_one(pid, lambda b=blob: b):
-                n += 1
+        tally = [0.0]
+        take = getattr(self._writer, "take_ship_clocks", None)
+        try:
+            for pid, payload in items:
+                if self._write_one(pid, payload, tally):
+                    n += 1
+        finally:
+            trace_mod.note("ship_labels", tally[0], accumulated=True)
+            trace_mod.count(profiles=n)
+            if take is not None:
+                c = take()
+                trace_mod.note("ship_gzip", c["gzip_s"], accumulated=True)
+                trace_mod.note("ship_enqueue", c["enqueue_s"],
+                               accumulated=True)
+                trace_mod.count(pprof_bytes=c["pprof_bytes"],
+                                gzip_bytes=c["gzip_bytes"])
         return n
 
     def _ship_encoded(self, out, prep) -> None:
@@ -802,8 +839,7 @@ class CPUProfiler:
             # gaps during fallback storms are observable.
             self._sinks.count_skipped()
         profiles = self._fallback.aggregate(snapshot)
-        for prof in profiles:
-            self._write_profile(prof)
+        self._write_profiles(profiles)
         return len(profiles)
 
     # palint: fail-open
@@ -880,7 +916,7 @@ class CPUProfiler:
             # spans from the SAME numbers its stats export (lockstep).
             fed = self._feeder.stats.get("last_window_feed_s", 0.0)
             if fed:
-                tr.add_span("feed", fed)
+                tr.add_span("feed", fed, accumulated=True)
             # The double-buffer overlap split (docs/perf.md "sub-RTT
             # close"): capture-thread seconds spent DISPATCHING feeds —
             # work whose device execution overlaps capture instead of
@@ -888,7 +924,7 @@ class CPUProfiler:
             # this span; the overlap is visible in /debug/windows.
             disp = self._feeder.stats.get("last_window_dispatch_s", 0.0)
             if disp:
-                tr.add_span("feed_dispatch_overlap", disp)
+                tr.add_span("feed_dispatch_overlap", disp, accumulated=True)
             # The ingest-wall split (docs/perf.md "ingest wall"): what
             # this window's drains spent HASHING batches vs COALESCING
             # them to (stack, weight) pairs. Same lockstep contract as
@@ -897,29 +933,19 @@ class CPUProfiler:
             # so an empty or fallback window records nothing stale.
             hsh = self._feeder.stats.get("last_window_hash_s", 0.0)
             if hsh:
-                tr.add_span("feed_hash", hsh)
+                tr.add_span("feed_hash", hsh, accumulated=True)
             co = self._feeder.stats.get("last_window_coalesce_s", 0.0)
             if co:
-                tr.add_span("feed_coalesce", co)
+                tr.add_span("feed_coalesce", co, accumulated=True)
             ca = self._feeder.stats.get("last_window_carry_s", 0.0)
             if ca:
-                tr.add_span("feed_carry", ca)
+                tr.add_span("feed_carry", ca, accumulated=True)
             if self._feeder.stats.get("last_window_streamed", 0):
                 tr.add_span("fetch",
                             self._feeder.stats.get("last_close_s", 0.0))
         if kind == "counts":
-            # Buffer-flip and delta-fetch spans come from the close that
-            # just ran (streamed or one-shot): the aggregator's timings
-            # dict carries buffer_flip on every double-buffered close and
-            # delta_fetch only when THIS close fetched touched blocks
-            # instead of the full prefix (dict.py close_collect).
-            tim = getattr(self._aggregator, "timings", None) or {}
-            flip = tim.get("buffer_flip", 0.0)
-            if flip:
-                tr.add_span("buffer_flip", flip)
-            delta = tim.get("delta_fetch", 0.0)
-            if delta:
-                tr.add_span("delta_fetch", delta)
+            # (buffer_flip and delta_fetch are children of the close,
+            # recorded where they run: dict.py close_dispatch/collect.)
             n_piped = self._submit_to_pipeline(out, snapshot, tr)
             if n_piped is not None:
                 self.metrics.samples_aggregated += snapshot.total_samples()
@@ -948,8 +974,7 @@ class CPUProfiler:
             tr.annotate(path="scalar-fallback",
                         fallback_reason=fallback_reason)
             with tr.span("ship"):
-                for prof in out:
-                    self._write_profile(prof)
+                self._write_profiles(out)
             return len(out)
         tr.annotate(path="inline")
         try:
@@ -1090,7 +1115,10 @@ class CPUProfiler:
                 if not self.run_iteration():
                     return
                 elapsed = time.monotonic() - t0
-                self._stop.wait(max(0.0, self._duration - elapsed))
+                # The wait for the next period, on the device trace's
+                # clock: the chip's idle time under it is headroom.
+                with trace_mod.annotation("sleep"):
+                    self._stop.wait(max(0.0, self._duration - elapsed))
         except BaseException as e:
             # Anything escaping run_iteration is a bug, not an iteration
             # failure; record it so the CLI can exit nonzero instead of

@@ -93,7 +93,7 @@ class EncodePipeline:
         self._snapshot = snapshot
         self._snapshot_every = snapshot_every
         self._cond = threading.Condition()
-        self._window = None   # pending (prep, ctx, fallback, trace) hand-off
+        self._window = None   # pending (prep, ctx, fallback, trace, t) hand-off
         self._prebuild = None        # latest coalesced (period_ns, budget_s)
         self._state = "idle"         # idle | encode | prebuild
         self._handoff = False        # profiler parked the worker
@@ -138,7 +138,9 @@ class EncodePipeline:
         if self.disabled or self._stopping:
             return None
         t0 = time.perf_counter()
-        with self._cond:
+        # The capture thread's wait for the worker to park (a prebuild
+        # yields at its next batch): wide-event only.
+        with trace.span("handoff_wait", histogram=False), self._cond:
             if self._state == "encode" or self._window is not None:
                 self.stats["backpressure_fallbacks"] += 1
                 return None
@@ -192,7 +194,9 @@ class EncodePipeline:
             # _handoff first would let a pending prebuild slip in ahead
             # of the window (with _interrupt already cleared, nothing
             # would yield it) and delay the encode by a whole budget.
-            self._window = (prep, rollup_ctx, fallback, trace)
+            # The worker spans its wait from this clock read (encode_wait).
+            self._window = (prep, rollup_ctx, fallback, trace,
+                            time.monotonic())
             self._handoff = False
             self._interrupt.clear()
             self._cond.notify_all()
@@ -325,8 +329,11 @@ class EncodePipeline:
                       error=repr(e))
 
     def _do_window(self, prep, rollup_ctx, fallback,
-                   trace=NULL_TRACE) -> None:
-        t0 = time.perf_counter()
+                   trace=NULL_TRACE, t_handoff: float | None = None) -> None:
+        t0 = time.monotonic()
+        if t_handoff is not None:
+            # How long the prepared window waited for this worker.
+            trace.add_span("encode_wait", t0 - t_handoff, start_s=t_handoff)
         # Chaos site: an injected crash here is a worker death — the
         # window ships via the caller's fallback, the pipeline disables,
         # and the supervisor's probe revives it.
@@ -338,7 +345,7 @@ class EncodePipeline:
         statics0 = getattr(self._enc, "stats", {}).get(
             "statics_build_s_total", 0.0)
         out = self._enc.encode_prepared(prep, views=self._views)
-        enc_s = time.perf_counter() - t0
+        enc_s = time.monotonic() - t0
         self.stats["last_encode_s"] = enc_s
         self.stats["overlap_s_total"] += enc_s
         statics_s = getattr(self._enc, "stats", {}).get(
@@ -348,11 +355,15 @@ class EncodePipeline:
             # call into the "statics" stage histogram; this span is the
             # per-window wide-event view only (double-feeding the same
             # seconds would distort the distribution).
-            trace.add_span("statics", statics_s, histogram=False)
-        trace.add_span("encode", enc_s)
-        t0 = time.perf_counter()
+            trace.add_span("statics", statics_s, histogram=False,
+                           start_s=t0, accumulated=True)
+        trace.add_span("encode", enc_s, start_s=t0)
         try:
-            self._ship(out, prep)
+            # A context span: what the ship is made of is recorded under
+            # it (profiler/cpu.py _write_all), and a failed ship's span
+            # carries the error.
+            with trace.span("ship") as sp_ship:
+                self._ship(out, prep)
         except Exception as e:  # noqa: BLE001 - ship != encoder failure
             # A writer error is NOT an encoder failure: the template is
             # healthy, re-shipping via the fallback would duplicate the
@@ -363,13 +374,9 @@ class EncodePipeline:
             self.stats["ship_errors"] += 1
             _log.warn("pipelined ship failed; window partially shipped",
                       error=repr(e))
-            trace.add_span("ship", time.perf_counter() - t0,
-                           error=repr(e)[:200])
             trace.complete(error=f"ship failed: {e!r}"[:200])
             return
-        ship_s = time.perf_counter() - t0
-        self.stats["last_ship_s"] = ship_s
-        trace.add_span("ship", ship_s)
+        self.stats["last_ship_s"] = sp_ship.duration_s
         self.stats["windows_pipelined"] += 1
         trace.complete()
         if self._rollup is not None and (rollup_ctx is not None

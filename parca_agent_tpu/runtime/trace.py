@@ -8,10 +8,16 @@ minutes, a multi-second statics rebuild) had to be reconstructed from
 logs after the fact. This module is the always-on
 instrumentation substrate (docs/observability.md):
 
-  * ``WindowTrace`` — one trace per window, trace id = window seq,
-    carrying per-stage spans (drain, close, feed, fetch, prepare,
-    statics, encode, ship, symbolize, total) recorded by the profiler
-    loop, the encode pipeline's worker, and the encoder.
+  * ``WindowTrace`` — one trace per window, trace id = window seq: a
+    tree of spans on one clock (``time.monotonic()``; the trace exports
+    its ``t0_monotonic_s``). The stages of the window (drain, identity,
+    close, prepare, encode_wait, encode, ship, total, ...) are recorded
+    by the profiler loop and the encode pipeline's worker; what happens
+    inside a stage (the aggregator's hash, pack, dispatch, fetch and
+    unpack under ``close``; labels, gzip and enqueue under ``ship``) is
+    recorded where the work is through :func:`child`, with the stage
+    open on that thread as parent. While a ``jax.profiler`` session
+    runs, the same spans show in its trace as ``pa/<stage>``.
   * ``FlightRecorder`` — a bounded ring of completed traces (the flight
     recorder `/debug/windows` serves as wide-event JSON) plus one
     streaming log-bucket histogram per stage (p50/p90/p99/max), exported
@@ -35,15 +41,20 @@ prove exactly that.
 Like ``utils/faults.py``, a process-global recorder can be installed so
 deep components (batch client, spool, gRPC client, encoder) observe
 stage durations without plumbing: production pays one module-attribute
-read per site when tracing is off.
+read per site when tracing is off. :func:`child`, :func:`note` and
+:func:`count` follow the same pattern through a per-thread stack of the
+open spans.
 """
 
 from __future__ import annotations
 
 import base64
 import collections
+import contextlib
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 
@@ -136,28 +147,103 @@ class StageHistogram:
         }
 
 
+# One clock for every span of every window: ``time.monotonic()``. It is
+# the machine's, so a span's ``t0_monotonic_s + start_s`` lies on one
+# line with another window's, with an outside reader's own stamps, and
+# (through the ``pa/*`` annotations below) with the device trace.
+_clock = time.monotonic
+
+# Per-thread stack of the spans open on this thread (innermost last):
+# a span's parent is the top of its thread's stack when it begins.
+_tls = threading.local()
+
+
+def _stack() -> list:
+    stack = getattr(_tls, "stack", None)
+    if stack is None:
+        stack = _tls.stack = []
+    return stack
+
+
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def annotation(stage: str, **kv):
+    """``jax.profiler.TraceAnnotation("pa/<stage>", **kv)`` when JAX is
+    already imported, else a context manager that does nothing: the
+    annotation lands on the host plane of the same ``.xplane.pb`` as the
+    device's programs, so a profiler session shows what the host did
+    while the chip idled. Never imports JAX (``--aggregator cpu`` stays
+    JAX-free) and costs a no-op TraceMe while no profiler session runs."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return _NO_ANNOTATION
+    try:
+        return jax.profiler.TraceAnnotation("pa/" + stage, **kv)
+    except Exception:  # noqa: BLE001 - tracing is fail-open
+        return _NO_ANNOTATION
+
+
 class _SpanCtx:
     """Context manager for one timed span. Always measures (the gauges
     that must stay in lockstep with the histograms read .duration_s even
     when tracing is disabled); recording is the trace's problem and is
-    fail-open there. User exceptions are recorded and re-raised."""
+    fail-open there. User exceptions are recorded and re-raised.
 
-    __slots__ = ("_trace", "_stage", "_t0", "duration_s")
+    While open it sits on its thread's stack, so spans begun inside it
+    (``trace.span`` of the same window, ``child`` from deep components)
+    name it as their parent. ``merge`` marks a child: a second span of
+    the same stage under the same parent adds to the first."""
 
-    def __init__(self, trace, stage: str):
+    __slots__ = ("_trace", "_stage", "_hist", "_merge", "_ann", "id",
+                 "parent", "start_s", "duration_s")
+
+    def __init__(self, trace, stage: str, histogram: bool = True,
+                 merge: bool = False):
         self._trace = trace
         self._stage = stage
-        self.duration_s = 0.0
+        self._hist = histogram
+        self._merge = merge
+        self._ann = _NO_ANNOTATION
+        self.id = self.parent = None
+        self.start_s = self.duration_s = 0.0
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
+        tr = self._trace
+        if tr is not NULL_TRACE:
+            try:
+                stack = _stack()
+                top = stack[-1] if stack else None
+                if top is not None and top._trace is tr:
+                    self.parent = top.id
+                self.id = tr.new_id()
+                stack.append(self)
+                self._ann = annotation(self._stage, window=tr.seq)
+                self._ann.__enter__()
+            except Exception as e:  # noqa: BLE001 - tracing is fail-open
+                tr._rec._record_error(e)
+        self.start_s = _clock()
         return self
 
     def __exit__(self, et, ev, tb):
-        self.duration_s = time.perf_counter() - self._t0
-        self._trace.add_span(
+        self.duration_s = _clock() - self.start_s
+        tr = self._trace
+        if tr is NULL_TRACE:
+            return False
+        try:
+            self._ann.__exit__(et, ev, tb)
+            stack = _stack()
+            if stack and stack[-1] is self:
+                stack.pop()
+            elif self in stack:
+                stack.remove(self)
+        except Exception as e:  # noqa: BLE001 - tracing is fail-open
+            tr._rec._record_error(e)
+        tr.add_span(
             self._stage, self.duration_s,
-            error=(repr(ev)[:200] if ev is not None else None))
+            error=(repr(ev)[:200] if ev is not None else None),
+            histogram=self._hist, start_s=self.start_s,
+            parent=self.parent, span_id=self.id, merge=self._merge)
         return False
 
 
@@ -169,14 +255,17 @@ class _NullTrace:
     completed = True
     detached = False
 
-    def span(self, stage: str) -> _SpanCtx:
+    def span(self, stage: str, histogram: bool = True) -> _SpanCtx:
         return _SpanCtx(self, stage)
 
     def add_span(self, stage, duration_s, error=None,
-                 histogram=True) -> None:
+                 histogram=True, **_kw) -> None:
         pass
 
     def annotate(self, **kv) -> None:
+        pass
+
+    def count(self, **kv) -> None:
         pass
 
     def detach(self) -> None:
@@ -200,43 +289,84 @@ class WindowTrace:
     profiler thread; ownership may transfer to the encode pipeline's
     worker (detach) — the hand-off lock gives the happens-before edge,
     so spans never need their own lock. complete() is idempotent and
-    routes through the recorder (ring + histograms + slow detector)."""
+    routes through the recorder (ring + histograms + slow detector).
 
-    __slots__ = ("seq", "time_ns", "t0_s", "spans", "meta", "error",
-                 "completed", "detached", "_rec")
+    Every span is a dict ``{id, parent, stage, start_s, duration_s,
+    thread}``: ``start_s`` counts from ``t0_monotonic_s`` (the trace's
+    birth on ``time.monotonic()``), ``parent`` is the id of the span of
+    this window that was open on the same thread when this one began
+    (None at the top level), and ``accumulated`` marks a duration
+    summed over several intervals."""
+
+    __slots__ = ("seq", "time_ns", "t0_monotonic_s", "spans", "meta",
+                 "error", "completed", "detached", "_rec", "_ids",
+                 "_compiles0")
 
     def __init__(self, rec, seq: int, time_ns: int):
         self._rec = rec
         self.seq = seq
         self.time_ns = time_ns
-        self.t0_s = time.perf_counter()
+        self.t0_monotonic_s = _clock()
         self.spans: list[dict] = []
         self.meta: dict = {}
         self.error: str | None = None
         self.completed = False
         self.detached = False
+        self._ids = itertools.count(1)
+        self._compiles0 = _xla_compile_requests()
 
-    def span(self, stage: str) -> _SpanCtx:
-        return _SpanCtx(self, stage)
+    def new_id(self) -> int:
+        return next(self._ids)  # atomic under the GIL: threads share it
+
+    def span(self, stage: str, histogram: bool = True) -> _SpanCtx:
+        return _SpanCtx(self, stage, histogram)
 
     # palint: fail-open
     def add_span(self, stage: str, duration_s: float,
                  error: str | None = None,
-                 histogram: bool = True) -> None:
-        """Record one span; fail-open (a tracing fault must never cost
-        the window — the trace.record chaos site injects exactly here).
+                 histogram: bool = True, *,
+                 start_s: float | None = None,
+                 parent: int | None = None,
+                 accumulated: bool = False,
+                 span_id: int | None = None,
+                 merge: bool = False) -> None:
+        """Record one span, at its end; fail-open (a tracing fault must
+        never cost the window — the trace.record chaos site injects
+        exactly here). EVERY span of a window goes through this one
+        method, looked up on the class at call time: an outside reader
+        that wants its own clock at a stage's end wraps it.
+
         ``histogram=False`` keeps the span out of the stage histograms
         at completion: for stages whose histogram is fed elsewhere
         (the encoder observes each statics build per call; the worker's
-        per-window statics span would double-count it)."""
+        per-window statics span would double-count it) and for children,
+        which are wide-event only. ``start_s`` is the span's start on
+        ``time.monotonic()``; without it the span is taken to end now.
+        ``accumulated`` says the duration is a sum of several intervals
+        that began at ``start_s``. ``merge`` adds the duration to a span
+        of the same stage and parent if the window already has one (a
+        feed in chunks is one ``feed_hash`` span, then accumulated)."""
         try:
             faults.inject("trace.record")
-            now = time.perf_counter()
+            if parent is not None and self.completed:
+                return  # a child of an abandoned call, after the fact
+            dur = float(duration_s)
+            if merge:
+                for s in self.spans:
+                    if s["stage"] == stage and s["parent"] == parent:
+                        s["duration_s"] = round(s["duration_s"] + dur, 6)
+                        s["accumulated"] = True
+                        return
+            if start_s is None:
+                start_s = _clock() - dur
             self.spans.append({
+                "id": span_id if span_id is not None else self.new_id(),
+                "parent": parent,
                 "stage": stage,
-                "start_s": round(max(0.0, now - duration_s - self.t0_s), 6),
-                "duration_s": round(float(duration_s), 6),
+                "start_s": round(max(0.0, start_s - self.t0_monotonic_s), 6),
+                "duration_s": round(dur, 6),
                 "thread": threading.current_thread().name,
+                **({"accumulated": True} if accumulated else {}),
                 **({} if histogram else {"nohist": True}),
                 **({"error": error} if error else {}),
             })
@@ -255,6 +385,19 @@ class WindowTrace:
             # two unlocked rebinds would lose one writer's keys.
             with self._rec._lock:
                 self.meta = {**self.meta, **kv}
+        except Exception as e:  # noqa: BLE001 - tracing is fail-open
+            self._rec._record_error(e)
+
+    # palint: fail-open
+    def count(self, **kv) -> None:
+        """Add to the window's counts in ``meta`` (rows fed, misses,
+        bytes shipped): counts taken where the work happens, summed
+        over a window that does the work in several calls."""
+        try:
+            with self._rec._lock:
+                self.meta = {**self.meta,
+                             **{k: self.meta.get(k, 0) + v
+                                for k, v in kv.items()}}
         except Exception as e:  # noqa: BLE001 - tracing is fail-open
             self._rec._record_error(e)
 
@@ -289,9 +432,12 @@ class WindowTrace:
         d = {
             "seq": self.seq,
             "time_ns": self.time_ns,
+            "t0_monotonic_s": round(self.t0_monotonic_s, 6),
             "complete": self.completed,
+            # No total yet: the top-level spans (children lie inside).
             "duration_s": total if total is not None else round(
-                sum(s["duration_s"] for s in self.spans), 6),
+                sum(s["duration_s"] for s in self.spans
+                    if s["parent"] is None), 6),
             "spans": list(self.spans),
         }
         if self.meta:
@@ -299,6 +445,15 @@ class WindowTrace:
         if self.error:
             d["error"] = self.error
         return d
+
+
+def _xla_compile_requests() -> float:
+    """``parca_agent_xla_compile_requests_total`` as the installed
+    device telemetry counts it; 0 without one (a numpy-only agent never
+    loads that module, and this never imports it)."""
+    dtel = sys.modules.get("parca_agent_tpu.runtime.device_telemetry")
+    tel = dtel.get() if dtel is not None else None
+    return tel.xla["compile_requests_total"] if tel is not None else 0
 
 
 class FlightRecorder:
@@ -389,13 +544,21 @@ class FlightRecorder:
                 trace.completed = True
             if error:
                 trace.error = error
-            total_s = time.perf_counter() - trace.t0_s
+            total_s = _clock() - trace.t0_monotonic_s
             trace.spans.append({
+                "id": trace.new_id(),
+                "parent": None,
                 "stage": "total",
                 "start_s": 0.0,
                 "duration_s": round(total_s, 6),
                 "thread": threading.current_thread().name,
             })
+            compiles = _xla_compile_requests() - trace._compiles0
+            if compiles:
+                # Put a compile down to the window it fell in (a
+                # pipelined window lasts through its ship, so a compile
+                # in the next window's close shows in both).
+                trace.count(xla_compiles=int(compiles))
             worst = None  # (ratio, stage, duration, budget)
             with self._lock:
                 for s in trace.spans:
@@ -605,8 +768,11 @@ class FlightRecorder:
     def traces(self, limit: int | None = None) -> list[dict]:
         """The ring, oldest first, as wide-event dicts (/debug/windows)."""
         with self._lock:
-            out = [t.to_dict() for t in self._ring]
-        return out[-limit:] if limit else out
+            # Slice before building dicts: the benchmark's harness asks
+            # for ?limit=1 fifty times a second over a ring of thousands.
+            ring = self._ring if not limit else reversed(
+                list(itertools.islice(reversed(self._ring), limit)))
+            return [t.to_dict() for t in ring]
 
     def trace(self, seq: int) -> dict | None:
         with self._lock:
@@ -658,3 +824,77 @@ def observe(stage: str, duration_s: float) -> None:
     encoder): free when no recorder is installed."""
     if _active is not None:
         _active.observe(stage, duration_s)
+
+
+# -- deep components: children of whatever span is open on this thread --------
+
+
+def current() -> _SpanCtx | None:
+    """The innermost span open on the calling thread, or None."""
+    stack = getattr(_tls, "stack", None)
+    return stack[-1] if stack else None
+
+
+def child(stage: str, histogram: bool = False) -> _SpanCtx:
+    """A context manager that times ``stage`` as a child of the
+    innermost span open on the calling thread (the aggregator's hash,
+    pack, dispatch, fetch and unpack inside the profiler's ``close``).
+    It always measures ``.duration_s``, so the site's other consumers
+    (``timings[...]``, ``dtel.record``) take that number and the clock
+    is read once; with nothing open (library use, a disabled recorder)
+    it records nowhere and costs one attribute read more than the
+    clock. Children are wide-event only unless ``histogram`` is set:
+    no ``/metrics`` series, no slow-window budget."""
+    stack = getattr(_tls, "stack", None)
+    if not stack:
+        return _SpanCtx(NULL_TRACE, stage)
+    return _SpanCtx(stack[-1]._trace, stage, histogram, merge=True)
+
+
+def note(stage: str, duration_s: float, start_s: float | None = None,
+         accumulated: bool = False, histogram: bool = False) -> None:
+    """Record an interval measured elsewhere as a child of the innermost
+    span open on the calling thread. An ``accumulated`` one (a sum kept
+    where the work is, over the parent's many calls) starts where its
+    parent starts; any other is taken to end now unless ``start_s``
+    says when it began."""
+    top = current()
+    if top is None or top._trace is NULL_TRACE:
+        return
+    if start_s is None and accumulated:
+        start_s = top.start_s
+    top._trace.add_span(stage, duration_s, histogram=histogram,
+                        start_s=start_s, parent=top.id,
+                        accumulated=accumulated, merge=True)
+
+
+def count(**kv) -> None:
+    """Add to the counts of the window whose span is open on the
+    calling thread (``WindowTrace.count``); nothing open, nothing done."""
+    top = current()
+    if top is not None:
+        top._trace.count(**kv)
+
+
+class adopt:
+    """Make ``ctx`` (a span open on another thread) the innermost open
+    span of this thread for the length of a ``with`` block: the device
+    watchdog runs the close on an abandonable thread of its own, and the
+    aggregator's children belong under the profiler's ``close``."""
+
+    __slots__ = ("_ctx",)
+
+    def __init__(self, ctx: _SpanCtx | None):
+        self._ctx = ctx
+
+    def __enter__(self):
+        if self._ctx is not None:
+            _stack().append(self._ctx)
+        return self
+
+    def __exit__(self, et, ev, tb):
+        if self._ctx is not None:
+            stack = _stack()
+            if self._ctx in stack:
+                stack.remove(self._ctx)
+        return False

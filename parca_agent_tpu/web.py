@@ -760,7 +760,12 @@ class AgentHTTPServer:
                     "stats": dict(outer.recorder.stats),
                     "stage_percentiles": outer.recorder.percentiles(),
                 }
-                self._send(200, json.dumps(body, indent=1).encode(),
+                # Compact: an indent sends json through its pure-Python
+                # encoder, ~4x the time, on the thread that fights the
+                # ship for the interpreter lock; a poller asks many
+                # times a second (pipe through `python -m json.tool`
+                # to read one; /debug/trace/<seq> stays indented).
+                self._send(200, json.dumps(body).encode(),
                            "application/json")
 
             def _debug_device(self, url):

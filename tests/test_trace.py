@@ -486,3 +486,325 @@ def test_encoder_statics_build_feeds_global_histogram():
         enc.encode(counts, snap.time_ns, snap.window_ns, snap.period_ns)
     finally:
         trace_mod.install(None)
+
+
+# -- one span tree on one clock (ISSUE 24) -----------------------------------
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+CLOSE_CHILDREN = ("feed_hash", "feed_coalesce", "feed_pack", "feed_dispatch",
+                  "feed_settle", "close_dispatch", "buffer_flip",
+                  "close_fetch", "close_unpack")
+SHIP_CHILDREN = ("ship_labels", "ship_gzip", "ship_enqueue")
+
+
+class RawSink:
+    """The batch client's side of RemoteProfileWriter."""
+
+    def __init__(self):
+        self.n = 0
+
+    def write_raw(self, labels, sample):
+        self.n += 1
+
+
+class _StubResolver:
+    def tenant_of(self, pid):
+        return "t0"
+
+
+def _traced_windows(pipelined: bool, n: int = 3, admission=None,
+                    identity=None):
+    """n windows through the real profiler on XLA:CPU, one at a time (a
+    pipelined window is shipped before the next is handed over)."""
+    from parca_agent_tpu.agent.writer import RemoteProfileWriter
+
+    rec = FlightRecorder()
+    prof = CPUProfiler(
+        source=ListSource([_snap(seed=5) for _ in range(n)]),
+        aggregator=DictAggregator(capacity=1 << 12),
+        fallback_aggregator=CPUAggregator(),
+        profile_writer=RemoteProfileWriter(RawSink()), duration_s=0.0,
+        fast_encode=True, encode_pipeline=pipelined, trace_recorder=rec,
+        admission=admission, identity=identity)
+    for _ in range(n):
+        assert prof.run_iteration()
+        assert prof.last_error is None
+        if pipelined:
+            assert prof._pipeline.flush(30.0)
+    if pipelined:
+        assert prof._pipeline.close(30.0)
+    traces = rec.traces()
+    assert len(traces) == n
+    assert {t["meta"]["path"] for t in traces} \
+        == {"pipeline" if pipelined else "inline"}
+    return rec, traces
+
+
+@pytest.mark.parametrize("pipelined", [True, False],
+                         ids=["pipelined", "inline"])
+def test_every_span_is_recorded_by_one_add_span_on_the_class(
+        pipelined, monkeypatch):
+    """The benchmark takes the end-to-end metric's two edges by
+    replacing ``WindowTrace.add_span`` on the class and reading
+    ``(seq, stage)`` from the call (benchmarks/lib/harness.py
+    EdgeClock). So every span of a window, context-managed or after
+    the fact, child or not, has to go through that one method, looked
+    up on the class when the span ends."""
+    seen = []
+    sound = trace_mod.WindowTrace.add_span
+
+    def add_span(window_trace, stage, *args, **kwargs):
+        seen.append((window_trace.seq, stage))
+        return sound(window_trace, stage, *args, **kwargs)
+
+    monkeypatch.setattr(trace_mod.WindowTrace, "add_span", add_span)
+    _rec, traces = _traced_windows(pipelined)
+    for t in traces:
+        stages = [s["stage"] for s in t["spans"] if s["stage"] != "total"]
+        assert {"drain", "close", "encode", "ship"} <= set(stages)
+        for stage in stages:
+            assert (t["seq"], stage) in seen, (t["seq"], stage)
+    # The two edges of window_to_pprof, in the order the metric needs.
+    last = traces[-1]["seq"]
+    assert seen.index((last, "drain")) < seen.index((last, "encode"))
+
+
+def test_the_span_tree_parents_intervals_and_honest_starts():
+    from parca_agent_tpu.process.identity import ProcessIdentityTracker
+    from parca_agent_tpu.runtime.admission import AdmissionController
+
+    before = time.monotonic()
+    rec, traces = _traced_windows(
+        True, admission=AdmissionController(_StubResolver()),
+        identity=ProcessIdentityTracker(starttime_of=lambda pid: 1))
+    after = time.monotonic()
+    for t in traces:
+        assert before <= t["t0_monotonic_s"] <= after
+        by_id = {s["id"]: s for s in t["spans"]}
+        assert len(by_id) == len(t["spans"])           # ids are unique
+        by_stage = {s["stage"]: s for s in t["spans"]}
+        assert len(by_stage) == len(t["spans"])        # one span a stage
+        for stage in ("drain", "identity", "admission", "close",
+                      "handoff_wait", "prepare", "encode_wait", "encode",
+                      "ship", "total"):
+            assert by_stage[stage]["parent"] is None, stage
+        for stage in CLOSE_CHILDREN:
+            assert by_stage[stage]["parent"] == by_stage["close"]["id"], stage
+        for stage in SHIP_CHILDREN:
+            assert by_stage[stage]["parent"] == by_stage["ship"]["id"], stage
+            assert by_stage[stage]["accumulated"] is True
+        for s in t["spans"]:
+            assert s["start_s"] >= 0 and s["duration_s"] >= 0
+            # Every span ended before the trace was read.
+            assert t["t0_monotonic_s"] + s["start_s"] + s["duration_s"] \
+                <= after + 1e-3
+            if s["parent"] is not None:
+                p = by_id[s["parent"]]
+                assert s["start_s"] >= p["start_s"] - 2e-6, s["stage"]
+                assert s["start_s"] + s["duration_s"] \
+                    <= p["start_s"] + p["duration_s"] + 2e-6, s["stage"]
+        for parent, kids in (("close", CLOSE_CHILDREN),
+                             ("ship", SHIP_CHILDREN)):
+            assert by_stage[parent]["duration_s"] - sum(
+                by_stage[k]["duration_s"] for k in kids) >= -1e-5, parent
+        # The stages of the capture thread follow one another, and the
+        # after-the-fact spans start where the work started: the wait
+        # for the worker begins at the hand-off and ends where the
+        # encode begins; the ship begins where the encode ended.
+        order = ["drain", "identity", "admission", "close", "handoff_wait",
+                 "prepare", "encode_wait", "encode", "ship"]
+        for a, b in zip(order, order[1:]):
+            assert by_stage[a]["start_s"] + by_stage[a]["duration_s"] \
+                <= by_stage[b]["start_s"] + 2e-6, (a, b)
+        wait, enc = by_stage["encode_wait"], by_stage["encode"]
+        assert wait["start_s"] + wait["duration_s"] \
+            == pytest.approx(enc["start_s"], abs=1e-4)
+        assert wait["thread"] == enc["thread"] != by_stage["close"]["thread"]
+        meta = t["meta"]
+        assert meta["rows"] == 200 and 0 < meta["rows_fed"] <= 200
+        assert meta["profiles"] == 6
+        assert 0 < meta["gzip_bytes"] < meta["pprof_bytes"]
+    # The first window met every stack for the first time.
+    first = {s["stage"]: s for s in traces[0]["spans"]}
+    assert first["feed_miss"]["parent"] == first["close"]["id"]
+    assert traces[0]["meta"]["misses"] == traces[0]["meta"]["rows_fed"]
+    assert traces[-1]["meta"]["misses"] == 0
+    # Children are wide-event only: no histogram, so no /metrics series.
+    hists = set(rec.export_histograms())
+    assert {"identity", "admission", "encode_wait"} <= hists
+    assert not hists & {*CLOSE_CHILDREN, *SHIP_CHILDREN, "handoff_wait",
+                        "feed_miss"} - {"buffer_flip"}
+
+
+def test_a_stage_in_chunks_is_one_accumulated_span():
+    rec = FlightRecorder()
+    tr = rec.begin()
+    with tr.span("close") as close:
+        for _ in range(3):
+            with trace_mod.child("feed_hash") as sp:
+                time.sleep(0.001)
+            assert sp.duration_s >= 0.001
+        trace_mod.count(rows_fed=5)
+        trace_mod.count(rows_fed=7)
+    tr.complete()
+    t = rec.traces()[-1]
+    (fh,) = [s for s in t["spans"] if s["stage"] == "feed_hash"]
+    assert fh["accumulated"] is True and fh["parent"] == close.id
+    assert 0.003 <= fh["duration_s"] <= close.duration_s
+    assert t["meta"]["rows_fed"] == 12
+
+
+def test_child_with_nothing_open_measures_and_records_nowhere():
+    rec = FlightRecorder()
+    trace_mod.install(rec)
+    with trace_mod.child("feed_hash") as sp:
+        time.sleep(0.002)
+    assert sp.duration_s >= 0.002
+    trace_mod.note("ship_gzip", 0.5, accumulated=True)
+    trace_mod.count(rows_fed=3)
+    assert rec.traces() == [] and rec.stats["record_errors"] == 0
+    assert trace_mod.current() is None
+
+
+@pytest.mark.chaos
+def test_child_is_fail_open_under_the_trace_record_chaos_site():
+    rec = FlightRecorder()
+    tr = rec.begin()
+    with tr.span("close"):
+        faults.install(faults.FaultInjector.from_spec("trace.record:error"))
+        with trace_mod.child("feed_hash") as sp:
+            time.sleep(0.001)
+        trace_mod.note("delta_fetch", 0.001)
+        faults.install(None)
+    assert sp.duration_s >= 0.001           # measured all the same
+    assert rec.stats["record_errors"] >= 2  # counted, never raised
+    tr.complete()
+    assert [s["stage"] for s in rec.traces()[-1]["spans"]] \
+        == ["close", "total"]
+    assert trace_mod.current() is None      # nothing left on the stack
+
+
+def test_traces_limit_builds_only_what_it_returns(monkeypatch):
+    rec = FlightRecorder(ring=64)
+    for _ in range(40):
+        rec.begin().complete()
+    built = []
+    sound = trace_mod.WindowTrace.to_dict
+    monkeypatch.setattr(trace_mod.WindowTrace, "to_dict",
+                        lambda self: built.append(self.seq) or sound(self))
+    assert [t["seq"] for t in rec.traces(limit=2)] == [39, 40]
+    assert built == [39, 40]
+    assert len(rec.traces()) == 40
+
+
+def test_fallback_duration_sums_top_level_spans_only():
+    rec = FlightRecorder()
+    tr = rec.begin()
+    with tr.span("close"):
+        with trace_mod.child("feed_hash"):
+            time.sleep(0.002)
+    d = tr.to_dict()                         # no total yet
+    (close,) = [s for s in d["spans"] if s["stage"] == "close"]
+    assert d["duration_s"] == close["duration_s"]
+
+
+@pytest.mark.parametrize("metric, program", [
+    ("feed_probe_roofline", "feed"),
+    ("close_roofline", "close"),
+    ("close_roofline", "close_delta"),
+])
+def test_device_program_names_match_the_benchmarks_patterns(metric, program):
+    """``benchmarks/metrics/*_roofline.json`` find the device programs
+    by the names XLA gives them; the named scopes inside the programs
+    must not change those."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from parca_agent_tpu.aggregator import dict as dict_mod
+
+    with open(os.path.join(REPO, "benchmarks", "metrics",
+                           metric + ".json")) as f:
+        pattern = json.load(f)["args"]["pattern"]
+    u32 = jax.ShapeDtypeStruct
+    if program == "feed":
+        lowered = dict_mod._feed_program(1024, 512, 64, 4, 128, "lax").lower(
+            u32((1024, 4), jnp.uint32), u32((512,), jnp.int32),
+            u32((4,), jnp.int32), u32((4, 64), jnp.uint32),
+            u32((), jnp.uint32))
+    elif program == "close":
+        lowered = dict_mod._close_program(512, 512, 8, 16).lower(
+            u32((512,), jnp.int32))
+    else:
+        lowered = dict_mod._close_program_delta(512, 512, 8, 16, 2, 128) \
+            .lower(u32((512,), jnp.int32), u32((4,), jnp.int32))
+    text = lowered.as_text()
+    name = re.search(r"module @(\S+)", text).group(1)
+    assert re.search(pattern, name), (pattern, name)
+    scope = {"feed": "probe", "close": "pack",
+             "close_delta": "touched_blocks"}[program]
+    assert scope in lowered.as_text(debug_info=True)
+
+
+def test_a_traced_window_without_a_device_aggregator_never_imports_jax():
+    """``--aggregator cpu`` stays JAX-free: the ``pa/*`` annotations are
+    entered only when something else already imported JAX."""
+    import subprocess
+    import sys
+
+    code = """
+import sys
+import parca_agent_tpu.runtime.trace as trace_mod
+from parca_agent_tpu.aggregator.cpu import CPUAggregator
+from parca_agent_tpu.capture.synthetic import SyntheticSpec, generate
+from parca_agent_tpu.profiler.cpu import CPUProfiler
+
+class Source:
+    def __init__(self):
+        self.left = [generate(SyntheticSpec(n_pids=3, n_unique_stacks=40,
+                                            n_rows=40, total_samples=160,
+                                            seed=1))]
+    def poll(self):
+        return self.left.pop() if self.left else None
+
+class Writer:
+    def write(self, labels, blob):
+        pass
+
+rec = trace_mod.FlightRecorder()
+trace_mod.install(rec)
+prof = CPUProfiler(source=Source(), aggregator=CPUAggregator(),
+                   profile_writer=Writer(), duration_s=0.0,
+                   trace_recorder=rec)
+prof.run()
+(t,) = rec.traces()
+assert {"drain", "close", "ship", "total"} <= {s["stage"] for s in t["spans"]}
+assert "jax" not in sys.modules, "jax was imported"
+print("ok")
+"""
+    out = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                         capture_output=True, text=True, timeout=120,
+                         env={**os.environ, "PYTHONPATH": REPO})
+    assert out.returncode == 0 and out.stdout.strip() == "ok", out.stderr
+
+
+def test_a_compile_is_put_down_to_the_window_it_fell_in():
+    from parca_agent_tpu.runtime import device_telemetry as dtel
+
+    tel = dtel.DeviceTelemetry()
+    dtel.install(tel)
+    try:
+        rec = FlightRecorder()
+        quiet = rec.begin()
+        quiet.complete()
+        busy = rec.begin()
+        tel.note_xla("compile_requests_total")
+        tel.note_xla("compile_requests_total")
+        busy.complete()
+    finally:
+        dtel.install(None)
+    first, second = rec.traces()
+    assert "xla_compiles" not in first.get("meta", {})   # only when not 0
+    assert second["meta"]["xla_compiles"] == 2
