@@ -25,16 +25,49 @@ unreadable stat, a raising invalidator, or an injected fault
 (``process.identity``) is counted and the window proceeds unhardened
 rather than lost.
 
+The check is made once per DISTINCT pid, in bulk. The profiler hands
+over the per-row pid column (262,144 rows at firehose size); it is
+reduced with ``np.unique``, so no Python statement runs per row. With
+the procfs reader, one ``listdir("/proc")`` a window then says which of
+the window's pids exist: a pid that is not listed takes the "exited
+mid-window" branch (an error counted, the remembered generation kept,
+``absent_total``) without an open, and only a listed pid gets its
+bounded stat read. The table and the counters are updated under one
+lock acquisition a window, and ``reused`` comes back in ascending pid
+order.
+
+Why a pid absent from the listing needs no read: a recycled pid's stale
+state must be invalidated before any sample of the NEW generation
+resolves. The samples in hand were drained before ``observe_window``
+runs, so they belong to whatever held the pid during the window. A pid
+missing from the listing had exited by then; if it is forked again
+between the listing and the read the per-pid code would have made, its
+first samples can only arrive with the NEXT drain, when it is listed,
+read, and found to differ from the remembered generation. The evidence
+stays ``(pid, starttime)`` for every live pid in every window: no
+watermark on ``ns_last_pid``, no cache of "checked recently", no
+sampling of pids — those would be a weaker check, not a faster one. A
+listing that fails falls back to a read per pid (fail-open, same
+result), and an injected ``starttime_of`` is the world: it is asked for
+every distinct pid and no listing is made. (``/proc/<n>/stat`` opens
+for a number that is only a THREAD id although ``/proc`` does not list
+it; such a number is settled as absent. The capture's pid column holds
+thread-group ids, listed while any thread lives.)
+
 ``PARCA_NO_PID_GENERATION=1`` pins the hardening off — the bench zoo's
 misattribution control arm, same idiom as PARCA_NO_CAPTURE_HASH.
 """
 
 from __future__ import annotations
 
+import functools
 import os
 import threading
 from typing import Callable, Iterable
 
+import numpy as np
+
+from parca_agent_tpu.runtime import trace
 from parca_agent_tpu.utils import faults
 from parca_agent_tpu.utils.poison import read_bounded
 from parca_agent_tpu.utils.vfs import VFS, RealFS
@@ -65,8 +98,10 @@ def read_starttime(fs: VFS, pid: int) -> int:
 class ProcessIdentityTracker:
     """Per-window pid-generation check with pluggable invalidation.
 
-    ``starttime_of`` defaults to the procfs read; tests and the bench
-    zoo inject a callable backed by their scenario's world state.
+    ``starttime_of`` defaults to the procfs reader (one listing of
+    ``/proc`` a window, then a bounded stat read per listed pid); tests
+    and the bench zoo inject a callable backed by their scenario's
+    world state, which is asked for every distinct pid.
     Invalidators are ``(name, fn(pid))`` pairs registered by the wiring
     layer (cli.py / the zoo runner); each fires under its own guard so
     one raising layer never blocks the others from dropping stale
@@ -74,9 +109,9 @@ class ProcessIdentityTracker:
 
     def __init__(self, starttime_of: Callable[[int], int] | None = None,
                  fs: VFS | None = None, enabled: bool | None = None):
-        fs = fs if fs is not None else RealFS()
-        self._start_of = (starttime_of if starttime_of is not None
-                          else lambda pid: read_starttime(fs, pid))
+        self._fs = fs if fs is not None else RealFS()
+        # None: procfs, asked which pids exist before any is opened.
+        self._start_of = starttime_of
         if enabled is None:
             enabled = os.environ.get("PARCA_NO_PID_GENERATION", "") != "1"
         self.enabled = enabled
@@ -91,6 +126,7 @@ class ProcessIdentityTracker:
             "invalidations_total": 0,
             "invalidation_errors_total": 0,
             "errors_total": 0,
+            "absent_total": 0,
             "trims_total": 0,
         }
         # guarded-by: _lock — last detected reuse, for /healthz.
@@ -109,72 +145,109 @@ class ProcessIdentityTracker:
 
     # palint: fail-open
     def observe_window(self, pids: Iterable[int]) -> list[int]:
-        """Check every pid in this window's capture against its
-        remembered starttime; fire invalidators for recycled pids.
-        Returns the reused pids. Fail-open end to end: any error —
-        including the injected ``process.identity`` fault — is counted
-        and the window proceeds with whatever hardening landed."""
+        """Check every distinct pid in this window's capture against
+        its remembered starttime; fire invalidators for recycled pids.
+        ``pids`` may be the per-row column: it is reduced as an array,
+        nothing here runs once per row. Returns the reused pids in
+        ascending order. Fail-open end to end: any error — including
+        the injected ``process.identity`` fault — is counted and the
+        window proceeds with whatever hardening landed."""
         reused: list[int] = []
         try:
             if not self.enabled:
                 return reused
             faults.inject("process.identity")
-            seen: set[int] = set()
-            for pid in pids:
-                pid = int(pid)
-                if pid in seen or pid < 0:
-                    continue  # kernel pseudo-pids have no /proc identity
-                seen.add(pid)
-                try:
-                    start = int(self._start_of(pid))
-                except Exception:
-                    # Exited mid-window (or unreadable): keep the
-                    # remembered generation — if the pid comes back it
-                    # is BY DEFINITION a new incarnation and the stale
-                    # entry is what lets us detect it.
-                    with self._lock:
-                        self.stats["errors_total"] += 1
-                    continue
-                with self._lock:
-                    self.stats["checks_total"] += 1
-                    prev = self._gens.get(pid)
-                    self._gens[pid] = start
-                if prev is not None and prev != start:
-                    reused.append(pid)
-                    with self._lock:
-                        self.stats["reuse_detected_total"] += 1
-                        self._last_reuse = {
-                            "pid": pid, "old_starttime": prev,
-                            "new_starttime": start}
-                    self._invalidate(pid)
-            self._trim(seen)
+            arr = (pids if isinstance(pids, np.ndarray)
+                   else np.fromiter(pids, np.int64))
+            distinct = np.unique(arr)
+            # Kernel pseudo-pids have no /proc identity.
+            distinct = distinct[distinct >= 0]
+            checked, starts, n_reads, n_absent = self._starttimes(distinct)
+            with self._lock:
+                prevs = list(map(self._gens.get, checked))
+                self._gens.update(zip(checked, starts))
+                hits = [(pid, prev, start) for pid, prev, start
+                        in zip(checked, prevs, starts)
+                        if prev is not None and prev != start]
+                reused = [pid for pid, _prev, _start in hits]
+                if hits:
+                    pid, prev, start = hits[-1]
+                    self._last_reuse = {
+                        "pid": pid, "old_starttime": prev,
+                        "new_starttime": start}
+                st = self.stats
+                st["checks_total"] += len(checked)
+                # Exited mid-window (or unreadable): the remembered
+                # generation is kept — if the pid comes back it is BY
+                # DEFINITION a new incarnation and the stale entry is
+                # what lets us detect it.
+                st["errors_total"] += len(distinct) - len(checked)
+                st["absent_total"] += n_absent
+                st["reuse_detected_total"] += len(reused)
+                self._trim(distinct)
+                hooks = list(self._invalidators) if reused else ()
+            trace.count(identity_pids=len(distinct),
+                        identity_stat_reads=n_reads,
+                        identity_absent=n_absent)
+            if hooks:
+                self._invalidate(reused, hooks)
         except Exception:
             with self._lock:
                 self.stats["errors_total"] += 1
         return reused
 
-    def _invalidate(self, pid: int) -> None:
-        with self._lock:
-            hooks = list(self._invalidators)
-        for _name, fn in hooks:
+    def _starttimes(self, distinct: np.ndarray
+                    ) -> tuple[list[int], list[int], int, int]:
+        """(pids whose starttime was read, their starttimes, reads
+        attempted, pids settled absent by the listing with no read)."""
+        start_of = self._start_of
+        n_absent = 0
+        if start_of is None:
+            start_of = functools.partial(read_starttime, self._fs)
             # palint: fail-open
             try:
-                fn(pid)
-                with self._lock:
-                    self.stats["invalidations_total"] += 1
+                live = np.fromiter(
+                    (int(n) for n in self._fs.listdir("/proc")
+                     if n.isdigit()), np.int64)
+                listed = distinct[np.isin(distinct, live)]
+                n_absent = len(distinct) - len(listed)
+                distinct = listed
             except Exception:
-                with self._lock:
-                    self.stats["invalidation_errors_total"] += 1
+                pass  # no listing: a read per pid, the same result
+        checked: list[int] = []
+        starts: list[int] = []
+        for pid in distinct.tolist():
+            try:
+                start = int(start_of(pid))
+            except Exception:
+                continue
+            checked.append(pid)
+            starts.append(start)
+        return checked, starts, len(distinct), n_absent
 
-    def _trim(self, live: set[int]) -> None:
-        """Bound the generation table: past _MAX_TRACKED, keep only the
-        pids seen in the current window (held under the lock — the table
-        swap must not interleave with a concurrent forget)."""
+    def _invalidate(self, reused: list[int], hooks) -> None:
+        fired = failed = 0
+        for pid in reused:
+            for _name, fn in hooks:
+                # palint: fail-open
+                try:
+                    fn(pid)
+                    fired += 1
+                except Exception:
+                    failed += 1
         with self._lock:
-            if len(self._gens) <= max(_MAX_TRACKED, 4 * len(live)):
-                return
-            self._gens = {p: s for p, s in self._gens.items() if p in live}
-            self.stats["trims_total"] += 1
+            self.stats["invalidations_total"] += fired
+            self.stats["invalidation_errors_total"] += failed
+
+    def _trim(self, distinct: np.ndarray) -> None:  # palint: holds=_lock
+        """Bound the generation table: past _MAX_TRACKED, keep only the
+        pids seen in the current window. Called with the lock held — the
+        table swap must not interleave with a concurrent forget."""
+        if len(self._gens) <= max(_MAX_TRACKED, 4 * len(distinct)):
+            return
+        live = set(distinct.tolist())
+        self._gens = {p: s for p, s in self._gens.items() if p in live}
+        self.stats["trims_total"] += 1
 
     def metrics(self) -> dict:
         with self._lock:

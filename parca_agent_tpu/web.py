@@ -588,6 +588,8 @@ def render_metrics(profilers, batch_client=None, extra: dict | None = None,
              m.get("invalidations_total", 0))
         emit("parca_agent_pid_identity_errors_total",
              m.get("errors_total", 0))
+        emit("parca_agent_pid_identity_absent_total",
+             m.get("absent_total", 0))
     if regression is not None:
         # Regression sentinel (docs/regression.md): verdict counters by
         # kind, the fold/seal/baseline lifecycle counters, judgment

@@ -625,6 +625,9 @@ def test_the_span_tree_parents_intervals_and_honest_starts():
         assert meta["rows"] == 200 and 0 < meta["rows_fed"] <= 200
         assert meta["profiles"] == 6
         assert 0 < meta["gzip_bytes"] < meta["pprof_bytes"]
+        # An injected reader is asked for every distinct pid.
+        assert (meta["identity_pids"], meta["identity_stat_reads"],
+                meta["identity_absent"]) == (6, 6, 0)
     # The first window met every stack for the first time.
     first = {s["stage"]: s for s in traces[0]["spans"]}
     assert first["feed_miss"]["parent"] == first["close"]["id"]
@@ -635,6 +638,37 @@ def test_the_span_tree_parents_intervals_and_honest_starts():
     assert {"identity", "admission", "encode_wait"} <= hists
     assert not hists & {*CLOSE_CHILDREN, *SHIP_CHILDREN, "handoff_wait",
                         "feed_miss"} - {"buffer_flip"}
+
+
+@pytest.mark.parametrize("pipelined", [True, False],
+                         ids=["pipelined", "inline"])
+def test_identity_counts_on_the_window_meta(pipelined):
+    """The bulk identity check says on the window's ``meta`` how many
+    distinct pids it saw, how many it read and how many the listing
+    settled without a read; the three add up in every window."""
+    from parca_agent_tpu.process.identity import ProcessIdentityTracker
+    from parca_agent_tpu.utils.vfs import FakeFS
+    from parca_agent_tpu.web import render_metrics
+
+    pids = sorted({int(p) for p in _snap(seed=5).pids})
+    assert len(pids) == 6
+    rest = " ".join(["R"] + ["0"] * 18 + ["4242", "0"])
+    listed = pids[:4]   # the other two are not processes of this host
+    fs = FakeFS({f"/proc/{p}/stat": f"{p} (x) {rest}".encode()
+                 for p in listed})
+    tracker = ProcessIdentityTracker(fs=fs, enabled=True)
+    _rec, traces = _traced_windows(pipelined, identity=tracker)
+    for t in traces:
+        meta = t["meta"]
+        assert meta["identity_pids"] == 6
+        assert meta["identity_stat_reads"] == 4
+        assert meta["identity_absent"] == 2
+        assert meta["identity_stat_reads"] + meta["identity_absent"] \
+            == meta["identity_pids"]
+    m = tracker.metrics()
+    assert (m["checks_total"], m["absent_total"]) == (12, 6)
+    assert "parca_agent_pid_identity_absent_total 6" \
+        in render_metrics([], identity=tracker)
 
 
 def test_a_stage_in_chunks_is_one_accumulated_span():
