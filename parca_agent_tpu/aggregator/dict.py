@@ -1043,17 +1043,13 @@ class DictAggregator:
         with trace.child("feed_coalesce") as sp:
             try:
                 faults.inject("feed.coalesce")
-                key = np.empty((n, 3), np.uint32)
-                key[:, 0] = h1c
-                key[:, 1] = h2c
-                key[:, 2] = h3c
-                folded = fold_rows_first_seen(
-                    key.view(np.dtype((np.void, 12))).ravel(), w64)
+                folded = self._fold_triples(h1c, h2c, h3c, w64)
                 if folded is not None:
-                    rep, _inv, fw = folded
+                    rep, fw = folded
                     h1c, h2c, h3c = h1c[rep], h2c[rep], h3c[rep]
                     w64 = fw
                     rows_map = rows_map[rep]
+                    trace.count(coalesce_folded=n - len(rep))
                 self.stats["coalesce_rows_in"] = \
                     self.stats.get("coalesce_rows_in", 0) + n
                 self.stats["coalesce_rows_out"] = \
@@ -1071,6 +1067,52 @@ class DictAggregator:
                     "batch", error=repr(e)[:200])
         self.timings["feed_coalesce"] = sp.duration_s
         return h1c, h2c, h3c, w64, rows_map
+
+    def _fold_triples(self, h1c, h2c, h3c, w64):
+        """The triple fold on 64-bit integer keys. Returns None when no
+        triple repeats, else ``(rep, weights)`` as fold_rows_first_seen
+        gives them: the first row of each distinct triple in first-
+        occurrence order, and the exact int64 sum of its rows' counts.
+
+        If no two rows share (h1, h2), no two share (h1, h2, h3): one
+        value sort of ``h1 << 32 | h2`` answers "does anything fold?"
+        for the window whose rows are already distinct (the steady
+        state) without a record sort, an index or an inverse. A batch
+        with repeats pays a stable integer argsort; a run of equal
+        (h1, h2) that holds more than one h3 (a 64-bit collision
+        between different stacks) is not grouped by that argsort, so
+        that batch goes whole to the record fold, which is exact."""
+        k = (h1c.astype(np.uint64) << np.uint64(32)) | h2c
+        s = np.sort(k)
+        same_k = s[1:] == s[:-1]
+        if not same_k.any():
+            self.stats["coalesce_unique_batches"] = \
+                self.stats.get("coalesce_unique_batches", 0) + 1
+            trace.count(coalesce_unique=1)
+            return None
+        order = np.argsort(k, kind="stable")  # k[order] is s
+        h3s = h3c[order]
+        new_h3 = h3s[1:] != h3s[:-1]
+        if (same_k & new_h3).any():
+            self.stats["coalesce_wide_folds"] = \
+                self.stats.get("coalesce_wide_folds", 0) + 1
+            key = np.empty((len(k), 3), np.uint32)
+            key[:, 0] = h1c
+            key[:, 1] = h2c
+            key[:, 2] = h3c
+            folded = fold_rows_first_seen(
+                key.view(np.dtype((np.void, 12))).ravel(), w64)
+            if folded is None:  # (h1, h2) repeats, no triple does
+                return None
+            rep, _inv, fw = folded
+            return rep, fw
+        # Every run of equal k is one triple; the stable argsort put its
+        # lowest original index first.
+        start = np.flatnonzero(np.concatenate(([True], ~same_k)))
+        first = order[start]
+        fw = np.add.reduceat(np.asarray(w64, np.int64)[order], start)
+        seen = np.argsort(first)  # groups back in first-occurrence order
+        return first[seen], fw[seen]
 
     def _carry_match(self, h1c, h2c, h3c, w64):
         """Cross-drain fold: batch rows whose keys already sit in the

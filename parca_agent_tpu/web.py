@@ -293,14 +293,21 @@ def render_metrics(profilers, batch_client=None, extra: dict | None = None,
             # Ingest-wall observability (docs/perf.md "ingest wall"):
             # how hard the feed-batch fold is working — rows in vs rows
             # actually dispatched (the gap is the cross-thread
-            # repetition coalesced away) and the counted fail-open
-            # fallbacks to the uncoalesced path.
+            # repetition coalesced away), the counted fail-open
+            # fallbacks to the uncoalesced path, and which way the fold
+            # went: batches one value sort of the 64-bit keys showed to
+            # hold no repeat, and batches a 64-bit collision sent to
+            # the exact record fold.
             emit("parca_agent_feed_coalesce_rows_in_total",
                  agg_stats.get("coalesce_rows_in", 0), lab)
             emit("parca_agent_feed_coalesce_rows_out_total",
                  agg_stats.get("coalesce_rows_out", 0), lab)
             emit("parca_agent_feed_coalesce_fallbacks_total",
                  agg_stats.get("coalesce_fallbacks", 0), lab)
+            emit("parca_agent_feed_coalesce_unique_batches_total",
+                 agg_stats.get("coalesce_unique_batches", 0), lab)
+            emit("parca_agent_feed_coalesce_wide_folds_total",
+                 agg_stats.get("coalesce_wide_folds", 0), lab)
             # Feed-endgame observability (docs/perf.md "feed endgame"):
             # the cross-drain carry cache — rows tested vs rows folded
             # host-side (hits/rows_in is the drain-cache hit rate), the

@@ -102,6 +102,116 @@ def test_fold_rows_first_seen_property():
                                 np.ones(16, np.int64)) is None
 
 
+def _triple_case(name):
+    """(h1, h2, h3, weights) for one case of the integer-fold property
+    test, each lane uint32."""
+    rng = np.random.default_rng(29)
+
+    def lanes(n):
+        return [rng.integers(0, 2**32, n, dtype=np.uint64).astype(np.uint32)
+                for _ in range(3)]
+
+    if name == "n=2-same":
+        return (*[x[[0, 0]] for x in lanes(1)], np.array([3, 4], np.int64))
+    if name.startswith("n="):
+        n = int(name[2:])
+        return (*lanes(n), rng.integers(1, 100, n).astype(np.int64))
+    if name == "distinct":
+        # Distinct on (h1, h2) by construction, whatever the draw.
+        h1, h2, h3 = lanes(4096)
+        h2 = rng.permutation(4096).astype(np.uint32)
+        return h1, h2, h3, rng.integers(1, 100, 4096).astype(np.int64)
+    if name == "h3-alone-repeats":
+        # Rows that share h3 and nothing else are distinct triples.
+        h1, h2, h3 = lanes(512)
+        h2 = rng.permutation(512).astype(np.uint32)
+        h3[:] = 7
+        return h1, h2, h3, rng.integers(1, 100, 512).astype(np.int64)
+    if name == "heavy-repeats":
+        idx = rng.integers(0, 40, 3000)
+        return (*[x[idx] for x in lanes(40)],
+                rng.integers(1, 100, 3000).astype(np.int64))
+    if name == "one-triple":
+        return (*[x[np.zeros(257, np.int64)] for x in lanes(1)],
+                rng.integers(1, 100, 257).astype(np.int64))
+    if name in ("collision-two-h3", "collision-no-triple-repeats"):
+        # A forced 64-bit collision: one (h1, h2) under two h3 values,
+        # interleaved so the run is not grouped by h3 after a stable
+        # argsort of the 64-bit key (A, B, A).
+        idx = rng.integers(0, 64, 600)
+        h1, h2, h3 = [x[idx] for x in lanes(64)]
+        if name == "collision-no-triple-repeats":
+            h1, h2, h3 = lanes(600)
+            h2 = rng.permutation(600).astype(np.uint32)
+        h1[[5, 9, 300]] = h1[5]
+        h2[[5, 9, 300]] = h2[5]
+        h3[[5, 9, 300]] = (11, 12, 11)
+        if name == "collision-no-triple-repeats":
+            h3[300] = 13
+        return h1, h2, h3, rng.integers(1, 100, 600).astype(np.int64)
+    if name in ("mass=2^53", "mass>2^53"):
+        idx = rng.integers(0, 16, 200)
+        w = np.ones(200, np.int64)
+        # Odd masses above 2^53 are where a float64 sum goes wrong.
+        w[:3] = (2**53 - 199, 1, 2) if name == "mass=2^53" \
+            else (2**53 + 1, 2**60 + 1, 2**61 + 3)
+        idx[:3] = (0, 0, 1)
+        return (*[x[idx] for x in lanes(16)], w)
+    raise AssertionError(name)
+
+
+@pytest.mark.parametrize("name,how", [
+    ("n=0", "unique"), ("n=1", "unique"), ("n=2", "unique"),
+    ("n=2-same", "folded"), ("distinct", "unique"),
+    ("h3-alone-repeats", "unique"), ("heavy-repeats", "folded"),
+    ("one-triple", "folded"), ("collision-two-h3", "wide"),
+    ("collision-no-triple-repeats", "wide-unique"),
+    ("mass=2^53", "folded"), ("mass>2^53", "folded")])
+def test_integer_triple_fold_is_the_record_fold(name, how, monkeypatch):
+    """The feed folds on 64-bit integer keys; what comes out is what
+    fold_rows_first_seen gives for the same rows as 12-byte records, to
+    the element: representatives, folded weights, first-occurrence
+    order. The counters say which way the batch went."""
+    import parca_agent_tpu.aggregator.dict as D
+
+    h1, h2, h3, w = _triple_case(name)
+    n = len(h1)
+    key = np.stack([h1, h2, h3], axis=1).astype(np.uint32)
+    want = fold_rows_first_seen(
+        np.ascontiguousarray(key).view(np.dtype((np.void, 12))).ravel(), w)
+    record_folds = []
+    sound = D.fold_rows_first_seen
+    monkeypatch.setattr(D, "fold_rows_first_seen",
+                        lambda *a: record_folds.append(1) or sound(*a))
+    agg = DictAggregator(capacity=1 << 10)
+    rows_map = np.arange(100, 100 + n, dtype=np.int64)
+    g1, g2, g3, gw, gmap = agg._coalesce_triples(h1, h2, h3, w, rows_map)
+    if want is None:
+        assert how in ("unique", "wide-unique")
+        # The inputs come back as they are: nothing was rebuilt.
+        assert (g1 is h1 and g2 is h2 and g3 is h3 and gw is w
+                and gmap is rows_map)
+        rep = np.arange(n)
+    else:
+        assert how in ("folded", "wide")
+        rep, _inv, fw = want
+        assert np.array_equal(gmap, rows_map[rep])
+        assert gw.dtype == np.int64 and np.array_equal(gw, fw)
+        triples = [tuple(t) for t in key.tolist()]
+        assert [int(x) for x in gw] == [
+            sum(int(c) for c, t in zip(w, triples) if t == triples[r])
+            for r in rep]   # exact, in Python
+        assert np.array_equal(np.stack([g1, g2, g3], axis=1), key[rep])
+        assert np.array_equal(rep, np.sort(rep))  # first-occurrence order
+    st = agg.stats
+    assert st.get("coalesce_fallbacks", 0) == 0
+    assert (st["coalesce_rows_in"], st["coalesce_rows_out"]) == (n, len(rep))
+    assert st.get("coalesce_unique_batches", 0) == (how == "unique")
+    assert st.get("coalesce_wide_folds", 0) == how.startswith("wide")
+    # Only a 64-bit collision pays the record sort.
+    assert len(record_folds) == how.startswith("wide")
+
+
 # -- native batch hash kernel -------------------------------------------------
 
 

@@ -514,19 +514,24 @@ class _StubResolver:
 
 
 def _traced_windows(pipelined: bool, n: int = 3, admission=None,
-                    identity=None):
+                    identity=None, snaps=None, profilers=None):
     """n windows through the real profiler on XLA:CPU, one at a time (a
-    pipelined window is shipped before the next is handed over)."""
+    pipelined window is shipped before the next is handed over). The
+    profiler is appended to ``profilers`` for a test that reads it."""
     from parca_agent_tpu.agent.writer import RemoteProfileWriter
 
     rec = FlightRecorder()
+    snaps = [_snap(seed=5) for _ in range(n)] if snaps is None else snaps
+    n = len(snaps)
     prof = CPUProfiler(
-        source=ListSource([_snap(seed=5) for _ in range(n)]),
+        source=ListSource(snaps),
         aggregator=DictAggregator(capacity=1 << 12),
         fallback_aggregator=CPUAggregator(),
         profile_writer=RemoteProfileWriter(RawSink()), duration_s=0.0,
         fast_encode=True, encode_pipeline=pipelined, trace_recorder=rec,
         admission=admission, identity=identity)
+    if profilers is not None:
+        profilers.append(prof)
     for _ in range(n):
         assert prof.run_iteration()
         assert prof.last_error is None
@@ -669,6 +674,40 @@ def test_identity_counts_on_the_window_meta(pipelined):
     assert (m["checks_total"], m["absent_total"]) == (12, 6)
     assert "parca_agent_pid_identity_absent_total 6" \
         in render_metrics([], identity=tracker)
+
+
+@pytest.mark.parametrize("pipelined", [True, False],
+                         ids=["pipelined", "inline"])
+def test_coalesce_counts_on_the_window_meta_and_on_metrics(pipelined):
+    """The fold says on the window's ``meta`` which way it went: a
+    window whose rows are distinct was answered by the value sort alone
+    (``coalesce_unique``), one with repeats says how many rows folded
+    away (``coalesce_folded``); ``/metrics`` carries the two counters
+    beside the fold's rows in / rows out."""
+    from parca_agent_tpu.capture.formats import concat_snapshots
+    from parca_agent_tpu.ops.hashing import native_hash_available
+    from parca_agent_tpu.web import render_metrics
+
+    assert native_hash_available()   # else the fold runs on raw rows
+    snap = _snap(seed=5)
+    dup = concat_snapshots([snap] * 3)   # every row three times
+    profilers = []
+    _rec, traces = _traced_windows(pipelined, snaps=[snap, dup, snap],
+                                   profilers=profilers)
+    metas = [t["meta"] for t in traces]
+    assert [m.get("coalesce_unique", 0) for m in metas] == [1, 0, 1]
+    assert [m.get("coalesce_folded", 0) for m in metas] == [0, 400, 0]
+    assert [m["rows"] for m in metas] == [200, 600, 200]
+    assert [m["rows_fed"] for m in metas] == [200, 200, 200]
+    lines = [ln for ln in render_metrics(profilers).splitlines()
+             if not ln.startswith("#")]
+    at = lines.index(
+        'parca_agent_feed_coalesce_rows_in_total{profiler="cpu"} 1000')
+    assert lines[at + 1:at + 5] == [
+        'parca_agent_feed_coalesce_rows_out_total{profiler="cpu"} 600',
+        'parca_agent_feed_coalesce_fallbacks_total{profiler="cpu"} 0',
+        'parca_agent_feed_coalesce_unique_batches_total{profiler="cpu"} 2',
+        'parca_agent_feed_coalesce_wide_folds_total{profiler="cpu"} 0']
 
 
 def test_a_stage_in_chunks_is_one_accumulated_span():
