@@ -96,9 +96,8 @@ bench-statics:
 	JAX_PLATFORMS=cpu PARCA_BENCH_STATICS_CHILD=1 $(PYTHON) bench.py
 
 # The sub-RTT close drill alone (docs/perf.md "sub-RTT close"):
-# double-buffer overlap, delta-fetch byte accounting, and the Pallas
-# batch-probe kernel vs the lax sort, gated on pprof byte identity.
-# Host-bound (interpret-mode Pallas), so it pins the cpu backend.
+# double-buffer overlap and delta-fetch byte accounting, gated on pprof
+# byte identity. Host-bound, so it pins the cpu backend.
 bench-close:
 	JAX_PLATFORMS=cpu PARCA_BENCH_CLOSE_CHILD=1 $(PYTHON) bench.py
 

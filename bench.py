@@ -31,9 +31,7 @@ counts-only; per-pid profile assembly and pprof encode are identical
 downstream costs excluded from both.
 
 Phase breakdown (close_fetch = dispatch+kernel+D2H of the packed buffer,
-close_unpack = host-side unpack) and the batch-kernel numbers
-(`batch_kernel_ms`: the one-shot _window_kernel with device-resident
-inputs at full scale) are published alongside.
+close_unpack = host-side unpack) is published alongside.
 
 One process per chip: the parent process never touches JAX. It
 pre-generates the synthetic window (numpy only) and runs the ENTIRE
@@ -61,7 +59,6 @@ Scale knobs via env:
   PARCA_BENCH_PIDS     (default 50000)
   PARCA_BENCH_REPS     (default 7)  TPU close reps (median)
   PARCA_BENCH_CPU_REPS (default 5)  CPU rebuild reps (median)
-  PARCA_BENCH_BATCH    (default 1)  also bench the one-shot batch kernel
   PARCA_BENCH_REP_IDLE_S (default 1.0) idle between reps (TPU and CPU
                        alike), modeling the 10s-window duty cycle; 0 =
                        fully saturated host
@@ -206,7 +203,6 @@ def run(emit=None) -> dict:
     pids = int(os.environ.get("PARCA_BENCH_PIDS", 50_000))
     reps = int(os.environ.get("PARCA_BENCH_REPS", 7))
     cpu_reps = int(os.environ.get("PARCA_BENCH_CPU_REPS", 5))
-    bench_batch = os.environ.get("PARCA_BENCH_BATCH", "1") != "0"
     bench_pprof = os.environ.get("PARCA_BENCH_PPROF", "1") != "0"
 
     import jax
@@ -554,9 +550,8 @@ def run(emit=None) -> dict:
         _emit_partial()
 
     # Sub-RTT close drill (docs/perf.md "sub-RTT close"): double-buffer
-    # overlap, delta-fetch byte accounting, and the Pallas batch-probe
-    # kernel, all gated on pprof byte identity. Reduced-scale and
-    # host-bound (interpret-mode Pallas): it cannot hang the attempt.
+    # overlap and delta-fetch byte accounting, gated on pprof byte
+    # identity. Reduced-scale and host-bound: it cannot hang the attempt.
     if os.environ.get("PARCA_BENCH_CLOSE", "1") != "0" \
             and _budget_left(0.12, "close_overlap"):
         try:
@@ -678,44 +673,6 @@ def run(emit=None) -> dict:
             extras["ab_sketch_error"] = repr(e)[:120]
 
     _progress("A/B sketch phase passed")
-    if bench_batch and _budget_left(0.5, "batch_kernel"):
-        try:
-            import jax.numpy as jnp
-
-            from parca_agent_tpu.aggregator.tpu import (
-                _jitted_kernel,
-                pack_window_inputs,
-            )
-
-            # l_cap=None sizes the location table from the exact
-            # unique-(pid, frame) count (pack_window_inputs), so no
-            # doubling recompile should ever fire.
-            host_args, dims = pack_window_inputs(snap)
-            dev_args = tuple(jnp.asarray(a) for a in host_args)
-            while True:
-                out = _jitted_kernel()(*dev_args, **dims)
-                n_locs = int(np.asarray(out[1]))
-                if n_locs <= dims["l_cap"]:
-                    break
-                dims["l_cap"] *= 2  # safety net; should not trigger
-            bt = []
-            for _ in range(2):
-                t0 = time.perf_counter()
-                out = _jitted_kernel()(*dev_args, **dims)
-                # Force execution with a scalar fetch: the timed region
-                # ends when a result is on the host, not at dispatch.
-                int(np.asarray(out[0]))
-                bt.append(time.perf_counter() - t0)
-            extras["batch_kernel_ms"] = round(_median_ms(bt), 1)
-            # Context for the reader: the one-shot kernel re-dedups every
-            # frame of every stack; the synthetic window's near-total
-            # address uniqueness (~n_locs unique locations) is its
-            # adversarial case and the motivation for the streaming dict
-            # path, which is the production default and the headline.
-            extras["batch_kernel_n_locs"] = n_locs
-        except Exception as e:  # noqa: BLE001 - report, don't fail the bench
-            extras["batch_kernel_error"] = repr(e)[:120]
-
     return {**result, **extras}
 
 
@@ -898,10 +855,10 @@ def _cold_restart(agg, snap, hashes) -> dict:
 
 def _close_overlap() -> dict:
     """Sub-RTT close drill (docs/perf.md "sub-RTT close"): the
-    double-buffered window accumulator, delta-fetch, and the Pallas
-    batch-probe kernel, with exactness enforced at the pprof byte level.
+    double-buffered window accumulator and delta-fetch, with exactness
+    enforced at the pprof byte level.
 
-    Four measurements, one identity gate:
+    Two measurements, one identity gate:
 
       * Overlap: a steady-state hot-set window fed in drain-sized chunks
         through two arms — SYNC (each feed settles its miss check
@@ -914,21 +871,16 @@ def _close_overlap() -> dict:
         of the full close's fetched bytes (the rows/bytes percentages
         ride out), with the first hot window exercising the documented
         grow-on-misprediction retry.
-      * Byte identity: all arms (full-fetch baseline, delta + overlap
-        split-close, Pallas feed probe when available) encode every
-        window through their own WindowEncoder; the pprof bytes must be
-        identical across arms, window by window.
-      * Batch kernel: the one-shot kernel's location dedup as hash-table
-        build+probe (Pallas, interpret on CPU) vs the lax sort path, on
-        the same window — timed, and the pprof bytes must match.
+      * Byte identity: both arms (full-fetch baseline, delta + overlap
+        split-close) encode every window through their own
+        WindowEncoder; the pprof bytes must be identical across arms,
+        window by window.
 
-    Reduced-scale and host-bound by design (interpret-mode Pallas on the
-    cpu backend exercises the same kernel code Mosaic compiles on a
-    TPU); rides the same mechanical scoring stamp as the headline."""
+    Reduced-scale and host-bound by design; rides the same mechanical
+    scoring stamp as the headline."""
     import hashlib as _hl
 
     from parca_agent_tpu.aggregator.dict import DictAggregator
-    from parca_agent_tpu.aggregator.pallas_probe import pallas_available
     from parca_agent_tpu.capture.synthetic import SyntheticSpec, generate
     from parca_agent_tpu.pprof.window_encoder import WindowEncoder
 
@@ -949,16 +901,12 @@ def _close_overlap() -> dict:
     # the touched-block tracking is built for.
     hot_lo, hot_hi = rows // 8, rows // 8 + rows // 8
 
-    use_pallas = pallas_available()
     arms = {
         "full": DictAggregator(capacity=cap, overflow="raise",
                                delta_fetch=False),
         "delta": DictAggregator(capacity=cap, overflow="raise",
                                 delta_fetch=True),
     }
-    if use_pallas:
-        arms["pallas"] = DictAggregator(capacity=cap, overflow="raise",
-                                        probe_backend="pallas")
     encs = {k: WindowEncoder(a) for k, a in arms.items()}
     hashes = {k: a.hash_rows(snap) for k, a in arms.items()}
 
@@ -1035,40 +983,8 @@ def _close_overlap() -> dict:
         "delta_closes": dstats.get("delta_closes", 0),
         "delta_retries": dstats.get("delta_retries", 0),
         "buffer_flips": dstats.get("buffer_flips", 0),
-        "pallas": use_pallas,
         "bytes_identical": identical,
     }
-
-    # The batch kernel's location dedup: hash-table (Pallas) vs sort.
-    from parca_agent_tpu.aggregator.tpu import TPUAggregator
-    from parca_agent_tpu.pprof.builder import build_pprof
-
-    bsnap = generate(SyntheticSpec(
-        n_pids=64, n_unique_stacks=2048, n_rows=2048,
-        total_samples=8192, mean_depth=8, seed=78))
-
-    def batch_arm(dedup):
-        ta = TPUAggregator()
-        ta.dedup = dedup
-        ta.aggregate(bsnap)  # compile
-        t0 = time.perf_counter()
-        profs = ta.aggregate(bsnap)
-        ms = (time.perf_counter() - t0) * 1e3
-        h = _hl.sha256()
-        for p in sorted(profs, key=lambda p: p.pid):
-            h.update(build_pprof(p, compress=False))
-        return round(ms, 1), h.hexdigest(), ta._hash_disabled
-
-    sort_ms, sort_digest, _ = batch_arm("sort")
-    phase["batch_kernel_lax_ms"] = sort_ms
-    if use_pallas:
-        hash_ms, hash_digest, hash_fell_back = batch_arm("hash")
-        phase["batch_kernel_pallas_ms"] = hash_ms
-        phase["batch_kernel_identical"] = hash_digest == sort_digest
-        if hash_fell_back:
-            phase["error"] = "hash dedup fell back to sort at runtime"
-        elif hash_digest != sort_digest:
-            phase["error"] = "hash vs sort batch kernel pprof mismatch"
 
     if not identical:
         phase["error"] = "pprof bytes differ across close arms"
@@ -2459,7 +2375,7 @@ def _statics_main() -> None:
 def _close_main() -> None:
     """`make bench-close`: the close_overlap drill alone, host-scale,
     one JSON line. Runs on whatever backend the env pins (the Make
-    target pins cpu — the drill is interpret-mode by design)."""
+    target pins cpu — the drill is host-bound by design)."""
     try:
         phase = _close_overlap()
     except Exception as e:  # noqa: BLE001 - the line must still print
@@ -2629,7 +2545,7 @@ def main() -> int:
         rows, pids = min(rows, 1 << 17), min(pids, 10_000)
         extra_env = {"PARCA_BENCH_ROWS": str(rows),
                      "PARCA_BENCH_PIDS": str(pids),
-                     "PARCA_BENCH_REPS": "3", "PARCA_BENCH_BATCH": "0"}
+                     "PARCA_BENCH_REPS": "3"}
 
     # Pre-generate the window here so the child's attempt budget is
     # spent measuring. Prune stale cache tags first so the temp dir

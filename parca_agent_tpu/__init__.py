@@ -13,8 +13,9 @@ mesh with XLA collectives.
 Layer map (mirrors SURVEY.md section 1, re-architected TPU-first):
 
   capture/     window snapshot data contracts, synthetic/replay/perf sources
-  aggregator/  pluggable Aggregator: CPU (numpy oracle) and TPU (JAX) backends
-  ops/         hashing, segment reductions, vectorized lookups, pallas kernels
+  aggregator/  pluggable Aggregator: CPU (numpy oracle) and the device-resident
+               stack dictionary (JAX), alone or sharded over a mesh
+  ops/         row hashing (numpy/JAX twins), count-min and HLL sketches
   pprof/       pprof profile.proto wire encoder + profile builder
   symbolize/   kallsyms, JIT perf maps, /proc/maps, ELF bases, build IDs
   unwind/      .eh_frame -> compact fixed-width unwind tables

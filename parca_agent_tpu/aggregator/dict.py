@@ -94,7 +94,7 @@ def prefix_sum(x):
 
 
 def make_feed(cap: int, id_cap: int, n_pad: int, n_blocks: int = 0,
-              blk: int = 0, probe=None):
+              blk: int = 0):
     """Pure (unjitted) streaming-window accumulate: batched linear-probe
     lookup of all rows against the device stack dictionary, scatter-adding
     hits into a persistent device accumulator.
@@ -108,9 +108,7 @@ def make_feed(cap: int, id_cap: int, n_pad: int, n_blocks: int = 0,
     With n_blocks > 0 the feed also maintains a touched-block flag array
     (one int32 per `blk` consecutive stack ids): every accumulated hit
     marks its id's block, and the delta close (make_close_delta) fetches
-    only marked blocks. `probe`, when given, replaces the inline lax
-    probe loop (same semantics — the Pallas re-expression from
-    aggregator/pallas_probe.py plugs in here)."""
+    only marked blocks."""
     import jax
     import jax.numpy as jnp
 
@@ -128,28 +126,25 @@ def make_feed(cap: int, id_cap: int, n_pad: int, n_blocks: int = 0,
         # trace (``probe`` where the op list says ``while.5``); the
         # module's own name (``jit_feed``) is not theirs to change.
         with jax.named_scope("probe"):
-            if probe is not None:
-                found_id = probe(table, h1, h2, h3)
-            else:
-                mask = jnp.uint32(cap - 1)
+            mask = jnp.uint32(cap - 1)
 
-                def step(k, state):
-                    found_id, done = state
-                    idx = ((h1 + jnp.uint32(k)) & mask).astype(jnp.int32)
-                    row = table[idx]
-                    occ = row[:, 3] > 0
-                    hit = occ & (row[:, 0] == h1) & (row[:, 1] == h2) \
-                        & (row[:, 2] == h3)
-                    stop = hit | ~occ
-                    found_id = jnp.where(hit & ~done,
-                                         row[:, 3].astype(jnp.int32) - 1,
-                                         found_id)
-                    return found_id, done | stop
+            def step(k, state):
+                found_id, done = state
+                idx = ((h1 + jnp.uint32(k)) & mask).astype(jnp.int32)
+                row = table[idx]
+                occ = row[:, 3] > 0
+                hit = occ & (row[:, 0] == h1) & (row[:, 1] == h2) \
+                    & (row[:, 2] == h3)
+                stop = hit | ~occ
+                found_id = jnp.where(hit & ~done,
+                                     row[:, 3].astype(jnp.int32) - 1,
+                                     found_id)
+                return found_id, done | stop
 
-                found_id = jnp.full(h1.shape, -1, jnp.int32)
-                done = jnp.zeros(h1.shape, bool)
-                found_id, _ = jax.lax.fori_loop(0, _PROBES, step,
-                                                (found_id, done))
+            found_id = jnp.full(h1.shape, -1, jnp.int32)
+            done = jnp.zeros(h1.shape, bool)
+            found_id, _ = jax.lax.fori_loop(0, _PROBES, step,
+                                            (found_id, done))
 
         with jax.named_scope("accumulate"):
             live = cnt > 0
@@ -173,15 +168,10 @@ def make_feed(cap: int, id_cap: int, n_pad: int, n_blocks: int = 0,
 
 @functools.lru_cache(maxsize=8)
 def _feed_program(cap: int, id_cap: int, n_pad: int, n_blocks: int,
-                  blk: int, backend: str):
+                  blk: int):
     import jax
 
-    probe = None
-    if backend == "pallas":
-        from parca_agent_tpu.aggregator.pallas_probe import make_batch_probe
-
-        probe = make_batch_probe(cap, _PROBES)
-    return jax.jit(make_feed(cap, id_cap, n_pad, n_blocks, blk, probe),
+    return jax.jit(make_feed(cap, id_cap, n_pad, n_blocks, blk),
                    donate_argnums=(1, 2))
 
 
@@ -436,7 +426,6 @@ class DictAggregator:
                  cm_spec: "CountMinSpec | None" = None,
                  rotate_min_age: int = 6,
                  delta_fetch: bool = True,
-                 probe_backend: str = "lax",
                  coalesce: bool = True,
                  carry: bool = False):
         from parca_agent_tpu.ops.sketch import CountMinSpec, HLLSpec
@@ -445,20 +434,9 @@ class DictAggregator:
             raise ValueError("capacity must be a power of two")
         if overflow not in ("sketch", "raise"):
             raise ValueError("overflow must be 'sketch' or 'raise'")
-        if probe_backend not in ("lax", "pallas", "auto"):
-            raise ValueError("probe_backend must be 'lax', 'pallas' or "
-                             "'auto'")
         self._cap = capacity
         self._id_cap = id_cap or capacity // 2
         self._overflow = overflow
-        # Probe implementation for the feed program: "lax" (default — the
-        # proven hot path), "pallas" (aggregator/pallas_probe.py), or
-        # "auto" (pallas where it runs interpreted, lax on a TPU, where
-        # Mosaic refuses the kernel). Resolved lazily at
-        # the first dispatch; the resolution can only downgrade pallas ->
-        # lax (never upgrade mid-run: the jit cache keys on it).
-        self._probe_backend = probe_backend
-        self._probe_resolved: str | None = None
         # Host-side feed coalescing (docs/perf.md "ingest wall"): dedupe
         # each feed batch into (stack, weight) pairs on the (h1, h2, h3)
         # identity BEFORE packing, so dispatch rows track unique stacks,
@@ -1287,36 +1265,6 @@ class DictAggregator:
 
         return jnp.zeros(self._n_blocks, jnp.int32)
 
-    def _probe_backend_name(self) -> str:
-        if self._probe_resolved is None:
-            from parca_agent_tpu.aggregator import pallas_probe
-
-            want = self._probe_backend
-            if want == "auto":
-                # Chosen from the platform, not a fallback: Mosaic
-                # refuses the kernel on a TPU (pallas_probe module docs).
-                want = "pallas" if pallas_probe.auto_uses_pallas() \
-                    else "lax"
-            elif want == "pallas" and not pallas_probe.pallas_available():
-                from parca_agent_tpu.utils.log import get_logger
-
-                get_logger("aggregator.dict").warn(
-                    "pallas probe requested but unavailable; "
-                    "using the lax probe loop")
-                want = "lax"
-            self._probe_resolved = want
-            interp = None
-            if want == "pallas":
-                interp = pallas_probe.default_interpret()
-            # An explicit pallas request resolving to lax IS the silent
-            # fallback the one-hot gauge exists to surface
-            # (docs/observability.md "device flight recorder").
-            dtel.note_backend(
-                "feed_probe", requested=self._probe_backend, resolved=want,
-                interpret=interp,
-                fallback=(want == "lax" and self._probe_backend == "pallas"))
-        return self._probe_resolved
-
     def _feed_dispatch_async(self, packed: np.ndarray, n_pad: int,
                              reset: int):
         """Dispatch the feed program over the device state WITHOUT a host
@@ -1326,48 +1274,20 @@ class DictAggregator:
 
         import jax.numpy as jnp
 
-        backend = self._probe_backend_name()
         prog = _feed_program(self._cap, self._id_cap, n_pad,
-                             self._n_blocks, self._blk, backend)
+                             self._n_blocks, self._blk)
         # The feed program's jit cache key doubles as the telemetry
         # shape signature: a new key is the dispatch that pays compile.
-        sig = (self._cap, self._id_cap, n_pad, self._n_blocks, self._blk,
-               backend)
+        sig = (self._cap, self._id_cap, n_pad, self._n_blocks, self._blk)
         acc = self._acc
         touch = self._touch if self._blk else jnp.zeros(1, jnp.int32)
         self._acc = None    # donated: invalid if the call throws
         self._touch = None
         # One clock pair for the span, timings[...] and the telemetry.
         with trace.child("feed_dispatch") as sp:
-            try:
-                acc, touch, n_miss, miss_rows = prog(
-                    self._dev, acc, touch, jnp.asarray(packed),
-                    jnp.uint32(reset))
-            except Exception as e:  # noqa: BLE001 - pallas path only
-                if self._probe_resolved != "pallas":
-                    raise
-                # Automatic fallback, mirroring TPUAggregator.aggregate: a
-                # Pallas build/lowering failure on this backend (the CPU
-                # interpret probe can pass while Mosaic later refuses the
-                # kernel) degrades the probe to the lax loop — never a lost
-                # feed, at worst the old speed. Latched so the per-feed hot
-                # path does not retry a broken lowering. Safe to retry with
-                # the held acc/touch: a lowering failure raises at compile,
-                # before donation consumes the buffers.
-                self._probe_resolved = "lax"
-                dtel.note_backend("feed_probe", resolved="lax", fallback=True)
-                from parca_agent_tpu.utils.log import get_logger
-
-                get_logger("aggregator.dict").warn(
-                    "pallas batch probe failed; falling back to the lax "
-                    "probe loop", error=repr(e)[:200])
-                prog = _feed_program(self._cap, self._id_cap, n_pad,
-                                     self._n_blocks, self._blk, "lax")
-                sig = (self._cap, self._id_cap, n_pad, self._n_blocks,
-                       self._blk, "lax")
-                acc, touch, n_miss, miss_rows = prog(
-                    self._dev, acc, touch, jnp.asarray(packed),
-                    jnp.uint32(reset))
+            acc, touch, n_miss, miss_rows = prog(
+                self._dev, acc, touch, jnp.asarray(packed),
+                jnp.uint32(reset))
         self.timings["feed_dispatch"] = sp.duration_s
         dtel.record("feed_probe", sp.duration_s, shape=sig,
                     h2d_bytes=packed.nbytes)
@@ -2138,8 +2058,7 @@ class DictAggregator:
     def _place_new_keys_vec(self, h1n, h2n, stop):
         """First-empty-slot arbitration for a batch of new keys: every
         key starts at its chain's first pre-batch empty slot; contested
-        slots go to the lowest batch rank (deterministic — the same
-        min-lane arbitration idiom as the Pallas loc-table builder) and
+        slots go to the lowest batch rank (deterministic) and
         losers walk forward past slots occupied pre-batch or claimed
         this batch. The result is a valid linear-probe layout (a key
         only ever stops where its whole chain prefix is occupied), so

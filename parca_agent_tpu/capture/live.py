@@ -362,10 +362,9 @@ def columns_to_snapshot(
         hashes = tuple(np.asarray(h, np.uint32) for h in hashes)
     if len(pids) and int(pids.min()) < 0:
         # perf delivers unattributable/idle-context samples as pid -1;
-        # they carry no process to profile, and downstream the uint32
-        # cast would alias the device kernels' dead-row sentinel
-        # (aggregator/tpu.py pack guard). Drop the records, not the
-        # window.
+        # they carry no process to profile, and downstream a uint32
+        # cast would turn them into pid 4294967295. Drop the records,
+        # not the window.
         keep = pids >= 0
         pids, tids = pids[keep], np.asarray(tids)[keep]
         ulen, klen = np.asarray(ulen)[keep], np.asarray(klen)[keep]

@@ -216,7 +216,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "Filtered at the window boundary, so streaming "
                         "feeds run one-shot")
     p.add_argument("--aggregator", default="cpu",
-                   choices=["cpu", "tpu", "dict", "dict+cm", "sharded"],
+                   choices=["cpu", "dict", "dict+cm", "sharded"],
                    help="window aggregation backend (dict = stateful "
                         "device-resident stack dictionary, the TPU "
                         "production mode; dict+cm = bounded-memory dict "
@@ -629,7 +629,7 @@ def run(argv=None) -> int:
     # accounting, latched backend identity, and the window-SLO budget
     # layer keyed to the configured profiling period. Installed
     # process-globally so the kernel dispatch sites in
-    # aggregator/{dict,tpu,sharded}.py report without plumbing; storms
+    # aggregator/{dict,sharded}.py report without plumbing; storms
     # route through the window recorder's incident machinery below.
     from parca_agent_tpu.runtime import device_telemetry as dtel_mod
 
@@ -764,12 +764,7 @@ def run(argv=None) -> int:
 
     # -- aggregation backend -------------------------------------------------
     fallback = None
-    if args.aggregator == "tpu":
-        from parca_agent_tpu.aggregator.tpu import TPUAggregator
-
-        aggregator = TPUAggregator()
-        fallback = CPUAggregator()
-    elif args.aggregator == "sharded":
+    if args.aggregator == "sharded":
         if device_health.platform is None:
             # The mesh is built from the devices this process owns; with
             # no backend claimed (bring-up failed, see above) asking JAX

@@ -462,7 +462,7 @@ class CPUProfiler:
                     else sum(int(p.total()) for p in x)
 
             return mass(a) == mass(b)
-        from parca_agent_tpu.aggregator.tpu import shadow_compare
+        from parca_agent_tpu.runtime.device_health import shadow_compare
 
         return shadow_compare(a, b)
 

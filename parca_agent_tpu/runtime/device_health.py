@@ -146,6 +146,20 @@ def inprocess_probe(timeout_s: float) -> tuple[bool, str, str | None]:
     return True, f"ok on {platform}", platform
 
 
+def shadow_compare(device_profiles, cpu_profiles) -> bool:
+    """A/B correctness gate between two aggregations of the SAME window
+    (the shadow-window promotion check, :meth:`record_shadow`): per pid,
+    total sample mass and unique-stack count must agree,
+    order-insensitively. A backend that answers promptly but WRONGLY (a
+    half-reset dict table after a wedge, a corrupted transfer) fails
+    here and stays demoted."""
+    def digest(profiles):
+        return {int(p.pid): (int(p.total()), int(len(p.values)))
+                for p in profiles}
+
+    return digest(device_profiles) == digest(cpu_profiles)
+
+
 class DeviceHealthRegistry:
     """The device-backend trust state machine (module docs above).
 
@@ -536,7 +550,7 @@ class DeviceHealthRegistry:
         self.state = STATE_DEGRADED
         # Latch the demotion into the device flight recorder's backend
         # gauges: a node running its windows on the CPU fallback must be
-        # visible from /metrics next to the per-kernel pallas/lax state.
+        # visible from /metrics.
         dtel.note_backend("device", resolved="cpu_fallback", fallback=True)
         if prev != STATE_DEGRADED:
             _log.warn("device demoted to the CPU fallback", reason=reason,

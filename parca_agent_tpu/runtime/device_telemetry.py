@@ -5,10 +5,11 @@ host-side tail — but the hardware arc (ROADMAP item 1) is blind exactly
 where its truth lives: kernel dispatch cost is folded into whatever
 stage span happens to contain it, the first call's XLA compile (seconds)
 is indistinguishable from steady-state execution (microseconds), a
-Pallas->lax fallback latches silently behind a one-shot log line, and
-nothing accounts the H2D/D2H bytes each kernel moves. This module is
-the device-side twin: a process-global :class:`DeviceTelemetry`
-registry that every kernel dispatch site reports into —
+demotion to the CPU fallback latches silently behind a one-shot log
+line, and nothing accounts the H2D/D2H bytes each kernel moves. This
+module is the device-side twin: a process-global
+:class:`DeviceTelemetry` registry that every kernel dispatch site
+reports into —
 
   * per-kernel streaming latency histograms discriminating
     ``event=compile|execute`` via a shape-signature first-call latch
@@ -26,16 +27,16 @@ registry that every kernel dispatch site reports into —
     count, jax / jaxlib / libtpu versions) that the device's OWNER
     learns once and hands in (:func:`collect_identity`,
     :meth:`DeviceTelemetry.set_identity`) — reading it never
-    initialises a backend — plus per-kernel pallas/lax resolution and
-    interpret flags, exported as info-style gauges so a node that
-    landed on the wrong platform or silently fell back to lax is
-    visible from /metrics, not just logs;
+    initialises a backend — plus the device/cpu_fallback resolution
+    the device-health registry latches, exported as info-style gauges
+    so a node that landed on the wrong platform or runs its windows on
+    the CPU fallback is visible from /metrics, not just logs;
   * a window-SLO layer rolling capture-thread busy time plus off-thread
     kernel seconds into a per-window budget-used ratio and a
     windows-over-budget burn counter keyed to the configured period —
     the instrument the sub-second-window work is measured against.
 
-Reporting sites (aggregator/{dict,tpu,sharded}.py) call the module-level
+Reporting sites (aggregator/{dict,sharded}.py) call the module-level
 hooks (:func:`record`, :func:`transfer`, :func:`note_backend`,
 :func:`tick_window`) — the faults.py pattern: one module-attribute read
 when telemetry is off. Several sites sit on the CAPTURE PATH (palint's
@@ -69,7 +70,6 @@ _log = get_logger("device_telemetry")
 #   close_pack   full close pack dispatch (aggregator/dict.py)
 #   close_delta  delta close pack dispatch (aggregator/dict.py)
 #   close_fetch  the packed close D2H collect (aggregator/dict.py)
-#   loc_dedup    batched window kernel + loc-table dedup (aggregator/tpu.py)
 #   shard_put    per-device sharded feed puts (aggregator/sharded.py)
 EVENTS = ("compile", "execute")
 
@@ -116,8 +116,8 @@ def collect_identity() -> dict:
         "jax_version": str(jax.__version__),
         "jaxlib_version": _dist_version("jaxlib"),
         "libtpu_version": _dist_version("libtpu"),
-        # Pallas kernels compile through Mosaic on a TPU and run in the
-        # interpreter everywhere else (aggregator/pallas_probe.py).
+        # Whether a kernel written for the chip's own compiler would
+        # run interpreted here: on any platform but the TPU.
         "interpret_default": platform != "tpu",
         "hostname": socket.gethostname(),
     }
@@ -245,7 +245,7 @@ class DeviceTelemetry:
                      interpret: bool | None = None,
                      fallback: bool | None = None) -> None:
         """Latch one kernel's backend resolution (requested vs resolved
-        pallas/lax, interpret-mode flag, fallback one-hot). Fields are
+        backend, interpret-mode flag, fallback one-hot). Fields are
         sticky per call — last write wins, None leaves a field alone.
         Fail-open."""
         try:
@@ -510,7 +510,7 @@ def transfer(kernel: str, direction: str, nbytes: int) -> None:
 
 
 def note_backend(kernel: str, **fields) -> None:
-    """Backend-resolution latch hook (pallas/lax/interpret/fallback)."""
+    """Backend-resolution latch hook (resolved/interpret/fallback)."""
     if _active is not None:
         _active.note_backend(kernel, **fields)
 

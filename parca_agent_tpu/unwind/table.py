@@ -21,8 +21,7 @@ deviates are marked END_OF_FDE (unsupported) exactly like rows the
 reference's unwinder refuses (cpu.bpf.c unsupported-expression stats).
 
 The vectorized `lookup_rows` is the host twin of the BPF program's
-`find_offset_for_pc` binary search (reference bpf/cpu/cpu.bpf.c:302-341);
-device-side lookups reuse the mapping-join binary search in aggregator/tpu.
+`find_offset_for_pc` binary search (reference bpf/cpu/cpu.bpf.c:302-341).
 """
 
 from __future__ import annotations

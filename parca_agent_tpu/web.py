@@ -489,10 +489,10 @@ def render_metrics(profilers, batch_client=None, extra: dict | None = None,
                  {"kernel": kernel})
         for kernel, rec in device_telemetry.backends().items():
             resolved = rec["resolved"] or "unresolved"
-            # One-hot over the candidate backends plus whatever this
-            # kernel actually resolved to (the device-health kernel
-            # reports device/cpu_fallback rather than pallas/lax).
-            for backend in sorted({"pallas", "lax", resolved}):
+            # One-hot over the values the one reporter has
+            # (runtime/device_health.py, kernel="device") plus whatever
+            # this kernel actually resolved to.
+            for backend in sorted({"device", "cpu_fallback", resolved}):
                 emit("parca_agent_kernel_backend",
                      int(backend == resolved),
                      {"kernel": kernel, "backend": backend})

@@ -1,11 +1,8 @@
 """Sub-RTT close (docs/perf.md "sub-RTT close"): the device-resident
-double-buffered window accumulator, delta-fetch, and the Pallas
-batch-probe kernels — the swap/fallback matrix.
+double-buffered window accumulator and delta-fetch — the swap matrix.
 
-Everything here runs the Pallas kernels in ``interpret=True`` mode on
-the CPU backend (tier-1 exercises the same kernel code Mosaic compiles
-on a TPU), and every arm is gated on exactness: identical counts or
-identical pprof bytes against the lax/sort/CPU references.
+Every arm is gated on exactness: identical counts or identical pprof
+bytes against the full-fetch and CPU references.
 """
 
 from __future__ import annotations
@@ -25,264 +22,10 @@ def _no_leaked_injector():
     faults.install(None)
 
 
-@pytest.fixture()
-def device_telemetry():
-    """Installed device flight recorder for the fallback-latch cases:
-    the latched pallas->lax state must surface as the one-hot backend
-    gauge on /metrics (docs/observability.md "device flight recorder"),
-    not just as a private attribute."""
-    from parca_agent_tpu.runtime import device_telemetry as dtel_mod
-
-    tel = dtel_mod.DeviceTelemetry()
-    dtel_mod.install(tel)
-    yield tel
-    dtel_mod.install(None)
-
-
-def _assert_fallback_gauge(tel, kernel):
-    """The rendered /metrics must carry the latched lax fallback for
-    `kernel` as a one-hot gauge."""
-    from parca_agent_tpu.web import render_metrics
-
-    metrics = render_metrics([], device_telemetry=tel)
-    assert f'parca_agent_kernel_fallback{{kernel="{kernel}"}} 1' \
-        in metrics, metrics
-    assert f'parca_agent_kernel_backend{{kernel="{kernel}",' \
-        f'backend="lax"}} 1' in metrics
-    assert f'parca_agent_kernel_backend{{kernel="{kernel}",' \
-        f'backend="pallas"}} 0' in metrics
-
-
 def _snap(seed=1, rows=512, pids=8, per_row=3):
     return generate(SyntheticSpec(n_pids=pids, n_unique_stacks=rows,
                                   n_rows=rows, total_samples=rows * per_row,
                                   mean_depth=8, seed=seed))
-
-
-# -- Pallas kernels, interpret=True (CPU tier-1 coverage) ---------------------
-
-
-def _np_probe_reference(table, h1, h2, h3, probes):
-    """Host reference of the feed's bounded linear probe: hit => stored
-    id - 1, empty-slot stop or chain past the bound => -1."""
-    cap = len(table)
-    out = np.full(len(h1), -1, np.int64)
-    for i in range(len(h1)):
-        for k in range(probes):
-            idx = (int(h1[i]) + k) & (cap - 1)
-            row = table[idx]
-            if row[3] == 0:
-                break
-            if (row[0], row[1], row[2]) == (h1[i], h2[i], h3[i]):
-                out[i] = int(row[3]) - 1
-                break
-    return out
-
-
-def test_pallas_batch_probe_matches_reference():
-    from parca_agent_tpu.aggregator.pallas_probe import make_batch_probe
-
-    rng = np.random.default_rng(3)
-    cap, probes, n = 64, 4, 128
-    table = np.zeros((cap, 4), np.uint32)
-    # 20 entries, some in probe chains (forced same home slot).
-    keys = rng.integers(1, 2**32, size=(20, 3), dtype=np.uint64)
-    keys[5:9, 0] = keys[4, 0]  # a 5-long chain, beyond the probe bound
-    occ = np.zeros(cap, bool)
-    for sid, (a, b, c) in enumerate(keys):
-        idx = int(a) & (cap - 1)
-        while occ[idx]:
-            idx = (idx + 1) & (cap - 1)
-        occ[idx] = True
-        table[idx] = (a, b, c, sid + 1)
-    # Queries: every inserted key, plus misses (unknown keys).
-    q = np.concatenate([keys, rng.integers(1, 2**32, size=(n - 20, 3),
-                                           dtype=np.uint64)])
-    h1 = q[:, 0].astype(np.uint32)
-    h2 = q[:, 1].astype(np.uint32)
-    h3 = q[:, 2].astype(np.uint32)
-    probe = make_batch_probe(cap, probes, interpret=True)
-    got = np.asarray(probe(table, h1, h2, h3))
-    want = _np_probe_reference(table, h1, h2, h3, probes)
-    assert np.array_equal(got, want)
-    # The chain tail past the probe bound must come back as misses
-    # (the host absorbs them) — never a wrong id.
-    assert (got[:20] == -1).sum() > 0
-    assert ((got[:20] == -1) | (got[:20] == np.arange(20))).all()
-
-
-def test_pallas_loc_table_builder_dedup_exact():
-    from parca_agent_tpu.aggregator.pallas_probe import make_loc_table_builder
-
-    rng = np.random.default_rng(7)
-    f_cap, cap_l = 256, 64
-    uniq = rng.integers(1, 2**31, size=(24, 3), dtype=np.uint64)
-    pick = rng.integers(0, 24, size=f_cap)
-    kpid = uniq[pick, 0].astype(np.uint32)
-    khi = uniq[pick, 1].astype(np.uint32)
-    klo = uniq[pick, 2].astype(np.uint32)
-    dead = rng.random(f_cap) < 0.25
-    kpid[dead] = np.uint32(0xFFFFFFFF)
-    # Adversarial probe bases: heavy collisions (mod 8) must only
-    # lengthen chains, never break exactness.
-    base = (kpid % 8).astype(np.uint32)
-    build = make_loc_table_builder(f_cap, cap_l, interpret=True)
-    slot, tpid, thi, tlo = map(np.asarray, build(kpid, khi, klo, base))
-    assert (slot[dead] == -1).all()
-    live = ~dead
-    assert (slot[live] >= 0).all()  # table is big enough: everyone places
-    # Each live lane's claimed slot holds exactly its key.
-    assert np.array_equal(tpid[slot[live]], kpid[live])
-    assert np.array_equal(thi[slot[live]], khi[live])
-    assert np.array_equal(tlo[slot[live]], klo[live])
-    # Dedup: same key => same slot; distinct keys => distinct slots.
-    seen = {}
-    for i in np.flatnonzero(live):
-        key = (int(kpid[i]), int(khi[i]), int(klo[i]))
-        assert seen.setdefault(key, int(slot[i])) == int(slot[i])
-    assert len(set(seen.values())) == len(seen)
-
-
-def test_pallas_loc_table_builder_overflow_reports_unplaced():
-    from parca_agent_tpu.aggregator.pallas_probe import make_loc_table_builder
-
-    rng = np.random.default_rng(9)
-    f_cap, cap_l = 64, 8  # 40+ unique keys vs 8 slots: must overflow
-    kpid = rng.integers(1, 2**31, size=f_cap).astype(np.uint32)
-    khi = rng.integers(1, 2**31, size=f_cap).astype(np.uint32)
-    klo = rng.integers(1, 2**31, size=f_cap).astype(np.uint32)
-    base = (kpid & np.uint32(cap_l - 1)).astype(np.uint32)
-    build = make_loc_table_builder(f_cap, cap_l, interpret=True)
-    slot, tpid, thi, tlo = map(np.asarray, build(kpid, khi, klo, base))
-    unplaced = slot < 0
-    assert unplaced.any()  # the caller's doubled-cap retry contract
-    # Everyone that DID place is exact regardless.
-    ok = ~unplaced
-    assert np.array_equal(tpid[slot[ok]], kpid[ok])
-
-
-# -- feed probe backend: pallas vs lax, and the unavailable fallback ----------
-
-
-def test_dict_pallas_probe_matches_lax():
-    from parca_agent_tpu.aggregator.pallas_probe import pallas_available
-
-    if not pallas_available():
-        pytest.skip("Pallas unavailable in this environment")
-    snap = _snap(seed=11)
-    lax = DictAggregator(capacity=1 << 11, overflow="raise")
-    pal = DictAggregator(capacity=1 << 11, overflow="raise",
-                         probe_backend="pallas")
-    h = lax.hash_rows(snap)
-    for w in range(3):
-        lax.feed(snap, h)
-        pal.feed(snap, h)
-        cl = lax.close_window()
-        cp = pal.close_window()
-        assert np.array_equal(cl, cp), w
-    assert pal._probe_resolved == "pallas"
-    assert pal.stats["inserts"] == lax.stats["inserts"]
-
-
-def test_dict_probe_backend_falls_back_when_pallas_unavailable(
-        monkeypatch, device_telemetry):
-    from parca_agent_tpu.aggregator import pallas_probe
-
-    from parca_agent_tpu.web import render_metrics
-
-    monkeypatch.setattr(pallas_probe, "pallas_available", lambda: False)
-    snap = _snap(seed=13, rows=128, pids=4)
-    for backend in ("pallas", "auto"):
-        a = DictAggregator(capacity=1 << 10, overflow="raise",
-                           probe_backend=backend)
-        a.feed(snap, a.hash_rows(snap))
-        c = a.close_window()
-        assert a._probe_resolved == "lax"
-        assert int(c.sum()) == snap.total_samples()
-        if backend == "pallas":
-            # Asked for by name and not delivered: the fallback gauge.
-            _assert_fallback_gauge(device_telemetry, "feed_probe")
-    # "auto" resolving to lax is auto doing its job, not a fallback.
-    assert 'parca_agent_kernel_fallback{kernel="feed_probe"} 0' \
-        in render_metrics([], device_telemetry=device_telemetry)
-
-
-def test_auto_never_selects_a_kernel_mosaic_refused(
-        monkeypatch, device_telemetry):
-    """On a TPU (default_interpret() False) Mosaic refuses both Pallas
-    kernels at lowering — the chip's verdict, recorded in the
-    pallas_probe module docs — so "auto" must resolve to the lax
-    programs there, up front, with no failed compile and no fallback
-    flag: neither probe_backend="auto" nor --aggregator tpu's default
-    dedup="auto" may select them."""
-    from parca_agent_tpu.aggregator import pallas_probe
-    from parca_agent_tpu.aggregator.tpu import TPUAggregator
-    from parca_agent_tpu.web import render_metrics
-
-    def _must_not_build(*a, **kw):
-        raise AssertionError("auto built a Pallas kernel on a TPU")
-
-    monkeypatch.setattr(pallas_probe, "default_interpret", lambda: False)
-    monkeypatch.setattr(pallas_probe, "make_batch_probe", _must_not_build)
-    monkeypatch.setattr(pallas_probe, "make_loc_table_builder",
-                        _must_not_build)
-    assert pallas_probe.auto_uses_pallas() is False
-    snap = _snap(seed=19, rows=128, pids=4)
-    a = DictAggregator(capacity=1 << 10, overflow="raise",
-                       probe_backend="auto")
-    a.feed(snap, a.hash_rows(snap))
-    assert int(a.close_window().sum()) == snap.total_samples()
-    assert a._probe_resolved == "lax"
-    t = TPUAggregator()
-    assert t.dedup == "auto" and t._use_hash() is False
-    assert not t._hash_disabled  # selected, not latched off
-    metrics = render_metrics([], device_telemetry=device_telemetry)
-    for kernel in ("feed_probe", "loc_dedup"):
-        assert f'parca_agent_kernel_fallback{{kernel="{kernel}"}} 0' \
-            in metrics
-        assert f'parca_agent_kernel_backend{{kernel="{kernel}",' \
-            f'backend="lax"}} 1' in metrics
-
-
-def test_dict_probe_runtime_failure_latches_lax(
-        monkeypatch, device_telemetry):
-    """pallas_available() can pass (CPU interpret round-trip) while the
-    real lowering later refuses the kernel at first dispatch — the feed
-    must latch the lax fallback instead of failing every window
-    (mirrors TPUAggregator.aggregate's latched fallback)."""
-    from parca_agent_tpu.aggregator import dict as dict_mod
-    from parca_agent_tpu.aggregator import pallas_probe
-
-    def _broken_probe(cap, probes, interpret=None):
-        def probe(table, h1, h2, h3):
-            raise RuntimeError("mosaic refused the probe kernel")
-
-        return probe
-
-    monkeypatch.setattr(pallas_probe, "pallas_available", lambda: True)
-    monkeypatch.setattr(pallas_probe, "make_batch_probe", _broken_probe)
-    # The feed program cache would otherwise serve a pre-poisoned (or
-    # later a poisoned) pallas program to same-shape aggregators.
-    dict_mod._feed_program.cache_clear()
-    try:
-        snap = _snap(seed=17, rows=96, pids=4)
-        a = DictAggregator(capacity=1 << 9, overflow="raise",
-                           probe_backend="auto")
-        a.feed(snap, a.hash_rows(snap))
-        c = a.close_window()
-        assert a._probe_resolved == "lax"  # latched: no per-feed retry
-        assert int(c.sum()) == snap.total_samples()
-        # Subsequent windows stay on the lax path without re-raising.
-        a.feed(snap, a.hash_rows(snap))
-        assert int(a.close_window().sum()) == snap.total_samples()
-        _assert_fallback_gauge(device_telemetry, "feed_probe")
-    finally:
-        dict_mod._feed_program.cache_clear()
-
-
-def test_dict_rejects_unknown_probe_backend():
-    with pytest.raises(ValueError):
-        DictAggregator(capacity=1 << 10, probe_backend="mosaic")
 
 
 # -- double-buffered close: the flip, the split API, delta-fetch --------------
@@ -687,7 +430,7 @@ def test_shadow_compare_passes_with_double_buffering_on():
     """The PR 5 promotion gate must hold over the flip/delta machinery:
     profiles built from double-buffered, delta-fetch closes digest-match
     the CPU aggregator's, window after window."""
-    from parca_agent_tpu.aggregator.tpu import shadow_compare
+    from parca_agent_tpu.runtime.device_health import shadow_compare
 
     snap = _snap(seed=59, rows=1024, pids=16)
     a = DictAggregator(capacity=1 << 13, overflow="raise", delta_fetch=True)
@@ -712,64 +455,3 @@ def test_shadow_compare_passes_with_double_buffering_on():
         got = a._build_profiles(snap, a.close_window())
         assert shadow_compare(got, sub_cpu.aggregate(sub)), w
     assert a.stats.get("delta_closes", 0) >= 1
-
-
-# -- the one-shot batch kernel: hash dedup vs the lax sort --------------------
-
-
-def test_batch_kernel_hash_dedup_matches_sort_bytes():
-    from parca_agent_tpu.aggregator.pallas_probe import pallas_available
-    from parca_agent_tpu.aggregator.tpu import TPUAggregator
-    from parca_agent_tpu.pprof.builder import build_pprof
-
-    if not pallas_available():
-        pytest.skip("Pallas unavailable in this environment")
-    snap = _snap(seed=61, rows=512, pids=8)
-    ts = TPUAggregator()
-    ts.dedup = "sort"
-    th = TPUAggregator()
-    th.dedup = "hash"
-    ps = sorted(ts.aggregate(snap), key=lambda p: p.pid)
-    ph = sorted(th.aggregate(snap), key=lambda p: p.pid)
-    assert not th._hash_disabled
-    assert b"".join(build_pprof(p, compress=False) for p in ps) == \
-        b"".join(build_pprof(p, compress=False) for p in ph)
-
-
-def test_batch_kernel_hash_failure_falls_back_to_sort(
-        monkeypatch, device_telemetry):
-    """A Pallas build/lowering failure at dispatch degrades to the lax
-    sort kernel — same profiles, and the fallback is latched so the hot
-    path doesn't retry a broken lowering every window."""
-    from parca_agent_tpu.aggregator import pallas_probe
-    from parca_agent_tpu.aggregator.tpu import TPUAggregator
-
-    def boom(*a, **k):
-        raise RuntimeError("injected lowering failure")
-
-    monkeypatch.setattr(pallas_probe, "make_loc_table_builder", boom)
-    snap = _snap(seed=67, rows=128, pids=4)
-    t = TPUAggregator()
-    t.dedup = "hash"
-    profs = t.aggregate(snap)
-    assert t._hash_disabled
-    assert sum(p.total() for p in profs) == snap.total_samples()
-    # Latched: the second window never re-enters the hash path.
-    profs2 = t.aggregate(snap)
-    assert sum(p.total() for p in profs2) == snap.total_samples()
-    _assert_fallback_gauge(device_telemetry, "loc_dedup")
-
-
-def test_batch_kernel_hash_unavailable_uses_sort(
-        monkeypatch, device_telemetry):
-    from parca_agent_tpu.aggregator import pallas_probe
-    from parca_agent_tpu.aggregator.tpu import TPUAggregator
-
-    monkeypatch.setattr(pallas_probe, "pallas_available", lambda: False)
-    snap = _snap(seed=71, rows=128, pids=4)
-    t = TPUAggregator()
-    t.dedup = "hash"
-    profs = t.aggregate(snap)
-    assert t._hash_disabled
-    assert sum(p.total() for p in profs) == snap.total_samples()
-    _assert_fallback_gauge(device_telemetry, "loc_dedup")

@@ -739,7 +739,7 @@ def test_cli_flags_parse():
 
 
 def test_shadow_compare_digests():
-    from parca_agent_tpu.aggregator.tpu import shadow_compare
+    from parca_agent_tpu.runtime.device_health import shadow_compare
 
     snap = _snap()
     a = CPUAggregator().aggregate(snap)

@@ -69,9 +69,9 @@ def _no_global_state():
 
 def test_first_signature_is_compile_rest_execute():
     t = DeviceTelemetry()
-    t.record("feed_probe", 0.2, shape=(4096, 8, "pallas"))
+    t.record("feed_probe", 0.2, shape=(4096, 8, 128))
     for _ in range(3):
-        t.record("feed_probe", 0.001, shape=(4096, 8, "pallas"))
+        t.record("feed_probe", 0.001, shape=(4096, 8, 128))
     p = t.percentiles()["feed_probe"]
     assert p["compile"]["count"] == 1
     assert p["execute"]["count"] == 3
@@ -154,12 +154,12 @@ def test_transfer_accounting_by_kernel_and_direction():
 
 def test_note_backend_fields_are_sticky():
     t = DeviceTelemetry()
-    t.note_backend("loc_dedup", requested="auto", resolved="pallas",
-                   interpret=True, fallback=False)
-    t.note_backend("loc_dedup", resolved="lax", fallback=True)
-    b = t.backends()["loc_dedup"]
-    assert b == {"requested": "auto", "resolved": "lax",
-                 "interpret": True, "fallback": True}
+    t.note_backend("device", requested="device", resolved="device",
+                   interpret=False, fallback=False)
+    t.note_backend("device", resolved="cpu_fallback", fallback=True)
+    b = t.backends()["device"]
+    assert b == {"requested": "device", "resolved": "cpu_fallback",
+                 "interpret": False, "fallback": True}
 
 
 def test_identity_latches_once_and_names_the_backend():
@@ -255,7 +255,7 @@ def test_telemetry_fault_is_swallowed_and_counted():
     t = DeviceTelemetry(period_s=1.0)
     t.record("feed_probe", 0.01, shape=(1,), h2d_bytes=64)
     t.record_transfer("miss_settle", "h2d", 64)
-    t.note_backend("feed_probe", resolved="lax")
+    t.note_backend("device", resolved="cpu_fallback")
     t.tick_window(0.5)
     assert t.stats["record_errors"] == 4
     assert t.stats["events_total"] == 0
@@ -270,7 +270,7 @@ def test_module_hooks_are_free_without_telemetry():
     dtel_mod.install(None)
     dtel_mod.record("feed_probe", 0.01, shape=(1,))
     dtel_mod.transfer("miss_settle", "h2d", 64)
-    dtel_mod.note_backend("feed_probe", resolved="lax")
+    dtel_mod.note_backend("device", resolved="cpu_fallback")
     dtel_mod.tick_window(0.5)
     assert dtel_mod.get() is None
 
@@ -330,8 +330,8 @@ def test_render_metrics_kernel_transfer_and_budget_families():
     t = DeviceTelemetry(period_s=1.0)
     t.record("feed_probe", 0.2, shape=(4096,), h2d_bytes=1024)
     t.record("feed_probe", 0.001, shape=(4096,))
-    t.note_backend("feed_probe", requested="auto", resolved="pallas",
-                   interpret=True, fallback=False)
+    t.note_backend("device", resolved="cpu_fallback", interpret=True,
+                   fallback=True)
     t.tick_window(0.5)
     t.tick_window(1.5)
     m = render_metrics([], device_telemetry=t)
@@ -342,11 +342,13 @@ def test_render_metrics_kernel_transfer_and_budget_families():
         '{kernel="feed_probe",event="execute"} 1' in m
     assert 'parca_agent_kernel_compiles_total{kernel="feed_probe"} 1' in m
     assert 'parca_agent_kernel_recompiles_total{kernel="feed_probe"} 0' in m
-    assert 'parca_agent_kernel_backend{kernel="feed_probe",' \
-        'backend="pallas"} 1' in m
-    assert 'parca_agent_kernel_backend{kernel="feed_probe",' \
-        'backend="lax"} 0' in m
-    assert 'parca_agent_kernel_interpret{kernel="feed_probe"} 1' in m
+    assert 'parca_agent_kernel_backend{kernel="device",' \
+        'backend="cpu_fallback"} 1' in m
+    assert 'parca_agent_kernel_backend{kernel="device",' \
+        'backend="device"} 0' in m
+    assert m.count('parca_agent_kernel_backend{') == 2
+    assert 'parca_agent_kernel_fallback{kernel="device"} 1' in m
+    assert 'parca_agent_kernel_interpret{kernel="device"} 1' in m
     assert 'parca_agent_transfer_bytes_total{kernel="feed_probe",' \
         'direction="h2d"} 1024' in m
     assert "parca_agent_window_budget_windows_total 2" in m

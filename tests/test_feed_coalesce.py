@@ -703,26 +703,3 @@ def test_sharded_partition_vectorized_matches_reference():
     assert np.array_equal(out2, ref)
     out3 = ShardedDictAggregator._partition_packed(fake, packed)
     assert out3 is out  # alternation wraps
-
-
-def test_tpu_one_shot_folds_cross_tid_duplicates():
-    """The one-shot kernel's padded upload shrinks to unique rows; the
-    profiles must equal the raw run's exactly (the kernel would have
-    merged the same rows by full-row compare)."""
-    from parca_agent_tpu.aggregator.tpu import (
-        TPUAggregator,
-        _coalesce_snapshot_rows,
-    )
-
-    snap = _snap(seed=61, rows=256, pids=8)
-    dup = _dup(snap, dup=3)
-    folded = _coalesce_snapshot_rows(dup)
-    assert len(folded) == len(snap)
-    assert folded.total_samples() == dup.total_samples()
-    # All-unique input passes through untouched (no copy).
-    assert _coalesce_snapshot_rows(snap) is snap
-    got = {p.pid: p for p in TPUAggregator().aggregate(dup)}
-    want = {p.pid: p for p in TPUAggregator().aggregate(snap)}
-    assert set(got) == set(want)
-    for pid, p in want.items():
-        assert got[pid].total() == 3 * p.total()

@@ -123,7 +123,7 @@ def test_synthetic_n_funcs_controls_location_entropy():
     """The n_funcs knob sets per-object function-pool size: small pools
     model real hosts (a pid's hot frames repeat across its stacks),
     large pools are the adversarial near-all-unique case for location
-    dedup (docs/perf.md batch_kernel_n_locs discussion)."""
+    dedup (docs/perf.md "Secondary boundaries")."""
 
     def uniq_pid_frames(snap):
         pids = np.repeat(snap.pids.astype(np.uint64), snap.stacks.shape[1])

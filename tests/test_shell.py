@@ -427,10 +427,11 @@ def test_cli_help_and_flags():
     from parca_agent_tpu.cli import build_parser
 
     p = build_parser()
-    args = p.parse_args(["--aggregator", "tpu", "--profiling-duration", "5"])
-    assert args.aggregator == "tpu" and args.profiling_duration == 5.0
-    with pytest.raises(SystemExit):
-        p.parse_args(["--aggregator", "gpu"])
+    args = p.parse_args(["--aggregator", "dict", "--profiling-duration", "5"])
+    assert args.aggregator == "dict" and args.profiling_duration == 5.0
+    for refused in ("gpu", "tpu"):
+        with pytest.raises(SystemExit):
+            p.parse_args(["--aggregator", refused])
 
 
 def test_status_page_renders_process_errors():

@@ -803,7 +803,7 @@ def test_device_program_names_match_the_benchmarks_patterns(metric, program):
         pattern = json.load(f)["args"]["pattern"]
     u32 = jax.ShapeDtypeStruct
     if program == "feed":
-        lowered = dict_mod._feed_program(1024, 512, 64, 4, 128, "lax").lower(
+        lowered = dict_mod._feed_program(1024, 512, 64, 4, 128).lower(
             u32((1024, 4), jnp.uint32), u32((512,), jnp.int32),
             u32((4,), jnp.int32), u32((4, 64), jnp.uint32),
             u32((), jnp.uint32))
