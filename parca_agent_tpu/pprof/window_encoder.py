@@ -278,6 +278,102 @@ class _ContentCache:
             self.evictions += 1
 
 
+class _PieceCache:
+    """One slot per template group for the COMPRESSED form of the group's
+    static span, kept for the ship path (agent/writer.py splices it into
+    every window's gzip member instead of deflating the span again). A
+    slot holds (revision, piece); the revision is the one the span's
+    bytes were written under (_Template.span_rev), so a piece made from
+    bytes since rewritten is never handed out. The cache is an attribute
+    of the template's layout: a relayout, a reset or a rotation replaces
+    it whole, and nothing else ever evicts."""
+
+    __slots__ = ("slots", "nbytes")
+
+    def __init__(self, n_groups: int):
+        self.slots: list = [None] * n_groups
+        self.nbytes = 0  # compressed bytes held
+
+
+class _SpanTable:
+    """One encoded window's static spans by group, as plain lists (the
+    template's arrays at emit time), with the layout's piece cache: what
+    every _SpanBlob of the window shares, so a blob itself is three
+    references."""
+
+    __slots__ = ("off", "length", "rev", "pieces")
+
+    def __init__(self, tmpl: "_Template"):
+        self.off = tmpl.span_off.tolist()
+        self.length = tmpl.span_len.tolist()
+        self.rev = tmpl.span_rev.tolist()
+        self.pieces = tmpl.pieces
+
+
+class _SpanBlob:
+    """One pid's profile as the writer receives it from a pipelined
+    window: a zero-copy bytes-like over the blob in the template buffer
+    (buffer protocol, len()) that also says where the blob's
+    static span [head][locations][tail] lies (`static_span`: offset and
+    length inside the blob) and reaches the group's slot for the span's
+    compressed piece. Valid, like the view, until the next encode."""
+
+    __slots__ = ("_view", "_spans", "_g")
+
+    def __init__(self, view: memoryview, spans: _SpanTable, g: int):
+        self._view = view
+        self._spans = spans
+        self._g = g
+
+    def __buffer__(self, flags: int) -> memoryview:
+        return self._view
+
+    def __len__(self) -> int:
+        return self._view.nbytes
+
+    @property
+    def static_span(self) -> tuple[int, int]:
+        t, g = self._spans, self._g
+        return t.off[g], t.length[g]
+
+    def static_piece(self) -> bytes | None:
+        """The span's compressed piece, if one made from the bytes now
+        in the span is held."""
+        t, g = self._spans, self._g
+        slot = t.pieces.slots[g]
+        if slot is not None and slot[0] == t.rev[g]:
+            return slot[1]
+        return None
+
+    def keep_static_piece(self, piece: bytes) -> None:
+        t, g = self._spans, self._g
+        cache = t.pieces
+        old = cache.slots[g]
+        cache.slots[g] = (t.rev[g], piece)
+        cache.nbytes += len(piece) - (len(old[1]) if old is not None else 0)
+
+
+class _SpanViews(list):
+    """What a views=True encode returns: [(pid, memoryview)] as it always
+    was, to every consumer that iterates, indexes or measures it, plus
+    the window's span table. The ship path asks for `span_blobs()`: the
+    same pairs with each view wrapped, one at a time as it is written,
+    into a _SpanBlob (so the wrapping costs the encode nothing and no
+    window's worth of wrappers is ever alive at once)."""
+
+    __slots__ = ("_spans", "_groups")
+
+    def __init__(self, pairs, spans: _SpanTable, groups: list):
+        super().__init__(pairs)
+        self._spans = spans
+        self._groups = groups    # group index of each pair
+
+    def span_blobs(self):
+        spans = self._spans
+        for (pid, view), g in zip(self, self._groups):
+            yield pid, _SpanBlob(view, spans, g)
+
+
 class _Template:
     """Cached whole-window serialization: every pid's profile bytes laid
     out in one uint8 buffer, one independent blob slice per pid, with the
@@ -308,8 +404,8 @@ class _Template:
     __slots__ = ("buf", "n_rows", "row_of", "row_id", "row_group",
                  "val_pos", "pids", "blob_start", "blob_end", "cap_end",
                  "time_pos", "group_of", "g_head_len", "g_tail_len",
-                 "g_loc_len", "alloc_end", "waste", "rotations",
-                 "period_ns")
+                 "g_loc_len", "span_off", "span_len", "span_rev", "pieces",
+                 "alloc_end", "waste", "rotations", "period_ns")
 
     def __init__(self):
         self.buf = None          # np.uint8 big buffer
@@ -327,6 +423,16 @@ class _Template:
         self.g_head_len = None   # int64 [G] static head bytes in blob
         self.g_tail_len = None   # int64 [G] static tail bytes in blob
         self.g_loc_len = None    # int64 [G] location bytes in blob
+        # The static span: the contiguous run [head][locations][tail] as
+        # it was LAID DOWN, blob-relative (a relocation moves a blob, not
+        # the span inside it). Not g_head_len + g_loc_len + g_tail_len:
+        # _append_rows adds a location delta behind the time tail and
+        # raises g_loc_len by it, so that sum outgrows the run.
+        self.span_off = None     # int64 [G] span start inside the blob
+        self.span_len = None     # int64 [G] span length
+        self.span_rev = None     # int64 [G] bumped when the span's bytes
+        #                          are rewritten (never by a move)
+        self.pieces = None       # _PieceCache, one slot per group
         self.alloc_end = 0       # buffer high-water mark
         self.waste = 0           # relocation holes, bytes
         self.rotations = -1      # aggregator rotation epoch at build
@@ -428,6 +534,9 @@ class WindowEncoder:
         # provably a no-op and is skipped (it used to run per drain).
         self._statics_clean: tuple | None = None
         self._tmpl = _Template()
+        # Static-span revisions: one counter for the encoder's life, so
+        # a revision is never handed out twice (reset() keeps it).
+        self._span_rev = 0
         self.timings: dict[str, float] = {}
         # Per-encode observability (ADVICE round 5): the churn-tolerant
         # template ships dead rows as count-0 samples — legal protobuf,
@@ -551,6 +660,11 @@ class WindowEncoder:
         self._statics_clean = None
         self._tmpl = _Template()
         self.last_prep = None
+
+    def static_piece_bytes(self) -> int:
+        """Compressed bytes the template's piece cache holds now."""
+        pieces = self._tmpl.pieces
+        return 0 if pieces is None else pieces.nbytes
 
     def _ensure_order(self) -> None:
         """Rebuild the id-by-pid sort order if stale. Lazy and separate
@@ -1168,6 +1282,11 @@ class WindowEncoder:
         tmpl.g_tail_len = np.array([len(s.tail) for s in statics], np.int64)
         tmpl.g_loc_len = np.array(
             [len(s.loc_bytes) for s in statics], np.int64)
+        tmpl.span_off = samples_per_g
+        tmpl.span_len = static_lens
+        self._span_rev += 1
+        tmpl.span_rev = np.full(len(pids), self._span_rev, np.int64)
+        tmpl.pieces = _PieceCache(len(pids))
         tmpl.alloc_end = total
         tmpl.waste = 0
         tmpl.rotations = self._rotations
@@ -1332,9 +1451,11 @@ class WindowEncoder:
                 (tmpl.pids, np.array(cols[0], np.int32)))
             for slot, col in zip(("blob_start", "blob_end", "cap_end",
                                   "time_pos", "g_head_len", "g_tail_len",
-                                  "g_loc_len"), cols[1:]):
+                                  "g_loc_len", "span_off", "span_len",
+                                  "span_rev"), cols[1:]):
                 setattr(tmpl, slot, np.concatenate(
                     (getattr(tmpl, slot), np.array(col, np.int64))))
+            tmpl.pieces.slots.extend([None] * len(pend))
         # Register the new rows (one concatenate per array per window).
         tmpl.row_id = np.concatenate((tmpl.row_id[:n0], new_ids))
         tmpl.row_group = np.concatenate((tmpl.row_group[:n0], add_group))
@@ -1345,7 +1466,9 @@ class WindowEncoder:
 
     def _relocate_blob(self, g: int, extra: int) -> None:
         """Move group g's blob to the end of the buffer with fresh slack
-        sized for `extra` more bytes; the old region becomes waste."""
+        sized for `extra` more bytes; the old region becomes waste. The
+        static span moves with the blob, byte for byte: its offset inside
+        the blob and its revision stand, and so does its cached piece."""
         tmpl = self._tmpl
         start, end = int(tmpl.blob_start[g]), int(tmpl.blob_end[g])
         blob_len = end - start
@@ -1393,10 +1516,12 @@ class WindowEncoder:
         tpos = a
         buf[tpos] = (P_TIME_NANOS << 3)
         buf[tpos + 1 + self._TIME_W] = (P_DURATION_NANOS << 3)
+        self._span_rev += 1  # the span's bytes were just (re)written
         if g is None:
             g = next_g
             pend.append((pid, base, base + blob_len, base + cap, tpos,
-                         len(st.head), len(st.tail), len(st.loc_bytes)))
+                         len(st.head), len(st.tail), len(st.loc_bytes),
+                         int(s_off[-1]), static_len, self._span_rev))
             tmpl.group_of[pid] = g
         else:
             tmpl.waste += int(tmpl.cap_end[g]) - int(tmpl.blob_start[g])
@@ -1407,6 +1532,9 @@ class WindowEncoder:
             tmpl.g_head_len[g] = len(st.head)
             tmpl.g_tail_len[g] = len(st.tail)
             tmpl.g_loc_len[g] = len(st.loc_bytes)
+            tmpl.span_off[g] = int(s_off[-1])
+            tmpl.span_len[g] = static_len
+            tmpl.span_rev[g] = self._span_rev
             if len(rows_g):
                 tmpl.val_pos[rows_g] = base + vp_rel[: len(rows_g)]
         tmpl.alloc_end = base + cap
@@ -1457,9 +1585,11 @@ class WindowEncoder:
         """Serialize one closed window: per-stack-id counts (as returned by
         close_window/window_counts) -> [(pid, profile.proto bytes)].
 
-        views=True returns zero-copy memoryviews into the template buffer —
-        valid only until the next encode() call; for callers (bench, batch
-        writer) that consume within the window.
+        views=True returns zero-copy memoryviews into the template buffer
+        (a _SpanViews list: it also knows where each blob's static span
+        lies, for the ship path's gzip) — valid only until the next
+        encode() call; for callers (bench, batch writer) that consume
+        within the window.
         """
         prep = self.prepare(counts, time_ns, duration_ns, period_ns)
         if self.track_prep:
@@ -1572,10 +1702,15 @@ class WindowEncoder:
                     out.append((pid, _gzip.compress(
                         bytes(mv[int(bs[g]): int(be[g])]), 1)))
         elif views:
+            # The views go out with the window's span table, for the
+            # ship path's gzip (_SpanViews.span_blobs).
             mv = buf.data
-            for g, pid in enumerate(pid_list):
-                if live_g[g]:
-                    out.append((pid, mv[int(bs[g]): int(be[g])]))
+            live = np.flatnonzero(live_g)
+            out = _SpanViews(
+                [(pid, mv[a:b]) for pid, a, b in zip(
+                    tmpl.pids[live].tolist(), bs[live].tolist(),
+                    be[live].tolist())],
+                _SpanTable(tmpl), live.tolist())
         else:
             for g, pid in enumerate(pid_list):
                 if live_g[g]:

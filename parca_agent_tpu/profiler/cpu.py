@@ -62,6 +62,13 @@ class ProfilerMetrics:
     # into the void — now it is logged and counted here.
     device_abandoned_ok_total: int = 0
     device_abandoned_err_total: int = 0
+    # What the ship's gzip did (agent/writer.py): static pieces spliced
+    # from the encoder's cache or built anew, bytes that went through
+    # deflate, and splices that fell back to a plain gzip.compress.
+    ship_static_reused_total: int = 0
+    ship_static_built_total: int = 0
+    ship_deflated_bytes_total: int = 0
+    ship_gzip_fallbacks_total: int = 0
 
 
 class CPUProfiler:
@@ -787,8 +794,15 @@ class CPUProfiler:
             for p in profiles)
 
     def _write_encoded(self, out) -> int:
-        """Ship [(pid, blob)] from the fast encoder through the writer."""
-        return self._write_all((pid, lambda b=blob: b) for pid, blob in out)
+        """Ship [(pid, blob)] from the fast encoder through the writer.
+        Where the encoder's output knows its blobs' static spans (a
+        pipelined window's views: window_encoder._SpanViews), each blob
+        goes to the writer with its span, so that the writer's gzip can
+        splice what did not change (agent/writer.py)."""
+        span_blobs = getattr(out, "span_blobs", None)
+        return self._write_all(
+            (pid, lambda b=blob: b)
+            for pid, blob in (out if span_blobs is None else span_blobs()))
 
     def _write_all(self, items) -> int:
         """One window's ship: every (pid, payload) through _write_one.
@@ -811,8 +825,18 @@ class CPUProfiler:
                 trace_mod.note("ship_gzip", c["gzip_s"], accumulated=True)
                 trace_mod.note("ship_enqueue", c["enqueue_s"],
                                accumulated=True)
-                trace_mod.count(pprof_bytes=c["pprof_bytes"],
-                                gzip_bytes=c["gzip_bytes"])
+                counts = {k: c[k] for k in (
+                    "pprof_bytes", "gzip_bytes", "gzip_static_reused",
+                    "gzip_static_built", "gzip_deflated_bytes",
+                    "gzip_fallbacks")}
+                trace_mod.count(**counts)
+                m = self.metrics
+                with self._write_mu:
+                    m.ship_static_reused_total += counts["gzip_static_reused"]
+                    m.ship_static_built_total += counts["gzip_static_built"]
+                    m.ship_deflated_bytes_total += \
+                        counts["gzip_deflated_bytes"]
+                    m.ship_gzip_fallbacks_total += counts["gzip_fallbacks"]
         return n
 
     def _ship_encoded(self, out, prep) -> None:

@@ -9,6 +9,10 @@ layer the ship-path components consult at NAMED SITES:
     grpc.handshake    channel construction (TLS handshake class)
     spool.write       spill-segment write (disk_full)
     writer.write      local-store profile write (disk_full)
+    writer.splice     one profile's spliced gzip member (agent/writer.py)
+                      — fail-open: an injected fault ships that profile
+                      through plain gzip.compress, counted
+                      (gzip_fallbacks), never lost
     batch.flush       one flush attempt of the batch client
     actor.<name>      a supervised actor's loop tick (crash)
     statics.snapshot  warm statics+registry snapshot write
@@ -157,6 +161,7 @@ SITES = {
     "grpc.handshake": "channel construction (agent/grpc_client.py)",
     "spool.write": "spill-segment write (agent/spool.py)",
     "writer.write": "local-store profile write (agent/writer.py)",
+    "writer.splice": "one profile's spliced gzip member (agent/writer.py)",
     "batch.flush": "one flush attempt (agent/batch.py)",
     "actor.*": "a supervised actor's loop tick (runtime/supervisor.py)",
     "statics.snapshot": "warm statics snapshot (pprof/statics_store.py)",

@@ -245,6 +245,19 @@ def render_metrics(profilers, batch_client=None, extra: dict | None = None,
              p.metrics.device_abandoned_ok_total, lab)
         emit("parca_agent_profiler_device_abandoned_err_total",
              p.metrics.device_abandoned_err_total, lab)
+        # The ship's gzip (docs/perf.md "the spliced gzip member"): a
+        # steady window reuses one static piece a profile and deflates
+        # only what changed; `built` rises when registries grow, and
+        # `fallbacks` is the counted fail-open arm.
+        emit("parca_agent_ship_static_pieces_total",
+             p.metrics.ship_static_reused_total,
+             {**lab, "outcome": "reused"})
+        emit("parca_agent_ship_static_pieces_total",
+             p.metrics.ship_static_built_total, {**lab, "outcome": "built"})
+        emit("parca_agent_ship_deflated_bytes_total",
+             p.metrics.ship_deflated_bytes_total, lab)
+        emit("parca_agent_ship_gzip_fallbacks_total",
+             p.metrics.ship_gzip_fallbacks_total, lab)
         pipe = getattr(p, "_pipeline", None)
         if pipe is not None:
             # Encode-pipeline observability: how much encode/ship work ran
@@ -346,6 +359,9 @@ def render_metrics(profilers, batch_client=None, extra: dict | None = None,
             for k, v in enc.stats.items():
                 emit(f"parca_agent_encoder_{k}",
                      round(v, 6) if isinstance(v, float) else v, lab)
+            pieces = getattr(enc, "static_piece_bytes", None)
+            if pieces is not None:
+                emit("parca_agent_ship_static_cache_bytes", pieces(), lab)
     if batch_client is not None:
         emit("parca_agent_remote_write_batches_sent_total",
              batch_client.sent_batches)

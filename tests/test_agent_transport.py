@@ -3,10 +3,14 @@ writers, and a live in-process gRPC loopback."""
 
 import gzip
 import random
+import struct
 import threading
 import time
+import zlib
 
+import numpy as np
 import pytest
+import span_scenarios
 
 from parca_agent_tpu.agent.batch import BatchWriteClient, NoopStoreClient
 from parca_agent_tpu.agent.listener import MatchingProfileListener, equals_matcher
@@ -489,3 +493,276 @@ def test_batch_buffered_depth_gauge():
     assert c.buffered() == (2, 3)
     c.flush()
     assert c.buffered() == (0, 0)
+
+
+# -- the spliced gzip member (agent/writer.py _gzip) ---------------------------
+# Blobs of the fast encoder say where their static block lies; the writer
+# deflates that block once and splices the piece into every later member.
+# The windows come from tests/span_scenarios.py, shared with
+# tests/test_window_encoder.py.
+
+
+class _KeepSink:
+    def __init__(self):
+        self.samples = []
+
+    def write_raw(self, labels, sample):
+        self.samples.append(sample)
+
+
+def _writer(kind, tmp_path):
+    """(writer, function returning the members written so far in order)."""
+    if kind == "remote":
+        sink = _KeepSink()
+        return RemoteProfileWriter(sink), lambda: list(sink.samples)
+    w = FileProfileWriter(str(tmp_path))
+    return w, lambda: [p.read_bytes() for p in sorted(
+        tmp_path.iterdir(), key=lambda p: int(p.name.split(".")[-3]))]
+
+
+def _ship(writer, members, out):
+    """Write one window's blobs; assert every member inflates to the live
+    bytes with a matching trailer; return the thread's sums."""
+    n0 = len(members())
+    want = [bytes(b) for _, b in out]
+    for pid, blob in out.span_blobs():
+        writer.write({"pid": str(pid)}, blob)
+    got = members()[n0:]
+    assert len(got) == len(want)
+    for member, raw in zip(got, want):
+        assert gzip.decompress(member) == raw
+        crc, size = struct.unpack("<II", member[-8:])
+        assert (crc, size) == (zlib.crc32(raw), len(raw))
+    sums = writer.take_ship_clocks()
+    assert sums["pprof_bytes"] == sum(map(len, want))
+    assert sums["gzip_bytes"] == sum(map(len, got))
+    return sums, got
+
+
+@pytest.mark.parametrize("kind", ["remote", "file"])
+@pytest.mark.parametrize("shape", sorted(span_scenarios.SHAPES))
+def test_spliced_member_inflates_to_the_blob(shape, kind, tmp_path):
+    """Three windows of one population with redrawn counts: the first
+    builds a piece a profile, the next two reuse every one and deflate
+    only the sample rows; every member is a plain gzip member of exactly
+    the blob, no larger than gzip.compress(.., 1) makes it."""
+    from parca_agent_tpu.aggregator.dict import DictAggregator
+    from parca_agent_tpu.pprof.window_encoder import WindowEncoder
+
+    snap = span_scenarios.shape_snapshot(shape)
+    agg = DictAggregator(capacity=1 << 12)
+    enc = WindowEncoder(agg)
+    counts = np.asarray(agg.window_counts(snap))
+    writer, members = _writer(kind, tmp_path)
+    for k in range(3):
+        c = counts + 5 * k
+        out = enc.encode(c, snap.time_ns + k, snap.window_ns,
+                         snap.period_ns, views=True)
+        sums, got = _ship(writer, members, out)
+        n = len(out)
+        assert sums["gzip_fallbacks"] == 0
+        assert (sums["gzip_static_built"], sums["gzip_static_reused"]) \
+            == ((n, 0) if k == 0 else (0, n))
+        rows = sum(b.static_span[0] for _, b in out.span_blobs())
+        static = sum(b.static_span[1] for _, b in out.span_blobs())
+        assert sums["gzip_deflated_bytes"] \
+            == rows + (static if k == 0 else 0)
+        assert all(m[:10] == b"\x1f\x8b\x08\x00\x00\x00\x00\x00\x04\xff"
+                   for m in got)
+        plain = sum(len(gzip.compress(bytes(b), 1)) for _, b in out)
+        assert sums["gzip_bytes"] <= plain + 16 * n
+    assert enc.static_piece_bytes() > 0
+
+
+@pytest.mark.parametrize("site", span_scenarios.SITES)
+def test_spliced_member_after_churn(site, tmp_path):
+    """Through every site that lays down, moves or rewrites a static
+    span: the member inflates to the live bytes, and a piece is built
+    for exactly the groups whose span was rewritten."""
+    writer, members = _writer("remote", tmp_path)
+    for enc, out, rewritten, note in span_scenarios.run(site):
+        sums, _ = _ship(writer, members, out)
+        want_built = len(out) if rewritten is None \
+            else len(rewritten & {p for p, _ in out})
+        assert sums["gzip_fallbacks"] == 0, note
+        assert sums["gzip_static_built"] == want_built, note
+        assert sums["gzip_static_reused"] == len(out) - want_built, note
+
+
+@pytest.mark.parametrize("kind", ["bytes", "bytearray", "memoryview"])
+def test_spanless_payload_is_plain_gzip(kind):
+    """A payload that carries no span (the scalar path's build_pprof
+    bytes, the self-profile) gets what it always got."""
+    raw = bytes(range(256)) * 40
+    payload = {"bytes": raw, "bytearray": bytearray(raw),
+               "memoryview": memoryview(raw)}[kind]
+    sink = _KeepSink()
+    w = RemoteProfileWriter(sink)
+    w.write({"pid": "1"}, payload)
+    (member,) = sink.samples
+    assert gzip.decompress(member) == raw
+    # gzip.compress(raw, 1) itself, but for the time in its header.
+    assert member[:4] + member[8:10] == b"\x1f\x8b\x08\x00\x04\xff"
+    assert member[10:] == gzip.compress(raw, 1)[10:]
+    sums = w.take_ship_clocks()
+    assert (sums["gzip_static_reused"], sums["gzip_static_built"],
+            sums["gzip_fallbacks"]) == (0, 0, 0)
+    assert sums["gzip_deflated_bytes"] == sums["pprof_bytes"] == len(raw)
+
+
+def _one_window():
+    gen = span_scenarios.run("counts")
+    next(gen)
+    enc, out = next(gen)[:2]
+    return enc, list(out.span_blobs())
+
+
+def test_two_threads_write_at_once():
+    """The encode worker splices while the profiler thread's scalar
+    fallback writes plain bytes through the same writer: each thread has
+    its own compressor and its own sums."""
+    enc, out = _one_window()
+    sink = _KeepSink()
+    w = RemoteProfileWriter(sink)
+    raws = {bytes(b) for _, b in out}
+    plain = [bytes([k]) * 3000 + bytes(range(256)) for k in range(60)]
+    start = threading.Barrier(2)
+    sums = {}
+
+    def spliced():
+        start.wait()
+        for _ in range(6):
+            for pid, blob in out:
+                w.write({"pid": str(pid)}, blob)
+        sums["spliced"] = w.take_ship_clocks()
+
+    def scalar():
+        start.wait()
+        for raw in plain:
+            w.write({"pid": "0"}, raw)
+        sums["plain"] = w.take_ship_clocks()
+
+    threads = [threading.Thread(target=f) for f in (spliced, scalar)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=30)
+    inflated = [gzip.decompress(m) for m in sink.samples]
+    assert len(inflated) == 6 * len(out) + len(plain)
+    assert {r for r in inflated if r not in raws} == set(plain)
+    assert sum(r in raws for r in inflated) == 6 * len(out)
+    assert sums["spliced"]["gzip_static_built"] == len(out)
+    assert sums["spliced"]["gzip_static_reused"] == 5 * len(out)
+    assert sums["plain"]["gzip_static_built"] == 0
+    assert sums["plain"]["pprof_bytes"] == sum(map(len, plain))
+    assert sums["spliced"]["gzip_fallbacks"] == 0
+
+
+class _RaisingBlob:
+    """A span-carrying payload whose piece slot is broken."""
+
+    def __init__(self, blob):
+        self._blob = blob
+        self.static_span = blob.static_span
+
+    def __buffer__(self, flags):
+        return memoryview(self._blob)
+
+    def __len__(self):
+        return len(self._blob)
+
+    def static_piece(self):
+        raise RuntimeError("slot gone")
+
+
+@pytest.mark.chaos
+@pytest.mark.parametrize("cause", ["injected", "raising_payload"])
+def test_splice_fault_ships_by_the_fallback_and_counts(cause):
+    """A splice that raises costs that profile the splice, never the
+    profile: it ships through gzip.compress(.., 1), counted, and the
+    next profile splices again from a fresh compressor."""
+    from parca_agent_tpu.utils import faults
+    from parca_agent_tpu.utils.faults import FaultInjector
+
+    enc, out = _one_window()
+    sink = _KeepSink()
+    w = RemoteProfileWriter(sink)
+    blobs = [b for _, b in out]
+    for b in blobs:                       # warm: every piece built
+        w.write({}, b)
+    w.take_ship_clocks()
+    sink.samples.clear()
+    try:
+        if cause == "injected":
+            faults.install(FaultInjector.from_spec(
+                "writer.splice:error:count=1", seed=0))
+            w.write({}, blobs[0])
+        else:
+            w.write({}, _RaisingBlob(blobs[0]))
+        for b in blobs[1:]:
+            w.write({}, b)
+    finally:
+        faults.install(None)
+    assert [gzip.decompress(m) for m in sink.samples] \
+        == [bytes(b) for b in blobs]
+    sums = w.take_ship_clocks()
+    assert sums["gzip_fallbacks"] == 1
+    assert sums["gzip_static_reused"] == len(blobs) - 1
+    assert sums["gzip_static_built"] == 0
+    # The fallback's member is gzip.compress's own (its header carries a
+    # time); the spliced ones carry none.
+    assert sink.samples[0][4:8] != b"\x00\x00\x00\x00"
+    assert all(m[4:8] == b"\x00\x00\x00\x00" for m in sink.samples[1:])
+
+
+def test_spliced_member_is_byte_deterministic():
+    """Equal input, equal member (mtime 0), whatever the compressor and
+    the piece cache went through before: a fresh writer on a fresh
+    thread, a warm writer, and a warm writer after a fallback."""
+    enc, out = _one_window()
+    blobs = [b for _, b in out]
+
+    def members(writer_warmup):
+        sink = _KeepSink()
+        w = RemoteProfileWriter(sink)
+        writer_warmup(w)
+        sink.samples.clear()
+        for b in blobs:
+            w.write({}, b)
+        return list(sink.samples)
+
+    first = members(lambda w: None)
+    warm = members(lambda w: [w.write({}, b) for b in blobs * 2])
+    mixed = members(lambda w: [w.write({}, p) for p in
+                               (blobs[3], b"x" * 70_000,
+                                _RaisingBlob(blobs[0]), blobs[1])])
+    assert first == warm == mixed
+    box = []
+    t = threading.Thread(target=lambda: box.append(members(lambda w: None)))
+    t.start()
+    t.join(timeout=30)
+    assert box == [first]
+
+
+def test_tee_writer_sums_every_arm(tmp_path):
+    """Both arms of a Tee splice for themselves (the second finds the
+    piece the first built) and the sums add up."""
+    from parca_agent_tpu.agent.writer import TeeProfileWriter
+
+    enc, out = _one_window()
+    sink = _KeepSink()
+    tee = TeeProfileWriter(FileProfileWriter(str(tmp_path)),
+                           RemoteProfileWriter(sink))
+    assert enc.static_piece_bytes() == 0
+    for pid, b in out:
+        tee.write({"pid": str(pid)}, b)
+    want = [bytes(b) for _, b in out]
+    assert [gzip.decompress(m) for m in sink.samples] == want
+    files = sorted(tmp_path.iterdir(),
+                   key=lambda p: int(p.name.split(".")[-3]))
+    assert [gzip.decompress(p.read_bytes()) for p in files] == want
+    sums = tee.take_ship_clocks()
+    assert sums["gzip_static_built"] == len(out)
+    assert sums["gzip_static_reused"] == len(out)
+    assert sums["pprof_bytes"] == 2 * sum(map(len, want))
+    assert sums["gzip_fallbacks"] == 0

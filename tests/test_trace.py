@@ -881,3 +881,43 @@ def test_a_compile_is_put_down_to_the_window_it_fell_in():
     first, second = rec.traces()
     assert "xla_compiles" not in first.get("meta", {})   # only when not 0
     assert second["meta"]["xla_compiles"] == 2
+
+
+@pytest.mark.parametrize("pipelined", [True, False],
+                         ids=["pipelined", "inline"])
+def test_gzip_counts_on_the_window_meta_and_on_metrics(pipelined):
+    """The ship says on the window's ``meta`` what its gzip did: a
+    pipelined window's blobs carry their static span, so the first one
+    builds a piece a profile and every later one reuses them and
+    deflates a fraction of its bytes; an inline window ships plain
+    copies, which are deflated whole. ``/metrics`` carries the sums."""
+    from parca_agent_tpu.web import render_metrics
+
+    profilers = []
+    _rec, traces = _traced_windows(pipelined, profilers=profilers)
+    metas = [t["meta"] for t in traces]
+    assert all(m["profiles"] == 6 and m["gzip_fallbacks"] == 0
+               for m in metas)
+    if pipelined:
+        assert [m["gzip_static_built"] for m in metas] == [6, 0, 0]
+        assert [m["gzip_static_reused"] for m in metas] == [0, 6, 6]
+        assert metas[0]["gzip_deflated_bytes"] < metas[0]["pprof_bytes"]
+        assert all(m["gzip_deflated_bytes"] < m["pprof_bytes"] // 2
+                   for m in metas[1:])
+    else:
+        assert all(m["gzip_static_built"] == m["gzip_static_reused"] == 0
+                   and m["gzip_deflated_bytes"] == m["pprof_bytes"]
+                   for m in metas)
+    text = render_metrics(profilers)
+    built, reused = (6, 12) if pipelined else (0, 0)
+    assert ('parca_agent_ship_static_pieces_total{profiler="cpu",'
+            f'outcome="reused"}} {reused}') in text
+    assert ('parca_agent_ship_static_pieces_total{profiler="cpu",'
+            f'outcome="built"}} {built}') in text
+    assert ('parca_agent_ship_deflated_bytes_total{profiler="cpu"} '
+            f'{sum(m["gzip_deflated_bytes"] for m in metas)}') in text
+    assert 'parca_agent_ship_gzip_fallbacks_total{profiler="cpu"} 0' in text
+    cache = [ln for ln in text.splitlines()
+             if ln.startswith("parca_agent_ship_static_cache_bytes")]
+    assert len(cache) == 1
+    assert (float(cache[0].split()[-1]) > 0) == pipelined

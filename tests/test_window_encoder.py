@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import numpy as np
 import pytest
+import span_scenarios
 
 from parca_agent_tpu.aggregator.dict import DictAggregator
 from parca_agent_tpu.capture.synthetic import SyntheticSpec, generate
@@ -521,3 +522,103 @@ def test_adopt_statics_short_circuits_build():
     assert [(p, bytes(b)) for p, b in out] == [
         (p, bytes(b)) for p, b in enc1.encode(
             c1, snap.time_ns, snap.window_ns, snap.period_ns)]
+
+
+# -- the static span handed to the ship path ----------------------------------
+# (tests/span_scenarios.py; the gzip member spliced from it is held by
+# tests/test_agent_transport.py)
+
+
+def _span_bytes(blob) -> bytes:
+    off, ln = blob.static_span
+    return bytes(blob)[off: off + ln]
+
+
+def _assert_span_is_the_static_block(enc, out) -> None:
+    """Every blob's span is [head][locations as laid down][tail] of its
+    pid, and what follows it begins with the 22-byte time tail: the span
+    never reaches into bytes that change every window."""
+    from parca_agent_tpu.pprof.builder import P_DURATION_NANOS, P_TIME_NANOS
+
+    for pid, blob in out.span_blobs():
+        raw = bytes(blob)
+        off, ln = blob.static_span
+        st = enc._static[pid]
+        n_loc = ln - len(st.head) - len(st.tail)
+        assert 0 <= n_loc <= len(st.loc_bytes)
+        assert raw[off: off + ln] \
+            == st.head + bytes(st.loc_bytes)[:n_loc] + st.tail
+        tail = raw[off + ln: off + ln + 22]
+        assert len(tail) == 22
+        assert tail[0] == P_TIME_NANOS << 3
+        assert tail[11] == P_DURATION_NANOS << 3
+
+
+@pytest.mark.parametrize("shape", sorted(span_scenarios.SHAPES))
+def test_span_blob_is_a_bytes_like_of_the_same_bytes(shape):
+    """A views=True output knows where each blob's static block lies;
+    to every consumer its blobs are the bytes views=False copies."""
+    import hashlib
+
+    snap = span_scenarios.shape_snapshot(shape)
+    agg = DictAggregator(capacity=1 << 12)
+    enc = WindowEncoder(agg)
+    c = agg.window_counts(snap)
+    out = enc.encode(c, snap.time_ns, snap.window_ns, snap.period_ns,
+                     views=True)
+    _assert_same_profiles(agg, snap, c, [(p, bytes(b)) for p, b in out])
+    _assert_span_is_the_static_block(enc, out)
+    # To whoever iterates it the output is the [(pid, memoryview)] it
+    # was; the ship path's wrapped blobs are the same bytes.
+    assert all(type(view) is memoryview for _, view in out)
+    assert [(p, bytes(b)) for p, b in out.span_blobs()] \
+        == [(p, bytes(v)) for p, v in out]
+    for pid, blob in out.span_blobs():
+        raw = bytes(blob)
+        assert len(blob) == len(raw)
+        assert hashlib.sha256(blob).digest() == hashlib.sha256(raw).digest()
+        assert np.frombuffer(blob, np.uint8).tobytes() == raw
+        off, ln = blob.static_span
+        assert off > 0 and ln > 0 and off + ln + 22 == len(raw)
+        assert blob.static_piece() is None
+    if shape == "no_locations":
+        bare = dict(out.span_blobs())[999_999]
+        st = enc._static[999_999]
+        assert st.n_locs == 0 and _span_bytes(bare) == st.head + st.tail
+    copies = enc.encode(c, snap.time_ns, snap.window_ns, snap.period_ns)
+    assert [(p, bytes(b)) for p, b in out] == copies
+    assert all(type(b) is bytes for _, b in copies)
+
+
+@pytest.mark.parametrize("site", span_scenarios.SITES)
+def test_static_span_revision_follows_every_rewrite(site):
+    """A span's revision changes when, and only when, the bytes inside it
+    are rewritten; a move keeps it, and so keeps the group's compressed
+    piece; a relayout, a reset and a rotation start a new piece cache."""
+    seen: dict[int, tuple[bytes, int]] = {}   # pid -> (span bytes, rev)
+    cache = None
+    for enc, out, rewritten, note in span_scenarios.run(site):
+        _assert_span_is_the_static_block(enc, out)
+        tmpl = enc._tmpl
+        if rewritten is None:
+            assert tmpl.pieces is not cache, note
+            assert tmpl.pieces.nbytes == 0
+            seen.clear()
+        else:
+            assert tmpl.pieces is cache, note
+        cache = tmpl.pieces
+        assert len(cache.slots) == len(tmpl.pids) == len(tmpl.span_rev)
+        for pid, blob in out.span_blobs():
+            rev = int(tmpl.span_rev[tmpl.group_of[pid]])
+            span = _span_bytes(blob)
+            if pid in seen and pid not in (rewritten or ()):
+                assert (span, rev) == seen[pid], (note, pid)
+                assert blob.static_piece() == b"piece-%d" % rev
+            else:
+                assert pid not in seen or rev != seen[pid][1], (note, pid)
+                # No piece made from other bytes is handed out.
+                assert blob.static_piece() is None, (note, pid)
+                blob.keep_static_piece(b"piece-%d" % rev)
+            seen[pid] = (span, rev)
+        assert enc.static_piece_bytes() == sum(
+            len(s[1]) for s in cache.slots if s is not None)
