@@ -175,6 +175,31 @@ def _feed_program(cap: int, id_cap: int, n_pad: int, n_blocks: int,
                    donate_argnums=(1, 2))
 
 
+# Rows a miss-scatter call writes. A window's newly inserted rows go to
+# the device table in chunks of this many, the last one padded with a
+# slot beyond the table (dropped), so the program has ONE shape whatever
+# the count of new stacks: it compiles in the first window that misses
+# on a dictionary that holds something and never again (an eager
+# ``.at[slots].set(vals)`` compiled one set of programs per count).
+# 8,192 rows are 160 KB a call: a rollout window (80 to 10,000 new
+# stacks) is one or two calls.
+_SCATTER_CHUNK = 1 << 13
+
+
+@functools.lru_cache(maxsize=4)
+def _scatter_program(cap: int):
+    """``table[slots] = vals`` over one chunk, in place (the table is
+    donated); a slot >= cap is padding. XLA names it
+    ``jit_miss_scatter`` (the benchmark's roofline reader finds it by
+    that name)."""
+    import jax
+
+    def miss_scatter(table, slots, vals):
+        return table.at[slots].set(vals, mode="drop")
+
+    return jax.jit(miss_scatter, donate_argnums=0)
+
+
 # Overflow sideband caps for the packed close fetch: ids whose window
 # count exceeds the packing sentinel. The accumulator is NOT cleared by
 # close (it resets on the next window's first feed), so a sideband overrun
@@ -336,6 +361,19 @@ def _close_program_delta(id_cap: int, n_fetch: int, width: int,
                                     n_blk_buf, blk))
 
 
+def _scatter_chunks(slots: np.ndarray, vals: np.ndarray, pad_slot: int):
+    """(slots int32 [_SCATTER_CHUNK], vals uint32 [_SCATTER_CHUNK, 4])
+    pieces of a batch of new rows; the last piece is padded with
+    ``pad_slot`` (out of the table's range: the scatter drops it)."""
+    for lo in range(0, len(slots), _SCATTER_CHUNK):
+        n = min(_SCATTER_CHUNK, len(slots) - lo)
+        slots_c = np.full(_SCATTER_CHUNK, pad_slot, np.int32)
+        slots_c[:n] = slots[lo:lo + n]
+        vals_c = np.zeros((_SCATTER_CHUNK, 4), np.uint32)
+        vals_c[:n] = vals[lo:lo + n]
+        yield slots_c, vals_c
+
+
 class _CloseHandle:
     """One dispatched-but-uncollected window close (close_dispatch). The
     accumulator/touch references are the PRE-FLIP buffers: immutable jax
@@ -483,6 +521,9 @@ class DictAggregator:
         self._over_hll = None            # lazy [m] int32 registers
         self._rotate_min_age = rotate_min_age
         self._rotate_pending = False
+        # stats["inserts"] at the last window boundary: what the window
+        # just closed inserted is the churn _maybe_reclaim sizes room by.
+        self._inserts_mark = 0
         # Pids whose invalidate_pid arrived while a close/miss was in
         # flight; drained at the next window boundary (same safety
         # contract as rotation).
@@ -667,11 +708,13 @@ class DictAggregator:
     @property
     def registry_epoch(self) -> int:
         """Rotation epoch of the id space: bumped whenever a cold-stack
-        rotation OR a pid-identity invalidation compaction remaps stack
-        ids wholesale. Mirrors consumers (the window encoder, the statics
-        snapshot header) key their validity on this."""
+        rotation, a pid-identity invalidation compaction or a reclaim
+        (_maybe_reclaim) remaps stack ids wholesale. Mirrors consumers
+        (the window encoder, the statics snapshot header) key their
+        validity on this."""
         return (self.stats.get("rotations", 0)
-                + self.stats.get("invalidation_compactions", 0))
+                + self.stats.get("invalidation_compactions", 0)
+                + self.stats.get("reclaims", 0))
 
     def footprint_bytes(self) -> dict:
         """Per-lane host-memory accounting for the endurance sentinel
@@ -801,6 +844,7 @@ class DictAggregator:
             # safe — nothing live indexes stack ids.
             self._apply_pending_invalidations()
             self._maybe_rotate()
+            self._maybe_reclaim()
         # Dispatch-row state: `rows_map` maps each dispatch row back to
         # a representative snapshot row (absolute index) for miss
         # resolution; `w64` carries its exact (possibly folded) mass.
@@ -978,6 +1022,8 @@ class DictAggregator:
         self.timings["feed_settle"] = sp.duration_s
         trace.count(misses=len(miss_rel))
         if len(miss_rel):
+            self.stats["misses"] = self.stats.get("misses", 0) \
+                + len(miss_rel)
             # Miss indices address dispatch rows: rows_map translates
             # back to representative snapshot rows, and the dispatch-
             # row-aligned hash lanes and FOLDED weights (a
@@ -1720,6 +1766,44 @@ class DictAggregator:
         self._compact_ids(keep)
         self.stats["rotations"] = self.stats.get("rotations", 0) + 1
 
+    def _maybe_reclaim(self) -> None:
+        """The exact dictionary gives ids back (overflow="raise" only;
+        the sketch mode rotates instead). At a window boundary, when a
+        window with twice the churn of the one just closed would fail
+        the room test _resolve_misses applies before it inserts, evict
+        ids by last-seen age, oldest first and never one seen in the
+        window just closed, until half the id space is free, and
+        compact. Counts stay exact: an evicted stack that comes back
+        misses and registers again, as a new stack does. Triggered by
+        room and never by the clock: a compaction remaps every id, and
+        every mirror of the id space (the window encoder's templates and
+        statics among them) is laid out again after it. A live set that
+        does not fit still raises where it is inserted."""
+        if self._overflow != "raise":
+            return
+        if self._close_handle is not None or self._miss_inflight is not None:
+            return  # as _maybe_rotate: ids are still referenced
+        churn = self.stats["inserts"] - self._inserts_mark
+        self._inserts_mark = self.stats["inserts"]
+        n = self._next_id
+        room = min(self._id_cap, self._cap // 2)
+        if n + 2 * churn <= room:
+            return
+        last = self._last_seen[:n]
+        cold = np.flatnonzero(last < self.stats["windows"])
+        drop = min(len(cold), n - room // 2)
+        if drop <= 0:
+            return
+        with trace.child("dict_reclaim"):
+            keep = np.ones(n, bool)
+            keep[cold[np.argsort(last[cold], kind="stable")[:drop]]] = False
+            self._compact_ids(keep)
+        self.stats["reclaims"] = self.stats.get("reclaims", 0) + 1
+        self.stats["reclaimed_ids"] = \
+            self.stats.get("reclaimed_ids", 0) + drop
+        trace.count(reclaimed_ids=drop)
+        trace.annotate(ids_after=self._next_id)
+
     def invalidate_pid(self, pid: int) -> bool:
         """Generation-stamped identity invalidation (process/identity.py):
         the pid was RECYCLED, so every stack id and the location registry
@@ -1896,6 +1980,13 @@ class DictAggregator:
         # or device-table entries, corrupting every later window. (Device
         # misses that are merely probe-bound overflows of known keys cost
         # nothing here.)
+        with trace.child("miss_plan"):
+            plan = self._plan_misses_scalar(rows, h1, h2, h3, wts)
+        return self._commit_misses_scalar(snapshot, *plan)
+
+    def _plan_misses_scalar(self, rows, h1, h2, h3, wts):
+        """Classify the miss rows, validate room, then take slots and
+        ids on the host mirror for the new keys (see the caller)."""
         classified: list[tuple[int, int, tuple, int | None]] = []
         n_new = 0
         seen_batch: set = set()
@@ -1967,7 +2058,10 @@ class DictAggregator:
             new_rows.append(r)
             pending.append((sid, w))
             self.stats["inserts"] += 1
+        return new_slots, new_rows, absorb_h, absorb_c, pending
 
+    def _commit_misses_scalar(self, snapshot, new_slots, new_rows,
+                              absorb_h, absorb_c, pending):
         if absorb_h:
             self._sketch_add(np.array(absorb_h, np.uint32),
                              np.array(absorb_c, np.int64))
@@ -1981,7 +2075,9 @@ class DictAggregator:
             self._grow_id_hashes(base)
             self._id_h1[base:self._next_id] = self._h1[new_slots]
             self._id_h2[base:self._next_id] = self._h2[new_slots]
-            self._register_stacks_bulk(snapshot, np.array(new_rows, np.int64))
+            with trace.child("miss_register"):
+                self._register_stacks_bulk(snapshot,
+                                           np.array(new_rows, np.int64))
             slots = np.array(new_slots, np.int64)
             vals = np.zeros((len(new_slots), 4), np.uint32)
             vals[:, 0] = self._h1[new_slots]
@@ -2095,11 +2191,11 @@ class DictAggregator:
                 active = active[blocked]
         return slots
 
-    def _resolve_misses_vec(self, snapshot, rows, h1, h2, h3, wts):
-        """Plan-then-commit vectorized twin of the scalar miss loop.
-        Returns the pending corrections, or None to fall back (nothing
-        mutated). Id assignment stays in first-occurrence row order, so
-        output bytes are identical to the scalar path's."""
+    def _plan_misses_vec(self, rows, h1, h2, h3, wts):
+        """The plan half: fold the miss rows to unique keys, classify
+        them against the host mirror and arbitrate slots for the new
+        ones. Pure reads; None when the scalar path has to take the
+        batch (an overrun, a capacity shortfall)."""
         h1m = np.ascontiguousarray(h1, np.uint32)
         h2m = np.ascontiguousarray(h2, np.uint32)
         h3m = np.ascontiguousarray(h3, np.uint32)
@@ -2121,18 +2217,32 @@ class DictAggregator:
         if overrun:
             return None
         new = np.flatnonzero(ids < 0)
+        slots = None
+        if len(new):
+            worst = self._next_id + len(new)
+            if worst > self._id_cap or worst * 2 > self._cap:
+                return None  # degradation: the scalar path owns it
+            # Subclass pre-mutation room validation (raise-mode sharded).
+            self._check_insert_room_vec(h1u[new], h2u[new], h3u[new])
+            slots = self._place_new_keys_vec(h1u[new], h2u[new], stop[new])
+            if slots is None:
+                return None
+        return urep, uw, row_mult, ids, new, (h1u, h2u, h3u), slots
+
+    def _resolve_misses_vec(self, snapshot, rows, h1, h2, h3, wts):
+        """Plan-then-commit vectorized twin of the scalar miss loop.
+        Returns the pending corrections, or None to fall back (nothing
+        mutated). Id assignment stays in first-occurrence row order, so
+        output bytes are identical to the scalar path's."""
+        with trace.child("miss_plan"):
+            plan = self._plan_misses_vec(rows, h1, h2, h3, wts)
+        if plan is None:
+            return None
+        urep, uw, row_mult, ids, new, (h1u, h2u, h3u), slots = plan
         n_new = len(new)
         pending: list[tuple[int, int]] = []
         if n_new:
-            worst = self._next_id + n_new
-            if worst > self._id_cap or worst * 2 > self._cap:
-                return None  # degradation: the scalar path owns it
             h1n, h2n, h3n = h1u[new], h2u[new], h3u[new]
-            # Subclass pre-mutation room validation (raise-mode sharded).
-            self._check_insert_room_vec(h1n, h2n, h3n)
-            slots = self._place_new_keys_vec(h1n, h2n, stop[new])
-            if slots is None:
-                return None
             # -- commit (mirrors the scalar tail, batch-at-once) --------
             base_sid = self._next_id
             sids = np.arange(base_sid, base_sid + n_new, dtype=np.int64)
@@ -2160,7 +2270,8 @@ class DictAggregator:
             self._grow_id_hashes(base_sid)
             self._id_h1[base_sid:self._next_id] = h1n
             self._id_h2[base_sid:self._next_id] = h2n
-            self._register_stacks_bulk(snapshot, rows[urep[new]])
+            with trace.child("miss_register"):
+                self._register_stacks_bulk(snapshot, rows[urep[new]])
             vals = np.zeros((n_new, 4), np.uint32)
             vals[:, 0] = h1n
             vals[:, 1] = h2n
@@ -2189,12 +2300,43 @@ class DictAggregator:
         return pending
 
     def _dev_scatter(self, slots: np.ndarray, vals: np.ndarray) -> None:
-        """Write newly inserted rows into the device table twin."""
+        """Write newly inserted rows into the device table twin, in
+        fixed-size chunks (_scatter_program: no compile per count). The
+        cold insert of a population into an empty dictionary goes as one
+        scatter at its own count (_scatter_cold)."""
+        if self._dev is None:
+            return  # no twin to patch: the next feed builds it whole
+        with trace.child("miss_scatter"):
+            if self._next_id == len(slots):
+                self._scatter_cold(slots, vals)
+                shipped = 4 * len(slots) + vals.nbytes
+            else:
+                shipped = 0
+                for slots_c, vals_c in _scatter_chunks(slots, vals,
+                                                       self._cap):
+                    self._scatter_chunk(slots_c, vals_c)
+                    shipped += slots_c.nbytes + vals_c.nbytes  # padding too
+        dtel.transfer("miss_settle", "h2d", shipped)
+
+    def _scatter_cold(self, slots: np.ndarray, vals: np.ndarray) -> None:
+        """The whole batch in one eager scatter (subclasses shard it):
+        a program per count, which a process pays once, at set-up, and
+        whose result is a buffer of its own. On the table that chunks
+        written in place leave where the first transfer put it, node's
+        probe loop read 5.8 ms a window against 4.8 on this one
+        (PERF.md section 6, PR 32)."""
         import jax.numpy as jnp
 
         self._dev = self._dev.at[jnp.asarray(slots.astype(np.int32))].set(
             jnp.asarray(vals))
-        dtel.transfer("miss_settle", "h2d", 4 * len(slots) + vals.nbytes)
+
+    def _scatter_chunk(self, slots_c: np.ndarray, vals_c: np.ndarray) -> None:
+        """One chunk into the device table (subclasses shard it). The
+        table is donated to the call, so the twin is None while it is in
+        flight: a call that throws leaves it to be rebuilt from the host
+        mirror (_ensure_device), which already holds the batch."""
+        dev, self._dev = self._dev, None
+        self._dev = _scatter_program(self._cap)(dev, slots_c, vals_c)
 
     def _check_insert_room(self, classified, seen_batch) -> None:
         """Pre-mutation room validation hook for subclasses with placement

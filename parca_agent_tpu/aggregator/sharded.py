@@ -159,6 +159,23 @@ def _sharded_close_program(mesh, n_shards: int, id_cap: int, n_fetch: int,
     return jax.jit(fn)
 
 
+@functools.lru_cache(maxsize=4)
+def _sharded_scatter_program(mesh):
+    """``table[shard, slot] = vals`` over one chunk of newly inserted
+    rows, in place, the table staying sharded over the mesh (dict.py
+    _scatter_program's twin: one shape whatever the count)."""
+    import jax
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    def miss_scatter(table, shard, slot, vals):
+        return table.at[shard, slot].set(vals, mode="drop")
+
+    return jax.jit(miss_scatter, donate_argnums=0,
+                   out_shardings=NamedSharding(
+                       mesh, P(FLEET_AXIS, None, None)))
+
+
 class ShardedDictAggregator(DictAggregator):
     """DictAggregator with the device table and probe work sharded over an
     n-device mesh. Semantics (exact counts, miss/insert protocol, sketch
@@ -477,12 +494,20 @@ class ShardedDictAggregator(DictAggregator):
                            n_fetch, width, n_over_buf))
         return out
 
-    def _dev_scatter(self, slots: np.ndarray, vals: np.ndarray) -> None:
+    def _scatter_cold(self, slots: np.ndarray, vals: np.ndarray) -> None:
+        """The base class's one eager scatter, addressed (shard, slot in
+        shard)."""
         import jax.numpy as jnp
 
         s_idx = (slots // self._cap_s).astype(np.int32)
         w_idx = (slots % self._cap_s).astype(np.int32)
         self._dev = self._dev.at[jnp.asarray(s_idx), jnp.asarray(w_idx)].set(
             jnp.asarray(vals))
-        dtel.transfer("miss_settle", "h2d", 8 * len(slots) + vals.nbytes)
 
+    def _scatter_chunk(self, slots_c: np.ndarray, vals_c: np.ndarray) -> None:
+        """The base class's one-shape chunk, addressed (shard, slot in
+        shard); the padding slot lands on a shard that does not exist
+        and is dropped."""
+        dev, self._dev = self._dev, None
+        self._dev = _sharded_scatter_program(self._mesh)(
+            dev, slots_c // self._cap_s, slots_c % self._cap_s, vals_c)

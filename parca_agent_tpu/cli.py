@@ -219,15 +219,20 @@ def build_parser() -> argparse.ArgumentParser:
                    choices=["cpu", "dict", "dict+cm", "sharded"],
                    help="window aggregation backend (dict = stateful "
                         "device-resident stack dictionary, the TPU "
-                        "production mode; dict+cm = bounded-memory dict "
+                        "production mode: exact counts, and at capacity "
+                        "it gives back the ids of stacks no longer seen, "
+                        "oldest first, at a window boundary; it fails "
+                        "only when the stacks of one window do not fit; "
+                        "dict+cm = bounded-memory dict "
                         "that degrades overflow to a count-min sketch and "
                         "rotates cold stacks instead of growing; sharded "
                         "= dict+cm semantics with the table + probe work "
                         "sharded over local devices via shard_map — "
                         "multi-chip hosts)")
     p.add_argument("--aggregator-capacity", type=int, default=1 << 21,
-                   help="dict table slots (power of two); dict+cm keeps "
-                        "memory bounded at this size under stack churn")
+                   help="dict table slots (power of two; half as many "
+                        "stack ids); dict and dict+cm both keep memory "
+                        "bounded at this size under stack churn")
     p.add_argument("--fast-encode", action="store_true",
                    help="dict aggregators only: serialize windows with the "
                         "vectorized template encoder and ship profiles "
@@ -521,6 +526,13 @@ def run(argv=None) -> int:
     log.info("starting parca-agent-tpu", version=binfo.display(),
              python=binfo.python)
 
+    # The allocator, before the windows start (utils/heap.py): the heap
+    # is held where glibc's moving thresholds would leave it, so the
+    # same window does not run at two speeds.
+    from parca_agent_tpu.utils.heap import hold_heap
+
+    log.debug("glibc heap held", applied=hold_heap())
+
     # -- window cadence (docs/perf.md "sub-second windows") ------------------
     # Window-denominated registry knobs are authored against the 10 s
     # reference window and converted through runtime/window_clock, so
@@ -794,9 +806,11 @@ def run(argv=None) -> int:
         from parca_agent_tpu.aggregator.dict import DictAggregator
         from parca_agent_tpu.runtime.window_clock import windows_for
 
-        # Both modes share the implementation; "dict" fails fast at
-        # capacity (fixed-population benchmarking), "dict+cm" degrades to
-        # the count-min sideband + cold-stack rotation (always-on agents).
+        # Both modes share the implementation. "dict" is exact: when
+        # the id space runs short it reclaims the ids of stacks no
+        # longer seen at a window boundary (_maybe_reclaim) and raises
+        # only for a live set that does not fit; "dict+cm" degrades to
+        # the count-min sideband + cold-stack rotation instead.
         # The cross-drain carry cache only pays off when a window spans
         # several feeds, i.e. under --streaming-window.
         aggregator = DictAggregator(

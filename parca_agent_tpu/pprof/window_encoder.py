@@ -545,6 +545,10 @@ class WindowEncoder:
         # records the deviation).
         self.stats: dict[str, float | int] = {
             "windows_encoded": 0,
+            # Windows that laid every template out again (the first, one
+            # after the id space was compacted, one with more than half
+            # its rows dead or new) instead of patching counts.
+            "layouts_built": 0,
             "template_rows": 0,
             "dead_rows": 0,
             "dead_row_fraction": 0.0,
@@ -612,7 +616,9 @@ class WindowEncoder:
         a concurrent feed assigns ids before their metadata lands, and the
         watermark only advances once the rows are complete."""
         agg = self._agg
-        rot = agg.stats.get("rotations", 0)
+        # The id space's epoch: a rotation, a pid invalidation or a
+        # reclaim compacted it (aggregators without one never remap).
+        rot = getattr(agg, "registry_epoch", 0)
         if rot != self._rotations:
             # Rotation remapped ids wholesale: drop every mirror. But
             # first rescue the location blobs into the content cache —
@@ -1054,7 +1060,14 @@ class WindowEncoder:
         import time as _time
 
         t0 = _time.perf_counter()
-        self._sync()
+        if caps is None:
+            # A prepared window (caps) was synced where it was prepared,
+            # on the thread that owns the aggregator. Its encode runs on
+            # the worker while that thread may be compacting the id
+            # space for the next window: adopting the new epoch from
+            # here would lay this window's old ids out against the new
+            # mirrors.
+            self._sync()
         agg = self._agg
         version = (getattr(agg, "_reg_version", None), period_ns)
         if version[0] is not None and self._statics_clean == version:
@@ -1648,6 +1661,7 @@ class WindowEncoder:
                    and tmpl.waste <= tmpl.alloc_end // 3)
         if not hit:
             self._build_layout(idx, pids_live, period_ns, caps=caps)
+            self.stats["layouts_built"] += 1
             tmpl.period_ns = period_ns
             row = tmpl.row_of[idx]
         else:

@@ -14,7 +14,11 @@ This pipeline moves encode + ship onto a dedicated worker thread:
     encoding plus the shutdown sentinel. There is deliberately NO deeper
     backlog — a second pending window would need its mirrors synced while
     the worker still reads them. If the worker is still busy at the next
-    close, submit() refuses (backpressure) and the caller ships that
+    close, submit() waits for it to park, for at most the closing
+    window's own length (the second window ever offered waits as long
+    as flush() would: the worker is on the cold build of everything;
+    counted: handoff_waits, and in the window's handoff_wait span).
+    Past that bound it refuses (backpressure) and the caller ships that
     window inline through its scalar fallback, counted and observable.
   * The streaming feeder's drain-tick statics prebuild is routed here too
     (request_prebuild), so ALL encoder-state touches outside prepare()
@@ -44,6 +48,10 @@ from parca_agent_tpu.utils import faults
 from parca_agent_tpu.utils.log import get_logger
 
 _log = get_logger("encode-pipeline")
+
+# How long flush() waits for the worker, and submit() of the second
+# window for a worker on its first.
+_FLUSH_TIMEOUT_S = 60.0
 
 THREAD_NAME = "encode-pipeline"  # self-profile attribution (selfprofile.py)
 
@@ -99,6 +107,7 @@ class EncodePipeline:
         self._handoff = False        # profiler parked the worker
         self._interrupt = threading.Event()  # yields a running prebuild
         self._stopping = False
+        self._submits = 0            # windows offered (profiler thread)
         self._thread: threading.Thread | None = None
         self.disabled = False
         self.last_error: Exception | None = None
@@ -107,6 +116,7 @@ class EncodePipeline:
             "windows_lost": 0,
             "ship_errors": 0,
             "backpressure_fallbacks": 0,
+            "handoff_waits": 0,
             "prebuilds": 0,
             "encoder_exceptions": 0,
             "last_handoff_s": 0.0,
@@ -128,8 +138,10 @@ class EncodePipeline:
                fallback=None, trace=NULL_TRACE) -> int | None:
         """Hand one closed window to the worker. Returns the number of
         live pids handed off, or None when the pipeline is disabled or
-        still busy with the previous window (backpressure — the caller
-        must ship the window itself, normally via its scalar fallback).
+        still busy with the previous window after a wait of at most
+        `duration_ns` (flush()'s bound for the second window ever
+        offered; backpressure — the caller must ship the window
+        itself, normally via its scalar fallback).
         `fallback`, a zero-arg callable, re-aggregates and ships the
         window if the worker dies on it. `trace`, the window's
         WindowTrace, detaches on a successful hand-off: the worker
@@ -137,13 +149,35 @@ class EncodePipeline:
         Profiler thread only."""
         if self.disabled or self._stopping:
             return None
+        self._submits += 1
         t0 = time.perf_counter()
         # The capture thread's wait for the worker to park (a prebuild
         # yields at its next batch): wide-event only.
         with trace.span("handoff_wait", histogram=False), self._cond:
-            if self._state == "encode" or self._window is not None:
-                self.stats["backpressure_fallbacks"] += 1
-                return None
+            if self._busy_locked():
+                # The worker is still on the window before. Wait for it
+                # to park, for at most this window's own length: the
+                # scalar path that the refusal leads to blocks this same
+                # thread for many times that (30-50 s at 12,500 pids),
+                # and a worker that is a fraction of a window late costs
+                # that fraction, counted in this span. The second window
+                # a pipeline is offered finds the worker on its first,
+                # which is no measure of a window: it lays out every
+                # template and builds every static and gzip piece (~10 s
+                # at 12,500 pids, right where a 10 s bound falls). That
+                # once, the worker is waited out as flush() would, so
+                # the second window's path does not hang on which side
+                # of the bound the cold build lands.
+                bound_s = _FLUSH_TIMEOUT_S if self._submits == 2 \
+                    else duration_ns / 1e9
+                if not self._cond.wait_for(
+                        lambda: not self._busy_locked() or self.disabled
+                        or self._stopping, bound_s) \
+                        or self.disabled or self._stopping:
+                    self.stats["backpressure_fallbacks"] += 1
+                    return None
+                self.stats["handoff_waits"] += 1
+                trace.annotate(handoff_waited=True)
             # Park the worker: a budgeted prebuild yields at its next
             # batch boundary; nothing new starts while _handoff is set.
             self._handoff = True
@@ -216,12 +250,15 @@ class EncodePipeline:
             self._cond.notify_all()
         self._ensure_thread()
 
+    def _busy_locked(self) -> bool:  # palint: holds=_cond
+        return self._window is not None or self._state == "encode"
+
     @property
     def busy(self) -> bool:
         with self._cond:
-            return self._window is not None or self._state == "encode"
+            return self._busy_locked()
 
-    def flush(self, timeout_s: float = 60.0) -> bool:
+    def flush(self, timeout_s: float = _FLUSH_TIMEOUT_S) -> bool:
         """Block until no window is pending or being encoded (pending
         prebuilds are not waited for). False on timeout."""
         deadline = time.monotonic() + timeout_s
@@ -342,14 +379,18 @@ class EncodePipeline:
         # post-rotation rebuild) is the latency cliff the trace exists
         # for: span it from the encoder's own accumulated-build clock so
         # the span and the encoder's stats can never disagree.
-        statics0 = getattr(self._enc, "stats", {}).get(
-            "statics_build_s_total", 0.0)
+        enc_stats = getattr(self._enc, "stats", {})
+        statics0 = enc_stats.get("statics_build_s_total", 0.0)
+        layouts0 = enc_stats.get("layouts_built", 0)
         out = self._enc.encode_prepared(prep, views=self._views)
         enc_s = time.monotonic() - t0
         self.stats["last_encode_s"] = enc_s
         self.stats["overlap_s_total"] += enc_s
-        statics_s = getattr(self._enc, "stats", {}).get(
-            "statics_build_s_total", 0.0) - statics0
+        # Which encode this was: every template laid out again
+        # ("build") or counts patched into the ones that stand.
+        trace.annotate(encode="build" if enc_stats.get(
+            "layouts_built", 0) > layouts0 else "patch")
+        statics_s = enc_stats.get("statics_build_s_total", 0.0) - statics0
         if statics_s > 0:
             # histogram=False: the encoder already observed each build
             # call into the "statics" stage histogram; this span is the

@@ -876,6 +876,15 @@ def count(**kv) -> None:
         top._trace.count(**kv)
 
 
+def annotate(**kv) -> None:
+    """Set keys in the ``meta`` of the window whose span is open on the
+    calling thread (``WindowTrace.annotate``); nothing open, nothing
+    done."""
+    top = current()
+    if top is not None:
+        top._trace.annotate(**kv)
+
+
 class adopt:
     """Make ``ctx`` (a span open on another thread) the innermost open
     span of this thread for the length of a ``with`` block: the device

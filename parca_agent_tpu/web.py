@@ -266,6 +266,14 @@ def render_metrics(profilers, batch_client=None, extra: dict | None = None,
             emit("parca_agent_encode_pipeline_disabled", int(pipe.disabled),
                  lab)
             for k, v in pipe.stats.items():
+                if k == "handoff_waits":
+                    # Windows that met a busy worker and waited for it
+                    # (bounded) instead of going the scalar way: under
+                    # the profiler's own name, beside the backpressure
+                    # counter it spares.
+                    emit("parca_agent_profiler_encode_handoff_waits_total",
+                         v, lab)
+                    continue
                 emit(f"parca_agent_encode_pipeline_{k}",
                      round(v, 6) if isinstance(v, float) else v, lab)
         perf = getattr(getattr(p, "_symbolizer", None), "_perf", None)
@@ -340,6 +348,16 @@ def render_metrics(profilers, batch_client=None, extra: dict | None = None,
                  agg_stats.get("carry_fallbacks", 0), lab)
             emit("parca_agent_feed_miss_vec_inserts_total",
                  agg_stats.get("miss_vec_inserts", 0), lab)
+            # The miss path (docs/perf.md "the miss path"): rows the
+            # device probe did not find (new stacks, and the few known
+            # ones beyond its probe bound), and what the exact mode's
+            # reclaim gave back at window boundaries.
+            emit("parca_agent_dict_misses_total",
+                 agg_stats.get("misses", 0), lab)
+            emit("parca_agent_dict_reclaims_total",
+                 agg_stats.get("reclaims", 0), lab)
+            emit("parca_agent_dict_reclaimed_ids_total",
+                 agg_stats.get("reclaimed_ids", 0), lab)
         feeder = getattr(p, "_feeder", None)
         if feeder is not None and getattr(feeder, "stats", None):
             # The ingest ceiling as a first-class number: the fraction

@@ -12,6 +12,7 @@ pprof output vs the synchronous path.
 
 from __future__ import annotations
 
+import dataclasses
 import threading
 import time
 
@@ -87,9 +88,14 @@ def test_pipeline_overlap_and_backpressure():
         assert gate.wait(10)
         return real(prep, views=views)
 
-    enc.encode_prepared = slow_encode
     shipped = []
     pipe = EncodePipeline(enc, ship=lambda out, prep: shipped.append(out))
+    # The worker's first window (the cold build) is waited out by the
+    # window behind it; the contract below is the steady one.
+    assert pipe.submit(counts, snap.time_ns - 1, snap.window_ns,
+                       snap.period_ns) is not None
+    assert pipe.flush(10)
+    enc.encode_prepared = slow_encode
     t0 = time.perf_counter()
     assert pipe.submit(counts, snap.time_ns, snap.window_ns,
                        snap.period_ns) is not None
@@ -97,14 +103,121 @@ def test_pipeline_overlap_and_backpressure():
     assert entered.wait(10)
     assert handoff < 5.0          # submit did not wait for the encode
     assert pipe.busy
-    # Next window closes while the worker is still busy: refused, counted.
-    assert pipe.submit(counts, snap.time_ns + 1, snap.window_ns,
+    # Next window closes while the worker is still busy and stays busy
+    # past the window's own length (50 ms here): refused, counted.
+    assert pipe.submit(counts, snap.time_ns + 1, 50_000_000,
                        snap.period_ns) is None
     assert pipe.stats["backpressure_fallbacks"] == 1
+    assert pipe.stats["handoff_waits"] == 0
     gate.set()
     assert pipe.flush(10)
-    assert len(shipped) == 1
-    assert pipe.stats["windows_pipelined"] == 1
+    assert len(shipped) == 2
+    assert pipe.stats["windows_pipelined"] == 2
+    assert pipe.close()
+
+
+def _busy_pipeline(snap, agg, counts, hold_s, cold=False):
+    """A pipeline whose worker is inside an encode that lasts
+    ``hold_s`` (its first window if ``cold``, else its second), and the
+    recorder its windows trace into."""
+    from parca_agent_tpu.runtime.trace import FlightRecorder
+
+    enc = WindowEncoder(agg)
+    entered = threading.Event()
+    real = enc.encode_prepared
+    shipped = []
+    pipe = EncodePipeline(enc, ship=lambda out, prep: shipped.append(out))
+    if not cold:
+        assert pipe.submit(counts, snap.time_ns - 1, snap.window_ns,
+                           snap.period_ns) is not None
+        assert pipe.flush(10)
+        shipped.clear()
+
+    def slow_encode(prep, views=False):
+        entered.set()
+        time.sleep(hold_s)
+        return real(prep, views=views)
+
+    enc.encode_prepared = slow_encode
+    assert pipe.submit(counts, snap.time_ns, snap.window_ns,
+                       snap.period_ns) is not None
+    assert entered.wait(10)
+    enc.encode_prepared = real    # the window behind encodes at once
+    return pipe, shipped, FlightRecorder(ring=8)
+
+
+def test_submit_waits_for_a_worker_that_is_a_little_late():
+    """A worker still busy for 50 ms at the next close: the window waits
+    for it inside its handoff_wait span and is handed off; nothing goes
+    the scalar way."""
+    snap = _snap(seed=2)
+    agg = DictAggregator(capacity=1 << 12)
+    counts = np.asarray(agg.window_counts(snap))
+    pipe, shipped, rec = _busy_pipeline(snap, agg, counts, hold_s=0.05)
+    tr = rec.begin(snap.time_ns + 1)
+    assert pipe.submit(counts, snap.time_ns + 1, snap.window_ns,
+                       snap.period_ns, trace=tr) is not None
+    assert pipe.stats["backpressure_fallbacks"] == 0
+    assert pipe.stats["handoff_waits"] == 1
+    assert pipe.close()
+    assert len(shipped) == 2 and pipe.stats["windows_pipelined"] == 3
+    d = rec.traces()[-1]
+    wait = next(s for s in d["spans"] if s["stage"] == "handoff_wait")
+    assert 0.02 < wait["duration_s"] < 5.0      # the wait is in the span
+    assert d["meta"]["handoff_waited"] is True
+    assert d["meta"]["encode"] == "patch"       # the template stood
+
+
+def test_submit_gives_up_past_the_windows_own_length():
+    """Busy past the bound (the window's duration_ns): today's refusal,
+    counted as before, after no more than about that long."""
+    snap = _snap(seed=2)
+    agg = DictAggregator(capacity=1 << 12)
+    counts = np.asarray(agg.window_counts(snap))
+    pipe, shipped, _rec = _busy_pipeline(snap, agg, counts, hold_s=0.6)
+    t0 = time.perf_counter()
+    assert pipe.submit(counts, snap.time_ns + 1, 50_000_000,
+                       snap.period_ns) is None
+    assert 0.04 < time.perf_counter() - t0 < 0.5
+    assert pipe.stats["backpressure_fallbacks"] == 1
+    assert pipe.stats["handoff_waits"] == 0
+    assert pipe.close() and len(shipped) == 1
+
+
+def test_second_window_waits_out_the_workers_cold_first_window():
+    """The worker's first window lays everything out and is no measure
+    of a window: the window behind it waits past its own length (50 ms
+    here against an encode of 0.3 s) and is handed off; the one after
+    is held to its own length again."""
+    snap = _snap(seed=2)
+    agg = DictAggregator(capacity=1 << 12)
+    counts = np.asarray(agg.window_counts(snap))
+    pipe, shipped, _rec = _busy_pipeline(snap, agg, counts, hold_s=0.3,
+                                         cold=True)
+    assert pipe.submit(counts, snap.time_ns + 1, 50_000_000,
+                       snap.period_ns) is not None
+    assert pipe.stats["backpressure_fallbacks"] == 0
+    assert pipe.stats["handoff_waits"] == 1
+    assert pipe.close() and len(shipped) == 2
+
+
+@pytest.mark.parametrize("how", ["disabled", "stopping"])
+def test_submit_returns_at_once_when_disabled_or_stopping(how):
+    snap = _snap(seed=2)
+    agg = DictAggregator(capacity=1 << 12)
+    counts = np.asarray(agg.window_counts(snap))
+    pipe, _shipped, _rec = _busy_pipeline(snap, agg, counts, hold_s=0.4)
+    if how == "disabled":
+        pipe.disabled = True
+    else:
+        pipe._stopping = True
+    t0 = time.perf_counter()
+    assert pipe.submit(counts, snap.time_ns + 1, snap.window_ns,
+                       snap.period_ns) is None
+    assert time.perf_counter() - t0 < 0.2       # no wait, no count
+    assert pipe.stats["backpressure_fallbacks"] == 0
+    pipe._stopping = False
+    pipe.disabled = False
     assert pipe.close()
 
 
@@ -287,9 +400,10 @@ def test_profiler_backpressure_scalar_fallback_is_counted():
     """Worker still encoding window N at window N+1's close: N+1 ships
     inline through the scalar fallback, the counter increments, and no
     mass is lost."""
-    snap = _snap(seed=10)
+    # A 50 ms window bounds the hand-off's wait for the blocked worker.
+    snap = dataclasses.replace(_snap(seed=10), window_ns=50_000_000)
     w = Collect()
-    p = CPUProfiler(source=ReplaySource([snap, snap]),
+    p = CPUProfiler(source=ReplaySource([snap, snap, snap]),
                     aggregator=DictAggregator(capacity=1 << 12),
                     fallback_aggregator=CPUAggregator(),
                     profile_writer=w, fast_encode=True,
@@ -302,15 +416,17 @@ def test_profiler_backpressure_scalar_fallback_is_counted():
         assert gate.wait(10)
         return real(prep, views=views)
 
+    assert p.run_iteration()      # window 1: the worker's cold first
+    assert p._pipeline.flush(10)
     enc.encode_prepared = slow
-    assert p.run_iteration()      # window 1 pipelined, worker blocked
-    assert p.run_iteration()      # window 2: backpressure -> scalar
+    assert p.run_iteration()      # window 2 pipelined, worker blocked
+    assert p.run_iteration()      # window 3: backpressure -> scalar
     assert p.last_error is None
     assert p.metrics.encode_backpressure_total == 1
-    assert _mass(w.got) == snap.total_samples()  # window 2, already shipped
+    assert _mass(w.got) == 2 * snap.total_samples()  # 1, and 3 by scalar
     gate.set()
     assert p._pipeline.close()
-    assert _mass(w.got) == 2 * snap.total_samples()
+    assert _mass(w.got) == 3 * snap.total_samples()
 
 
 def test_profiler_pipeline_failure_falls_back_then_inline():

@@ -550,11 +550,15 @@ def test_metrics_and_healthz_surface_per_sink_stats(tmp_path):
 def test_scalar_path_windows_counted_as_skipped():
     """A backpressure scalar fallback ships no prepared window: the
     registry counts the sink coverage gap."""
-    snap = _snap(seed=10)
+    import dataclasses
+
+    # A 50 ms window: the hand-off waits that long for the busy worker
+    # (the gate below holds it longer) before it takes the scalar way.
+    snap = dataclasses.replace(_snap(seed=10), window_ns=50_000_000)
     series = SeriesSink()
     reg = SinkRegistry([PprofSink(), series])
     w = Collect()
-    p = CPUProfiler(source=ReplaySource([snap, snap]),
+    p = CPUProfiler(source=ReplaySource([snap, snap, snap]),
                     aggregator=DictAggregator(capacity=1 << 12),
                     fallback_aggregator=CPUAggregator(),
                     profile_writer=w, fast_encode=True,
@@ -567,12 +571,14 @@ def test_scalar_path_windows_counted_as_skipped():
         assert gate.wait(10)
         return real(prep, views=views)
 
+    assert p.run_iteration()      # window 1: the worker's cold first,
+    assert p._pipeline.flush(10)  # which the next window would wait out
     enc.encode_prepared = slow
-    assert p.run_iteration()      # window 1 pipelined, worker blocked
-    assert p.run_iteration()      # window 2: backpressure -> scalar
+    assert p.run_iteration()      # window 2 pipelined, worker blocked
+    assert p.run_iteration()      # window 3: backpressure -> scalar
     gate.set()
     assert p._pipeline.close()
     assert p.metrics.encode_backpressure_total == 1
     m = reg.metrics()
     assert m["_registry"]["windows_skipped"] == 1
-    assert series.stats["windows"] == 1  # the pipelined window folded
+    assert series.stats["windows"] == 2  # the pipelined windows folded
