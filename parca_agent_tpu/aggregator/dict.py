@@ -40,7 +40,6 @@ import os
 import numpy as np
 
 from parca_agent_tpu.aggregator.base import PidProfile, ProfileMapping
-from parca_agent_tpu.aggregator.cpu import _pid_mappings
 from parca_agent_tpu.capture.formats import (
     KERNEL_ADDR_START,
     STACK_SLOTS,
@@ -65,6 +64,15 @@ _PROBES = 16
 # (plan-then-commit over the host mirror, one registry append per batch);
 # below it the scalar loop's constant factors win and the batch is noise.
 _VEC_MISS_MIN = 512
+
+# Live frames of new stacks that the register step takes at once (whole
+# pids; _register_stacks_bulk). The sorts' working set and, above all,
+# the Python integers that one tolist() a column makes of a group (~130
+# bytes a fresh address over the four columns) stay in the tens of MB
+# whatever the batch: a first window of 1M stacks is ~25M frames. A
+# steady-state window's misses (a build node's ~220,000 frames) fit in
+# one group. A constant, not a setting: results do not depend on it.
+_REGISTER_FRAME_BUDGET = 1 << 18
 
 
 # Block length of the two-level prefix sum below.
@@ -359,6 +367,29 @@ def _close_program_delta(id_cap: int, n_fetch: int, width: int,
 
     return jax.jit(make_close_delta(id_cap, n_fetch, width, n_over_buf,
                                     n_blk_buf, blk))
+
+
+def _obj_name(names: tuple, obj: int) -> str:
+    """A mapping row's object path or build id ("" where the table has
+    none for it)."""
+    return names[obj] if 0 <= obj < len(names) else ""
+
+
+def _table_mappings(table, rows: np.ndarray, ids: np.ndarray):
+    """The mappings that first-seen pids' registries start with, for
+    all such pids of a group at once (cpu.py's ``_pid_mappings``, one
+    tolist() a column): the ``ProfileMapping`` of every table row in
+    ``rows``, its 1-based position in its pid's table as id, and their
+    ``mapping_index`` keys."""
+    starts, ends, offsets = (c[rows].tolist() for c in (
+        table.starts, table.ends, table.offsets))
+    objs = table.objs[rows].tolist()
+    maps = [ProfileMapping(*m) for m in zip(
+        ids.tolist(), starts, ends, offsets,
+        [_obj_name(table.obj_paths, o) for o in objs],
+        [_obj_name(table.obj_buildids, o) for o in objs],
+        table.bases[rows].tolist())]
+    return maps, list(zip(starts, ends, offsets))
 
 
 def _scatter_chunks(slots: np.ndarray, vals: np.ndarray, pad_slot: int):
@@ -1020,7 +1051,8 @@ class DictAggregator:
         with trace.child("feed_settle") as sp:  # the wait for the kernel
             miss_rel = self._settle_dispatch(handle)
         self.timings["feed_settle"] = sp.duration_s
-        trace.count(misses=len(miss_rel))
+        trace.count(misses=len(miss_rel), registered_pids=0,
+                    registered_first_seen=0)  # _register_stacks_bulk adds
         if len(miss_rel):
             self.stats["misses"] = self.stats.get("misses", 0) \
                 + len(miss_rel)
@@ -2433,106 +2465,216 @@ class DictAggregator:
         self._published = need_ids
 
     def _register_stacks_bulk(self, snapshot, rows: np.ndarray) -> None:
-        """Vectorized per-pid location registration for a batch of newly
-        inserted stacks (the first window inserts everything — a python
-        per-frame loop would dwarf the device work it replaces)."""
-        pids = snapshot.pids[rows]
-        depths = (snapshot.user_len + snapshot.kernel_len)[rows]
-        table = snapshot.mappings
-        # Batch outputs indexed by position in `rows` — positions correspond
-        # 1:1 to the contiguous sids the caller just assigned, so the global
-        # per-id arrays stay aligned with stack ids. Each pid group's loc-id
-        # runs scatter straight into the ragged batch buffer (a dense
-        # [nb, STACK_SLOTS] staging matrix would be a ~0.5 GB transient on
-        # a 1M-insert first window).
+        """Per-pid location registration for a batch of newly inserted
+        stacks, in one pass over all of the batch's pids (the first
+        window inserts everything, and a window of short-lived processes
+        brings thousands of pids of a few stacks each: numpy work per
+        pid, or Python work per frame, would dwarf the device work it
+        feeds).
+
+        The bytes are what a pass per pid in ascending pid order would
+        leave: a pid's fresh addresses take location ids in ascending
+        address order after its existing ones, new mapping ranges are
+        appended in ascending table-row order, registries enter
+        ``_pids`` in ascending pid order, and all of them are complete
+        before ``_append_id_meta`` publishes the batch."""
         from parca_agent_tpu.pprof.vec import ragged_gather
 
+        pids = snapshot.pids[rows]
+        depths = (snapshot.user_len + snapshot.kernel_len)[rows].astype(
+            np.int64)
         nb = len(rows)
-        depths64 = depths.astype(np.int64)
+        # Batch outputs indexed by position in `rows` — positions correspond
+        # 1:1 to the contiguous sids the caller just assigned, so the global
+        # per-id arrays stay aligned with stack ids.
         boff = np.zeros(nb + 1, np.int64)
-        np.cumsum(depths64, out=boff[1:])
+        np.cumsum(depths, out=boff[1:])
         flat_vals = np.empty(int(boff[-1]), np.int32)
 
-        for pid in np.unique(pids):
-            sel = np.flatnonzero(pids == pid)
-            reg = self._pids.get(int(pid))
-            if reg is None:
-                mappings = _pid_mappings(table, int(pid))
-                reg = _PidRegistry(
-                    {}, [], [], [], [], mappings,
-                    {(m.start, m.end, m.offset): m.id for m in mappings},
-                )
-                self._pids[int(pid)] = reg
+        # The batch's rows by ascending pid, in batch order within a pid.
+        order = np.argsort(pids, kind="stable")
+        spids = pids[order]
+        head = np.ones(nb, bool)
+        head[1:] = spids[1:] != spids[:-1]
+        first = np.flatnonzero(head)
+        row_off = np.append(first, nb)        # rows of pid k in `order`
+        upids = spids[first]
+        frames = np.add.reduceat(depths[order], first)  # live, by pid
+        table = snapshot.mappings
+        tlo = np.searchsorted(table.pids, upids, "left")
+        thi = np.searchsorted(table.pids, upids, "right")
+        stacks_flat = snapshot.stacks.reshape(-1)
 
-            prows = rows[sel]
-            pdepths = depths[sel]
-            stacks = snapshot.stacks[prows]
-            live = np.arange(STACK_SLOTS)[None, :] < pdepths[:, None]
-            addrs = stacks[live]
-            uniq = np.unique(addrs)
-            # New addresses for this pid's registry.
-            known = np.array([int(a) in reg.addr_to_loc for a in uniq], bool)
-            fresh = uniq[~known] if len(uniq) else uniq
-            if len(fresh):
-                is_kernel = fresh >= np.uint64(KERNEL_ADDR_START)
-                mrows = table.rows_for_pid(int(pid))
-                norm = fresh.copy()
-                map_id = np.zeros(len(fresh), np.int32)
-                if len(mrows):
-                    starts = table.starts[mrows]
-                    ends = table.ends[mrows]
-                    offsets = table.offsets[mrows]
-                    bases = table.bases[mrows]
-                    j = np.searchsorted(starts, fresh, "right").astype(np.int64) - 1
-                    safe = np.clip(j, 0, len(mrows) - 1)
-                    hit = (j >= 0) & (fresh < ends[safe]) & ~is_kernel
-                    norm = np.where(hit, fresh - bases[safe], fresh)
-                    # Window-table rows -> registry-stable mapping ids
-                    # (appending ranges this registry hasn't seen yet).
-                    row_to_reg = np.zeros(len(mrows), np.int32)
-                    for row in np.unique(safe[hit]) if hit.any() else []:
-                        r = int(row)
-                        mkey = (int(starts[r]), int(ends[r]), int(offsets[r]))
-                        rid = reg.mapping_index.get(mkey)
-                        if rid is None:
-                            obj = int(table.objs[mrows[r]])
-                            rid = len(reg.mappings) + 1
-                            reg.mappings.append(ProfileMapping(
-                                id=rid, start=mkey[0], end=mkey[1],
-                                offset=mkey[2],
-                                path=(table.obj_paths[obj]
-                                      if 0 <= obj < len(table.obj_paths)
-                                      else ""),
-                                build_id=(table.obj_buildids[obj]
-                                          if 0 <= obj < len(table.obj_buildids)
-                                          else ""),
-                                base=int(table.bases[mrows[r]]),
-                            ))
-                            reg.mapping_index[mkey] = rid
-                        row_to_reg[r] = rid
-                    map_id = np.where(hit, row_to_reg[safe], 0)
-                base = len(reg.loc_address)
-                reg.loc_address.extend(fresh.tolist())
-                reg.loc_normalized.extend(norm.tolist())
-                reg.loc_mapping_id.extend(map_id.tolist())
-                reg.loc_is_kernel.extend(is_kernel.tolist())
-                for k, a in enumerate(fresh.tolist()):
-                    reg.addr_to_loc[a] = base + k + 1
-
-            # Translate every frame to its 1-based loc id in one pass.
-            # stacks[live] selects row-major, so frame_ids is already the
-            # concatenation of this group's live prefixes in row order —
-            # scatter the runs to their batch-flat positions directly.
-            lut = np.array([reg.addr_to_loc[int(a)] for a in uniq], np.int32)
-            frame_ids = lut[np.searchsorted(uniq, stacks[live])]
-            pd64 = pdepths.astype(np.int64)
-            src_starts = np.zeros(len(sel), np.int64)
-            np.cumsum(pd64[:-1], out=src_starts[1:])
-            ragged_gather(frame_ids, src_starts, pd64,
+        # Whole pids, taken in groups of about _REGISTER_FRAME_BUDGET
+        # live frames: a pid goes with the group its first frame falls
+        # in and is never split, so the grouping changes no result.
+        frames_before = np.cumsum(frames) - frames
+        gid = frames_before // _REGISTER_FRAME_BUDGET
+        cuts = np.flatnonzero(gid[1:] != gid[:-1]) + 1
+        n_first_seen = 0
+        for p0, p1 in zip(np.concatenate(([0], cuts)).tolist(),
+                          np.append(cuts, len(upids)).tolist()):
+            sel = order[row_off[p0]:row_off[p1]]
+            d = depths[sel]
+            frame_ids, n_new = self._register_pid_group(
+                table, upids[p0:p1], frames[p0:p1], tlo[p0:p1], thi[p0:p1],
+                ragged_gather(stacks_flat, rows[sel] * STACK_SLOTS, d)[0])
+            n_first_seen += n_new
+            ragged_gather(frame_ids, np.cumsum(d) - d, d,
                           out=flat_vals, out_starts=boff[sel])
 
-        self._append_id_meta(pids.astype(np.int32), depths64, flat_vals)
+        self._append_id_meta(pids.astype(np.int32), depths, flat_vals)
         self._reg_version += 1
+        trace.count(registered_pids=len(upids),
+                    registered_first_seen=n_first_seen)
+
+    def _register_pid_group(self, table, gpids, frames, tlo, thi, addrs):
+        """Register one group of whole pids (ascending ``gpids``):
+        ``addrs`` are their live frames, pid-major, ``frames[k]`` of them
+        pid k's; ``[tlo[k], thi[k])`` its rows of the window's mapping
+        table. Returns every frame's 1-based location id (int32, in
+        ``addrs``' order) and how many of the pids were first seen."""
+        n_pids = len(gpids)
+        pid_list = gpids.tolist()
+        regs = [self._pids.get(p) for p in pid_list]
+        known = np.array([r is not None for r in regs], bool)
+
+        # Distinct (pid, address) pairs, by pid then address: one sort by
+        # address (equal pairs are one pair, so it need not be stable),
+        # then a stable one by the pid's index in the group, whose dtype
+        # is as narrow as the group allows (16 bits take a radix sort).
+        # The frames arrive pid-major, so that index, sorted, is itself.
+        pidx = np.repeat(np.arange(n_pids, dtype=np.uint16
+                                   if n_pids <= 1 << 16 else np.uint32),
+                         frames)
+        by = np.argsort(addrs)
+        by = by[np.argsort(pidx[by], kind="stable")]
+        sa = addrs[by]
+        head = np.ones(len(sa), bool)
+        head[1:] = (sa[1:] != sa[:-1]) | (pidx[1:] != pidx[:-1])
+        pair_of_frame = np.empty(len(sa), np.int64)
+        pair_of_frame[by] = np.cumsum(head) - 1
+        ua = sa[head]
+        upi = pidx[head].astype(np.int64)
+
+        # A first-seen pid's addresses are all fresh; a known pid's are
+        # tested against its registry, the one dictionary look-up per
+        # address that is still due.
+        loc = np.zeros(len(ua), np.int64)
+        if known.any():
+            kp = np.flatnonzero(known[upi])
+            loc[kp] = [regs[k].addr_to_loc.get(a, 0)
+                       for k, a in zip(upi[kp].tolist(), ua[kp].tolist())]
+        fresh = loc == 0
+        fa = ua[fresh]
+        fpi = upi[fresh]
+
+        n_fresh = np.bincount(fpi, minlength=n_pids)
+        fresh_off = np.zeros(n_pids + 1, np.int64)
+        np.cumsum(n_fresh, out=fresh_off[1:])
+
+        # Every fresh address against its pid's rows of the mapping
+        # table: a merge of the two, both sorted by (pid, value), taken
+        # from the side of the rows, which are the few. Each row finds
+        # where its start falls among its own pid's fresh addresses (a
+        # bisection of all the rows at once, each inside its pid's run),
+        # and the running count of those positions is, at every address,
+        # the rows that start at or below it: its own pid's, after all
+        # the rows of the pids before. Addresses use all 64 bits, so no
+        # key of (pid, address) fits one integer; this compares them as
+        # they are.
+        n_rows = thi - tlo
+        row_off = np.zeros(n_pids + 1, np.int64)
+        np.cumsum(n_rows, out=row_off[1:])
+        rpi = np.repeat(np.arange(n_pids), n_rows)
+        within = np.arange(len(rpi), dtype=np.int64) - row_off[rpi]
+        trows = tlo[rpi] + within        # the group's rows of the table
+        starts = table.starts[trows]
+        lo, hi = fresh_off[rpi], fresh_off[rpi + 1]
+        for _ in range(int(n_fresh.max(initial=0)).bit_length()):
+            mid = (lo + hi) >> 1
+            open_ = lo < hi
+            right = open_ & (fa[np.minimum(mid, len(fa) - 1)] < starts)
+            hi = np.where(open_ & ~right, mid, hi)
+            lo = np.where(right, mid + 1, lo)
+        below = np.cumsum(np.bincount(lo, minlength=len(fa) + 1))[:len(fa)]
+        is_kernel = fa >= np.uint64(KERNEL_ADDR_START)
+        hit = (below > row_off[fpi]) & ~is_kernel
+        if len(trows):
+            trow = trows[np.maximum(below - 1, 0)]
+            hit &= fa < table.ends[trow]
+            norm = np.where(hit, fa - table.bases[trow], fa)
+        else:
+            norm = fa
+        # Table rows -> registry-stable mapping ids. A first-seen pid's
+        # registry starts as the window's table, so its ids are the rows'
+        # positions there; a known pid's go through its mapping_index.
+        map_id = np.where(hit, below - row_off[fpi], 0).astype(np.int32)
+        kh = np.flatnonzero(hit & known[fpi])
+        if len(kh):
+            krows = np.unique(trow[kh])
+            map_id[kh] = np.array(
+                self._known_mapping_ids(table, krows), np.int32)[
+                    np.searchsorted(krows, trow[kh])]
+
+        # Location ids: a pid's fresh addresses, ascending, after the
+        # locations it has.
+        base = np.array([len(r.loc_address) if r is not None else 0
+                         for r in regs], np.int64)
+        loc[fresh] = (base - fresh_off[:-1])[fpi] \
+            + np.arange(1, len(fa) + 1, dtype=np.int64)
+
+        # What is left per pid is what has to be Python objects, built
+        # from slices of one tolist() a column: no numpy call below.
+        first_seen = np.flatnonzero(~known[rpi])
+        new_maps, new_keys = _table_mappings(
+            table, trows[first_seen], within[first_seen] + 1)
+        # (A first-seen pid's rows in new_maps: the rows before it less
+        # those of known pids; a known pid's entry is not read.)
+        map_off = (row_off[:-1] - np.cumsum(n_rows * known)).tolist()
+        map_len = n_rows.tolist()
+        f_addr, f_norm = fa.tolist(), norm.tolist()
+        f_map, f_kern = map_id.tolist(), is_kernel.tolist()
+        offs = fresh_off.tolist()
+        for k, (pid, reg) in enumerate(zip(pid_list, regs)):
+            f0, f1 = offs[k], offs[k + 1]
+            a = f_addr[f0:f1]
+            if reg is None:
+                m0, m1 = map_off[k], map_off[k] + map_len[k]
+                self._pids[pid] = _PidRegistry(
+                    dict(zip(a, range(1, len(a) + 1))), a, f_norm[f0:f1],
+                    f_map[f0:f1], f_kern[f0:f1], new_maps[m0:m1],
+                    dict(zip(new_keys[m0:m1], range(1, m1 - m0 + 1))))
+            elif a:
+                n0 = len(reg.loc_address)
+                reg.addr_to_loc.update(zip(a, range(n0 + 1,
+                                                    n0 + len(a) + 1)))
+                reg.loc_address.extend(a)
+                reg.loc_normalized.extend(f_norm[f0:f1])
+                reg.loc_mapping_id.extend(f_map[f0:f1])
+                reg.loc_is_kernel.extend(f_kern[f0:f1])
+        return loc[pair_of_frame].astype(np.int32), \
+            n_pids - int(known.sum())
+
+    def _known_mapping_ids(self, table, krows: np.ndarray) -> list[int]:
+        """Registry mapping ids of the table rows ``krows`` (ascending,
+        all of pids that have a registry), appending to a registry each
+        range it has not seen yet, in that order."""
+        cols = [c[krows].tolist() for c in (
+            table.pids, table.starts, table.ends, table.offsets, table.objs,
+            table.bases)]
+        rids = []
+        for pid, start, end, offset, obj, base in zip(*cols):
+            reg = self._pids[pid]
+            rid = reg.mapping_index.get((start, end, offset))
+            if rid is None:
+                rid = len(reg.mappings) + 1
+                reg.mappings.append(ProfileMapping(
+                    rid, start, end, offset, _obj_name(table.obj_paths, obj),
+                    _obj_name(table.obj_buildids, obj), base))
+                reg.mapping_index[(start, end, offset)] = rid
+            rids.append(rid)
+        return rids
 
     def _build_profiles(self, snapshot: WindowSnapshot,
                         counts: np.ndarray) -> list[PidProfile]:

@@ -282,3 +282,387 @@ def test_the_sharded_twin_scatters_in_one_shape_and_reclaims():
     # first to miss on a dictionary that holds something, compiles the
     # one shape, and no window after it compiles anything.
     assert in_miss_path[1] >= 1 and not any(in_miss_path[2:])
+
+
+# -- the register step: one pass over a batch, held to the loop it replaced ---
+
+
+def _register_loop(agg, snapshot, rows: np.ndarray) -> None:
+    """The plain reference: ``_register_stacks_bulk`` as it stood until
+    PR 33, a numpy pass per pid. It left the package with that PR and
+    lives on here, as what the one pass has to leave behind, field for
+    field."""
+    from parca_agent_tpu.aggregator.base import ProfileMapping
+    from parca_agent_tpu.aggregator.cpu import _pid_mappings
+    from parca_agent_tpu.aggregator.dict import _PidRegistry
+    from parca_agent_tpu.capture.formats import (KERNEL_ADDR_START,
+                                                 STACK_SLOTS)
+    from parca_agent_tpu.pprof.vec import ragged_gather
+
+    pids = snapshot.pids[rows]
+    depths = (snapshot.user_len + snapshot.kernel_len)[rows]
+    table = snapshot.mappings
+    nb = len(rows)
+    depths64 = depths.astype(np.int64)
+    boff = np.zeros(nb + 1, np.int64)
+    np.cumsum(depths64, out=boff[1:])
+    flat_vals = np.empty(int(boff[-1]), np.int32)
+
+    for pid in np.unique(pids):
+        sel = np.flatnonzero(pids == pid)
+        reg = agg._pids.get(int(pid))
+        if reg is None:
+            mappings = _pid_mappings(table, int(pid))
+            reg = _PidRegistry(
+                {}, [], [], [], [], mappings,
+                {(m.start, m.end, m.offset): m.id for m in mappings},
+            )
+            agg._pids[int(pid)] = reg
+
+        prows = rows[sel]
+        pdepths = depths[sel]
+        stacks = snapshot.stacks[prows]
+        live = np.arange(STACK_SLOTS)[None, :] < pdepths[:, None]
+        addrs = stacks[live]
+        uniq = np.unique(addrs)
+        known = np.array([int(a) in reg.addr_to_loc for a in uniq], bool)
+        fresh = uniq[~known] if len(uniq) else uniq
+        if len(fresh):
+            is_kernel = fresh >= np.uint64(KERNEL_ADDR_START)
+            mrows = table.rows_for_pid(int(pid))
+            norm = fresh.copy()
+            map_id = np.zeros(len(fresh), np.int32)
+            if len(mrows):
+                starts = table.starts[mrows]
+                ends = table.ends[mrows]
+                offsets = table.offsets[mrows]
+                bases = table.bases[mrows]
+                j = np.searchsorted(starts, fresh, "right").astype(
+                    np.int64) - 1
+                safe = np.clip(j, 0, len(mrows) - 1)
+                hit = (j >= 0) & (fresh < ends[safe]) & ~is_kernel
+                norm = np.where(hit, fresh - bases[safe], fresh)
+                row_to_reg = np.zeros(len(mrows), np.int32)
+                for row in np.unique(safe[hit]) if hit.any() else []:
+                    r = int(row)
+                    mkey = (int(starts[r]), int(ends[r]), int(offsets[r]))
+                    rid = reg.mapping_index.get(mkey)
+                    if rid is None:
+                        obj = int(table.objs[mrows[r]])
+                        rid = len(reg.mappings) + 1
+                        reg.mappings.append(ProfileMapping(
+                            id=rid, start=mkey[0], end=mkey[1],
+                            offset=mkey[2],
+                            path=(table.obj_paths[obj]
+                                  if 0 <= obj < len(table.obj_paths)
+                                  else ""),
+                            build_id=(table.obj_buildids[obj]
+                                      if 0 <= obj < len(table.obj_buildids)
+                                      else ""),
+                            base=int(table.bases[mrows[r]]),
+                        ))
+                        reg.mapping_index[mkey] = rid
+                    row_to_reg[r] = rid
+                map_id = np.where(hit, row_to_reg[safe], 0)
+            base = len(reg.loc_address)
+            reg.loc_address.extend(fresh.tolist())
+            reg.loc_normalized.extend(norm.tolist())
+            reg.loc_mapping_id.extend(map_id.tolist())
+            reg.loc_is_kernel.extend(is_kernel.tolist())
+            for k, a in enumerate(fresh.tolist()):
+                reg.addr_to_loc[a] = base + k + 1
+
+        lut = np.array([reg.addr_to_loc[int(a)] for a in uniq], np.int32)
+        frame_ids = lut[np.searchsorted(uniq, stacks[live])]
+        pd64 = pdepths.astype(np.int64)
+        src_starts = np.zeros(len(sel), np.int64)
+        np.cumsum(pd64[:-1], out=src_starts[1:])
+        ragged_gather(frame_ids, src_starts, pd64,
+                      out=flat_vals, out_starts=boff[sel])
+
+    agg._append_id_meta(pids.astype(np.int32), depths64, flat_vals)
+    agg._reg_version += 1
+
+
+def _with_the_loop(agg):
+    """``agg``, its register step replaced by the reference loop (both
+    settle paths and the cold insert call it through the instance)."""
+    agg._register_stacks_bulk = \
+        lambda snapshot, rows: _register_loop(agg, snapshot, rows)
+    return agg
+
+
+def _registered_state(agg) -> dict:
+    """Everything the register step writes. A registry is compared by
+    its ``repr``: every field, dictionaries in insertion order, a bool
+    told from an int."""
+    n = agg._next_id
+    return {
+        "pids": list(agg._pids),
+        "registries": {pid: repr(reg) for pid, reg in agg._pids.items()},
+        "id_pid": agg._id_pid[:n].tolist(),
+        "loc_off": agg._loc_off[:n + 1].tolist(),
+        "loc_flat": agg._loc_flat[:int(agg._loc_off[n])].tolist(),
+        "published": agg._published,
+        "reg_version": agg._reg_version,
+    }
+
+
+_K = 0xFFFF_8000_0000_0000  # KERNEL_ADDR_START
+
+
+def _snap(rows, mappings, objs=("/bin/a", "/lib/b.so", "/lib/c.so")):
+    """A window by hand: ``rows`` of (pid, [user addresses], [kernel
+    addresses]); ``mappings`` of (pid, start, end, offset, obj), which
+    the table wants sorted by (pid, start)."""
+    from parca_agent_tpu.capture.formats import (STACK_SLOTS, MappingTable,
+                                                 WindowSnapshot)
+
+    n = len(rows)
+    stacks = np.zeros((n, STACK_SLOTS), np.uint64)
+    for i, (_pid, user, kern) in enumerate(rows):
+        stacks[i, :len(user) + len(kern)] = np.array(
+            list(user) + list(kern), np.uint64)
+    cols = list(zip(*mappings)) if mappings else [[], [], [], [], []]
+    table = MappingTable(
+        np.array(cols[0], np.int32), np.array(cols[1], np.uint64),
+        np.array(cols[2], np.uint64), np.array(cols[3], np.uint64),
+        np.array(cols[4], np.int32), obj_paths=objs,
+        obj_buildids=tuple(f"id{k}" for k in range(len(objs))))
+    return WindowSnapshot(
+        pids=[r[0] for r in rows], tids=[r[0] for r in rows],
+        counts=np.ones(n, np.int64), user_len=[len(r[1]) for r in rows],
+        kernel_len=[len(r[2]) for r in rows], stacks=stacks,
+        mappings=table)
+
+
+def _batches_one_row():
+    snap = _snap([(7, [0x1010, 0x1020, 0x1010], [_K + 5])],
+                 [(7, 0x1000, 0x2000, 0x0, 0)])
+    return [(snap, [0])]
+
+
+def _batches_known_pid_some_addresses_registered():
+    maps = [(7, 0x1000, 0x2000, 0x0, 0), (7, 0x4000, 0x5000, 0x100, 1)]
+    snap = _snap([(7, [0x1010, 0x4020], []),
+                  (7, [0x1030, 0x1010, 0x4040], [_K + 1]),
+                  (7, [0x4020, 0x1005, 0x9999], [_K + 1, _K]),
+                  (7, [0x1030], [])], maps)
+    return [(snap, [0, 1]), (snap, [3, 2])]
+
+
+def _batches_synthetic(n_pids, per_pid, seed):
+    """Every pid first seen, ``per_pid`` stacks each, in one batch whose
+    rows come in the generator's order (pids interleaved)."""
+    n = n_pids * per_pid
+    snap = generate(SyntheticSpec(
+        n_pids=n_pids, n_unique_stacks=n, n_rows=n, total_samples=3 * n,
+        mean_depth=8, kernel_fraction=0.2, seed=seed))
+    return [(snap, np.arange(n))]
+
+
+def _batches_known_and_first_seen_at_21_a_pid():
+    (snap, rows), = _batches_synthetic(24, 21, seed=21)
+    rng = np.random.default_rng(21)
+    pid_set = np.unique(snap.pids)
+    early = np.isin(snap.pids, pid_set[::2])     # half the pids come first
+    part = early & (rng.random(len(rows)) < 0.5)  # with half their stacks
+    return [(snap, np.flatnonzero(part)),
+            (snap, rng.permutation(np.flatnonzero(~part)))]
+
+
+def _batches_mapping_table_gained_a_range():
+    before = _snap([(7, [0x1010, 0x4020], []), (9, [0x1010], [])],
+                   [(7, 0x1000, 0x2000, 0x0, 0),
+                    (7, 0x4000, 0x5000, 0x100, 1),
+                    (9, 0x1000, 0x2000, 0x0, 0)])
+    # pid 7 has dlopen'ed two objects (one below, one between its old
+    # ranges) and remapped the second range at another offset; its old
+    # first range is still there, at another row of the table.
+    after = _snap([(7, [0x1011, 0x0810, 0x3010, 0x4020, 0x4030], []),
+                   (9, [0x1010, 0x1020], []),
+                   (7, [0x3020, 0x1010, 0x0900], [_K + 3])],
+                  [(7, 0x0800, 0x0a00, 0x0, 2),
+                   (7, 0x1000, 0x2000, 0x0, 0),
+                   (7, 0x3000, 0x3800, 0x40, 2),
+                   (7, 0x4000, 0x5000, 0x900, 1),
+                   (9, 0x1000, 0x2000, 0x0, 0)])
+    return [(before, [0, 1]), (after, [0, 1, 2])]
+
+
+def _batches_a_pid_with_no_mappings():
+    snap = _snap([(5, [0x10, 0x20], []), (7, [0x1010, 0x10], [_K]),
+                  (8, [0x30], []), (5, [0x20, 0x40], [])],
+                 [(7, 0x1000, 0x2000, 0x0, 0)])
+    return [(snap, [0, 1, 2, 3])]
+
+
+def _batches_no_table_at_all():
+    snap = _snap([(5, [0x10, 0x20], [_K]), (6, [0x10], [])], [])
+    return [(snap, [1, 0])]
+
+
+def _batches_kernel_only_frames():
+    top = 0xFFFF_FFFF_FFFF_FFFF
+    snap = _snap([(7, [], [_K, _K + 8, top]), (7, [], [_K + 8]),
+                  (3, [], [top, _K]), (7, [0x1010], [top])],
+                 [(3, 0x1000, 0x2000, 0x0, 0), (7, 0x1000, 0x2000, 0x0, 0)])
+    return [(snap, [0, 1, 2]), (snap, [3])]
+
+
+def _batches_edges_of_the_address_space():
+    # Starts that equal an address, an end that equals one, an empty
+    # range that shares its start with the next, user addresses with
+    # the top bit set (below the kernel half), a mapping that reaches
+    # into the kernel half, a row of depth 0, and a pid that has only it.
+    hi = 0x8000_0000_0000_0000
+    snap = _snap([(7, [0x1000, 0x1fff, 0x2000, 0x0fff], []),
+                  (7, [hi, hi + 0x10, _K - 1, 0x3000], [_K + 1]),
+                  (7, [], []), (4, [], []),
+                  (2, [0x3000, 0x3000, 0x2fff, 0x30ff, 0x3100], [])],
+                 [(2, 0x3000, 0x3000, 0x0, 1), (2, 0x3000, 0x3100, 0x10, 1),
+                  (7, 0x1000, 0x2000, 0x0, 0), (7, 0x3000, 0x3000, 0x0, 5),
+                  (7, hi, _K + 0x100, 0x20, -1)])
+    return [(snap, [0, 2, 3]), (snap, [4, 1])]
+
+
+def _batches_turnover(pids, stacks, turnover):
+    """The benchmark's own mix through the whole settle (plan, register,
+    scatter), a window a batch: ``None`` for the rows."""
+    return [(snap, None) for snap in _turnover_windows(
+        4, pids, stacks, seed=33, turnover=turnover)]
+
+
+_REGISTER_CASES = {
+    "one-row": (_batches_one_row, None),
+    "known-pid-some-addresses-registered":
+        (_batches_known_pid_some_addresses_registered, None),
+    "all-first-seen-5-a-pid": (lambda: _batches_synthetic(60, 5, 5), None),
+    "known-and-first-seen-21-a-pid":
+        (_batches_known_and_first_seen_at_21_a_pid, None),
+    "mapping-table-gained-a-range":
+        (_batches_mapping_table_gained_a_range, None),
+    "pid-with-no-mappings": (_batches_a_pid_with_no_mappings, None),
+    "no-table-at-all": (_batches_no_table_at_all, None),
+    "kernel-only-frames": (_batches_kernel_only_frames, None),
+    "edges-of-the-address-space":
+        (_batches_edges_of_the_address_space, None),
+    # 504 rows of ~8 frames against a budget of 256: ~16 groups, and a
+    # pid of 21 stacks (~170 frames) now and then a group of its own.
+    "larger-than-the-frame-budget":
+        (_batches_known_and_first_seen_at_21_a_pid, 256),
+    "one-pid-a-group": (lambda: _batches_synthetic(60, 5, 6), 1),
+    # Whole windows: the scalar settle (under _VEC_MISS_MIN misses after
+    # the cold insert) and the vectorised one, nine pids in ten new.
+    "turnover-windows-scalar-settle":
+        (lambda: _batches_turnover(40, 400, 0.5), None),
+    "turnover-windows-vectorised-settle":
+        (lambda: _batches_turnover(160, 1600, 0.9), None),
+    "turnover-windows-in-groups":
+        (lambda: _batches_turnover(160, 1600, 0.9), 1000),
+}
+
+
+@pytest.mark.parametrize("case", list(_REGISTER_CASES))
+def test_the_one_pass_leaves_what_the_loop_per_pid_left(case, monkeypatch):
+    from parca_agent_tpu.aggregator import dict as dict_mod
+
+    make, budget = _REGISTER_CASES[case]
+    if budget is not None:
+        monkeypatch.setattr(dict_mod, "_REGISTER_FRAME_BUDGET", budget)
+    new = DictAggregator(capacity=1 << 14, overflow="raise")
+    old = _with_the_loop(DictAggregator(capacity=1 << 14, overflow="raise"))
+    for snap, rows in make():
+        for agg in (new, old):
+            if rows is None:
+                agg.window_counts(snap)
+                continue
+            rows = np.asarray(rows, np.int64)
+            agg._next_id += len(rows)   # the plan's part: ids handed out
+            agg._register_stacks_bulk(snap, rows)
+        have, want = _registered_state(new), _registered_state(old)
+        for field in want:
+            assert have[field] == want[field], field
+    assert new._pids and new._published == new._next_id > 0
+
+
+def _count_numpy_calls(monkeypatch, names):
+    """Patch ``np.<name>`` for each name (the package calls numpy through
+    the module, so the patch is what it calls); the dict gains a count
+    per name."""
+    seen = dict.fromkeys(names, 0)
+
+    def counted(name, real):
+        def call(*a, **kw):
+            seen[name] += 1
+            return real(*a, **kw)
+        return call
+
+    for name in names:
+        monkeypatch.setattr(np, name, counted(name, getattr(np, name)))
+    return seen
+
+
+_NUMPY_PER_PID_SUSPECTS = (
+    "unique", "searchsorted", "argsort", "lexsort", "sort", "flatnonzero",
+    "cumsum", "where", "array", "repeat", "arange", "concatenate",
+    "bincount", "isin")    # not zeros / empty: the id arrays grow by size
+
+
+def test_the_register_step_makes_as_many_numpy_calls_for_1800_pids_as_for_18(
+        monkeypatch):
+    """The loop per pid cannot come back unnoticed: one call of the
+    register step makes a number of numpy calls that does not grow with
+    the number of pids in the batch."""
+    calls = {}
+    for n_pids in (18, 1800):
+        (snap, rows), = _batches_synthetic(n_pids, 5, seed=n_pids)
+        agg = DictAggregator(capacity=1 << 15, overflow="raise")
+        agg._next_id += len(rows)
+        with monkeypatch.context() as m:
+            seen = _count_numpy_calls(m, _NUMPY_PER_PID_SUSPECTS)
+            agg._register_stacks_bulk(snap, rows)
+        # (The generator leaves a pid in a hundred without a row.)
+        assert n_pids * 0.95 < len(agg._pids) == len(np.unique(snap.pids))
+        calls[n_pids] = seen
+    # (Equal but for the merge's bisection: two ``where`` a step, and
+    # as many steps as the largest pid's address count has bits.)
+    assert all(calls[1800][name] - calls[18][name] <= 4 for name in seen)
+    assert 0 < sum(calls[1800].values()) < 100
+    # The reference loop, for scale: several calls a pid.
+    (snap, rows), = _batches_synthetic(18, 5, seed=18)
+    agg = DictAggregator(capacity=1 << 15, overflow="raise")
+    agg._next_id += len(rows)
+    with monkeypatch.context() as m:
+        seen = _count_numpy_calls(m, _NUMPY_PER_PID_SUSPECTS)
+        _register_loop(agg, snap, rows)
+    assert sum(seen.values()) > 18 * 10
+
+
+@pytest.mark.parametrize("pids, stacks", [
+    (40, 400),        # the scalar settle
+    (160, 1600)])     # the vectorised one
+def test_a_windows_meta_counts_the_pids_it_registered(pids, stacks):
+    from parca_agent_tpu.runtime.trace import FlightRecorder
+
+    windows = _turnover_windows(3, pids, stacks, seed=9, turnover=0.75)
+    agg = DictAggregator(capacity=1 << 14, overflow="raise")
+    rec = FlightRecorder()
+    for snap in windows + [windows[-1]]:
+        had = set(agg._pids)
+        ids = agg._next_id
+        tr = rec.begin(snap.time_ns)
+        with tr.span("close"):
+            agg.window_counts(snap)
+        tr.complete()
+        meta = rec.traces()[-1]["meta"]
+        new_rows = agg._id_pid[ids:agg._next_id]
+        held = set(new_rows.tolist())
+        assert meta["misses"] == len(new_rows)
+        assert meta["registered_pids"] == len(held)
+        assert meta["registered_first_seen"] == len(held - had)
+        assert set(agg._pids) == had | held
+    # The last window came twice: the second time nothing missed, and
+    # the counts are there and say so.
+    assert (meta["misses"], meta["registered_pids"],
+            meta["registered_first_seen"]) == (0, 0, 0)
