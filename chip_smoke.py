@@ -276,7 +276,7 @@ def judge_live(metrics_text: str, healthz: dict, windows: dict,
         got = _samples(metrics, name)
         return got[0][1] if got else default
 
-    if one("parca_agent_streaming_windows_streamed", 0) < 1:
+    if one("parca_agent_streaming_windows_streamed_total", 0) < 1:
         fails.append("no window streamed")
     if one("parca_agent_streaming_disabled", 1) != 0:
         fails.append("the streaming feeder is disabled")
@@ -649,8 +649,8 @@ def phase_live(children, work, out_dir, platform, want_kind,
                             _loads(final, "debug/windows"), platform,
                             want_kind)
         report["streaming"] = {
-            n[len("parca_agent_streaming_"):]: v
-            for n, _lab, v in parse_metrics(final["metrics"])
+            ".".join([n[len("parca_agent_streaming_"):], *lab.values()]): v
+            for n, lab, v in parse_metrics(final["metrics"])
             if n.startswith("parca_agent_streaming_")}
     return report, fails
 

@@ -183,6 +183,31 @@ def _feed_program(cap: int, id_cap: int, n_pad: int, n_blocks: int,
                    donate_argnums=(1, 2))
 
 
+# The fewest rows a feed is padded to. A feed's program has one shape per
+# power of two of its rows, and a streamed window's later drains ship only
+# the stacks the carry cache has not met: a handful, whose count differs
+# from drain to drain. Up to this floor they share ONE program, compiled
+# in a run's first window (whose drains shrink through it as the cache
+# fills); without it every count from 16 rows up compiled its own, on the
+# feed thread and under its 3 s watchdog, in whichever window first
+# brought it. 1,024 rows are 16 KB of H2D a feed and ~0.3 ms of the probe
+# loop where 16 rows are ~0.04 (PERF.md section 6, PR 37).
+_FEED_PAD_MIN = 1 << 10
+
+# What a deferred settle records, by where it runs. A feed's settle runs
+# inside the next feed (or, one-shot, inside the close of its own
+# window); a streamed window's LAST feed is settled by the close, on
+# another thread and under another parent than the nine before it, and
+# a stage name stands under one parent only (the benchmark's readers key
+# a window's spans by stage), so that settle has names of its own.
+_SETTLE_STAGES = {k: k for k in (
+    "feed_settle", "feed_miss", "miss_plan", "miss_register",
+    "miss_scatter", "carry_admit")}
+_CLOSE_SETTLE_STAGES = {
+    "feed_settle": "close_settle", "feed_miss": "close_miss",
+    "miss_plan": "close_miss_plan", "miss_register": "close_miss_register",
+    "miss_scatter": "close_miss_scatter", "carry_admit": "close_carry_admit"}
+
 # Rows a miss-scatter call writes. A window's newly inserted rows go to
 # the device table in chunks of this many, the last one padded with a
 # slot beyond the table (dropped), so the program has ONE shape whatever
@@ -546,6 +571,7 @@ class DictAggregator:
         self._carry_starts = np.zeros(2, np.int64)
         self._carry_open_mass = 0   # mass carried for the open window
         self._carry_disabled = False  # fault: match off until boundary
+        self._floor_met = False     # the floor feed shape has been run
         self._cm_spec = cm_spec or CountMinSpec()
         self._hll_spec = HLLSpec()
         self._cm = None                  # lazy [depth, width] int64
@@ -637,6 +663,8 @@ class DictAggregator:
         # representative snapshot row, w64 is its (possibly folded)
         # mass, h1/h2/h3 its identity triple.
         self._miss_inflight = None
+        # The span names of the settle in progress (_settle_misses).
+        self._stages = _SETTLE_STAGES
         # Dispatched-but-uncollected close (close_dispatch/close_collect).
         self._close_handle: _CloseHandle | None = None
         # Keys at probe-chain positions >= _PROBES: device lookups can
@@ -979,20 +1007,21 @@ class DictAggregator:
             if keep is not None:
                 h1c, h2c, h3c = h1c[keep], h2c[keep], h3c[keep]
                 w64, rows_map = w64[keep], rows_map[keep]
-        if not len(h1c):
+        nd = len(h1c)
+        trace.count(rows_fed=nd)
+        self.stats["rows_fed"] = self.stats.get("rows_fed", 0) + nd
+        if not nd:
             # The whole batch carried: nothing to dispatch — its mass
             # rides the carry cache to the close flush.
             return
         counts_c = w64.astype(np.uint32)
-        nd = len(h1c)
-        trace.count(rows_fed=nd)
         with trace.child("feed_pack") as sp:
             counts_c, corrections = self._prefilter_unreachable(
                 h1c, h2c, h3c, counts_c)
             # (corrections join _pending only after the device call succeeds,
             # mirroring the miss path: a failed feed must not leave partial
             # host-side mass that a recovery close would emit as a window.)
-            n_pad = 1 << max(4, (nd - 1).bit_length())
+            n_pad = max(_FEED_PAD_MIN, 1 << (nd - 1).bit_length())
             # LRU (dict order = recency order via pop/re-insert): an
             # evict-smallest policy would pin stale large buffers after a
             # burst while current small sizes churn through one slot.
@@ -1017,6 +1046,17 @@ class DictAggregator:
             self._touch = self._new_touch()
         handle = self._feed_dispatch_async(packed, n_pad,
                                            1 if self._needs_reset else 0)
+        if self._carry and not self._floor_met:
+            # With the carry cache every later feed is small: the floor
+            # shape is the one this run will live in. Meet it now, in the
+            # process's first feed (the one the feeder gives its long
+            # budget), not in whichever later drain first falls under the
+            # floor: an all-padding batch counts nothing.
+            self._floor_met = True
+            if n_pad > _FEED_PAD_MIN:
+                self._feed_dispatch_async(
+                    np.zeros((4, _FEED_PAD_MIN), np.uint32),
+                    _FEED_PAD_MIN, 0)
         self._needs_reset = False
         self._pending.extend(corrections)
         # _fed_total means "mass in the DEVICE accumulator" (the close
@@ -1035,20 +1075,21 @@ class DictAggregator:
     # palint: sync-ok — THE deferred sync boundary: by the next feed (or
     # the close) the kernel has completed, so this is a completion
     # check, not the kernel-latency stall the old inline sync paid.
-    def _settle_misses(self) -> None:
+    def _settle_misses(self, stages: dict = _SETTLE_STAGES) -> None:
         """Settle the deferred miss check of the last dispatched feed:
         sync the miss count, resolve any misses (insert new stacks,
         queue host-side count corrections), then admit the dispatched
         keys into the carry cache so later drains fold against them.
         Runs at the next feed and at close — always before the window's
-        counts are read."""
-        import time as _time
-
+        counts are read. ``stages`` names its spans (the close of a
+        streamed window passes its own: _CLOSE_SETTLE_STAGES)."""
         inflight, self._miss_inflight = self._miss_inflight, None
         if inflight is None:
             return
+        self._stages = stages
         handle, _packed, snapshot, rows_map, w64, h1d, h2d, h3d = inflight
-        with trace.child("feed_settle") as sp:  # the wait for the kernel
+        # The wait for the kernel.
+        with trace.child(stages["feed_settle"]) as sp:
             miss_rel = self._settle_dispatch(handle)
         self.timings["feed_settle"] = sp.duration_s
         trace.count(misses=len(miss_rel), registered_pids=0,
@@ -1061,17 +1102,18 @@ class DictAggregator:
             # row-aligned hash lanes and FOLDED weights (a
             # representative's own count would drop its duplicates'
             # mass) ride the inflight tuple with them.
-            with trace.child("feed_miss") as sp:
+            with trace.child(stages["feed_miss"]) as sp:
                 self._pending.extend(self._resolve_misses(
                     snapshot, rows_map[miss_rel], h1d[miss_rel],
                     h2d[miss_rel], h3d[miss_rel], w64[miss_rel]))
             self.timings["feed_miss"] = sp.duration_s
         if self._carry and not self._carry_disabled:
-            t0 = _time.perf_counter()
-            self._carry_admit(h1d, h2d, h3d)
+            # A span of its own: feed_carry is the match of a feed's own
+            # rows, this the admission of the feed before it.
+            with trace.child(stages["carry_admit"]) as sp:
+                self._carry_admit(h1d, h2d, h3d)
             self.timings["feed_carry"] = \
-                self.timings.get("feed_carry", 0.0) \
-                + (_time.perf_counter() - t0)
+                self.timings.get("feed_carry", 0.0) + sp.duration_s
 
     # -- cross-drain carry cache (docs/perf.md "feed endgame") ---------------
 
@@ -1183,9 +1225,14 @@ class DictAggregator:
         if not self._carry or self._carry_disabled \
                 or not len(self._carry_h1) or not len(h1c):
             return None
-        import time as _time
+        with trace.child("feed_carry") as sp:
+            keep = self._carry_match_rows(h1c, h2c, h3c, w64)
+        self.timings["feed_carry"] = \
+            self.timings.get("feed_carry", 0.0) + sp.duration_s
+        return keep
 
-        t0 = _time.perf_counter()
+    def _carry_match_rows(self, h1c, h2c, h3c, w64):
+        """The match itself (_carry_match times it)."""
         try:
             faults.inject("feed.carry")
             # Bucket walk: each needle scans its prefix bucket (sorted,
@@ -1242,6 +1289,7 @@ class DictAggregator:
             # failed open without double-counting the batch.
             self._carry_w += add
             self._carry_open_mass += carried
+            trace.count(carry_matched_rows=n_hit)
             return ~hit
         except Exception as e:  # noqa: BLE001 - counted fallback
             self._carry_disabled = True
@@ -1253,10 +1301,6 @@ class DictAggregator:
                 "feed carry match failed; dispatching per drain for "
                 "the rest of the window", error=repr(e)[:200])
             return None
-        finally:
-            self.timings["feed_carry"] = \
-                self.timings.get("feed_carry", 0.0) \
-                + (_time.perf_counter() - t0)
 
     def _carry_admit(self, h1d, h2d, h3d) -> None:
         """Admit a dispatch's keys into the carry cache. h1 stays
@@ -1439,9 +1483,12 @@ class DictAggregator:
             return 8
         return 16
 
-    def close_window(self, copy: bool = True) -> np.ndarray:
+    def close_window(self, copy: bool = True,
+                     streamed: bool = False) -> np.ndarray:
         """Finish the open window: fetch exact int64 counts indexed by
         stack id (length == number of stacks known after this window).
+        ``streamed`` says the window was fed while it was open, by
+        another caller than this close's (close_dispatch).
 
         Internally close_dispatch() + close_collect(): the accumulator
         flips at dispatch, so the pack/fetch (and any retry) runs against
@@ -1455,23 +1502,26 @@ class DictAggregator:
         with it within their own window (the bench's measured close does;
         library consumers should take the default). A caller that must
         hold the view longer transfers ownership via pin_counts()."""
-        return self.close_collect(self.close_dispatch(), copy=copy)
+        return self.close_collect(self.close_dispatch(streamed), copy=copy)
 
     # palint: capture-path — dispatch half of the split close: pack
     # kernel launch + buffer flip only; the fetch belongs to
     # close_collect, off this path.
-    def close_dispatch(self) -> "_CloseHandle | None":
+    def close_dispatch(self, streamed: bool = False) -> "_CloseHandle | None":
         """First half of the window close: settle deferred feed misses,
         dispatch the pack kernel against the open accumulator (no host
         sync), and FLIP the double buffers — from here on, feeds belong
         to the next window and land in the other accumulator while this
         window's pack/fetch proceeds. Returns None for an empty window
         (nothing fed, nothing pending) after counting it, matching the
-        old close_window fast path."""
+        old close_window fast path. Closing a ``streamed`` window, the
+        settle of its last feed records under the close's own stage
+        names (_CLOSE_SETTLE_STAGES); one-shot, under the feed's."""
 
         if self._close_handle is not None:
             raise RuntimeError("previous close not collected")
-        self._settle_misses()
+        self._settle_misses(
+            _CLOSE_SETTLE_STAGES if streamed else _SETTLE_STAGES)
         carry_sids, carry_cnts = self._carry_take()
         if self._fed_total == 0 and not self._pending \
                 and carry_sids is None:
@@ -2012,7 +2062,7 @@ class DictAggregator:
         # or device-table entries, corrupting every later window. (Device
         # misses that are merely probe-bound overflows of known keys cost
         # nothing here.)
-        with trace.child("miss_plan"):
+        with trace.child(self._stages["miss_plan"]):
             plan = self._plan_misses_scalar(rows, h1, h2, h3, wts)
         return self._commit_misses_scalar(snapshot, *plan)
 
@@ -2107,7 +2157,7 @@ class DictAggregator:
             self._grow_id_hashes(base)
             self._id_h1[base:self._next_id] = self._h1[new_slots]
             self._id_h2[base:self._next_id] = self._h2[new_slots]
-            with trace.child("miss_register"):
+            with trace.child(self._stages["miss_register"]):
                 self._register_stacks_bulk(snapshot,
                                            np.array(new_rows, np.int64))
             slots = np.array(new_slots, np.int64)
@@ -2266,7 +2316,7 @@ class DictAggregator:
         Returns the pending corrections, or None to fall back (nothing
         mutated). Id assignment stays in first-occurrence row order, so
         output bytes are identical to the scalar path's."""
-        with trace.child("miss_plan"):
+        with trace.child(self._stages["miss_plan"]):
             plan = self._plan_misses_vec(rows, h1, h2, h3, wts)
         if plan is None:
             return None
@@ -2302,7 +2352,7 @@ class DictAggregator:
             self._grow_id_hashes(base_sid)
             self._id_h1[base_sid:self._next_id] = h1n
             self._id_h2[base_sid:self._next_id] = h2n
-            with trace.child("miss_register"):
+            with trace.child(self._stages["miss_register"]):
                 self._register_stacks_bulk(snapshot, rows[urep[new]])
             vals = np.zeros((n_new, 4), np.uint32)
             vals[:, 0] = h1n
@@ -2338,7 +2388,7 @@ class DictAggregator:
         scatter at its own count (_scatter_cold)."""
         if self._dev is None:
             return  # no twin to patch: the next feed builds it whole
-        with trace.child("miss_scatter"):
+        with trace.child(self._stages["miss_scatter"]):
             if self._next_id == len(slots):
                 self._scatter_cold(slots, vals)
                 shipped = 4 * len(slots) + vals.nbytes

@@ -872,6 +872,14 @@ class PerfEventSampler:
             dedup=True)
         return cols
 
+    def mapping_table(self, pids) -> MappingTable:
+        """The capture-source protocol's table of a list of pids, from
+        this sampler's caches of ``/proc/<pid>/maps`` and object files
+        (the streaming feeder asks once a drain, ``poll()`` once a
+        window); a poisoned pid is charged to the quarantine registry."""
+        return mapping_table_for_pids(self._maps, self._objs, pids,
+                                      quarantine=self.quarantine)
+
     def poll(self) -> WindowSnapshot:
         deadline = time.monotonic() + self._window
         # Drain mid-window too so a ring never wraps (the reference sizes
@@ -920,8 +928,7 @@ class PerfEventSampler:
                         np.zeros((0, STACK_SLOTS), np.uint64),
                         np.zeros(0, np.int64)))]
             pid_iter = np.unique(cols[0]).tolist()
-        table = mapping_table_for_pids(self._maps, self._objs, pid_iter,
-                                       quarantine=self.quarantine)
+        table = self.mapping_table(pid_iter)
         period_ns = int(1e9 / self._freq)
         window_ns = int(self._window * 1e9)
         if self.capture_stack:

@@ -477,6 +477,13 @@ def build_parser() -> argparse.ArgumentParser:
                         "mode (multiple of 8, < 64 KiB)")
     p.add_argument("--replay", nargs="*", default=[],
                    help="snapshot files for --capture=replay")
+    p.add_argument("--replay-drains", type=int, default=1, metavar="K",
+                   help="how a replayed window arrives: as K drains of "
+                        "the sampler, --profiling-duration / K apart, "
+                        "each handed to the streaming feeder while the "
+                        "window is open, before the window's snapshot "
+                        "(10 is a 10 s window drained once a second); "
+                        "1 hands the snapshot over in one piece")
     p.add_argument("--metadata-external-labels", default="",
                    help="k=v,k2=v2 labels attached to every profile")
     p.add_argument("--debuginfo-upload-disable", action="store_true")
@@ -725,7 +732,10 @@ def run(argv=None) -> int:
     if args.capture == "replay":
         from parca_agent_tpu.capture.replay import ReplaySource
 
-        source = ReplaySource(args.replay)
+        if args.replay_drains < 1:
+            raise SystemExit("--replay-drains must be >= 1")
+        source = ReplaySource(args.replay, drains=args.replay_drains,
+                              period_s=args.profiling_duration)
     elif args.capture == "synthetic":
         from parca_agent_tpu.capture.synthetic import SyntheticSpec, generate
 
@@ -1102,23 +1112,29 @@ def run(argv=None) -> int:
         if not (args.fast_encode and hasattr(aggregator, "feed")):
             raise SystemExit("--streaming-window requires --fast-encode "
                              "and a dict aggregator")
-        if not (hasattr(source, "on_drain") and not getattr(
-                source, "capture_stack", False)):
-            log.warn("--streaming-window needs the perf capture source in "
-                     "FP mode; running one-shot")
+        # The capture-source protocol's streaming half: a source that
+        # tees its drains (on_drain) and answers for their mappings
+        # (mapping_table), in frame-pointer mode (a DWARF walk rewrites
+        # user chains after the drain).
+        if not (hasattr(source, "on_drain")
+                and hasattr(source, "mapping_table")
+                and not getattr(source, "capture_stack", False)):
+            log.warn("--streaming-window needs a capture source that "
+                     "drains during the window (on_drain and "
+                     "mapping_table, in frame-pointer mode); running "
+                     "one-shot", capture=args.capture)
         else:
             from parca_agent_tpu.profiler.streaming import (
                 StreamingWindowFeeder,
             )
 
             feeder = StreamingWindowFeeder(
-                aggregator, source._maps, source._objs,
+                aggregator, source,
                 # Seed the statics-prebuild period so amortization covers
                 # the FIRST window too (the exact window the cold-statics
                 # transient hits); the profiler refreshes it per window.
                 prebuild_period_ns=int(
-                    1e9 / args.profiling_cpu_sampling_frequency),
-                quarantine=quarantine)
+                    1e9 / args.profiling_cpu_sampling_frequency))
             source.on_drain = feeder.on_drain
 
     # -- window flight recorder (docs/observability.md) ----------------------
@@ -1407,11 +1423,7 @@ def run(argv=None) -> int:
             out["parca_agent_remote_store_channel_resets_total"] = \
                 store.stats.get("channel_resets", 0)
         if feeder is not None:
-            out["parca_agent_streaming_disabled"] = int(feeder.disabled)
-            for k, v in feeder.stats.items():
-                if isinstance(v, (int, float)):
-                    out[f"parca_agent_streaming_{k}"] = round(v, 4) \
-                        if isinstance(v, float) else v
+            out.update(feeder.metrics())
         if fleet_merger is not None:
             # Degrade/rejoin accounting (collective-timeout path): how
             # many merge rounds ran node-local-only, timeouts, rejoins.

@@ -911,6 +911,7 @@ class CPUProfiler:
                 # inside the aggregator; touching it now would race the
                 # donation contract. Raise into the watchdog machinery:
                 # the CPU fallback shares no state with the dict.
+                self._feeder.count_fallback("blocked")
                 raise RuntimeError(
                     "abandoned streaming feed still in flight")
             counts = None
@@ -934,39 +935,10 @@ class CPUProfiler:
         # registry planned a fallback window, or the device call failed
         # or hung) vs "encode" (the device answered; the encoder did not).
         fallback_reason = "device" if kind == "prof" else None
-        if self._feeder is not None and kind == "counts":
-            # Streamed windows: the mid-window feed work and the packed
-            # close fetch are tracked by the feeder — record them as
-            # spans from the SAME numbers its stats export (lockstep).
-            fed = self._feeder.stats.get("last_window_feed_s", 0.0)
-            if fed:
-                tr.add_span("feed", fed, accumulated=True)
-            # The double-buffer overlap split (docs/perf.md "sub-RTT
-            # close"): capture-thread seconds spent DISPATCHING feeds —
-            # work whose device execution overlaps capture instead of
-            # stalling it. The deferred settle residue is feed minus
-            # this span; the overlap is visible in /debug/windows.
-            disp = self._feeder.stats.get("last_window_dispatch_s", 0.0)
-            if disp:
-                tr.add_span("feed_dispatch_overlap", disp, accumulated=True)
-            # The ingest-wall split (docs/perf.md "ingest wall"): what
-            # this window's drains spent HASHING batches vs COALESCING
-            # them to (stack, weight) pairs. Same lockstep contract as
-            # feed/feed_dispatch_overlap: the feeder resets these per
-            # window and pops the aggregator timings that source them,
-            # so an empty or fallback window records nothing stale.
-            hsh = self._feeder.stats.get("last_window_hash_s", 0.0)
-            if hsh:
-                tr.add_span("feed_hash", hsh, accumulated=True)
-            co = self._feeder.stats.get("last_window_coalesce_s", 0.0)
-            if co:
-                tr.add_span("feed_coalesce", co, accumulated=True)
-            ca = self._feeder.stats.get("last_window_carry_s", 0.0)
-            if ca:
-                tr.add_span("feed_carry", ca, accumulated=True)
-            if self._feeder.stats.get("last_window_streamed", 0):
-                tr.add_span("fetch",
-                            self._feeder.stats.get("last_close_s", 0.0))
+        # (A streamed window's feeds are spans of their own, recorded
+        # where they ran: stream_feed under drain, once a drain, with the
+        # aggregator's feed stages inside it — profiler/streaming.py; its
+        # close is this close span.)
         if kind == "counts":
             # (buffer_flip and delta_fetch are children of the close,
             # recorded where they run: dict.py close_dispatch/collect.)

@@ -43,37 +43,49 @@ from __future__ import annotations
 import threading
 import time
 
+import numpy as np
+
 from parca_agent_tpu.capture.formats import WindowSnapshot
-from parca_agent_tpu.capture.live import (
-    columns_to_snapshot,
-    mapping_table_for_pids,
-)
+from parca_agent_tpu.capture.live import columns_to_snapshot
+from parca_agent_tpu.runtime import trace
 from parca_agent_tpu.utils import faults
 from parca_agent_tpu.utils.log import get_logger
 
 _log = get_logger("streaming")
 
+# The aggregator's per-feed timings the feeder sums over a window.
+_FEED_TIMINGS = ("feed_dispatch", "feed_settle", "feed_hash",
+                 "feed_coalesce", "feed_carry")
+
+# Why a window was not streamed, as its ``meta`` (``stream_reason``) and
+# ``parca_agent_streaming_windows_fallback_total{reason}`` name it: the
+# feeder was cooling down after a failed or hung feed; the fed mass
+# differed from the snapshot's; an abandoned feed was still in flight.
+FALLBACK_REASONS = ("cooldown", "mass_mismatch", "blocked")
+
 
 class StreamingWindowFeeder:
-    """Per-drain feed glue between a LiveSampler (FP mode) and a
-    DictAggregator. Wire `sampler.on_drain = feeder.on_drain` and pass
-    the feeder to CPUProfiler(streaming_feeder=...)."""
+    """Per-drain feed glue between a capture source that drains during
+    the window (frame-pointer mode) and a DictAggregator. Wire
+    `source.on_drain = feeder.on_drain` and pass the feeder to
+    CPUProfiler(streaming_feeder=...).
 
-    def __init__(self, aggregator, maps_cache, objs_cache,
+    The source is the capture-source protocol's streaming half:
+    ``on_drain`` is called on its polling thread with each drain's
+    columnar chunk, and ``source.mapping_table(pids)`` answers with the
+    mappings of a drain's pids (the perf sampler from its caches of
+    ``/proc/<pid>/maps``, charging a poisoned pid to its quarantine
+    registry; the replay source from the open window's own table)."""
+
+    def __init__(self, aggregator, source,
                  feed_timeout_s: float = 3.0,
                  first_feed_timeout_s: float = 60.0,
                  reprobe_base_windows: int = 2,
                  reprobe_max_windows: int = 32,
                  prebuild_period_ns: int = 0,
-                 prebuild_budget_s: float = 0.25,
-                 quarantine=None):
+                 prebuild_budget_s: float = 0.25):
         self._agg = aggregator
-        self._maps = maps_cache
-        self._objs = objs_cache
-        # Ingest containment: the per-drain mini-table build reads the
-        # same untrusted /proc inputs as the window-end build; poisoned
-        # pids are charged and skipped per drain (runtime/quarantine.py).
-        self._quarantine = quarantine
+        self._source = source
         self._timeout = feed_timeout_s
         # The very FIRST feed attempt of the process gets the longer
         # budget: it includes backend work no later feed repeats (the
@@ -120,6 +132,8 @@ class StreamingWindowFeeder:
         # so on_drain skips entirely (the incomplete fed mass then makes
         # the window fall back, which is exactly right).
         self.external_blocked = None
+        # Windows that were not streamed, by reason (FALLBACK_REASONS).
+        self.fallback_reasons = dict.fromkeys(FALLBACK_REASONS, 0)
         self.stats = {"drains_fed": 0, "windows_streamed": 0,
                       "windows_fallback": 0, "reprobes": 0,
                       "statics_prebuilt": 0, "last_close_s": 0.0,
@@ -225,85 +239,89 @@ class StreamingWindowFeeder:
         except Exception:  # noqa: BLE001 - injected crash -> cooldown
             self._enter_cooldown("injected feeder crash")
             return
-        import numpy as np
+        if not len(cols[0]):
+            return
+        # One stream_feed span a drain, under the profiler's drain span
+        # open on this thread; a window's drains sum into the one span
+        # the window keeps, and what a drain does is recorded inside it
+        # where it runs.
+        with trace.child("stream_feed", histogram=True) as sp:
+            self._feed_drain(cols)
+        # Capture-thread seconds this window spent feeding.
+        self._window_feed_s += sp.duration_s
 
+    def _feed_drain(self, cols) -> None:
         # v1d chunks are 6 columns; v1h chunks (capture-side hash carry)
         # tail the drain-computed h1/h2/h3 triple.
         pids, tids, ulen, klen, stacks, counts = cols[:6]
         hashes = tuple(cols[6:9]) if len(cols) >= 9 else None
-        if not len(pids):
-            return
-        t_feed0 = time.perf_counter()
         try:
-            try:
-                table = mapping_table_for_pids(
-                    self._maps, self._objs, np.unique(pids).tolist(),
-                    quarantine=self._quarantine)
-            except Exception as e:  # noqa: BLE001 - a poisoned maps file
-                # (PoisonInput surfaces here only without a registry) must
-                # cost this DRAIN, not the capture loop: skip the feed; the
-                # fed-mass mismatch makes the window one-shot, exactly
-                # right.
-                _log.warn("drain mapping build failed; skipping feed",
-                          error=repr(e))
-                return
+            with trace.child("drain_table"):
+                table = self._source.mapping_table(np.unique(pids).tolist())
+        except Exception as e:  # noqa: BLE001 - a poisoned maps file
+            # (PoisonInput surfaces here only without a registry) must
+            # cost this DRAIN, not the capture loop: skip the feed; the
+            # fed-mass mismatch makes the window one-shot, exactly
+            # right.
+            _log.warn("drain mapping build failed; skipping feed",
+                      error=repr(e))
+            return
+        with trace.child("drain_fold"):
             mini = columns_to_snapshot(pids, tids, ulen, klen, stacks,
                                        table, 0, 0, weights=counts,
                                        hashes=hashes)
             if hashes is not None:
                 mini, hashes = mini
-            if len(mini) == 0:
-                return
-            if self._fed_total == 0:
-                # First feed of a new window: a one-shot fallback window
-                # ran window_counts() on this same aggregator between the
-                # boundary and now, leaving ITS feed_dispatch/feed_settle
-                # timings behind — discard them so the pop below can't
-                # credit them to this window's overlap accounting.
-                tim = getattr(self._agg, "timings", None)
-                if tim is not None:
-                    tim.pop("feed_dispatch", None)
-                    tim.pop("feed_settle", None)
-                    tim.pop("feed_hash", None)
-                    tim.pop("feed_coalesce", None)
-                    tim.pop("feed_carry", None)
-            if self._fed_total == 0 \
-                    and (getattr(self._agg, "_fed_total", 0)
-                         or getattr(self._agg, "_pending", None)):
-                # First feed of a new window with residual open-window
-                # state: a one-shot failed partway (its feed dispatched
-                # mass and/or registered host-side pending rows, its close
-                # never ran). Discard it all — device acc via the reset
-                # flag, host mirrors directly — exactly as window_counts
-                # guards its own entry (aggregator/dict.py). Without this
-                # the residue would ride into the streamed close and
-                # inflate counts past the feeder's own fed-mass gate
-                # ("_pending" survives an acc reset: the flag only zeroes
-                # the device accumulator).
-                self._discard_open_window()
-            if not self._feed_guarded(mini, hashes):
-                # Do NOT try again this window: a wedged device would
-                # stall the capture loop on every subsequent drain.
-                # Re-probe only at a window boundary, after a
-                # capped-exponential cooldown.
-                self._enter_cooldown("streaming feed failed")
-                return
-            # Split the feed's capture-thread cost into dispatch (launch
-            # the probe kernel; its device execution overlaps capture)
-            # and settle (the PREVIOUS feed's deferred miss check — by
-            # now a completion check, not a kernel wait). Popped, not
-            # read: feed_settle is only written when an inflight check
-            # existed, and a stale value must not re-count.
-            tim = getattr(self._agg, "timings", None)
-            if tim is not None:
-                self._window_dispatch_s += tim.pop("feed_dispatch", 0.0)
-                self._window_settle_s += tim.pop("feed_settle", 0.0)
-                self._window_hash_s += tim.pop("feed_hash", 0.0)
-                self._window_coalesce_s += tim.pop("feed_coalesce", 0.0)
-                self._window_carry_s += tim.pop("feed_carry", 0.0)
-            self._fed_total += mini.total_samples()
-            self.stats["drains_fed"] += 1
-            if self._encoder is not None and self._prebuild_period:
+            mass = mini.total_samples()
+        if len(mini) == 0:
+            return
+        tim = getattr(self._agg, "timings", None)
+        if self._fed_total == 0 and tim is not None:
+            # First feed of a new window: a one-shot fallback window
+            # ran window_counts() on this same aggregator between the
+            # boundary and now, leaving ITS feed_dispatch/feed_settle
+            # timings behind — discard them so the pop below can't
+            # credit them to this window's overlap accounting.
+            for k in _FEED_TIMINGS:
+                tim.pop(k, None)
+        if self._fed_total == 0 \
+                and (getattr(self._agg, "_fed_total", 0)
+                     or getattr(self._agg, "_pending", None)):
+            # First feed of a new window with residual open-window
+            # state: a one-shot failed partway (its feed dispatched
+            # mass and/or registered host-side pending rows, its close
+            # never ran). Discard it all — device acc via the reset
+            # flag, host mirrors directly — exactly as window_counts
+            # guards its own entry (aggregator/dict.py). Without this
+            # the residue would ride into the streamed close and
+            # inflate counts past the feeder's own fed-mass gate
+            # ("_pending" survives an acc reset: the flag only zeroes
+            # the device accumulator).
+            self._discard_open_window()
+        if not self._feed_guarded(mini, hashes):
+            # Do NOT try again this window: a wedged device would
+            # stall the capture loop on every subsequent drain.
+            # Re-probe only at a window boundary, after a
+            # capped-exponential cooldown.
+            self._enter_cooldown("streaming feed failed")
+            return
+        # Split the feed's capture-thread cost into dispatch (launch
+        # the probe kernel; its device execution overlaps capture)
+        # and settle (the PREVIOUS feed's deferred miss check — by
+        # now a completion check, not a kernel wait). Popped, not
+        # read: feed_settle is only written when an inflight check
+        # existed, and a stale value must not re-count.
+        if tim is not None:
+            self._window_dispatch_s += tim.pop("feed_dispatch", 0.0)
+            self._window_settle_s += tim.pop("feed_settle", 0.0)
+            self._window_hash_s += tim.pop("feed_hash", 0.0)
+            self._window_coalesce_s += tim.pop("feed_coalesce", 0.0)
+            self._window_carry_s += tim.pop("feed_carry", 0.0)
+        self._fed_total += mass
+        self.stats["drains_fed"] += 1
+        trace.count(drains_fed=1)
+        if self._encoder is not None and self._prebuild_period:
+            with trace.child("statics_prebuild"):
                 try:
                     if self._prebuild_fn is not None:
                         self._prebuild_fn(self._prebuild_period,
@@ -315,10 +333,6 @@ class StreamingWindowFeeder:
                     self.stats["statics_prebuilt"] += 1
                 except Exception as e:  # noqa: BLE001 - never fail the tee
                     _log.warn("statics prebuild failed", error=repr(e))
-        finally:
-            # Capture-thread seconds this window spent feeding (the
-            # flight recorder's feed span reads the per-window total).
-            self._window_feed_s += time.perf_counter() - t_feed0
 
     def _feed_guarded(self, mini: WindowSnapshot, hashes=None) -> bool:
         """One feed under the shared abandonable guard (utils/
@@ -329,9 +343,28 @@ class StreamingWindowFeeder:
         timeout = self._first_timeout if not self._first_attempted \
             else self._timeout
         self._first_attempted = True
-        status, out, done, _box = bounded_call(
-            lambda: self._agg.feed(mini, hashes=hashes), timeout,
-            thread_name="stream-feed")
+        # The feed runs on a thread of its own; what the aggregator
+        # records belongs under the span open here (the drain's
+        # stream_feed). The two crossings between the threads (the
+        # thread's start and this one's wake-up, each a turn at the GIL
+        # that the encode worker may hold) are stages of their own.
+        open_span = trace.current()
+        clock = time.monotonic
+        at = [clock(), 0.0, 0.0]  # the call; the feed's start; its end
+
+        def site():
+            with trace.adopt(open_span):
+                at[1] = clock()
+                try:
+                    return self._agg.feed(mini, hashes=hashes)
+                finally:
+                    at[2] = clock()
+
+        status, out, done, _box = bounded_call(site, timeout,
+                                               thread_name="stream-feed")
+        if status != "hang":
+            trace.note("feed_handoff", at[1] - at[0], start_s=at[0])
+            trace.note("feed_return", clock() - at[2], start_s=at[2])
         if status == "hang":
             # Abandoned: the call may still be mutating the aggregator.
             self._inflight = done
@@ -368,7 +401,7 @@ class StreamingWindowFeeder:
         if snapshot.period_ns:
             self._prebuild_period = snapshot.period_ns
         if self.disabled:
-            self.stats["windows_fallback"] += 1
+            self.count_fallback("cooldown")
             self._cooldown -= 1
             # Re-probe here, at the boundary — never mid-window — and
             # only once any abandoned feed has actually returned (the
@@ -392,13 +425,14 @@ class StreamingWindowFeeder:
             # the whole partial window — including any deferred miss
             # check, which would otherwise settle its corrections into
             # the NEXT window.
-            self.stats["windows_fallback"] += 1
+            self.count_fallback("mass_mismatch")
             self._discard_open_window()
             return None
         t0 = time.perf_counter()
-        counts = self._agg.close_window(copy=False)
+        counts = self._agg.close_window(copy=False, streamed=True)
         self.stats["windows_streamed"] += 1
         self.stats["last_window_streamed"] = 1
+        trace.annotate(streamed=1)
         self.stats["last_close_s"] = time.perf_counter() - t0
         # The close settled the window's final feed (and paid its
         # dispatch bookkeeping) AFTER the boundary reset above — pop the
@@ -420,3 +454,31 @@ class StreamingWindowFeeder:
                 "feed_carry", 0.0)
         self._backoff = self._backoff_base  # healthy again: reset backoff
         return counts
+
+    def count_fallback(self, reason: str) -> None:
+        """A window that is not streamed, and why (FALLBACK_REASONS):
+        counted, and written on the ``meta`` of the window whose span is
+        open on this thread. ``blocked`` is the profiler's to report:
+        while an abandoned feed is in flight it does not ask the feeder
+        for the window at all."""
+        self.stats["windows_fallback"] += 1
+        self.fallback_reasons[reason] += 1
+        trace.annotate(streamed=0, stream_reason=reason)
+
+    def metrics(self) -> dict:
+        """The feeder's ``/metrics`` samples. What only rises is a
+        counter under a counter's name (the windows streamed; those that
+        were not, by reason); every other numeric stat is the gauge
+        ``parca_agent_streaming_<stat>`` it has always been."""
+        out = {"parca_agent_streaming_disabled": int(self.disabled),
+               "parca_agent_streaming_windows_streamed_total":
+                   self.stats["windows_streamed"]}
+        for reason, n in self.fallback_reasons.items():
+            out["parca_agent_streaming_windows_fallback_total"
+                f'{{reason="{reason}"}}'] = n
+        for k, v in self.stats.items():
+            if k not in ("windows_streamed", "windows_fallback") \
+                    and isinstance(v, (int, float)):
+                out[f"parca_agent_streaming_{k}"] = round(v, 4) \
+                    if isinstance(v, float) else v
+        return out

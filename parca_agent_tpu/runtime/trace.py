@@ -184,6 +184,46 @@ def annotation(stage: str, **kv):
         return _NO_ANNOTATION
 
 
+class waiting:
+    """``annotation(stage)`` around a wait inside an open span (a
+    source's wait for its next drain, inside the profiler's ``drain``),
+    with the annotations of the spans open on this thread closed for its
+    length and opened again after it: in the profiler's trace the wait
+    then lies beside ``pa/drain``, not inside it, and the chip's idle
+    time under it is counted once. The spans themselves stay open and
+    go on measuring."""
+
+    __slots__ = ("_stage", "_open", "_ann")
+
+    def __init__(self, stage: str):
+        self._stage = stage
+        self._open: list = []
+        self._ann = _NO_ANNOTATION
+
+    def __enter__(self):
+        try:
+            me = threading.get_ident()  # not a span adopted from another
+            self._open = [c for c in _stack() if c._tid == me
+                          and c._ann is not _NO_ANNOTATION]
+            for c in reversed(self._open):
+                c._ann.__exit__(None, None, None)
+            self._ann = annotation(self._stage)
+            self._ann.__enter__()
+        except Exception:  # noqa: BLE001 - tracing is fail-open
+            pass
+        return self
+
+    def __exit__(self, et, ev, tb):
+        try:
+            self._ann.__exit__(et, ev, tb)
+            for c in self._open:
+                c._ann = annotation(c._stage, window=c._trace.seq)
+                c._ann.__enter__()
+        except Exception:  # noqa: BLE001 - tracing is fail-open
+            pass
+        return False
+
+
 class _SpanCtx:
     """Context manager for one timed span. Always measures (the gauges
     that must stay in lockstep with the histograms read .duration_s even
@@ -195,8 +235,8 @@ class _SpanCtx:
     name it as their parent. ``merge`` marks a child: a second span of
     the same stage under the same parent adds to the first."""
 
-    __slots__ = ("_trace", "_stage", "_hist", "_merge", "_ann", "id",
-                 "parent", "start_s", "duration_s")
+    __slots__ = ("_trace", "_stage", "_hist", "_merge", "_ann", "_tid",
+                 "id", "parent", "start_s", "duration_s")
 
     def __init__(self, trace, stage: str, histogram: bool = True,
                  merge: bool = False):
@@ -205,6 +245,7 @@ class _SpanCtx:
         self._hist = histogram
         self._merge = merge
         self._ann = _NO_ANNOTATION
+        self._tid = None  # the thread whose profiler line holds _ann
         self.id = self.parent = None
         self.start_s = self.duration_s = 0.0
 
@@ -216,9 +257,16 @@ class _SpanCtx:
                 top = stack[-1] if stack else None
                 if top is not None and top._trace is tr:
                     self.parent = top.id
-                self.id = tr.new_id()
+                # A merged stage keeps the id of its first interval: what
+                # a later interval records inside it (a drain's
+                # stream_feed and the feed stages under it) names the one
+                # span the window keeps as its parent.
+                first = tr._by_key.get((self._stage, self.parent)) \
+                    if self._merge else None
+                self.id = first["id"] if first is not None else tr.new_id()
                 stack.append(self)
                 self._ann = annotation(self._stage, window=tr.seq)
+                self._tid = threading.get_ident()
                 self._ann.__enter__()
             except Exception as e:  # noqa: BLE001 - tracing is fail-open
                 tr._rec._record_error(e)
@@ -296,11 +344,11 @@ class WindowTrace:
     birth on ``time.monotonic()``), ``parent`` is the id of the span of
     this window that was open on the same thread when this one began
     (None at the top level), and ``accumulated`` marks a duration
-    summed over several intervals."""
+    summed over ``n`` intervals."""
 
     __slots__ = ("seq", "time_ns", "t0_monotonic_s", "spans", "meta",
                  "error", "completed", "detached", "_rec", "_ids",
-                 "_compiles0")
+                 "_compiles0", "_by_key")
 
     def __init__(self, rec, seq: int, time_ns: int):
         self._rec = rec
@@ -308,6 +356,9 @@ class WindowTrace:
         self.time_ns = time_ns
         self.t0_monotonic_s = _clock()
         self.spans: list[dict] = []
+        # (stage, parent) -> the first span recorded under that key: what
+        # a merged stage adds to, looked up and not searched for.
+        self._by_key: dict[tuple, dict] = {}
         self.meta: dict = {}
         self.error: str | None = None
         self.completed = False
@@ -345,21 +396,24 @@ class WindowTrace:
         ``accumulated`` says the duration is a sum of several intervals
         that began at ``start_s``. ``merge`` adds the duration to a span
         of the same stage and parent if the window already has one (a
-        feed in chunks is one ``feed_hash`` span, then accumulated)."""
+        feed in chunks is one ``feed_hash`` span, a window's ten drains
+        one ``stream_feed``: then ``accumulated``, with the number of
+        intervals as ``n``)."""
         try:
             faults.inject("trace.record")
             if parent is not None and self.completed:
                 return  # a child of an abandoned call, after the fact
             dur = float(duration_s)
             if merge:
-                for s in self.spans:
-                    if s["stage"] == stage and s["parent"] == parent:
-                        s["duration_s"] = round(s["duration_s"] + dur, 6)
-                        s["accumulated"] = True
-                        return
+                s = self._by_key.get((stage, parent))
+                if s is not None:
+                    s["duration_s"] = round(s["duration_s"] + dur, 6)
+                    s["accumulated"] = True
+                    s["n"] = s.get("n", 1) + 1
+                    return
             if start_s is None:
                 start_s = _clock() - dur
-            self.spans.append({
+            span = {
                 "id": span_id if span_id is not None else self.new_id(),
                 "parent": parent,
                 "stage": stage,
@@ -369,7 +423,9 @@ class WindowTrace:
                 **({"accumulated": True} if accumulated else {}),
                 **({} if histogram else {"nohist": True}),
                 **({"error": error} if error else {}),
-            })
+            }
+            self.spans.append(span)
+            self._by_key.setdefault((stage, parent), span)
         except Exception as e:  # noqa: BLE001 - tracing is fail-open
             self._rec._record_error(e)
 
@@ -888,8 +944,11 @@ def annotate(**kv) -> None:
 class adopt:
     """Make ``ctx`` (a span open on another thread) the innermost open
     span of this thread for the length of a ``with`` block: the device
-    watchdog runs the close on an abandonable thread of its own, and the
-    aggregator's children belong under the profiler's ``close``."""
+    watchdog runs the close on an abandonable thread of its own and the
+    streaming feeder each drain's feed, and the aggregator's children
+    belong under the profiler's ``close`` and the feeder's
+    ``stream_feed``. The caller of an abandonable call wraps its thunk
+    in this (utils/bounded.py knows no tracer)."""
 
     __slots__ = ("_ctx",)
 

@@ -331,13 +331,13 @@ def render_metrics(profilers, batch_client=None, extra: dict | None = None,
                  agg_stats.get("coalesce_wide_folds", 0), lab)
             # Feed-endgame observability (docs/perf.md "feed endgame"):
             # the cross-drain carry cache — rows tested vs rows folded
-            # host-side (hits/rows_in is the drain-cache hit rate), the
-            # carried sample mass, the cache population, and the
-            # counted fail-open fallbacks to per-drain dispatch.
+            # host-side (parca_agent_dict_carry_matched_rows_total, with
+            # the miss path's counts below, over rows_in is the
+            # drain-cache hit rate), the carried sample mass, the cache
+            # population, and the counted fail-open fallbacks to
+            # per-drain dispatch.
             emit("parca_agent_feed_carry_rows_in_total",
                  agg_stats.get("carry_rows_in", 0), lab)
-            emit("parca_agent_feed_carry_hits_total",
-                 agg_stats.get("carry_hits", 0), lab)
             emit("parca_agent_feed_carry_mass_total",
                  agg_stats.get("carry_mass", 0), lab)
             emit("parca_agent_feed_carry_entries",
@@ -354,6 +354,14 @@ def render_metrics(profilers, batch_client=None, extra: dict | None = None,
             # reclaim gave back at window boundaries.
             emit("parca_agent_dict_misses_total",
                  agg_stats.get("misses", 0), lab)
+            # Where a feed's rows went, counted by the aggregator where
+            # it decides: folded on the host by the carry cache, or
+            # dispatched to the device. One-shot, streamed and fallback
+            # windows all count here.
+            emit("parca_agent_dict_carry_matched_rows_total",
+                 agg_stats.get("carry_hits", 0), lab)
+            emit("parca_agent_dict_rows_fed_total",
+                 agg_stats.get("rows_fed", 0), lab)
             emit("parca_agent_dict_reclaims_total",
                  agg_stats.get("reclaims", 0), lab)
             emit("parca_agent_dict_reclaimed_ids_total",

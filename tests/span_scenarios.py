@@ -1,7 +1,10 @@
 """Windows that take a fast-encoder template through every site that
 lays down, moves or rewrites a blob's static span, shared by
 tests/test_window_encoder.py (the span table) and
-tests/test_agent_transport.py (the gzip member spliced from it).
+tests/test_agent_transport.py (the gzip member spliced from it); and
+(`streamed_profiler`, `turnover_windows`) windows that arrive as drains,
+for the flight recorder's span tree of a streamed window
+(tests/test_replay_drains.py).
 
 `run(site)` yields one step per encoded window:
 (encoder, views=True output (its `span_blobs()` are what the writer is
@@ -60,6 +63,88 @@ def shape_snapshot(shape: str):
                 (snap.stacks, np.zeros((1, snap.stacks.shape[1]),
                                        np.uint64))))
     return snap
+
+
+def turnover_windows(n: int, pids: int = 12, stacks: int = 300,
+                     turnover: float = 0.0, seed: int = 2147483659):
+    """``n`` windows of the benchmark's ``turnover`` generator (at 0.0,
+    of the stationary population its ``steady`` mix draws from), as the
+    agent's replay source would load them, and beside them as the
+    benchmark's reference reads them."""
+    import io
+    import os
+    import sys
+
+    from parca_agent_tpu.capture.formats import load_snapshot
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks")
+    sys.path.insert(0, bench)
+    try:
+        from lib import generate as bgen
+        from lib import snapfile
+        from lib.mixes import turnover as mix
+    finally:
+        sys.path.remove(bench)
+    pop = bgen.Population(pids=pids, stacks=stacks,
+                          samples_per_window=8 * stacks, mean_depth=8)
+    seq = mix.sequence(pop, {"turnover": turnover}, seed) if turnover \
+        else bgen.PopulationSequence(pop, seed)
+    raw = []
+    for _ in range(n):
+        w = seq.next()   # the sequence may rewrite its rows in place
+        raw.append(dataclasses.replace(
+            w, pids=w.pids.copy(), counts=w.counts.copy(),
+            stacks=w.stacks.copy(), user_len=w.user_len.copy(),
+            kernel_len=w.kernel_len.copy()))
+    return [load_snapshot(io.BytesIO(snapfile.snapshot_bytes(w)))
+            for w in raw], raw
+
+
+class PidSink:
+    """A profile writer that keeps what each window shipped."""
+
+    def __init__(self):
+        self.windows = [{}]
+
+    def write(self, labels, blob):
+        self.windows[-1][int(labels["pid"])] = bytes(blob)
+
+
+def streamed_profiler(snaps, drains=10, overflow="sketch", recorder=None,
+                      **feeder_kw):
+    """The DaemonSet's path over fixtures: a replay source that drains,
+    the streaming feeder fed from it, a dict aggregator with the carry
+    cache and an inline fast encoder. Returns (profiler, feeder,
+    aggregator, sink)."""
+    from parca_agent_tpu.aggregator.cpu import CPUAggregator
+    from parca_agent_tpu.capture.replay import ReplaySource
+    from parca_agent_tpu.profiler.cpu import CPUProfiler
+    from parca_agent_tpu.profiler.streaming import StreamingWindowFeeder
+
+    source = ReplaySource(snaps, drains=drains)
+    agg = DictAggregator(capacity=1 << 13, overflow=overflow, carry=True)
+    feeder = StreamingWindowFeeder(agg, source, **feeder_kw)
+    source.on_drain = feeder.on_drain
+    sink = PidSink()
+    prof = CPUProfiler(source=source, aggregator=agg,
+                       fallback_aggregator=CPUAggregator(),
+                       profile_writer=sink, fast_encode=True,
+                       encode_pipeline=False, streaming_feeder=feeder,
+                       duration_s=0.0, trace_recorder=recorder)
+    return prof, feeder, agg, sink
+
+
+def run_windows(prof, sink, n):
+    """``n`` more windows through the profiler; what each shipped,
+    ``{pid: bytes}``."""
+    out = []
+    for _ in range(n):
+        assert prof.run_iteration()
+        assert prof.last_error is None
+        out.append(sink.windows[-1])
+        sink.windows.append({})
+    return out
 
 
 def _enc(snap, counts, t, enc):

@@ -101,3 +101,65 @@ def test_build_node_under_compile_rehearses_correct_on_the_cpu():
     assert metrics["dict_reclaimed_ids_per_window"]["value"] > 0
     assert metrics["feed_miss_ms.p50"]["value"] > 0
     assert "miss_scatter_roofline" not in metrics   # a device number
+
+
+def test_node_streamed_under_rollout_rehearses_streamed_on_the_cpu():
+    """The DaemonSet's flags through the harness at a tiny size: every
+    window of the measured window is streamed (ten drains, fed while it
+    is open), the comparison reads 0 on every number, nothing fails,
+    nothing compiles inside the measured window, and the traced line
+    carries the feed thread's metrics: every window's new stacks are
+    dispatched by the drain that first holds them and settled from the
+    feed thread, and the rest of its rows the carry cache folds."""
+    out = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "rehearse.py"),
+         "--config", "node-streamed", "--traffic", "rollout",
+         "--pids", "40", "--stacks", "1024", "--samples", "8000",
+         "--capacity", "16384", "--seconds", "5", "--trace", "1"],
+        env={**os.environ, "JAX_PLATFORMS": "cpu"}, capture_output=True,
+        text=True, timeout=540, cwd=REPO)
+    assert out.returncode == 0, out.stderr[-2000:]
+    line = json.loads(out.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] >= 2 and line["rehearsal"] is True
+    assert line["device"]["platform"] == "cpu"
+    assert "compared stack_mismatches = 0 (limit 0)" in out.stdout
+    assert "in-window parca_agent_xla_compile_requests_total = 0" \
+        in out.stdout
+    metrics = line["metrics"]
+    assert metrics["streamed_windows_per_window"]["value"] == 1.0
+    assert metrics["stream_rows_fed_per_window"]["value"] > 0
+    assert metrics["carry_matched_rows_per_window"]["value"] > 0
+    assert metrics["misses_per_window"]["value"] > 0
+    # Every per-layer metric the cell lists that is no device number
+    # reads one here, and the feed thread's self time is no deficit.
+    cell = next(w for w in BENCHMARK["workloads"]
+                if w["name"] == "node-streamed-rollout")
+    listed = {m["name"]: m for m in BENCHMARK["per_layer"]
+              if cell["name"] in m.get("workloads", [])}
+    assert len(listed) >= 40
+    for name, m in listed.items():
+        if m["source"] != "device_trace":
+            assert name in metrics, name
+    assert metrics["stream_feed_self_ms.p50"]["value"] >= 0
+    assert metrics["stream_feed_ms.p50"]["value"] \
+        >= metrics["drain_fold_ms.p50"]["value"] > 0
+    assert "feed_probe_roofline" not in metrics     # a device number
+
+
+def test_the_8_bit_control_is_not_correct_for_node_streamed_under_rollout():
+    """The plain reference in the program's place, its counts carried
+    in 8 bits: the comparison that holds the streamed cell has to say
+    so (exit 0: the sound shipment read 0 everywhere, the control did
+    not)."""
+    out = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "control.py"),
+         "--config", "node-streamed", "--traffic", "rollout",
+         "--seeds", "3700000007"],
+        capture_output=True, text=True, timeout=240, cwd=REPO)
+    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
+    line = json.loads(out.stdout.strip().splitlines()[-1])
+    assert line["cell"] == "node-streamed-rollout" and line["bits"] == 8
+    assert line["sound"]["correct"] is True
+    assert line["control"]["correct"] is False
+    assert line["control"]["mass_gap"] > 0

@@ -56,7 +56,7 @@ def _metrics(platform="tpu", kind="TPU v5 lite", kernel_fallback=0,
             f'parca_agent_kernel_interpret{{kernel="feed_probe"}} '
             f'{interpret}')
     if streamed is not None:
-        lines += [f"parca_agent_streaming_windows_streamed {streamed}",
+        lines += [f"parca_agent_streaming_windows_streamed_total {streamed}",
                   "parca_agent_streaming_disabled 0",
                   'parca_agent_feed_carry_fallbacks_total{profiler="cpu"} 0']
     return "\n".join(lines) + "\n"

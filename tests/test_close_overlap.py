@@ -14,6 +14,7 @@ from parca_agent_tpu.aggregator.cpu import CPUAggregator
 from parca_agent_tpu.aggregator.dict import DictAggregator
 from parca_agent_tpu.capture.synthetic import SyntheticSpec, generate
 from parca_agent_tpu.utils import faults
+from streaming_sources import CacheSource
 
 
 @pytest.fixture(autouse=True)
@@ -329,7 +330,7 @@ def test_dispatch_hang_mid_flip_loses_zero_windows():
             self.got.append((labels, blob))
 
     agg = DictAggregator(capacity=1 << 11)
-    feeder = StreamingWindowFeeder(agg, FakeMaps(), FakeObjs())
+    feeder = StreamingWindowFeeder(agg, CacheSource(FakeMaps(), FakeObjs()))
     w = Collect()
     p = CPUProfiler(source=StreamingSource(feeder, 6), aggregator=agg,
                     fallback_aggregator=CPUAggregator(),
@@ -368,9 +369,9 @@ def test_dispatch_hang_mid_flip_loses_zero_windows():
 
 def test_streamed_windows_record_overlap_trace_spans():
     """Satellite wiring (ISSUE): the flight recorder sees the overlap —
-    every streamed window carries feed_dispatch_overlap and buffer_flip
-    spans (and their stage histograms) alongside the PR 7 mandatory
-    set."""
+    every streamed window carries its feeds' dispatch (feed_dispatch
+    under stream_feed, recorded where it ran) and the buffer_flip of
+    its close, alongside the PR 7 mandatory set."""
     from parca_agent_tpu.profiler.cpu import CPUProfiler
     from parca_agent_tpu.profiler.streaming import StreamingWindowFeeder
     from parca_agent_tpu.runtime.trace import FlightRecorder
@@ -408,7 +409,7 @@ def test_streamed_windows_record_overlap_trace_spans():
 
     rec = FlightRecorder()
     agg = DictAggregator(capacity=1 << 10)
-    feeder = StreamingWindowFeeder(agg, FakeMaps(), FakeObjs())
+    feeder = StreamingWindowFeeder(agg, CacheSource(FakeMaps(), FakeObjs()))
     p = CPUProfiler(source=Src(feeder, 3), aggregator=agg,
                     profile_writer=W(), fast_encode=True,
                     streaming_feeder=feeder, trace_recorder=rec)
@@ -417,9 +418,10 @@ def test_streamed_windows_record_overlap_trace_spans():
         assert p.last_error is None
     streamed = rec.traces()[-1]
     stages = {s["stage"] for s in streamed["spans"]}
-    assert {"feed_dispatch_overlap", "buffer_flip", "fetch"} <= stages
+    assert {"stream_feed", "feed_dispatch", "buffer_flip"} <= stages
+    assert not {"feed", "feed_dispatch_overlap", "fetch"} & stages
     pct = rec.percentiles()
-    assert pct["feed_dispatch_overlap"]["count"] >= 1
+    assert pct["stream_feed"]["count"] >= 1
     assert pct["buffer_flip"]["count"] >= 1
 
 

@@ -27,6 +27,7 @@ from parca_agent_tpu.pprof.builder import parse_pprof
 from parca_agent_tpu.pprof.window_encoder import WindowEncoder
 from parca_agent_tpu.profiler.cpu import CPUProfiler
 from parca_agent_tpu.profiler.encode_pipeline import EncodePipeline
+from streaming_sources import CacheSource
 
 
 def _snap(seed=7, n_pids=6, rows=200):
@@ -563,7 +564,7 @@ def test_streaming_feeder_routes_prebuild_through_pipeline():
 
     snap = _snap(seed=13, n_pids=3, rows=60)
     agg = DictAggregator(capacity=1 << 11)
-    feeder = StreamingWindowFeeder(agg, FakeMaps(), FakeObjs(),
+    feeder = StreamingWindowFeeder(agg, CacheSource(FakeMaps(), FakeObjs()),
                                    prebuild_period_ns=snap.period_ns
                                    or 10_000_000)
     enc = WindowEncoder(agg)

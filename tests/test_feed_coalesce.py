@@ -18,6 +18,7 @@ from parca_agent_tpu.capture.formats import fold_rows_first_seen
 from parca_agent_tpu.capture.synthetic import SyntheticSpec, generate
 from parca_agent_tpu.ops import hashing
 from parca_agent_tpu.utils import faults
+from streaming_sources import CacheSource
 
 
 @pytest.fixture(autouse=True)
@@ -568,7 +569,7 @@ def test_feeder_tracks_hash_and_coalesce_seconds(window_period):
 
     dup = _dup(_snap(seed=47, rows=256, pids=4), dup=3)
     agg = DictAggregator(capacity=1 << 11)
-    feeder = StreamingWindowFeeder(agg, _FakeMaps(), _FakeObjs())
+    feeder = StreamingWindowFeeder(agg, CacheSource(_FakeMaps(), _FakeObjs()))
     for lo in range(0, len(dup), 128):
         feeder.on_drain(_cols(dup, lo, min(lo + 128, len(dup))))
     counts = feeder.take_window_if_complete(dup)
@@ -594,7 +595,7 @@ def test_fallback_window_hash_timings_do_not_leak_into_next_stream(
 
     dup = _dup(_snap(seed=53, rows=256, pids=4), dup=3)
     agg = DictAggregator(capacity=1 << 11)
-    feeder = StreamingWindowFeeder(agg, _FakeMaps(), _FakeObjs())
+    feeder = StreamingWindowFeeder(agg, CacheSource(_FakeMaps(), _FakeObjs()))
     agg.window_counts(dup)  # one-shot fallback window
     assert "feed_hash" in agg.timings or "feed_coalesce" in agg.timings
     sentinel = 99.0
@@ -608,15 +609,17 @@ def test_fallback_window_hash_timings_do_not_leak_into_next_stream(
 
 
 def test_streamed_window_records_hash_and_coalesce_spans(window_period):
-    """The profiler's trace spans mirror the feeder's per-window split
-    (the same lockstep contract as feed/feed_dispatch_overlap)."""
+    """A streamed window's hash and coalesce are the aggregator's own
+    spans, recorded on the feed thread where they ran: under the
+    window's one stream_feed span, itself under drain, summed over the
+    drains (the feeder's per-window split stays on its stats)."""
     from parca_agent_tpu.profiler.cpu import CPUProfiler
     from parca_agent_tpu.profiler.streaming import StreamingWindowFeeder
     from parca_agent_tpu.runtime.trace import FlightRecorder
 
     dup = _dup(_snap(seed=59, rows=128, pids=4), dup=3)
     agg = DictAggregator(capacity=1 << 11)
-    feeder = StreamingWindowFeeder(agg, _FakeMaps(), _FakeObjs())
+    feeder = StreamingWindowFeeder(agg, CacheSource(_FakeMaps(), _FakeObjs()))
 
     class Src:
         def __init__(self, n):
@@ -643,11 +646,20 @@ def test_streamed_window_records_hash_and_coalesce_spans(window_period):
         assert prof.run_iteration()
         assert prof.last_error is None
     streamed = rec.traces()[-1]
-    stages = {s["stage"] for s in streamed["spans"]}
-    assert {"feed_hash", "feed_coalesce"} <= stages
-    pct = rec.percentiles()
-    assert pct["feed_hash"]["count"] >= 1
-    assert pct["feed_coalesce"]["count"] >= 1
+    by_stage = {s["stage"]: s for s in streamed["spans"]}
+    # No stage under two parents: a streamed window's last settle
+    # records under the close's own names.
+    assert len(by_stage) == len(streamed["spans"])
+    feed = by_stage["stream_feed"]
+    assert feed["parent"] == by_stage["drain"]["id"]
+    assert feed["accumulated"] is True and feed["n"] == 3   # three drains
+    for stage in ("feed_hash", "feed_coalesce"):
+        assert by_stage[stage]["parent"] == feed["id"], stage
+        assert by_stage[stage]["accumulated"] is True
+        assert by_stage[stage]["thread"] == "stream-feed"
+    assert streamed["meta"]["drains_fed"] == 3
+    assert streamed["meta"]["streamed"] == 1
+    assert rec.percentiles()["stream_feed"]["count"] >= 1
     if window_period:
         # The 1 s-period arm: every streamed window rolled into the
         # window-SLO layer, well under budget.

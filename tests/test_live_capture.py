@@ -465,7 +465,7 @@ def test_cli_streaming_window_live(tmp_path):
         burn.kill()
         burn.wait()
     assert rc == 0
-    assert scraped.get("parca_agent_streaming_windows_streamed", 0) >= 1
+    assert scraped.get("parca_agent_streaming_windows_streamed_total", 0) >= 1
     assert scraped.get("parca_agent_streaming_drains_fed", 0) >= 1
     total = 0
     for f in os.listdir(out):

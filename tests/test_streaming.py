@@ -12,6 +12,7 @@ from parca_agent_tpu.aggregator.dict import DictAggregator
 from parca_agent_tpu.capture.synthetic import SyntheticSpec, generate
 from parca_agent_tpu.profiler.cpu import CPUProfiler
 from parca_agent_tpu.profiler.streaming import StreamingWindowFeeder
+from streaming_sources import CacheSource
 
 
 class FakeMaps:
@@ -39,7 +40,7 @@ def _cols(snap, lo, hi):
 def test_feeder_streams_a_complete_window():
     snap = _snap()
     agg = DictAggregator(capacity=1 << 11)
-    feeder = StreamingWindowFeeder(agg, FakeMaps(), FakeObjs())
+    feeder = StreamingWindowFeeder(agg, CacheSource(FakeMaps(), FakeObjs()))
     n = len(snap)
     for lo in range(0, n, 64):
         feeder.on_drain(_cols(snap, lo, min(lo + 64, n)))
@@ -60,7 +61,7 @@ def test_feeder_streams_a_complete_window():
 def test_feeder_incomplete_window_falls_back():
     snap = _snap(seed=2)
     agg = DictAggregator(capacity=1 << 11)
-    feeder = StreamingWindowFeeder(agg, FakeMaps(), FakeObjs())
+    feeder = StreamingWindowFeeder(agg, CacheSource(FakeMaps(), FakeObjs()))
     feeder.on_drain(_cols(snap, 0, len(snap) // 2))  # half the window
     assert feeder.take_window_if_complete(snap) is None
     assert feeder.stats["windows_fallback"] == 1
@@ -79,7 +80,7 @@ def test_fallback_window_timings_do_not_leak_into_next_stream():
     the next streamed window must not pop them into ITS overlap stats."""
     snap = _snap(seed=9)
     agg = DictAggregator(capacity=1 << 11)
-    feeder = StreamingWindowFeeder(agg, FakeMaps(), FakeObjs())
+    feeder = StreamingWindowFeeder(agg, CacheSource(FakeMaps(), FakeObjs()))
     feeder.on_drain(_cols(snap, 0, len(snap) // 2))  # half: falls back
     assert feeder.take_window_if_complete(snap) is None
     agg.window_counts(snap)  # the one-shot fallback window
@@ -106,7 +107,7 @@ def test_feeder_disables_on_feed_failure():
             raise RuntimeError("device gone")
 
     agg = Boom(capacity=1 << 11)
-    feeder = StreamingWindowFeeder(agg, FakeMaps(), FakeObjs())
+    feeder = StreamingWindowFeeder(agg, CacheSource(FakeMaps(), FakeObjs()))
     feeder.on_drain(_cols(snap, 0, len(snap)))
     assert feeder.disabled
     assert feeder.take_window_if_complete(snap) is None
@@ -131,7 +132,7 @@ def test_feeder_recovers_after_transient_failure():
             return super().feed(*a, **kw)
 
     agg = Flaky(capacity=1 << 11)
-    feeder = StreamingWindowFeeder(agg, FakeMaps(), FakeObjs(),
+    feeder = StreamingWindowFeeder(agg, CacheSource(FakeMaps(), FakeObjs()),
                                    reprobe_base_windows=2)
     feeder.on_drain(_cols(snap, 0, len(snap)))
     assert feeder.disabled
@@ -161,7 +162,7 @@ def test_feeder_prebuilds_statics_during_window():
 
     snap = _snap(seed=10)
     agg = DictAggregator(capacity=1 << 11)
-    feeder = StreamingWindowFeeder(agg, FakeMaps(), FakeObjs(),
+    feeder = StreamingWindowFeeder(agg, CacheSource(FakeMaps(), FakeObjs()),
                                    prebuild_period_ns=10_000_000)
     enc = WindowEncoder(agg)
     feeder.attach_encoder(enc)
@@ -220,7 +221,7 @@ def test_feeder_discards_residual_device_mass():
     than emit inflated counts."""
     snap = _snap(seed=12)
     agg = DictAggregator(capacity=1 << 11)
-    feeder = StreamingWindowFeeder(agg, FakeMaps(), FakeObjs())
+    feeder = StreamingWindowFeeder(agg, CacheSource(FakeMaps(), FakeObjs()))
     # Simulate the partial one-shot: feed dispatched, close never ran.
     # The residue lives in BOTH the device accumulator and the host-side
     # _pending mirror (which an acc reset alone would not clear).
@@ -250,7 +251,7 @@ def test_feeder_reenable_resets_accumulator():
             return super().feed(*a, **kw)
 
     agg = Once(capacity=1 << 10)
-    feeder = StreamingWindowFeeder(agg, FakeMaps(), FakeObjs(),
+    feeder = StreamingWindowFeeder(agg, CacheSource(FakeMaps(), FakeObjs()),
                                    reprobe_base_windows=1)
     feeder.on_drain(_cols(snap, 0, len(snap)))
     assert feeder.disabled
@@ -270,7 +271,7 @@ def test_feeder_skips_while_externally_blocked():
     aggregator or encoder at all."""
     snap = _snap(seed=14, n=100, pids=3)
     agg = DictAggregator(capacity=1 << 10)
-    feeder = StreamingWindowFeeder(agg, FakeMaps(), FakeObjs())
+    feeder = StreamingWindowFeeder(agg, CacheSource(FakeMaps(), FakeObjs()))
     feeder.external_blocked = lambda: True
     feeder.on_drain(_cols(snap, 0, len(snap)))
     assert feeder.stats["drains_fed"] == 0
@@ -288,7 +289,7 @@ def test_feeder_backoff_doubles_and_caps():
             raise RuntimeError("device gone")
 
     agg = Boom(capacity=1 << 10)
-    feeder = StreamingWindowFeeder(agg, FakeMaps(), FakeObjs(),
+    feeder = StreamingWindowFeeder(agg, CacheSource(FakeMaps(), FakeObjs()),
                                    reprobe_base_windows=2,
                                    reprobe_max_windows=8)
     observed = []
@@ -315,7 +316,7 @@ def test_feeder_hang_is_bounded():
     # first_feed_timeout_s pinned down too: the cold-start budget is
     # deliberately long in production (it covers the XLA compile), and
     # this test wedges the very first feed.
-    feeder = StreamingWindowFeeder(agg, FakeMaps(), FakeObjs(),
+    feeder = StreamingWindowFeeder(agg, CacheSource(FakeMaps(), FakeObjs()),
                                    feed_timeout_s=0.2,
                                    first_feed_timeout_s=0.2)
     import time
@@ -366,7 +367,7 @@ def test_profiler_uses_streamed_close():
             self.got.append((labels, blob))
 
     agg = DictAggregator(capacity=1 << 11)
-    feeder = StreamingWindowFeeder(agg, FakeMaps(), FakeObjs())
+    feeder = StreamingWindowFeeder(agg, CacheSource(FakeMaps(), FakeObjs()))
     w = Collect()
     p = CPUProfiler(source=StreamingSource(feeder), aggregator=agg,
                     profile_writer=w, fast_encode=True,
@@ -402,7 +403,7 @@ def test_feeder_with_sharded_aggregator():
 
     snap = _snap(seed=7, n=400, pids=8)
     agg = ShardedDictAggregator(capacity=1 << 12, mesh=fleet_mesh(8))
-    feeder = StreamingWindowFeeder(agg, FakeMaps(), FakeObjs())
+    feeder = StreamingWindowFeeder(agg, CacheSource(FakeMaps(), FakeObjs()))
     for lo in range(0, len(snap), 96):
         feeder.on_drain(_cols(snap, lo, min(lo + 96, len(snap))))
     counts = feeder.take_window_if_complete(snap)
@@ -429,7 +430,7 @@ def test_first_feed_gets_the_compile_budget_then_short_timeout():
             return super().feed(*a, **kw)
 
     agg = Slow(capacity=1 << 10)
-    feeder = StreamingWindowFeeder(agg, FakeMaps(), FakeObjs(),
+    feeder = StreamingWindowFeeder(agg, CacheSource(FakeMaps(), FakeObjs()),
                                    feed_timeout_s=0.2,
                                    first_feed_timeout_s=5.0)
     # First feed: slower than feed_timeout_s but inside the first-feed
@@ -460,7 +461,7 @@ def test_wedged_boot_pays_the_long_budget_exactly_once():
             release.wait(30)
 
     agg = Wedge(capacity=1 << 10)
-    feeder = StreamingWindowFeeder(agg, FakeMaps(), FakeObjs(),
+    feeder = StreamingWindowFeeder(agg, CacheSource(FakeMaps(), FakeObjs()),
                                    feed_timeout_s=0.1,
                                    first_feed_timeout_s=0.5,
                                    reprobe_base_windows=1)

@@ -728,6 +728,79 @@ def test_a_stage_in_chunks_is_one_accumulated_span():
     assert t["meta"]["rows_fed"] == 12
 
 
+def test_a_merged_stage_keeps_its_first_id_and_counts_its_intervals():
+    """Three drains of one window: one stream_feed span under drain, the
+    sum of the three with n = 3; what the later drains record inside it
+    names that one span as parent and merges by key too."""
+    rec = FlightRecorder()
+    tr = rec.begin()
+    with tr.span("drain") as drain:
+        for _ in range(3):
+            with trace_mod.child("stream_feed") as feed:
+                with trace_mod.child("drain_fold"):
+                    time.sleep(0.001)
+                trace_mod.note("feed_handoff", 0.002,
+                               start_s=time.monotonic())
+            assert feed.parent == drain.id
+    tr.finish()
+    spans = {s["stage"]: s for s in rec.traces()[-1]["spans"]}
+    assert len(spans) == len(rec.traces()[-1]["spans"])
+    feed = spans["stream_feed"]
+    assert feed["accumulated"] is True and feed["n"] == 3
+    assert feed["parent"] == spans["drain"]["id"]
+    for stage in ("drain_fold", "feed_handoff"):
+        assert spans[stage]["parent"] == feed["id"]
+        assert spans[stage]["n"] == 3
+    assert spans["feed_handoff"]["duration_s"] == pytest.approx(0.006)
+    assert "n" not in spans["drain"]
+
+
+def test_a_wait_inside_a_span_lies_beside_its_annotation(monkeypatch):
+    """``trace.waiting``: the open span's ``pa/<stage>`` annotation is
+    closed for the wait's length and opened again after it, so the
+    profiler's trace never shows ``pa/sleep`` inside ``pa/drain``; a
+    span adopted from another thread is left alone."""
+    events = []
+
+    class Ann:
+        def __init__(self, stage):
+            self.stage = stage
+
+        def __enter__(self):
+            events.append(("enter", self.stage))
+
+        def __exit__(self, *exc):
+            events.append(("exit", self.stage))
+
+    monkeypatch.setattr(trace_mod, "annotation",
+                        lambda stage, **kv: Ann(stage))
+    rec = FlightRecorder()
+    tr = rec.begin()
+    with tr.span("drain") as drain:
+        with trace_mod.waiting("sleep"):
+            events.append(("wait", None))
+    assert events == [("enter", "drain"), ("exit", "drain"),
+                      ("enter", "sleep"), ("wait", None), ("exit", "sleep"),
+                      ("enter", "drain"), ("exit", "drain")]
+    assert drain.duration_s > 0
+    # From another thread's point of view the adopted span is not its
+    # own: a wait there closes nothing.
+    del events[:]
+    import threading
+
+    with tr.span("drain") as drain:
+        def other():
+            with trace_mod.adopt(drain), trace_mod.waiting("sleep"):
+                pass
+
+        t = threading.Thread(target=other)
+        t.start()
+        t.join()
+    assert events == [("enter", "drain"), ("enter", "sleep"),
+                      ("exit", "sleep"), ("exit", "drain")]
+    tr.finish()
+
+
 def test_child_with_nothing_open_measures_and_records_nowhere():
     rec = FlightRecorder()
     trace_mod.install(rec)
