@@ -697,10 +697,26 @@ class DictAggregator:
         """The capture-side identity triple. In production the capture
         source computes/carries this (the reference's BPF maps are KEYED by
         the stack hash — cpu.bpf.c:438-448 — so its hot loop never hashes
-        either); replay/synthetic paths call this explicitly."""
-        return row_hash_np(snapshot.stacks, snapshot.pids,
-                           snapshot.user_len, snapshot.kernel_len,
-                           n_hashes=3)
+        either); replay/synthetic paths call this explicitly. A large
+        batch is hashed as row ranges on several threads (ops/hashing.py
+        "The row hash across cores"); how this one went is counted here:
+        on the open window's `meta` and in `stats`. A ranged hash that
+        raised (chaos site feed.hash) was redone by the serial call,
+        the same bits, and counts as a fallback."""
+        facts: dict = {}
+        hashes = row_hash_np(snapshot.stacks, snapshot.pids,
+                             snapshot.user_len, snapshot.kernel_len,
+                             n_hashes=3, facts=facts)
+        if facts:
+            trace.count(hash_ranges=facts["ranges"],
+                        hash_threads=facts["threads"])
+            self.stats["hash_parallel_batches"] = \
+                self.stats.get("hash_parallel_batches", 0) \
+                + (facts["ranges"] > 1)
+            self.stats["hash_parallel_fallbacks"] = \
+                self.stats.get("hash_parallel_fallbacks", 0) \
+                + ("fallback" in facts)
+        return hashes
 
     def window_counts(self, snapshot: WindowSnapshot,
                       hashes=None) -> np.ndarray:

@@ -88,13 +88,21 @@ int64_t pa_put_varints_padded(uint8_t* out, int64_t out_len,
 // out is row-major [n_fam, n]. n_fam is capped at 4 (the hash-family
 // count baked into ops/hashing.py) — checked here because writing
 // through a caller-undersized acc would corrupt the stack.
-int64_t pa_row_hash(const uint64_t* stacks, int64_t n, int64_t slots,
-                    const uint32_t* pids, const uint32_t* ulen,
-                    const uint32_t* klen, const int32_t* depth,
-                    const uint32_t* coefs, int64_t coef_stride,
-                    const uint32_t* biases, int64_t n_fam, uint32_t* out) {
+//
+// pa_row_hash_range hashes rows [i0, i1) of those arrays into the same
+// [n_fam, n] output. A row reads its own inputs and writes out[f*n + i]
+// only, so calls over disjoint ranges may run at once on several
+// threads (ops/hashing.py splits a large batch that way); pa_row_hash
+// is the one-range case, every row on the calling thread.
+int64_t pa_row_hash_range(const uint64_t* stacks, int64_t n, int64_t slots,
+                          const uint32_t* pids, const uint32_t* ulen,
+                          const uint32_t* klen, const int32_t* depth,
+                          const uint32_t* coefs, int64_t coef_stride,
+                          const uint32_t* biases, int64_t n_fam,
+                          uint32_t* out, int64_t i0, int64_t i1) {
   if (n_fam < 1 || n_fam > 4 || coef_stride < 2 * slots + 3) return 0;
-  for (int64_t i = 0; i < n; i++) {
+  if (i0 < 0 || i1 > n || i0 > i1) return 0;
+  for (int64_t i = i0; i < i1; i++) {
     uint32_t acc[4] = {0, 0, 0, 0};
     const uint64_t* row = stacks + i * slots;
     int64_t d = depth[i];
@@ -125,6 +133,15 @@ int64_t pa_row_hash(const uint64_t* stacks, int64_t n, int64_t slots,
     }
   }
   return -1;
+}
+
+int64_t pa_row_hash(const uint64_t* stacks, int64_t n, int64_t slots,
+                    const uint32_t* pids, const uint32_t* ulen,
+                    const uint32_t* klen, const int32_t* depth,
+                    const uint32_t* coefs, int64_t coef_stride,
+                    const uint32_t* biases, int64_t n_fam, uint32_t* out) {
+  return pa_row_hash_range(stacks, n, slots, pids, ulen, klen, depth, coefs,
+                           coef_stride, biases, n_fam, out, 0, n);
 }
 
 // Ragged byte-run copy for vec.ragged_gather: run i is

@@ -17,10 +17,16 @@ hash alone — the dedup pipeline compares full rows before merging.
 
 from __future__ import annotations
 
+import collections
 import ctypes
 import os
+import threading
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
+
+from parca_agent_tpu.utils import faults
+from parca_agent_tpu.utils.log import get_logger
 
 # Enough coefficient lanes for [hi | lo | pid | user_len | kernel_len].
 _MAX_LANES = 2 * 128 + 8
@@ -120,6 +126,9 @@ def _load_native() -> ctypes.CDLL | None:
                 ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
                 ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
                 ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p]
+            lib.pa_row_hash_range.restype = ctypes.c_int64
+            lib.pa_row_hash_range.argtypes = lib.pa_row_hash.argtypes + [
+                ctypes.c_int64, ctypes.c_int64]
             _native = lib
         except Exception as e:  # noqa: BLE001 - fallback is numpy
             _native = None
@@ -127,20 +136,102 @@ def _load_native() -> ctypes.CDLL | None:
             # several times slower per window at scale (docs/perf.md
             # "ingest wall") and a host missing g++ would otherwise
             # regress invisibly.
-            from parca_agent_tpu.utils.log import get_logger
-
             get_logger("ops.hashing").warn(
                 "native row-hash kernel unavailable; falling back to the "
                 "numpy lane-matrix path", error=repr(e))
     return _native
 
 
+# The row hash across cores (docs/perf.md "The row hash across cores").
+# Rows are independent and a row writes its own output column only, so a
+# large batch is hashed as disjoint row ranges on several threads: the
+# calling thread and a few workers that are made once, on the first
+# large batch, and stay parked on the pool's queue between windows (the
+# chip's host is slow at system calls: no thread is made per window).
+# ctypes releases the GIL round each call. The degree follows the one
+# thing the input shows, its row count: under two ranges' worth of rows
+# the call is the serial pa_row_hash on the calling thread and nothing
+# here is touched. Constants, not switches: a node's window (10,240 rows;
+# 6,400 a drain) stays serial, a firehose window (262,144) is 16 ranges.
+_HASH_RANGE_ROWS = 16_384  # the least rows a range holds
+_HASH_WORKERS_MAX = 4  # parked workers beside the calling thread
+_pool: ThreadPoolExecutor | None = None
+_pool_lock = threading.Lock()
+
+
+def _hash_workers() -> int:
+    """Workers beside the calling thread: the cores this process may
+    run on, less one for the calling (capture) thread and one for the
+    encode worker, capped."""
+    return max(0, min(_HASH_WORKERS_MAX, len(os.sched_getaffinity(0)) - 2))
+
+
+def _hash_pool(workers: int) -> ThreadPoolExecutor:
+    global _pool
+    with _pool_lock:
+        if _pool is None:
+            _pool = ThreadPoolExecutor(workers,
+                                       thread_name_prefix="row-hash")
+        return _pool
+
+
+def _hash_ranged(lib, args, n: int, workers: int) -> dict:
+    """Hash rows [0, n) as n // _HASH_RANGE_ROWS even ranges, each a
+    pa_row_hash_range call: `workers` pool threads and the calling
+    thread take ranges off one queue until it is empty, so a worker
+    that wakes late does less and one that never wakes costs nothing
+    (its task is cancelled). Returns the split's facts; raises what a
+    range raised, after every thread has left the arrays."""
+    r = n // _HASH_RANGE_ROWS
+    pending = collections.deque(
+        (n * j // r, n * (j + 1) // r) for j in range(r))
+
+    def run():
+        while True:
+            try:
+                i0, i1 = pending.popleft()
+            except IndexError:
+                return
+            faults.inject("feed.hash")
+            if lib.pa_row_hash_range(*args, i0, i1) != -1:
+                raise ValueError("row-hash layout guard tripped")
+
+    futures = []
+    err = None
+    try:
+        pool = _hash_pool(workers)
+        for _ in range(min(workers, r - 1)):
+            futures.append(pool.submit(run))
+        run()
+    except Exception as e:  # noqa: BLE001 - re-raised below
+        err = e
+        pending.clear()
+    for f in futures:
+        if not f.cancel():  # running or done: wait, it holds the arrays
+            err = err or f.exception()
+    if err is not None:
+        raise err
+    return {"ranges": r, "threads": len(futures) + 1}
+
+
+def _u32(col) -> np.ndarray:
+    """A column as contiguous uint32: the snapshot's own int32 column
+    viewed in place (the bits a cast gives), anything else converted."""
+    col = np.asarray(col)
+    if col.dtype == np.int32 and col.flags.c_contiguous:
+        return col.view(np.uint32)
+    return np.ascontiguousarray(col, np.uint32)
+
+
 def _row_hash_native(stacks_u64, pids, user_len, kernel_len,
-                     n_hashes: int):
+                     n_hashes: int, facts: dict | None = None):
     """Native dispatch, or None when the kernel cannot take this input
     (unavailable, non-contiguous, or too many lanes). Bit-identical to
     the numpy twin for contract-valid rows (zero-padded past depth —
-    zero lanes contribute coef*0 to a multilinear hash either way)."""
+    zero lanes contribute coef*0 to a multilinear hash either way).
+    `facts`, when given, learns how the batch was hashed: `ranges`,
+    `threads`, and `fallback` when the ranged form raised and the
+    serial call hashed the whole batch instead."""
     lib = _load_native()
     if lib is None or n_hashes < 1 or n_hashes > N_FAMILIES:
         return None
@@ -152,22 +243,33 @@ def _row_hash_native(stacks_u64, pids, user_len, kernel_len,
     k = 2 * slots + 3
     if k > _MAX_LANES:
         raise ValueError(f"too many lanes to hash: {k} > {_MAX_LANES}")
-    pids_u = np.ascontiguousarray(pids, np.uint32)
-    ulen_u = np.ascontiguousarray(user_len, np.uint32)
-    klen_u = np.ascontiguousarray(kernel_len, np.uint32)
-    depth = np.ascontiguousarray(
-        np.asarray(user_len, np.int64) + np.asarray(kernel_len, np.int64),
-        np.int32)
+    pids_u, ulen_u, klen_u = _u32(pids), _u32(user_len), _u32(kernel_len)
+    # The same bits an int64 sum cast to int32 has, from one array: the
+    # chip's host pays every fresh page of a temporary (PERF.md, PR 38).
+    depth = (ulen_u + klen_u).view(np.int32)
     coefs = np.ascontiguousarray(_COEFS[:n_hashes, :k])
     biases = np.ascontiguousarray(_BIASES[:n_hashes])
     out = np.empty((n_hashes, n), np.uint32)
-    ok = lib.pa_row_hash(
-        stacks.ctypes.data, n, slots, pids_u.ctypes.data,
-        ulen_u.ctypes.data, klen_u.ctypes.data, depth.ctypes.data,
-        coefs.ctypes.data, coefs.shape[1], biases.ctypes.data, n_hashes,
-        out.ctypes.data)
-    if ok != -1:  # layout guard tripped (cannot happen from this wrapper)
-        return None
+    args = (stacks.ctypes.data, n, slots, pids_u.ctypes.data,
+            ulen_u.ctypes.data, klen_u.ctypes.data, depth.ctypes.data,
+            coefs.ctypes.data, coefs.shape[1], biases.ctypes.data, n_hashes,
+            out.ctypes.data)
+    how = {"ranges": 1, "threads": 1}
+    workers = _hash_workers() if n >= 2 * _HASH_RANGE_ROWS else 0
+    if workers:
+        try:
+            how = _hash_ranged(lib, args, n, workers)
+        except Exception as e:  # noqa: BLE001 - counted fallback
+            # Fail-open to the serial call over the whole batch: the
+            # same bits, whatever the ranges had written already.
+            how["fallback"] = 1
+            get_logger("ops.hashing").warn(
+                "ranged row hash failed; hashing the batch serially",
+                error=repr(e)[:200])
+    if how["ranges"] == 1 and lib.pa_row_hash(*args) != -1:
+        return None  # layout guard tripped (cannot happen from here)
+    if facts is not None:
+        facts.update(how)
     return tuple(out)
 
 
@@ -196,18 +298,20 @@ def hash_params(n_hashes: int, slots: int):
 
 
 def row_hash_np(stacks_u64: np.ndarray, pids, user_len, kernel_len,
-                n_hashes: int = 2):
+                n_hashes: int = 2, facts: dict | None = None):
     """Host-side (numpy) twin of the device row hash; used by sketches, the
     dictionary aggregator, and tests to confirm host/device agreement.
 
     Dispatches to the native batch kernel when available (bit-identical
     output — the dict aggregator's probe path and every cross-node join
     key on these exact values); PARCA_NO_NATIVE_HASH=1 pins the numpy
-    lane-matrix fallback."""
+    lane-matrix fallback. A `facts` dict learns how the native kernel
+    hashed the batch (`_row_hash_native`); the numpy path leaves it
+    empty."""
     stacks_u64 = np.asarray(stacks_u64, np.uint64)
     if not os.environ.get("PARCA_NO_NATIVE_HASH") and len(stacks_u64):
         got = _row_hash_native(stacks_u64, pids, user_len, kernel_len,
-                               n_hashes)
+                               n_hashes, facts)
         if got is not None:
             return got
     hi = (stacks_u64 >> np.uint64(32)).astype(np.uint32)

@@ -68,6 +68,13 @@ layer the ship-path components consult at NAMED SITES:
                       is counted (coalesce_fallbacks) and the batch
                       dispatches UNCOALESCED — identical counts and
                       pprof bytes, never a lost feed or window
+    feed.hash         one row range of a large batch's native row hash
+                      (ops/hashing.py; docs/perf.md "The row hash
+                      across cores") — fail-open by contract: an
+                      injected fault is counted
+                      (hash_parallel_fallbacks) and the serial call
+                      hashes the whole batch — the same bits, never a
+                      lost feed or window
     feed.carry        the cross-drain carry-cache match of one feed
                       batch (aggregator/dict.py; docs/perf.md "feed
                       endgame") — fail-open by contract: an injected
@@ -176,6 +183,7 @@ SITES = {
     "regression.baseline":
         "sentinel baseline save/adopt (runtime/regression.py)",
     "feed.coalesce": "feed-batch (stack, weight) fold (aggregator/dict.py)",
+    "feed.hash": "one row range of a large batch's hash (ops/hashing.py)",
     "feed.carry": "cross-drain carry-cache match (aggregator/dict.py)",
     "elf.read": "ElfFile construction (elf/reader.py)",
     "perfmap.parse": "JIT perf-map read+parse (symbolize/perfmap.py)",

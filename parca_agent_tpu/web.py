@@ -329,6 +329,13 @@ def render_metrics(profilers, batch_client=None, extra: dict | None = None,
                  agg_stats.get("coalesce_unique_batches", 0), lab)
             emit("parca_agent_feed_coalesce_wide_folds_total",
                  agg_stats.get("coalesce_wide_folds", 0), lab)
+            # The row hash across cores (docs/perf.md): batches large
+            # enough to be hashed as row ranges on several threads, and
+            # those of them the serial call had to redo.
+            emit("parca_agent_feed_hash_parallel_batches_total",
+                 agg_stats.get("hash_parallel_batches", 0), lab)
+            emit("parca_agent_feed_hash_parallel_fallbacks_total",
+                 agg_stats.get("hash_parallel_fallbacks", 0), lab)
             # Feed-endgame observability (docs/perf.md "feed endgame"):
             # the cross-drain carry cache — rows tested vs rows folded
             # host-side (parca_agent_dict_carry_matched_rows_total, with
