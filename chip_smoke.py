@@ -218,9 +218,6 @@ def judge_device(metrics_text: str, healthz: dict, windows: dict,
     for lab, v in _samples(metrics, "parca_agent_kernel_fallback"):
         if v:
             fails.append(f"kernel {lab.get('kernel')} fell back")
-    for lab, v in _samples(metrics, "parca_agent_kernel_interpret"):
-        if v:
-            fails.append(f"kernel {lab.get('kernel')} ran interpreted")
     moved = {"h2d": 0.0, "d2h": 0.0}
     for lab, v in _samples(metrics, "parca_agent_transfer_bytes_total"):
         if lab.get("direction") in moved:

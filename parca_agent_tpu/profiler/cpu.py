@@ -62,6 +62,9 @@ class ProfilerMetrics:
     # into the void — now it is logged and counted here.
     device_abandoned_ok_total: int = 0
     device_abandoned_err_total: int = 0
+    # The loop's wait between windows, measured: how much later than
+    # asked the next window began, summed over the windows of run().
+    loop_overshoot_seconds_total: float = 0.0
     # What the ship's gzip did (agent/writer.py): static pieces spliced
     # from the encoder's cache or built anew, bytes that went through
     # deflate, and splices that fell back to a plain gzip.compress.
@@ -533,8 +536,21 @@ class CPUProfiler:
         t_iter0 = time.perf_counter()
         tr = (self._recorder.begin() if self._recorder is not None
               else NULL_TRACE)
+        if self._loop_waited is not None:
+            # run() waited before this window: from the end of the
+            # iteration before to this trace's begin, less what the wait
+            # was asked for, is how late the loop came back (a thread
+            # that was not scheduled, a pause of the whole process).
+            t_end, wait_s = self._loop_waited
+            self._loop_waited = None
+            began = tr.t0_monotonic_s if tr is not NULL_TRACE \
+                else time.monotonic()
+            late = max(0.0, began - t_end - wait_s)
+            self.metrics.loop_overshoot_seconds_total += late
+            tr.annotate(loop_wait_s=round(wait_s, 6),
+                        loop_overshoot_s=round(late, 6))
         try:
-            with tr.span("drain"):
+            with tr.span("drain", usage=True):
                 snapshot = self._source.poll()
         except Exception as e:
             # Capture trouble is non-fatal, like any other iteration error
@@ -597,7 +613,7 @@ class CPUProfiler:
                     self.metrics.last_symbolize_duration_s = \
                         sp_sym.duration_s
 
-                with tr.span("ship"):
+                with tr.span("ship", usage=True):
                     self._write_profiles(profiles)
                 n_pids = len(profiles)
                 tr.annotate(pids=n_pids, path="scalar")
@@ -969,12 +985,12 @@ class CPUProfiler:
         if kind == "prof":
             tr.annotate(path="scalar-fallback",
                         fallback_reason=fallback_reason)
-            with tr.span("ship"):
+            with tr.span("ship", usage=True):
                 self._write_profiles(out)
             return len(out)
         tr.annotate(path="inline")
         try:
-            with tr.span("ship"):
+            with tr.span("ship", usage=True):
                 n = self._write_encoded(out)
         finally:
             # Secondaries run even when the pprof write raised (the
@@ -1034,7 +1050,7 @@ class CPUProfiler:
         _log.warn("encode pipeline busy at window close; scalar fallback "
                   "for this window")
         tr.annotate(path="scalar-backpressure")
-        with tr.span("ship"):
+        with tr.span("ship", usage=True):
             return self._ship_scalar(snapshot)
 
     def _encode_inline(self, counts, snapshot: WindowSnapshot):
@@ -1110,11 +1126,13 @@ class CPUProfiler:
                 faults.inject("actor.profiler")
                 if not self.run_iteration():
                     return
-                elapsed = time.monotonic() - t0
+                t_end = time.monotonic()
+                wait_s = max(0.0, self._duration - (t_end - t0))
+                self._loop_waited = (t_end, wait_s)
                 # The wait for the next period, on the device trace's
                 # clock: the chip's idle time under it is headroom.
                 with trace_mod.annotation("sleep"):
-                    self._stop.wait(max(0.0, self._duration - elapsed))
+                    self._stop.wait(wait_s)
         except BaseException as e:
             # Anything escaping run_iteration is a bug, not an iteration
             # failure; record it so the CLI can exit nonzero instead of
@@ -1141,6 +1159,9 @@ class CPUProfiler:
             self._restore_gc()
 
     crashed: BaseException | None = None
+    # (the end of the iteration before, what run() then waited for):
+    # read and cleared by the next run_iteration.
+    _loop_waited: tuple | None = None
 
     def stop(self) -> None:
         self._stop.set()

@@ -403,7 +403,7 @@ class EncodePipeline:
             # A context span: what the ship is made of is recorded under
             # it (profiler/cpu.py _write_all), and a failed ship's span
             # carries the error.
-            with trace.span("ship") as sp_ship:
+            with trace.span("ship", usage=True) as sp_ship:
                 self._ship(out, prep)
         except Exception as e:  # noqa: BLE001 - ship != encoder failure
             # A writer error is NOT an encoder failure: the template is
@@ -488,7 +488,7 @@ class EncodePipeline:
                 trace.annotate(window_lost=True)
                 return
             try:
-                with trace.span("ship"):
+                with trace.span("ship", usage=True):
                     fallback()
                 trace.annotate(path="scalar-pipeline-fail")
             except Exception as e2:  # noqa: BLE001 - like an iteration error

@@ -245,7 +245,7 @@ class StreamingWindowFeeder:
         # open on this thread; a window's drains sum into the one span
         # the window keeps, and what a drain does is recorded inside it
         # where it runs.
-        with trace.child("stream_feed", histogram=True) as sp:
+        with trace.child("stream_feed", histogram=True, usage=True) as sp:
             self._feed_drain(cols)
         # Capture-thread seconds this window spent feeding.
         self._window_feed_s += sp.duration_s

@@ -61,6 +61,7 @@ import threading
 import time
 
 from parca_agent_tpu.runtime import device_telemetry as dtel
+from parca_agent_tpu.runtime.trace import thread_ended
 from parca_agent_tpu.runtime.window_clock import (
     REFERENCE_WINDOW_S,
     check_window_s,
@@ -464,6 +465,7 @@ class DeviceHealthRegistry:
         except BaseException as e:  # noqa: BLE001 - a broken probe = failed
             ok, detail = False, repr(e)[:200]
         self._on_probe_result(gen, bool(ok), str(detail), platform)
+        thread_ended()  # a thread of its own, gone before any scrape
 
     def _check_probe_deadline_locked(self) -> None:  # palint: holds=_lock
         """A probe that outlived its deadline is a HANG: count it failed

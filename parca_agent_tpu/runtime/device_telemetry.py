@@ -116,9 +116,6 @@ def collect_identity() -> dict:
         "jax_version": str(jax.__version__),
         "jaxlib_version": _dist_version("jaxlib"),
         "libtpu_version": _dist_version("libtpu"),
-        # Whether a kernel written for the chip's own compiler would
-        # run interpreted here: on any platform but the TPU.
-        "interpret_default": platform != "tpu",
         "hostname": socket.gethostname(),
     }
 
@@ -242,10 +239,9 @@ class DeviceTelemetry:
     # palint: fail-open
     def note_backend(self, kernel: str, requested: str | None = None,
                      resolved: str | None = None,
-                     interpret: bool | None = None,
                      fallback: bool | None = None) -> None:
         """Latch one kernel's backend resolution (requested vs resolved
-        backend, interpret-mode flag, fallback one-hot). Fields are
+        backend, fallback one-hot). Fields are
         sticky per call — last write wins, None leaves a field alone.
         Fail-open."""
         try:
@@ -253,13 +249,11 @@ class DeviceTelemetry:
             with self._lock:
                 rec = self._backends.setdefault(kernel, {
                     "requested": None, "resolved": None,
-                    "interpret": None, "fallback": False})
+                    "fallback": False})
                 if requested is not None:
                     rec["requested"] = requested
                 if resolved is not None:
                     rec["resolved"] = resolved
-                if interpret is not None:
-                    rec["interpret"] = bool(interpret)
                 if fallback is not None:
                     rec["fallback"] = bool(fallback)
         except Exception as e:  # noqa: BLE001 - telemetry is fail-open
@@ -403,7 +397,7 @@ class DeviceTelemetry:
                     for (k, d), t in sorted(self._transfers.items())]
 
     def backends(self) -> dict[str, dict]:
-        """{kernel: {requested, resolved, interpret, fallback}}."""
+        """{kernel: {requested, resolved, fallback}}."""
         with self._lock:
             return {k: dict(v) for k, v in sorted(self._backends.items())}
 
@@ -510,7 +504,7 @@ def transfer(kernel: str, direction: str, nbytes: int) -> None:
 
 
 def note_backend(kernel: str, **fields) -> None:
-    """Backend-resolution latch hook (resolved/interpret/fallback)."""
+    """Backend-resolution latch hook (requested/resolved/fallback)."""
     if _active is not None:
         _active.note_backend(kernel, **fields)
 

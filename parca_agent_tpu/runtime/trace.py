@@ -54,9 +54,11 @@ import contextlib
 import itertools
 import json
 import os
+import re
 import sys
 import threading
 import time
+import weakref
 
 from parca_agent_tpu.utils import faults
 from parca_agent_tpu.utils.log import get_logger
@@ -165,6 +167,26 @@ def _stack() -> list:
     return stack
 
 
+# What a span that asks for it (``usage=True``) records of its threads
+# beside its wall time: their CPU (user + system) from each thread's own
+# CPU clock, ``time.thread_time()``, read at both edges. It is the clock
+# the per-thread counters read too, exact at the moment of the call. A
+# reading is a system call (~0.4 us on Linux, ~50 us inside the agent on
+# a sandboxed kernel such as gVisor), so the call site decides: the
+# stages that lie off the stretch from a window's last sample to its
+# pprof bytes ask, and nothing between those two edges does (PERF.md
+# section 6, PR 39).
+def _usage_fields(cpu_s: float, threads: int = 0) -> dict:
+    out = {"cpu_s": round(cpu_s, 6)} if cpu_s > 0 else {}
+    if threads:
+        out["threads"] = threads
+    return out
+
+
+# Serializes what adopting threads add to a span open elsewhere (an
+# abandoned feed thread may leave beside the next one).
+_adopt_lock = threading.Lock()
+
 _NO_ANNOTATION = contextlib.nullcontext()
 
 
@@ -233,19 +255,30 @@ class _SpanCtx:
     While open it sits on its thread's stack, so spans begun inside it
     (``trace.span`` of the same window, ``child`` from deep components)
     name it as their parent. ``merge`` marks a child: a second span of
-    the same stage under the same parent adds to the first."""
+    the same stage under the same parent adds to the first.
+
+    With ``usage`` a recorded span also reads its thread's CPU clock at
+    both edges, and holds what the threads that adopted it
+    (:class:`adopt`) used under it: such a span's CPU is that of every
+    thread that worked under it."""
 
     __slots__ = ("_trace", "_stage", "_hist", "_merge", "_ann", "_tid",
+                 "_usage", "_up", "_u0", "_adopted", "_threads",
                  "id", "parent", "start_s", "duration_s")
 
     def __init__(self, trace, stage: str, histogram: bool = True,
-                 merge: bool = False):
+                 merge: bool = False, usage: bool = False):
         self._trace = trace
         self._stage = stage
         self._hist = histogram
         self._merge = merge
+        self._usage = usage
         self._ann = _NO_ANNOTATION
         self._tid = None  # the thread whose profiler line holds _ann
+        self._up = None  # the span open around this one, adopted or not
+        self._u0 = None  # the thread's CPU clock at the start
+        self._adopted = 0.0  # CPU the adopting threads added
+        self._threads = 0  # how many did
         self.id = self.parent = None
         self.start_s = self.duration_s = 0.0
 
@@ -257,6 +290,7 @@ class _SpanCtx:
                 top = stack[-1] if stack else None
                 if top is not None and top._trace is tr:
                     self.parent = top.id
+                    self._up = top
                 # A merged stage keeps the id of its first interval: what
                 # a later interval records inside it (a drain's
                 # stream_feed and the feed stages under it) names the one
@@ -268,16 +302,35 @@ class _SpanCtx:
                 self._ann = annotation(self._stage, window=tr.seq)
                 self._tid = threading.get_ident()
                 self._ann.__enter__()
+                if self._usage:
+                    self._u0 = time.thread_time()
             except Exception as e:  # noqa: BLE001 - tracing is fail-open
                 tr._rec._record_error(e)
         self.start_s = _clock()
         return self
+
+    def _used(self) -> dict | None:
+        """This span's usage fields at its end: the thread's own CPU
+        since the start (none when the span is left on another thread
+        than it was entered on) plus what adopting threads added."""
+        if self._u0 is None:
+            return None
+        cpu_s = 0.0
+        if self._tid == threading.get_ident():
+            cpu_s = time.thread_time() - self._u0
+        with _adopt_lock:
+            return _usage_fields(cpu_s + self._adopted, self._threads)
 
     def __exit__(self, et, ev, tb):
         self.duration_s = _clock() - self.start_s
         tr = self._trace
         if tr is NULL_TRACE:
             return False
+        used = None
+        try:
+            used = self._used()
+        except Exception as e:  # noqa: BLE001 - the span keeps its wall
+            tr._rec._record_error(e)
         try:
             self._ann.__exit__(et, ev, tb)
             stack = _stack()
@@ -291,7 +344,8 @@ class _SpanCtx:
             self._stage, self.duration_s,
             error=(repr(ev)[:200] if ev is not None else None),
             histogram=self._hist, start_s=self.start_s,
-            parent=self.parent, span_id=self.id, merge=self._merge)
+            parent=self.parent, span_id=self.id, merge=self._merge,
+            used=used)
         return False
 
 
@@ -303,7 +357,8 @@ class _NullTrace:
     completed = True
     detached = False
 
-    def span(self, stage: str, histogram: bool = True) -> _SpanCtx:
+    def span(self, stage: str, histogram: bool = True,
+             usage: bool = False) -> _SpanCtx:
         return _SpanCtx(self, stage)
 
     def add_span(self, stage, duration_s, error=None,
@@ -344,7 +399,10 @@ class WindowTrace:
     birth on ``time.monotonic()``), ``parent`` is the id of the span of
     this window that was open on the same thread when this one began
     (None at the top level), and ``accumulated`` marks a duration
-    summed over ``n`` intervals."""
+    summed over ``n`` intervals. A span that asked for it
+    (``span(..., usage=True)``) has beside the wall time, each left out
+    when zero, ``cpu_s`` (user + system CPU of every thread that worked
+    under the span) and ``threads`` (how many threads adopted it)."""
 
     __slots__ = ("seq", "time_ns", "t0_monotonic_s", "spans", "meta",
                  "error", "completed", "detached", "_rec", "_ids",
@@ -369,8 +427,12 @@ class WindowTrace:
     def new_id(self) -> int:
         return next(self._ids)  # atomic under the GIL: threads share it
 
-    def span(self, stage: str, histogram: bool = True) -> _SpanCtx:
-        return _SpanCtx(self, stage, histogram)
+    def span(self, stage: str, histogram: bool = True,
+             usage: bool = False) -> _SpanCtx:
+        """A top-level stage. ``usage`` asks for the CPU of the threads
+        that work under it (``cpu_s``): two system calls, so a stage
+        between a window's last sample and its pprof bytes does not."""
+        return _SpanCtx(self, stage, histogram, usage=usage)
 
     # palint: fail-open
     def add_span(self, stage: str, duration_s: float,
@@ -380,7 +442,8 @@ class WindowTrace:
                  parent: int | None = None,
                  accumulated: bool = False,
                  span_id: int | None = None,
-                 merge: bool = False) -> None:
+                 merge: bool = False,
+                 used: dict | None = None) -> None:
         """Record one span, at its end; fail-open (a tracing fault must
         never cost the window — the trace.record chaos site injects
         exactly here). EVERY span of a window goes through this one
@@ -398,7 +461,9 @@ class WindowTrace:
         of the same stage and parent if the window already has one (a
         feed in chunks is one ``feed_hash`` span, a window's ten drains
         one ``stream_feed``: then ``accumulated``, with the number of
-        intervals as ``n``)."""
+        intervals as ``n``). ``used`` is what the span's threads used
+        (``cpu_s``, ``threads``); a merged span sums it as it sums the
+        duration."""
         try:
             faults.inject("trace.record")
             if parent is not None and self.completed:
@@ -410,6 +475,8 @@ class WindowTrace:
                     s["duration_s"] = round(s["duration_s"] + dur, 6)
                     s["accumulated"] = True
                     s["n"] = s.get("n", 1) + 1
+                    for k, v in (used or {}).items():
+                        s[k] = round(s.get(k, 0) + v, 6)
                     return
             if start_s is None:
                 start_s = _clock() - dur
@@ -423,6 +490,7 @@ class WindowTrace:
                 **({"accumulated": True} if accumulated else {}),
                 **({} if histogram else {"nohist": True}),
                 **({"error": error} if error else {}),
+                **(used or {}),
             }
             self.spans.append(span)
             self._by_key.setdefault((stage, parent), span)
@@ -536,6 +604,9 @@ class FlightRecorder:
         self._ring: collections.deque = collections.deque(  # guarded-by: _lock
             maxlen=max(1, ring))
         self._hists: dict[str, StageHistogram] = {}  # guarded-by: _lock
+        # stage -> cpu_s summed over every completed window's spans of
+        # that stage, children too.
+        self._stage_cpu_s: dict[str, float] = {}  # guarded-by: _lock
         self._seq = 0  # guarded-by: _lock
         self._slow_multiple = slow_multiple
         self._min_count = max(1, min_count)
@@ -619,6 +690,9 @@ class FlightRecorder:
             with self._lock:
                 for s in trace.spans:
                     stage, dur = s["stage"], s["duration_s"]
+                    if "cpu_s" in s:
+                        self._stage_cpu_s[stage] = \
+                            self._stage_cpu_s.get(stage, 0.0) + s["cpu_s"]
                     if s.pop("nohist", False):
                         # This stage's histogram AND slow detection are
                         # fed per-call elsewhere (encoder statics via
@@ -799,6 +873,7 @@ class FlightRecorder:
         finally:
             with self._lock:
                 self._dumping = False
+            thread_ended()  # a thread of its own, gone before any scrape
 
     def _self_profile_bytes(self) -> bytes:
         if self._self_profile is not None:
@@ -843,6 +918,12 @@ class FlightRecorder:
             return {stage: h.export()
                     for stage, h in sorted(self._hists.items())}
 
+    def export_stage_cpu(self) -> dict[str, float]:
+        """{stage: cpu_s}: the per-stage totals /metrics serves, for the
+        stages whose spans ask for their threads' CPU."""
+        with self._lock:
+            return dict(sorted(self._stage_cpu_s.items()))
+
     def percentiles(self) -> dict[str, dict]:
         """{stage: {p50_ms, p90_ms, p99_ms, max_ms, count}} — the compact
         distribution stamp (bench JSON, incident files)."""
@@ -882,6 +963,175 @@ def observe(stage: str, duration_s: float) -> None:
         _active.observe(stage, duration_s)
 
 
+# -- every thread's CPU ---------------------------------------------------------
+
+
+_THREAD_SERIAL = re.compile(r"(?:[-_ ]?\d+)+$")
+_DEFAULT_NAME = re.compile(r"Thread-\d+ \((.+)\)$")
+_COMM_DIGITS = re.compile(r"\d+")
+
+
+def thread_label(name: str) -> str:
+    """A thread's name with any trailing number taken off (`row-hash_3`
+    -> `row-hash`); a thread nobody named goes by its target
+    (`Thread-7 (channel_spin)` -> `channel_spin`)."""
+    m = _DEFAULT_NAME.match(name)
+    return _THREAD_SERIAL.sub("", m.group(1) if m else name) or "thread"
+
+
+def _task_stat(tid) -> tuple[bytes, int] | None:
+    """``(comm, utime + stime in clock ticks)`` of one of this process's
+    threads from ``/proc/self/task/<tid>/stat``; None for a thread that
+    is gone (no such file) or a line that does not parse."""
+    try:
+        with open(f"/proc/self/task/{tid}/stat", "rb") as f:
+            raw = f.read()
+        # "<tid> (<comm>) <state> ...": utime and stime are the 12th
+        # and 13th fields after the name's closing bracket.
+        close = raw.rindex(b")")
+        rest = raw[close + 2:].split()
+        return raw[raw.index(b"(") + 1:close], int(rest[11]) + int(rest[12])
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+class ThreadCpu:
+    """The process's CPU by thread, as monotone counters
+    (``parca_agent_thread_cpu_seconds_total{thread}``; docs/
+    observability.md lists the labels).
+
+    Most of the agent's work runs on threads that are gone when anyone
+    looks (``bounded_call`` starts one for every device call), so the
+    counters are fed from two sides: a thread that ends credits its own
+    ``time.thread_time()`` (:meth:`ended`, its last act), and a scrape
+    credits every live thread with the rise of what the kernel's
+    ``/proc/self/task/<tid>/stat`` says it has used (clock ticks) since
+    the scrape before. No thread asks for another thread's CPU clock: a
+    thread that ended under the scrape has no file, where its clock id
+    would be a dangling one. What one side has credited the other
+    leaves out, so each label only rises. ``native`` is the remainder:
+    the process's CPU less every Python thread's, which is XLA's,
+    libtpu's and gRPC's own threads (and a Python thread that ended
+    without saying so). Nothing here runs on a window's path: a scrape
+    pays it."""
+
+    _MAX_LABELS = 32  # thread names are the code's, not the input's
+    _MAX_COMMS = 16
+    # The native threads are ~100 files of ~100 us each on some hosts.
+    _NATIVE_EVERY_S = 1.0
+
+    def __init__(self):
+        self._tick_s = 1.0 / os.sysconf("SC_CLK_TCK")
+        self._lock = threading.Lock()
+        self._total: dict[str, float] = {}  # guarded-by: _lock
+        # Thread -> the CPU already credited for it; and the threads
+        # that said they were ending, which a scrape credits no more.
+        self._seen: weakref.WeakKeyDictionary = \
+            weakref.WeakKeyDictionary()  # guarded-by: _lock
+        self._ended: weakref.WeakSet = weakref.WeakSet()  # guarded-by: _lock
+        self._native = 0.0  # guarded-by: _lock
+        # The walk of the native threads has a lock of its own: a thread
+        # that reports its end never waits for file reads.
+        self._proc_lock = threading.Lock()
+        self._comm_total: dict[str, float] = {}  # guarded-by: _proc_lock
+        self._tid_ticks: dict[int, int] = {}  # guarded-by: _proc_lock
+        self._proc_at: float | None = None  # guarded-by: _proc_lock
+
+    def _credit(self, name: str, cpu_s: float) -> None:  # palint: holds=_lock
+        label = thread_label(name)
+        if label not in self._total and len(self._total) >= self._MAX_LABELS:
+            label = "other"
+        self._total[label] = self._total.get(label, 0.0) + cpu_s
+
+    # palint: fail-open
+    def ended(self) -> None:
+        """The calling thread is about to end: credit what it used and
+        no scrape has added yet. A scrape credits it no more."""
+        try:
+            t = threading.current_thread()
+            now = time.thread_time()
+            with self._lock:
+                self._credit(t.name, max(0.0, now - self._seen.get(t, 0.0)))
+                self._seen[t] = now
+                self._ended.add(t)
+        except Exception as e:  # noqa: BLE001 - accounting never fails a call
+            _log.debug("thread CPU credit failed (fail-open)", error=repr(e))
+
+    def scrape(self) -> dict:
+        """``{"threads": {label: s}, "native": s, "process": s,
+        "native_comm": {comm: s}}`` as of now."""
+        python_tids, live = set(), []
+        for t in threading.enumerate():
+            if isinstance(t, threading._DummyThread):
+                # A native thread that once called into Python: it may
+                # be long gone, and it is `native` anyway.
+                continue
+            python_tids.add(t.native_id)
+            stat = _task_stat(t.native_id)
+            # Alive after the read, so the file read was this thread's
+            # (a thread id is handed out again once its thread is gone).
+            if stat is not None and t.is_alive():
+                live.append((t, stat[1] * self._tick_s))
+        with self._lock:
+            for t, now in live:
+                seen = self._seen.get(t, 0.0)
+                if t not in self._ended and now > seen:
+                    self._credit(t.name, now - seen)
+                    self._seen[t] = now
+            process = time.process_time()
+            self._native = max(self._native,
+                               process - sum(self._total.values()))
+            out = {"threads": dict(sorted(self._total.items())),
+                   "native": self._native, "process": process}
+        with self._proc_lock:
+            self._scrape_native(python_tids)
+            out["native_comm"] = dict(sorted(self._comm_total.items()))
+        return out
+
+    def _scrape_native(self, python_tids: set) -> None:  # palint: holds=_proc_lock
+        """What `native` is made of, by the kernel's name for each
+        thread that is not Python's (information only; digits stripped
+        from the name, at most ``_MAX_COMMS`` names and the rest
+        ``other``), read at most once in ``_NATIVE_EVERY_S``."""
+        now = _clock()
+        if self._proc_at is not None \
+                and now - self._proc_at < self._NATIVE_EVERY_S:
+            return
+        self._proc_at = now
+        try:
+            tids = set(map(int, os.listdir("/proc/self/task")))
+        except (OSError, ValueError):
+            return
+        for tid in tids - python_tids:
+            stat = _task_stat(tid)
+            if stat is None:
+                continue
+            comm, ticks = stat
+            before = self._tid_ticks.get(tid, 0)
+            self._tid_ticks[tid] = ticks
+            if ticks <= before:
+                continue
+            label = _COMM_DIGITS.sub("", comm.decode("ascii", "replace")) \
+                or "thread"
+            if label not in self._comm_total \
+                    and len(self._comm_total) >= self._MAX_COMMS:
+                label = "other"
+            self._comm_total[label] = self._comm_total.get(label, 0.0) \
+                + (ticks - before) * self._tick_s
+        for tid in set(self._tid_ticks) - tids:
+            del self._tid_ticks[tid]
+
+
+THREAD_CPU = ThreadCpu()
+
+
+def thread_ended() -> None:
+    """The last act of a short-lived thread's target (``bounded_call``'s
+    threads, an HTTP request's): :meth:`ThreadCpu.ended` on the
+    process's accounting."""
+    THREAD_CPU.ended()
+
+
 # -- deep components: children of whatever span is open on this thread --------
 
 
@@ -891,7 +1141,8 @@ def current() -> _SpanCtx | None:
     return stack[-1] if stack else None
 
 
-def child(stage: str, histogram: bool = False) -> _SpanCtx:
+def child(stage: str, histogram: bool = False,
+          usage: bool = False) -> _SpanCtx:
     """A context manager that times ``stage`` as a child of the
     innermost span open on the calling thread (the aggregator's hash,
     pack, dispatch, fetch and unpack inside the profiler's ``close``).
@@ -900,11 +1151,13 @@ def child(stage: str, histogram: bool = False) -> _SpanCtx:
     is read once; with nothing open (library use, a disabled recorder)
     it records nowhere and costs one attribute read more than the
     clock. Children are wide-event only unless ``histogram`` is set:
-    no ``/metrics`` series, no slow-window budget."""
+    no ``/metrics`` series, no slow-window budget. ``usage`` as
+    :meth:`WindowTrace.span` takes it."""
     stack = getattr(_tls, "stack", None)
     if not stack:
         return _SpanCtx(NULL_TRACE, stage)
-    return _SpanCtx(stack[-1]._trace, stage, histogram, merge=True)
+    return _SpanCtx(stack[-1]._trace, stage, histogram, merge=True,
+                    usage=usage)
 
 
 def note(stage: str, duration_s: float, start_s: float | None = None,
@@ -948,21 +1201,48 @@ class adopt:
     streaming feeder each drain's feed, and the aggregator's children
     belong under the profiler's ``close`` and the feeder's
     ``stream_feed``. The caller of an abandonable call wraps its thunk
-    in this (utils/bounded.py knows no tracer)."""
+    in this (utils/bounded.py knows no tracer).
 
-    __slots__ = ("_ctx",)
+    Where the adopted span reads its thread's CPU (``usage=True``), so
+    does this thread at both ends of the block, and the difference is
+    added to the adopted span and to every span open around it:
+    ``stream_feed`` and the ``drain`` it lies in hold their
+    ``stream-feed`` threads' CPU. A block that outlives its span adds
+    to nothing."""
+
+    __slots__ = ("_ctx", "_u0")
 
     def __init__(self, ctx: _SpanCtx | None):
         self._ctx = ctx
+        self._u0 = None
 
     def __enter__(self):
-        if self._ctx is not None:
-            _stack().append(self._ctx)
+        ctx = self._ctx
+        if ctx is not None:
+            _stack().append(ctx)
+            try:
+                if ctx._u0 is not None \
+                        and ctx._tid != threading.get_ident():
+                    self._u0 = time.thread_time()
+            except Exception as e:  # noqa: BLE001 - tracing is fail-open
+                ctx._trace._rec._record_error(e)
         return self
 
     def __exit__(self, et, ev, tb):
-        if self._ctx is not None:
+        ctx = self._ctx
+        if ctx is not None:
+            if self._u0 is not None:
+                try:
+                    cpu_s = time.thread_time() - self._u0
+                    with _adopt_lock:
+                        up = ctx
+                        while up is not None:
+                            up._adopted += cpu_s
+                            up._threads += 1
+                            up = up._up
+                except Exception as e:  # noqa: BLE001 - fail-open
+                    ctx._trace._rec._record_error(e)
             stack = _stack()
-            if self._ctx in stack:
-                stack.remove(self._ctx)
+            if ctx in stack:
+                stack.remove(ctx)
         return False

@@ -14,6 +14,8 @@ from __future__ import annotations
 
 import threading
 
+from parca_agent_tpu.runtime.trace import thread_ended
+
 
 def bounded_call(thunk, timeout_s: float, thread_name: str = "bounded-call"):
     """Run ``thunk`` on an abandonable daemon thread, bounded by
@@ -45,6 +47,10 @@ def bounded_call(thunk, timeout_s: float, thread_name: str = "bounded-call"):
             box["err"] = e
         finally:
             done.set()
+            # After the event, so the caller never waits for it: this
+            # thread is gone when anyone looks, so it credits its own CPU
+            # (parca_agent_thread_cpu_seconds_total) as its last act.
+            thread_ended()
 
     threading.Thread(target=call, name=thread_name, daemon=True).start()
     if done.wait(timeout_s):

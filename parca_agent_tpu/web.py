@@ -20,6 +20,8 @@ import time
 import urllib.parse
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
+from parca_agent_tpu.runtime import trace as trace_mod
+
 
 # -- shared query-parameter validation ----------------------------------------
 # /query, /hotspots, and /diff grew the same hygiene in parallel across
@@ -245,6 +247,10 @@ def render_metrics(profilers, batch_client=None, extra: dict | None = None,
              p.metrics.device_abandoned_ok_total, lab)
         emit("parca_agent_profiler_device_abandoned_err_total",
              p.metrics.device_abandoned_err_total, lab)
+        # How late the loop came back from its waits between windows,
+        # summed (a window's own is loop_overshoot_s on its meta).
+        emit("parca_agent_profiler_loop_overshoot_seconds_total",
+             round(p.metrics.loop_overshoot_seconds_total, 6), lab)
         # The ship's gzip (docs/perf.md "the spliced gzip member"): a
         # steady window reuses one static piece a profile and deflates
         # only what changed; `built` rises when registries grow, and
@@ -505,6 +511,22 @@ def render_metrics(profilers, batch_client=None, extra: dict | None = None,
         for k, v in recorder.stats.items():
             name = f"parca_agent_trace_{k}"
             emit(name if name.endswith("_total") else name + "_total", v)
+        # CPU accounting (docs/observability.md "where the CPU goes"):
+        # what each stage's threads used, summed as windows complete,
+        # and every thread's CPU as of this scrape, which pays for it.
+        for stage, cpu_s in recorder.export_stage_cpu().items():
+            emit("parca_agent_stage_cpu_seconds_total",
+                 round(cpu_s, 6), {"stage": stage})
+        cpu = trace_mod.THREAD_CPU.scrape()
+        for thread, v in {**cpu["threads"],
+                          "native": cpu["native"]}.items():
+            emit("parca_agent_thread_cpu_seconds_total", round(v, 6),
+                 {"thread": thread})
+        emit("parca_agent_process_cpu_seconds_total",
+             round(cpu["process"], 6))
+        for comm, v in cpu["native_comm"].items():
+            emit("parca_agent_native_thread_cpu_seconds_total",
+                 round(v, 2), {"comm": comm})
     if device_telemetry is not None:
         # The DEVICE flight recorder (docs/observability.md "device
         # flight recorder"): latched backend identity as an info-style
@@ -547,9 +569,6 @@ def render_metrics(profilers, batch_client=None, extra: dict | None = None,
                      {"kernel": kernel, "backend": backend})
             emit("parca_agent_kernel_fallback", int(rec["fallback"]),
                  {"kernel": kernel})
-            if rec["interpret"] is not None:
-                emit("parca_agent_kernel_interpret",
-                     int(rec["interpret"]), {"kernel": kernel})
         for kernel, direction, nbytes, ops in device_telemetry.transfers():
             lab = {"kernel": kernel, "direction": direction}
             emit("parca_agent_transfer_bytes_total", nbytes, lab)
@@ -729,6 +748,20 @@ def render_metrics(profilers, batch_client=None, extra: dict | None = None,
         name, brace, rest = k.partition("{")
         buf.emit(name, v, labels=("{" + rest) if brace else None)
     return buf.render()
+
+
+class _Server(ThreadingHTTPServer):
+    """A thread a request, as the base class has it; each goes by the
+    server thread's name and credits its CPU as it ends
+    (``parca_agent_thread_cpu_seconds_total{thread="http"}``), since no
+    scrape ever meets the thread of the request before it."""
+
+    def process_request_thread(self, request, client_address):
+        threading.current_thread().name = "http"
+        try:
+            super().process_request_thread(request, client_address)
+        finally:
+            trace_mod.thread_ended()
 
 
 class AgentHTTPServer:
@@ -1165,7 +1198,7 @@ class AgentHTTPServer:
         self.version = version
         self.extra_metrics = extra_metrics
         self.capture_info = capture_info
-        self._httpd = ThreadingHTTPServer((host, port), Handler)
+        self._httpd = _Server((host, port), Handler)
         self.port = self._httpd.server_address[1]
         self._thread: threading.Thread | None = None
 

@@ -28,7 +28,7 @@ SAMPLES = 5_000_000
 
 
 def _metrics(platform="tpu", kind="TPU v5 lite", kernel_fallback=0,
-             interpret=None, h2d=67108864, d2h=5366872, errors=0,
+             h2d=67108864, d2h=5366872, errors=0,
              streamed=None):
     lines = [
         "# TYPE parca_agent_device_info gauge",
@@ -51,10 +51,6 @@ def _metrics(platform="tpu", kind="TPU v5 lite", kernel_fallback=0,
         "parca_agent_xla_cache_hits_total 7",
         "parca_agent_xla_backend_compile_seconds_total 0.25",
     ]
-    if interpret is not None:
-        lines.append(
-            f'parca_agent_kernel_interpret{{kernel="feed_probe"}} '
-            f'{interpret}')
     if streamed is not None:
         lines += [f"parca_agent_streaming_windows_streamed_total {streamed}",
                   "parca_agent_streaming_disabled 0",
@@ -113,8 +109,6 @@ def test_clean_scrape_passes():
      "device state 'degraded'"),
     ("a kernel fell back", {"metrics": _metrics(kernel_fallback=1)},
      "kernel feed_probe fell back"),
-    ("a kernel ran interpreted", {"metrics": _metrics(interpret=1)},
-     "ran interpreted"),
     ("nothing went up", {"metrics": _metrics(h2d=0)}, "no h2d transfer"),
     ("nothing came back", {"metrics": _metrics(d2h=0)}, "no d2h transfer"),
     ("an iteration error", {"metrics": _metrics(errors=1)},

@@ -155,11 +155,11 @@ def test_transfer_accounting_by_kernel_and_direction():
 def test_note_backend_fields_are_sticky():
     t = DeviceTelemetry()
     t.note_backend("device", requested="device", resolved="device",
-                   interpret=False, fallback=False)
+                   fallback=False)
     t.note_backend("device", resolved="cpu_fallback", fallback=True)
     b = t.backends()["device"]
     assert b == {"requested": "device", "resolved": "cpu_fallback",
-                 "interpret": False, "fallback": True}
+                 "fallback": True}
 
 
 def test_identity_latches_once_and_names_the_backend():
@@ -173,7 +173,6 @@ def test_identity_latches_once_and_names_the_backend():
     assert a["jax_version"] != "unknown"
     assert a["jaxlib_version"] not in ("unknown", "none")
     assert a["device_count"] >= 1
-    assert a["interpret_default"] is True
     assert a["hostname"]
     t.set_identity({"platform": "other"})  # first write wins
     assert t.ensure_identity() == a
@@ -330,8 +329,7 @@ def test_render_metrics_kernel_transfer_and_budget_families():
     t = DeviceTelemetry(period_s=1.0)
     t.record("feed_probe", 0.2, shape=(4096,), h2d_bytes=1024)
     t.record("feed_probe", 0.001, shape=(4096,))
-    t.note_backend("device", resolved="cpu_fallback", interpret=True,
-                   fallback=True)
+    t.note_backend("device", resolved="cpu_fallback", fallback=True)
     t.tick_window(0.5)
     t.tick_window(1.5)
     m = render_metrics([], device_telemetry=t)
@@ -348,7 +346,7 @@ def test_render_metrics_kernel_transfer_and_budget_families():
         'backend="device"} 0' in m
     assert m.count('parca_agent_kernel_backend{') == 2
     assert 'parca_agent_kernel_fallback{kernel="device"} 1' in m
-    assert 'parca_agent_kernel_interpret{kernel="device"} 1' in m
+    assert "parca_agent_kernel_interpret" not in m
     assert 'parca_agent_transfer_bytes_total{kernel="feed_probe",' \
         'direction="h2d"} 1024' in m
     assert "parca_agent_window_budget_windows_total 2" in m

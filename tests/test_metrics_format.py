@@ -309,3 +309,180 @@ def test_debug_windows_503_without_recorder():
         assert ei.value.code == 503
     finally:
         srv.stop()
+
+
+# -- CPU accounting families (ISSUE 39) ---------------------------------------
+
+
+def _burn(cpu_s: float) -> None:
+    import time
+
+    t0 = time.thread_time()
+    while time.thread_time() - t0 < cpu_s:
+        pass
+
+
+def _thread_cpu(text: str) -> dict:
+    fams = parse_prometheus_text(text)
+    return {lab["thread"]: v for _, lab, v in
+            fams["parca_agent_thread_cpu_seconds_total"]["samples"]}
+
+
+def test_cpu_accounting_families_are_strict_counters():
+    rec = FlightRecorder()
+    tr = rec.begin()
+    with tr.span("drain", usage=True):
+        _burn(0.01)
+    with tr.span("close"):
+        _burn(0.001)
+    tr.add_span("ship", 0.002, used={"cpu_s": 0.0015})
+    tr.complete()
+    fams = parse_prometheus_text(render_metrics([], recorder=rec))
+    assert fams["parca_agent_stage_cpu_seconds_total"]["type"] == "counter"
+    cpu = {lab["stage"]: v for _, lab, v in
+           fams["parca_agent_stage_cpu_seconds_total"]["samples"]}
+    # A series for each stage that asked for its threads' CPU.
+    assert set(cpu) == {"drain", "ship"}
+    assert cpu["ship"] == 0.0015 and cpu["drain"] >= 0.01
+    threads = {lab["thread"] for _, lab, _ in
+               fams["parca_agent_thread_cpu_seconds_total"]["samples"]}
+    assert {"MainThread", "native"} <= threads
+    assert fams["parca_agent_process_cpu_seconds_total"]["type"] == "counter"
+    # Without a recorder (--no-window-trace) none of it is served.
+    assert "parca_agent_thread_cpu" not in render_metrics([])
+
+
+def test_every_threads_cpu_is_a_monotone_counter():
+    """A bounded_call thread that has ended is credited under its name;
+    a live thread is credited by the scrape, once; over three scrapes
+    with threads starting and ending between them every label only
+    rises, and ``native`` (the remainder) is never negative. A live
+    thread's CPU is the kernel's count of it in clock ticks."""
+    import threading
+
+    from parca_agent_tpu.utils.bounded import bounded_call
+
+    rec = FlightRecorder()
+    stop = threading.Event()
+
+    def live():
+        while not stop.wait(0.001):
+            _burn(0.002)
+
+    worker = threading.Thread(target=live, name="cpu-test-live_1")
+    worker.start()
+    try:
+        before = _thread_cpu(render_metrics([], recorder=rec))
+        assert bounded_call(lambda: _burn(0.03), 10,
+                            "cpu-test-device")[0] == "ok"
+        first = _thread_cpu(render_metrics([], recorder=rec))
+        again = _thread_cpu(render_metrics([], recorder=rec))
+        assert bounded_call(lambda: _burn(0.02), 10,
+                            "cpu-test-device")[0] == "ok"
+        _burn(0.03)
+        third = _thread_cpu(render_metrics([], recorder=rec))
+    finally:
+        stop.set()
+        worker.join(10)
+    # The thread is gone when the scrape looks, and its CPU is there.
+    assert "cpu-test-device" not in {t.name for t in threading.enumerate()}
+    assert first["cpu-test-device"] - before.get("cpu-test-device", 0) \
+        == pytest.approx(0.03, abs=0.01)
+    assert third["cpu-test-device"] - first["cpu-test-device"] \
+        == pytest.approx(0.02, abs=0.01)
+    # A live thread (trailing number off its name) is not counted twice:
+    # two scrapes back to back differ by what it burnt between them.
+    assert again.get("cpu-test-live", 0) - first.get("cpu-test-live", 0) \
+        < 0.05
+    assert third["cpu-test-live"] > before.get("cpu-test-live", 0)
+    for a, b in ((before, first), (first, again), (again, third)):
+        for label, v in a.items():
+            assert b[label] >= v, label
+        assert b["native"] >= 0
+    assert third["MainThread"] - again["MainThread"] >= 0.01
+
+
+def test_the_thread_counters_add_up_to_the_process():
+    import time
+
+    from parca_agent_tpu.runtime.trace import ThreadCpu
+
+    acct = ThreadCpu()
+    _burn(0.02)
+    got = acct.scrape()
+    assert got["native"] >= 0
+    assert sum(got["threads"].values()) + got["native"] \
+        == pytest.approx(got["process"], abs=2e-3)
+    assert got["process"] <= time.process_time()
+
+
+def test_what_native_is_made_of_comes_from_proc_by_comm():
+    from parca_agent_tpu.runtime.trace import ThreadCpu
+
+    acct = ThreadCpu()
+    _burn(0.06)  # several clock ticks
+    # Told that no thread is Python's, it files this one under its comm
+    # (digits stripped), at tick resolution.
+    with acct._proc_lock:
+        acct._scrape_native(set())
+        first = dict(acct._comm_total)
+        acct._scrape_native(set())  # inside the rate limit: not read again
+        assert acct._comm_total == first
+    assert len(first) <= ThreadCpu._MAX_COMMS + 1
+    assert all(not any(c.isdigit() for c in comm) for comm in first)
+    assert sum(first.values()) >= 0.04
+    # And with the Python threads named, a process with no native
+    # thread at work serves next to nothing.
+    got = ThreadCpu().scrape()["native_comm"]
+    assert sum(got.values()) <= sum(first.values())
+
+
+def test_a_requests_thread_is_credited_as_http():
+    rec = FlightRecorder()
+    srv = AgentHTTPServer(port=0, profilers=[], recorder=rec)
+    srv.start()
+    try:
+        base = f"http://127.0.0.1:{srv.port}"
+        seen = []
+        for _ in range(3):
+            with urllib.request.urlopen(f"{base}/metrics", timeout=10) as r:
+                seen.append(_thread_cpu(r.read().decode()))
+    finally:
+        srv.stop()
+    # Each request ran on a thread of its own that credited its CPU as
+    # it ended; the label is the server thread's, and it only rises.
+    assert seen[0].get("http", 0) <= seen[1]["http"] <= seen[2]["http"]
+    assert seen[2]["http"] > 0
+    assert not any(label.startswith("Thread") for label in seen[-1])
+
+
+def test_a_scrape_asks_no_thread_for_its_clock(monkeypatch):
+    """``pthread_getcpuclockid`` of a thread that has ended is undefined
+    behaviour, and any thread may end under a scrape: a live thread's
+    CPU is read from ``/proc/self/task/<tid>/stat``, a thread that is
+    gone has no file there, and a file read for a thread that ended
+    meanwhile (its id may be another's by then) is thrown away."""
+    import threading
+    import time
+
+    from parca_agent_tpu.runtime import trace as trace_mod
+
+    def forbidden(*_a):
+        raise AssertionError("asked a thread for its clock id")
+
+    monkeypatch.setattr(time, "pthread_getcpuclockid", forbidden)
+    gone = threading.Thread(target=lambda: _burn(0.03), name="cpu-test-gone")
+    gone.start()
+    gone.join(10)
+    deadline = time.monotonic() + 10  # the kernel's thread outlives join()
+    while trace_mod._task_stat(gone.native_id) and time.monotonic() < deadline:
+        time.sleep(0.001)
+    assert trace_mod._task_stat(gone.native_id) is None
+    assert trace_mod._task_stat(threading.get_native_id())[1] >= 0
+    listed = threading.enumerate() + [gone]
+    monkeypatch.setattr(threading, "enumerate", lambda: listed)
+    acct = trace_mod.ThreadCpu()
+    assert "cpu-test-gone" not in acct.scrape()["threads"]
+    monkeypatch.setattr(trace_mod, "_task_stat", lambda tid: (b"x", 700))
+    got = acct.scrape()["threads"]
+    assert "cpu-test-gone" not in got and got["MainThread"] >= 7.0

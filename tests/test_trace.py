@@ -994,3 +994,307 @@ def test_gzip_counts_on_the_window_meta_and_on_metrics(pipelined):
              if ln.startswith("parca_agent_ship_static_cache_bytes")]
     assert len(cache) == 1
     assert (float(cache[0].split()[-1]) > 0) == pipelined
+
+
+# -- CPU accounting inside the program (ISSUE 39) -----------------------------
+
+
+def _burn(cpu_s: float) -> None:
+    t0 = time.thread_time()
+    while time.thread_time() - t0 < cpu_s:
+        pass
+
+
+def _spans_of(rec: FlightRecorder, seq: int = -1) -> dict:
+    return {s["stage"]: s for s in rec.traces()[seq]["spans"]}
+
+
+class _CountedClock:
+    """The ``time`` module as runtime/trace.py sees it, counting (or
+    failing) its reads of the thread's CPU clock."""
+
+    def __init__(self, fail: bool = False):
+        self.reads, self.fail = 0, fail
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+    def thread_time(self):
+        self.reads += 1
+        if self.fail:
+            raise OSError("no thread clock here")
+        return time.thread_time()
+
+
+@pytest.mark.parametrize("work,cpu_share", [
+    (lambda: _burn(0.05), (0.7, 1.1)),
+    (lambda: time.sleep(0.05), (0.0, 0.1)),
+], ids=["burns", "sleeps"])
+def test_a_span_that_asks_records_its_threads_cpu_beside_its_wall(
+        work, cpu_share):
+    """A span that works has CPU near its wall; one that waits has wall
+    and next to no CPU."""
+    rec = FlightRecorder()
+    tr = rec.begin()
+    with tr.span("drain", usage=True):
+        work()
+    tr.complete()
+    drain = _spans_of(rec)["drain"]
+    lo, hi = cpu_share
+    assert lo * drain["duration_s"] <= drain.get("cpu_s", 0.0) \
+        <= hi * drain["duration_s"]
+    assert "cpu_s" not in _spans_of(rec)["total"]
+
+
+def test_a_span_that_does_not_ask_reads_no_clock(monkeypatch):
+    """A reading is a system call (dear on a sandboxed kernel), so the
+    stages between a window's last sample and its pprof bytes take
+    none: the call site decides, and this module names no stage."""
+    import threading
+
+    clock = _CountedClock()
+    monkeypatch.setattr(trace_mod, "time", clock)
+    rec = FlightRecorder()
+    tr = rec.begin()
+    with tr.span("close"):
+        with trace_mod.child("feed_hash"):
+            pass
+        t = threading.Thread(target=lambda ctx: trace_mod.adopt(ctx)
+                             .__enter__().__exit__(None, None, None),
+                             args=(trace_mod.current(),))
+        t.start()
+        t.join(10)
+    tr.add_span("encode", 0.002)
+    assert clock.reads == 0
+    with tr.span("ship", usage=True):
+        pass
+    assert clock.reads == 2
+    tr.complete()
+    spans = _spans_of(rec)
+    for stage in ("close", "feed_hash", "encode"):
+        assert not {"cpu_s", "threads"} & set(spans[stage]), stage
+        assert spans[stage]["duration_s"] >= 0
+    assert set(rec.export_stage_cpu()) <= {"ship"}
+
+
+def test_usage_fields_are_left_out_when_zero():
+    rec = FlightRecorder()
+    tr = rec.begin()
+    with tr.span("drain", usage=True):
+        time.sleep(0.001)
+    tr.add_span("ship", 0.002, used={"cpu_s": 0.0015})
+    tr.complete()
+    spans = _spans_of(rec)
+    assert "threads" not in spans["drain"] and "threads" not in spans["ship"]
+    assert spans["drain"].get("cpu_s", 1.0) > 0  # there, or left out
+    assert spans["ship"]["cpu_s"] == 0.0015
+
+
+def test_a_merged_span_sums_what_its_intervals_used():
+    rec = FlightRecorder()
+    tr = rec.begin()
+    with tr.span("drain", usage=True):
+        for _ in range(3):
+            with trace_mod.child("stream_feed", usage=True):
+                _burn(0.01)
+    tr.complete()
+    spans = _spans_of(rec)
+    assert spans["stream_feed"]["n"] == 3
+    assert 0.03 <= spans["stream_feed"]["cpu_s"] <= spans["drain"]["cpu_s"]
+    assert rec.export_stage_cpu()["stream_feed"] \
+        == pytest.approx(spans["stream_feed"]["cpu_s"])
+
+
+def test_a_spans_cpu_is_that_of_every_thread_that_worked_under_it():
+    """The feeder's thread adopts the capture thread's ``stream_feed``:
+    that span and the ``drain`` around it hold the thread's CPU beside
+    the capture thread's wait, and what the thread records under it
+    without asking has none of its own."""
+    from parca_agent_tpu.utils.bounded import bounded_call
+
+    rec = FlightRecorder()
+    tr = rec.begin()
+    with tr.span("drain", usage=True):
+        for _ in range(2):
+            with trace_mod.child("stream_feed", usage=True):
+                open_span = trace_mod.current()
+
+                def feed():
+                    with trace_mod.adopt(open_span):
+                        with trace_mod.child("feed_carry"):
+                            _burn(0.02)
+
+                assert bounded_call(feed, 10, "stream-feed")[0] == "ok"
+    tr.complete()
+    spans = _spans_of(rec)
+    drain, feed = spans["drain"], spans["stream_feed"]
+    assert spans["feed_carry"]["thread"] == "stream-feed"
+    assert "cpu_s" not in spans["feed_carry"]
+    assert feed["threads"] == 2 and drain["threads"] == 2
+    # The capture thread only waited: what they hold is the others'.
+    assert 0.04 <= feed["cpu_s"] <= drain["cpu_s"] < 0.5
+    assert rec.export_stage_cpu()["drain"] == pytest.approx(drain["cpu_s"])
+
+
+def test_adopting_a_span_on_its_own_thread_counts_nothing_twice():
+    rec = FlightRecorder()
+    tr = rec.begin()
+    with tr.span("ship", usage=True):
+        with trace_mod.adopt(trace_mod.current()):
+            _burn(0.02)
+    tr.complete()
+    ship = _spans_of(rec)["ship"]
+    assert "threads" not in ship
+    assert 0.02 <= ship["cpu_s"] <= 1.5 * ship["duration_s"]
+
+
+def test_a_span_left_on_another_thread_records_no_usage():
+    import threading
+
+    rec = FlightRecorder()
+    tr = rec.begin()
+    sp = tr.span("ship", usage=True)
+    sp.__enter__()
+    _burn(0.01)
+    t = threading.Thread(target=sp.__exit__, args=(None, None, None))
+    t.start()
+    t.join(10)
+    assert not t.is_alive()
+    tr.complete()
+    ship = _spans_of(rec)["ship"]
+    assert ship["duration_s"] >= 0.01
+    assert "cpu_s" not in ship
+
+
+def test_a_failing_usage_read_loses_the_reading_and_never_the_span(
+        monkeypatch):
+    rec = FlightRecorder()
+    tr = rec.begin()
+    monkeypatch.setattr(trace_mod, "time", _CountedClock(fail=True))
+    with tr.span("drain", usage=True):
+        _burn(0.005)
+    with NULL_TRACE.span("ship", usage=True) as null_span:  # reads nothing
+        pass
+    assert trace_mod.time.reads == 1 and null_span.duration_s >= 0
+    tr.complete()
+    spans = _spans_of(rec)
+    assert "cpu_s" not in spans["drain"]
+    assert spans["drain"]["duration_s"] >= 0.005
+    assert rec.stats["record_errors"] >= 1
+
+
+@pytest.mark.chaos
+def test_trace_record_chaos_costs_spans_and_never_the_window():
+    """With every recorder entry point failing, the windows still ship
+    and the per-stage CPU totals simply hold nothing."""
+    faults.install(faults.FaultInjector.from_spec("trace.record:error"))
+    rec = FlightRecorder()
+    snaps = [_snap(seed=5) for _ in range(2)]
+    from parca_agent_tpu.agent.writer import RemoteProfileWriter
+
+    sink = RawSink()
+    prof = CPUProfiler(
+        source=ListSource(snaps),
+        aggregator=DictAggregator(capacity=1 << 12),
+        fallback_aggregator=CPUAggregator(),
+        profile_writer=RemoteProfileWriter(sink), duration_s=0.0,
+        fast_encode=True, encode_pipeline=True, trace_recorder=rec)
+    for _ in snaps:
+        assert prof.run_iteration()
+        assert prof.last_error is None
+        assert prof._pipeline.flush(30.0)
+    assert prof._pipeline.close(30.0)
+    faults.install(None)
+    assert sink.n == 12  # two windows of six profiles, all shipped
+    assert rec.stats["record_errors"] > 0
+    assert rec.export_stage_cpu() == {}
+
+
+@pytest.mark.parametrize("pipelined", [True, False],
+                         ids=["pipelined", "inline"])
+def test_only_the_stages_off_the_windows_path_carry_cpu(pipelined):
+    """drain and ship ask for their threads' CPU; nothing from the end
+    of drain to the end of encode does, child or stage."""
+    rec, traces = _traced_windows(pipelined)
+    totals = rec.export_stage_cpu()
+    summed = {}
+    for t in traces:
+        for s in t["spans"]:
+            if "cpu_s" in s:
+                summed[s["stage"]] = summed.get(s["stage"], 0.0) + s["cpu_s"]
+            assert "threads" not in s, s["stage"]  # close asks for none
+    assert set(summed) <= {"drain", "ship"} and summed["ship"] > 0
+    assert totals == pytest.approx(summed, abs=1e-5)
+
+
+def test_a_streamed_windows_feed_threads_cpu_is_under_stream_feed():
+    from span_scenarios import (
+        run_windows,
+        streamed_profiler,
+        turnover_windows,
+    )
+
+    rec = FlightRecorder()
+    snaps, _raw = turnover_windows(2, turnover=0.25)
+    prof, _feeder, _agg, sink = streamed_profiler(snaps, recorder=rec)
+    run_windows(prof, sink, 2)
+    for t in rec.traces():
+        by_stage = {s["stage"]: s for s in t["spans"]}
+        assert t["meta"]["streamed"] == 1
+        feed, drain = by_stage["stream_feed"], by_stage["drain"]
+        # Ten drains, each fed on a `stream-feed` thread of its own.
+        assert feed["threads"] == drain["threads"] == feed["n"] == 10
+        assert 0 < feed["cpu_s"] <= drain["cpu_s"]
+        assert {s["stage"] for s in t["spans"] if "cpu_s" in s} \
+            <= {"drain", "stream_feed", "ship"}
+
+
+def test_a_late_iteration_shows_on_the_next_windows_meta():
+    import threading
+
+    rec = FlightRecorder()
+    prof = CPUProfiler(
+        source=ListSource([_snap(seed=3) for _ in range(3)]),
+        aggregator=CPUAggregator(), profile_writer=Collect(),
+        duration_s=0.01, trace_recorder=rec)
+    asked = []
+
+    def oversleep(wait_s):
+        asked.append(wait_s)
+        time.sleep(wait_s + 0.05)
+        return False
+
+    prof._stop.wait = oversleep
+    t = threading.Thread(target=prof.run)
+    t.start()
+    t.join(30)
+    assert not t.is_alive() and prof.crashed is None
+    metas = [tr["meta"] for tr in rec.traces()]
+    assert len(metas) == 3
+    assert "loop_overshoot_s" not in metas[0]  # nothing waited before it
+    for m, wait_s in zip(metas[1:], asked):
+        assert m["loop_wait_s"] == pytest.approx(wait_s, abs=1e-5)
+        assert 0.05 <= m["loop_overshoot_s"] < 5.0
+    # The window the source ended on was waited for too, and discarded.
+    assert prof.metrics.loop_overshoot_seconds_total >= sum(
+        m["loop_overshoot_s"] for m in metas[1:])
+    from parca_agent_tpu.web import render_metrics
+
+    line = next(ln for ln in render_metrics([prof]).splitlines() if ln.startswith(
+        "parca_agent_profiler_loop_overshoot_seconds_total"))
+    assert float(line.split()[-1]) >= 0.1
+
+
+@pytest.mark.parametrize("name,label", [
+    ("actor-profiler", "actor-profiler"),
+    ("aggregate-device", "aggregate-device"),
+    ("row-hash_3", "row-hash"),
+    ("ThreadPoolExecutor-0_12", "ThreadPoolExecutor"),
+    ("Thread-7 (channel_spin)", "channel_spin"),
+    ("Thread-12", "Thread"),
+    ("discovery-kubernetes", "discovery-kubernetes"),
+    ("worker 4", "worker"),
+    ("17", "thread"),
+])
+def test_a_threads_label_is_its_name_less_a_trailing_number(name, label):
+    assert trace_mod.thread_label(name) == label
