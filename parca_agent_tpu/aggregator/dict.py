@@ -101,6 +101,103 @@ def prefix_sum(x):
     return (inner + (prefix_sum(totals) - totals)[:, None]).reshape(n)
 
 
+# The probe's narrow stages, for a feed of at least _PROBE_NARROW_MIN
+# lanes: (divisor of n_pad, steps probed at that width). Step 0 gathers a
+# dictionary row for every lane; a gather costs per lane, not per byte
+# (docs/perf.md "The probe"), and at the load the capacity guard admits
+# most lanes resolve at once, so the lanes that still search are
+# compacted into a buffer a quarter as wide for steps 1-3 and one a
+# sixty-fourth as wide for the twelve left. Constants of n_pad: what the
+# data decides is how many steps run and how many rounds a stage takes.
+_PROBE_STAGES = ((4, 3), (64, _PROBES - 4))
+_PROBE_NARROW_MIN = 1 << 15
+
+
+def make_probe(cap: int, n: int):
+    """Pure (unjitted) batched linear-probe lookup: for each of ``n``
+    lanes the id of the first slot among ``h1 .. h1 + _PROBES - 1`` (mod
+    ``cap``) that holds its ``(h1, h2, h3)``, the search ending at the
+    first empty slot; -1 where there is none, and for lanes not
+    ``live``. ``probe(table, keys, live)`` takes the lanes' keys as rows
+    (``[n, >= 3]``: h1, h2, h3) and returns ``(found_id, gathers)``,
+    ``gathers`` the dictionary rows it gathered (a gather's width, summed
+    over the gathers run; 16 * n when every step runs at full width).
+
+    A gather is issued only for buffers that hold a lane still searching:
+    every loop ends when none is left, and from ``_PROBE_NARROW_MIN``
+    lanes up the lanes left after step 0 are compacted, in lane order,
+    into the narrower buffers of ``_PROBE_STAGES``. A stage that is
+    handed more lanes than its buffer holds takes them in further
+    rounds: no lane is dropped at any load."""
+    import jax
+    import jax.numpy as jnp
+
+    mask = jnp.uint32(cap - 1)
+    plan = _PROBE_STAGES if n >= _PROBE_NARROW_MIN else ()
+
+    def steps(table, keys, k0, k1, found, active, gathers):
+        """Steps k0 .. k1 - 1 over the whole buffer, while a lane of it
+        searches."""
+        h1, h2, h3 = keys[:, 0], keys[:, 1], keys[:, 2]
+
+        def step(state):
+            k, found, active, gathers = state
+            row = table[((h1 + k) & mask).astype(jnp.int32)]
+            occ = row[:, 3] > 0
+            hit = occ & (row[:, 0] == h1) & (row[:, 1] == h2) \
+                & (row[:, 2] == h3)
+            found = jnp.where(active & hit, row[:, 3].astype(jnp.int32) - 1,
+                              found)
+            return k + 1, found, active & occ & ~hit, gathers + h1.shape[0]
+
+        _, found, active, gathers = jax.lax.while_loop(
+            lambda s: (s[0] < k1) & s[2].any(), step,
+            (jnp.uint32(k0), found, active, gathers))
+        return found, active, gathers
+
+    def narrow(table, keys, k0, stages, found, active, gathers):
+        """The ``active`` lanes of a buffer, probed from step k0 on in
+        buffers of the stages' widths; their ids land in ``found``."""
+        (div, n_steps), rest = stages[0], stages[1:]
+        n_w, w = keys.shape[0], n // div
+        ones = active.astype(jnp.int32)
+        pos = prefix_sum(ones) - 1
+        total = ones.sum()
+
+        def one_round(state):
+            r, found, gathers = state
+            at = pos - r * w
+            lanes = jnp.full((w,), n_w, jnp.int32).at[
+                jnp.where(active & (at >= 0), at, w)].set(
+                jnp.arange(n_w, dtype=jnp.int32), mode="drop")
+            sub_keys = keys[jnp.minimum(lanes, n_w - 1)]
+            sub_found, sub_active, gathers = steps(
+                table, sub_keys, k0, k0 + n_steps,
+                jnp.full((w,), -1, jnp.int32), lanes < n_w, gathers)
+            if rest:
+                sub_found, gathers = narrow(
+                    table, sub_keys, k0 + n_steps, rest, sub_found,
+                    sub_active, gathers)
+            return r + 1, found.at[lanes].set(sub_found, mode="drop"), \
+                gathers
+
+        _, found, gathers = jax.lax.while_loop(
+            lambda s: s[0] * w < total, one_round,
+            (jnp.int32(0), found, gathers))
+        return found, gathers
+
+    def probe(table, keys, live):
+        found, active, gathers = steps(
+            table, keys, 0, 1 if plan else _PROBES,
+            jnp.full((n,), -1, jnp.int32), live, jnp.int32(0))
+        if plan:
+            found, gathers = narrow(table, keys, 1, plan, found, active,
+                                    gathers)
+        return found, gathers
+
+    return probe
+
+
 def make_feed(cap: int, id_cap: int, n_pad: int, n_blocks: int = 0,
               blk: int = 0):
     """Pure (unjitted) streaming-window accumulate: batched linear-probe
@@ -120,6 +217,8 @@ def make_feed(cap: int, id_cap: int, n_pad: int, n_blocks: int = 0,
     import jax
     import jax.numpy as jnp
 
+    probe = make_probe(cap, n_pad)
+
     def feed(table, acc, touch, packed, reset):
         # reset != 0: this is the first feed of a new window; the previous
         # window's accumulator contents (kept across close for lossless
@@ -127,35 +226,19 @@ def make_feed(cap: int, id_cap: int, n_pad: int, n_blocks: int = 0,
         acc = jnp.where(reset != 0, 0, acc)
         if n_blocks:
             touch = jnp.where(reset != 0, 0, touch)
-        h1, h2, h3 = packed[0], packed[1], packed[2]
+        h1 = packed[0]
         cnt = packed[3].astype(jnp.int32)
+        live = cnt > 0
 
         # The named scopes are what an operator reads in the profiler's
-        # trace (``probe`` where the op list says ``while.5``); the
+        # trace (``probe`` where the op list says ``while``); the
         # module's own name (``jit_feed``) is not theirs to change.
+        # Lanes with no count (padding, rows the host settled as
+        # unreachable) never search.
         with jax.named_scope("probe"):
-            mask = jnp.uint32(cap - 1)
-
-            def step(k, state):
-                found_id, done = state
-                idx = ((h1 + jnp.uint32(k)) & mask).astype(jnp.int32)
-                row = table[idx]
-                occ = row[:, 3] > 0
-                hit = occ & (row[:, 0] == h1) & (row[:, 1] == h2) \
-                    & (row[:, 2] == h3)
-                stop = hit | ~occ
-                found_id = jnp.where(hit & ~done,
-                                     row[:, 3].astype(jnp.int32) - 1,
-                                     found_id)
-                return found_id, done | stop
-
-            found_id = jnp.full(h1.shape, -1, jnp.int32)
-            done = jnp.zeros(h1.shape, bool)
-            found_id, _ = jax.lax.fori_loop(0, _PROBES, step,
-                                            (found_id, done))
+            found_id, gathers = probe(table, packed.T, live)
 
         with jax.named_scope("accumulate"):
-            live = cnt > 0
             hit = (found_id >= 0) & live
             acc = acc.at[jnp.where(hit, found_id, id_cap)].add(
                 cnt, mode="drop")
@@ -169,7 +252,9 @@ def make_feed(cap: int, id_cap: int, n_pad: int, n_blocks: int = 0,
             miss_rows = jnp.full((n_pad,), -1, jnp.int32).at[mtgt].set(
                 jnp.arange(h1.shape[0], dtype=jnp.int32), mode="drop")
             n_miss = miss.astype(jnp.int32).sum()
-        return acc, touch, n_miss, miss_rows
+        # One small buffer for the settle's one fetch: the miss count and
+        # the dictionary rows the probe gathered.
+        return acc, touch, jnp.stack([n_miss, gathers]), miss_rows
 
     return feed
 
@@ -1423,7 +1508,7 @@ class DictAggregator:
         self._touch = None
         # One clock pair for the span, timings[...] and the telemetry.
         with trace.child("feed_dispatch") as sp:
-            acc, touch, n_miss, miss_rows = prog(
+            acc, touch, counts, miss_rows = prog(
                 self._dev, acc, touch, jnp.asarray(packed),
                 jnp.uint32(reset))
         self.timings["feed_dispatch"] = sp.duration_s
@@ -1431,15 +1516,20 @@ class DictAggregator:
                     h2d_bytes=packed.nbytes)
         self._acc = acc
         self._touch = touch if self._blk else None
-        return (n_miss, miss_rows)
+        return (counts, miss_rows)
 
     # palint: sync-ok — reached only through _settle_misses (same
-    # boundary); int(n_miss) IS the documented sync point.
+    # boundary); the fetch of counts IS the documented sync point.
     def _settle_dispatch(self, handle) -> np.ndarray:
         """Sync one dispatched feed's miss outputs; returns chunk-relative
-        miss row indices (empty in steady state)."""
-        n_miss, miss_rows = handle
-        nm = int(n_miss)  # device sync point (kernel completion)
+        miss row indices (empty in steady state). The same two-word
+        fetch brings how many dictionary rows the probe gathered."""
+        counts, miss_rows = handle
+        # device sync point (kernel completion)
+        nm, gathers = np.asarray(counts).tolist()
+        trace.count(probe_gathers=gathers)
+        self.stats["probe_gathers"] = \
+            self.stats.get("probe_gathers", 0) + gathers
         if not nm:
             return np.empty(0, np.int64)
         return np.asarray(miss_rows)[:nm].astype(np.int64)

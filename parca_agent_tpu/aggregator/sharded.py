@@ -36,9 +36,9 @@ import functools
 import numpy as np
 
 from parca_agent_tpu.aggregator.dict import (
-    _PROBES,
     DictAggregator,
     make_close,
+    make_probe,
     prefix_sum,
 )
 from parca_agent_tpu.parallel.mesh import FLEET_AXIS, fleet_mesh
@@ -79,38 +79,19 @@ def _sharded_feed_program(mesh, n_shards: int, cap_s: int, id_cap: int,
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
+    probe = make_probe(cap_s, n_pad_s)
+
     def node_fn(table, acc, packed, reset):
         # table [1, cap_s, 4]; acc [1, id_cap]; packed [1, 5, n_pad_s] —
         # THIS shard's rows only (host-partitioned by home shard), rows
         # being (h1, h2, h3, count, original packed-buffer position).
         t = table[0]
         a = jnp.where(reset != 0, 0, acc[0])
-        h1, h2, h3 = packed[0, 0], packed[0, 1], packed[0, 2]
         cnt = packed[0, 3].astype(jnp.int32)
         orig = packed[0, 4].astype(jnp.int32)
         live = cnt > 0  # pad lanes carry count 0
-        mask = jnp.uint32(cap_s - 1)
-
-        def probe(k, state):
-            found_id, done = state
-            idx = ((h1 + jnp.uint32(k)) & mask).astype(jnp.int32)
-            row = t[idx]
-            occ = row[:, 3] > 0
-            hit = occ & (row[:, 0] == h1) & (row[:, 1] == h2) \
-                & (row[:, 2] == h3)
-            stop = hit | ~occ
-            found_id = jnp.where(hit & ~done,
-                                 row[:, 3].astype(jnp.int32) - 1, found_id)
-            return found_id, done | stop
-
-        # The probe reads the node-sharded table, so the loop carry is
-        # node-varying; mark the (replicated-literal) initial carry to
-        # match.
-        found_id = jax.lax.pcast(jnp.full(h1.shape, -1, jnp.int32),
-                                 (FLEET_AXIS,), to="varying")
-        done = jax.lax.pcast(jnp.zeros(h1.shape, bool),
-                             (FLEET_AXIS,), to="varying")
-        found_id, _ = jax.lax.fori_loop(0, _PROBES, probe, (found_id, done))
+        # The single-chip feed's probe, over this shard's sub-table.
+        found_id, _ = probe(t, packed[0, :3].T, live)
 
         hit = (found_id >= 0) & live
         a = a.at[jnp.where(hit, found_id, id_cap)].add(
@@ -131,6 +112,10 @@ def _sharded_feed_program(mesh, n_shards: int, cap_s: int, id_cap: int,
         in_specs=(P(FLEET_AXIS, None, None), P(FLEET_AXIS, None),
                   P(FLEET_AXIS, None, None), P()),
         out_specs=(P(FLEET_AXIS, None), P(FLEET_AXIS), P(FLEET_AXIS, None)),
+        # The probe's loops start from literals and end on values read
+        # from the node-sharded table; every output is per shard, so
+        # nothing rests on the replication check.
+        check_vma=False,
     )
     return jax.jit(fn, donate_argnums=(1,))
 

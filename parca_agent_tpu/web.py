@@ -375,6 +375,13 @@ def render_metrics(profilers, batch_client=None, extra: dict | None = None,
                  agg_stats.get("carry_hits", 0), lab)
             emit("parca_agent_dict_rows_fed_total",
                  agg_stats.get("rows_fed", 0), lab)
+            # Dictionary rows the device probe gathered for them (16 a
+            # dispatched lane, padding included, is what a probe that
+            # never stops early reads): how often the probe's early
+            # exit and its narrow stages engage (docs/perf.md "The
+            # probe").
+            emit("parca_agent_dict_probe_gathers_total",
+                 agg_stats.get("probe_gathers", 0), lab)
             emit("parca_agent_dict_reclaims_total",
                  agg_stats.get("reclaims", 0), lab)
             emit("parca_agent_dict_reclaimed_ids_total",
