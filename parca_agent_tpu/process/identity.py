@@ -34,7 +34,11 @@ mid-window" branch (an error counted, the remembered generation kept,
 ``absent_total``) without an open, and only a listed pid gets its
 bounded stat read. The table and the counters are updated under one
 lock acquisition a window, and ``reused`` comes back in ascending pid
-order.
+order. The three steps are three spans under the profiler's
+``identity`` (``trace.child``, wall clock only): ``identity_list`` (the
+listing and the ``isin``), ``identity_read`` (the read loop),
+``identity_settle`` (the table update under the lock and the
+invalidators); what is left of ``identity`` is the ``np.unique``.
 
 Why a pid absent from the listing needs no read: a recycled pid's stale
 state must be invalidated before any sample of the NEW generation
@@ -46,7 +50,14 @@ first samples can only arrive with the NEXT drain, when it is listed,
 read, and found to differ from the remembered generation. The evidence
 stays ``(pid, starttime)`` for every live pid in every window: no
 watermark on ``ns_last_pid``, no cache of "checked recently", no
-sampling of pids — those would be a weaker check, not a faster one. A
+sampling of pids — those would be a weaker check, not a faster one.
+What the check costs was measured where it runs: ~75 us a read on the
+chip tool's host (gVisor; PERF.md sections 5 and 7), so ~30 ms of a
+window with 400 live pids, the largest stage between a node window's
+last sample and its pprof bytes. A change that makes it cheaper makes
+the READ cheaper (fewer system calls a read, reads off the GIL); it
+leaves the number of pids read where it is: every listed pid, every
+window (``identity_stat_reads`` on the window's ``meta``). A
 listing that fails falls back to a read per pid (fail-open, same
 result), and an injected ``starttime_of`` is the world: it is asked for
 every distinct pid and no listing is made. (``/proc/<n>/stat`` opens
@@ -163,34 +174,35 @@ class ProcessIdentityTracker:
             # Kernel pseudo-pids have no /proc identity.
             distinct = distinct[distinct >= 0]
             checked, starts, n_reads, n_absent = self._starttimes(distinct)
-            with self._lock:
-                prevs = list(map(self._gens.get, checked))
-                self._gens.update(zip(checked, starts))
-                hits = [(pid, prev, start) for pid, prev, start
-                        in zip(checked, prevs, starts)
-                        if prev is not None and prev != start]
-                reused = [pid for pid, _prev, _start in hits]
-                if hits:
-                    pid, prev, start = hits[-1]
-                    self._last_reuse = {
-                        "pid": pid, "old_starttime": prev,
-                        "new_starttime": start}
-                st = self.stats
-                st["checks_total"] += len(checked)
-                # Exited mid-window (or unreadable): the remembered
-                # generation is kept — if the pid comes back it is BY
-                # DEFINITION a new incarnation and the stale entry is
-                # what lets us detect it.
-                st["errors_total"] += len(distinct) - len(checked)
-                st["absent_total"] += n_absent
-                st["reuse_detected_total"] += len(reused)
-                self._trim(distinct)
-                hooks = list(self._invalidators) if reused else ()
-            trace.count(identity_pids=len(distinct),
-                        identity_stat_reads=n_reads,
-                        identity_absent=n_absent)
-            if hooks:
-                self._invalidate(reused, hooks)
+            with trace.child("identity_settle"):
+                with self._lock:
+                    prevs = list(map(self._gens.get, checked))
+                    self._gens.update(zip(checked, starts))
+                    hits = [(pid, prev, start) for pid, prev, start
+                            in zip(checked, prevs, starts)
+                            if prev is not None and prev != start]
+                    reused = [pid for pid, _prev, _start in hits]
+                    if hits:
+                        pid, prev, start = hits[-1]
+                        self._last_reuse = {
+                            "pid": pid, "old_starttime": prev,
+                            "new_starttime": start}
+                    st = self.stats
+                    st["checks_total"] += len(checked)
+                    # Exited mid-window (or unreadable): the remembered
+                    # generation is kept — if the pid comes back it is
+                    # BY DEFINITION a new incarnation and the stale
+                    # entry is what lets us detect it.
+                    st["errors_total"] += len(distinct) - len(checked)
+                    st["absent_total"] += n_absent
+                    st["reuse_detected_total"] += len(reused)
+                    self._trim(distinct)
+                    hooks = list(self._invalidators) if reused else ()
+                trace.count(identity_pids=len(distinct),
+                            identity_stat_reads=n_reads,
+                            identity_absent=n_absent)
+                if hooks:
+                    self._invalidate(reused, hooks)
         except Exception:
             with self._lock:
                 self.stats["errors_total"] += 1
@@ -204,25 +216,27 @@ class ProcessIdentityTracker:
         n_absent = 0
         if start_of is None:
             start_of = functools.partial(read_starttime, self._fs)
-            # palint: fail-open
-            try:
-                live = np.fromiter(
-                    (int(n) for n in self._fs.listdir("/proc")
-                     if n.isdigit()), np.int64)
-                listed = distinct[np.isin(distinct, live)]
-                n_absent = len(distinct) - len(listed)
-                distinct = listed
-            except Exception:
-                pass  # no listing: a read per pid, the same result
+            with trace.child("identity_list"):
+                # palint: fail-open
+                try:
+                    live = np.fromiter(
+                        (int(n) for n in self._fs.listdir("/proc")
+                         if n.isdigit()), np.int64)
+                    listed = distinct[np.isin(distinct, live)]
+                    n_absent = len(distinct) - len(listed)
+                    distinct = listed
+                except Exception:
+                    pass  # no listing: a read per pid, the same result
         checked: list[int] = []
         starts: list[int] = []
-        for pid in distinct.tolist():
-            try:
-                start = int(start_of(pid))
-            except Exception:
-                continue
-            checked.append(pid)
-            starts.append(start)
+        with trace.child("identity_read"):
+            for pid in distinct.tolist():
+                try:
+                    start = int(start_of(pid))
+                except Exception:
+                    continue
+                checked.append(pid)
+                starts.append(start)
         return checked, starts, len(distinct), n_absent
 
     def _invalidate(self, reused: list[int], hooks) -> None:

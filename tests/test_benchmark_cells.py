@@ -12,8 +12,10 @@ from __future__ import annotations
 import importlib
 import json
 import os
+import signal
 import subprocess
 import sys
+import time
 
 import pytest
 
@@ -147,19 +149,167 @@ def test_node_streamed_under_rollout_rehearses_streamed_on_the_cpu():
     assert "feed_probe_roofline" not in metrics     # a device number
 
 
-def test_the_8_bit_control_is_not_correct_for_node_streamed_under_rollout():
+@pytest.mark.parametrize("config, traffic, seed", [
+    ("node-streamed", "rollout", "3700000007"),
+    # Over the pool's pids: processes of this machine (node-live, below).
+    ("node-live", "steady-live", "4200000011"),
+])
+def test_the_8_bit_control_is_not_correct(config, traffic, seed):
     """The plain reference in the program's place, its counts carried
-    in 8 bits: the comparison that holds the streamed cell has to say
-    so (exit 0: the sound shipment read 0 everywhere, the control did
-    not)."""
+    in 8 bits: the comparison that holds the cell has to say so (exit
+    0: the sound shipment read 0 everywhere, the control did not)."""
     out = subprocess.run(
         [sys.executable, os.path.join(BENCH, "control.py"),
-         "--config", "node-streamed", "--traffic", "rollout",
-         "--seeds", "3700000007"],
+         "--config", config, "--traffic", traffic, "--seeds", seed],
         capture_output=True, text=True, timeout=240, cwd=REPO)
     assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
     line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert line["cell"] == "node-streamed-rollout" and line["bits"] == 8
+    assert line["cell"] == f"{config}-{traffic}" and line["bits"] == 8
     assert line["sound"]["correct"] is True
     assert line["control"]["correct"] is False
     assert line["control"]["mass_gap"] > 0
+
+
+# -- node-live: the window's pids are processes of this machine ---------------
+
+# The rehearsal in a process of its own, with the tracker's reads and
+# the labels that ride every shipped profile recorded on the way (the
+# result line carries neither) and said on stderr after the run, with
+# the pool's pids, so that the test can see that none is left.
+LIVE_REHEARSAL = '''
+import json, sys
+sys.path[:0] = [{repo!r}, {bench!r}]
+from parca_agent_tpu.agent import writer
+from parca_agent_tpu.process import identity
+
+windows, comms = [], {{}}
+sound_reads = identity.ProcessIdentityTracker._starttimes
+
+def counted_reads(self, distinct):
+    checked, starts, n_reads, n_absent = sound_reads(self, distinct)
+    windows.append([sorted(distinct.tolist()), sorted(checked), n_reads,
+                    n_absent])
+    return checked, starts, n_reads, n_absent
+
+identity.ProcessIdentityTracker._starttimes = counted_reads
+sound_write = writer.RemoteProfileWriter.write
+
+def seen_write(self, labels, *a, **kw):
+    comms.setdefault(labels.get("comm"), set()).add(int(labels["pid"]))
+    return sound_write(self, labels, *a, **kw)
+
+writer.RemoteProfileWriter.write = seen_write
+import rehearse
+from lib.mixes import live_ring
+code = rehearse.main({args!r})
+print("LIVE " + json.dumps({{
+    "pool": live_ring.pool({pids}).pids, "windows": windows,
+    "comms": {{str(c): sorted(p) for c, p in comms.items()}}}}),
+    file=sys.stderr, flush=True)
+sys.exit(code)
+'''
+
+
+def _pool_processes_left(pids) -> list[int]:
+    """Those of ``pids`` that are still processes of the idle pool
+    (a number the kernel has handed to another process is not)."""
+    left = []
+    for pid in pids:
+        try:
+            with open(f"/proc/{pid}/cmdline", "rb") as f:
+                if b"idle_pool.py" in f.read():
+                    left.append(pid)
+        except OSError:
+            pass
+    return left
+
+
+def _gone_within(pids, seconds: float) -> list[int]:
+    deadline = time.monotonic() + seconds
+    while (left := _pool_processes_left(pids)) \
+            and time.monotonic() < deadline:
+        time.sleep(0.05)
+    return left
+
+
+def _live_rehearsal():
+    args = ["--config", "node-live", "--traffic", "steady-live",
+            "--pids", "40", "--stacks", "1024", "--samples", "8000",
+            "--capacity", "16384", "--seconds", "4", "--trace", "1"]
+    code = LIVE_REHEARSAL.format(repo=REPO, bench=BENCH, args=args, pids=40)
+    out = subprocess.run([sys.executable, "-c", code],
+                         env={**os.environ, "JAX_PLATFORMS": "cpu"},
+                         capture_output=True, text=True, timeout=540,
+                         cwd=REPO)
+    said = [ln for ln in out.stderr.splitlines() if ln.startswith("LIVE ")]
+    return out, (json.loads(said[-1][5:]) if said else None)
+
+
+def test_node_live_rehearses_correct_with_every_pid_read_every_window():
+    """The deployment whose pids live, through the harness at a tiny
+    size with a pool of 40 real processes: the comparison reads 0 on
+    every number and nothing fails; every window the agent opened read
+    the ``stat`` of every one of its pids and settled none as absent;
+    every shipped profile carries the pool's real ``comm`` (``cat``);
+    the traced line carries the three spans' two metrics and the two
+    counts; and when the run is over no process of the pool is left."""
+    out, live = _live_rehearsal()
+    assert out.returncode == 0, out.stderr[-2000:]
+    line = json.loads(out.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] >= 2 and line["rehearsal"] is True
+    assert "compared stack_mismatches = 0 (limit 0)" in out.stdout
+    assert "in-window parca_agent_xla_compile_requests_total = 0" \
+        in out.stdout
+    pool = live["pool"]
+    assert len(pool) == 40 and pool == sorted(pool)
+    # The guarantee: every live pid of every window, read.
+    assert len(live["windows"]) >= line["attempted"] + 3
+    for distinct, checked, n_reads, n_absent in live["windows"]:
+        assert distinct == pool and checked == pool
+        assert (n_reads, n_absent) == (40, 0)
+    # Real labels: the pool's one comm on every pid's profiles.
+    assert live["comms"] == {"cat": pool}
+    metrics = line["metrics"]
+    assert metrics["identity_checks_per_window"]["value"] == 40.0
+    assert metrics["identity_absent_per_window"]["value"] == 0.0
+    assert metrics["identity_ms.p50"]["value"] \
+        >= metrics["identity_read_ms.p50"]["value"] > 0
+    assert metrics["identity_list_ms.p50"]["value"] > 0
+    # Every per-layer metric the cell lists that is no device number.
+    listed = {m["name"]: m for m in BENCHMARK["per_layer"]
+              if "node-live-steady" in m.get("workloads", [])}
+    assert len(listed) >= 40
+    for name, m in listed.items():
+        if m["source"] != "device_trace":
+            assert name in metrics, name
+    assert _gone_within(pool, 10.0) == []
+
+
+@pytest.mark.parametrize("how", ["an_exception", "a_kill"])
+def test_no_process_of_the_pool_outlives_its_harness(how):
+    """The pool's processes end with the process that started them:
+    when it raises out of its run, and when it is killed where it
+    stands (SIGKILL: no handler, no ``atexit``; the end of file on the
+    pipe it held is all there is)."""
+    code = (f"import sys; sys.path.insert(0, {BENCH!r})\n"
+            "from lib.mixes import live_ring\n"
+            "print(*live_ring.pool(24).pids, flush=True)\n"
+            + ("raise RuntimeError('the run failed')\n"
+               if how == "an_exception" else
+               "import time; time.sleep(600)\n"))
+    proc = subprocess.Popen([sys.executable, "-c", code], cwd=REPO,
+                            stdout=subprocess.PIPE, stderr=subprocess.PIPE,
+                            text=True)
+    try:
+        pids = [int(p) for p in proc.stdout.readline().split()]
+        assert len(pids) == 24
+        if how == "a_kill":
+            assert _pool_processes_left(pids) == pids
+            proc.send_signal(signal.SIGKILL)
+        proc.wait(timeout=60)
+        assert proc.returncode != 0
+    finally:
+        proc.kill()
+        proc.communicate()
+    assert _gone_within(pids, 10.0) == []

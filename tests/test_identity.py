@@ -13,7 +13,9 @@ off, and vanish with it on — through the real window loop, via the
 workload zoo's pid-reuse scenario), the ``process.identity`` chaos
 site's fail-open contract, and that the bulk check (one ``np.unique``,
 one ``/proc`` listing, a read per listed pid) equals the per-row loop
-it replaced, which stays here as the plain reference.
+it replaced, which stays here as the plain reference; that the check's
+three steps are spans under the profiler's ``identity``; and that every
+listed pid is read in every window, whatever the windows before read.
 """
 
 import sys
@@ -30,6 +32,7 @@ from parca_agent_tpu.process.identity import (
     ProcessIdentityTracker, read_starttime)
 from parca_agent_tpu.process.maps import ProcMapping, build_mapping_table
 from parca_agent_tpu.runtime.quarantine import QuarantineRegistry
+from parca_agent_tpu.runtime.trace import FlightRecorder
 from parca_agent_tpu.utils import faults
 from parca_agent_tpu.utils.vfs import FakeFS
 
@@ -670,3 +673,111 @@ def test_one_lock_a_window_loses_no_update_under_threads():
     assert (m["errors_total"], m["absent_total"]) == (0, 0)
     t.observe_window(col)
     assert t._gens == world
+
+
+# -- the three steps as spans under ``identity`` ------------------------------
+
+# What ``identity`` holds beside its children is the reduction of the
+# pid column (``np.unique``), the fault site and the clock readings of
+# the spans themselves: ~0.1 ms. The tolerance leaves room for a test
+# machine that takes the thread off the core in between; the children
+# never add up to MORE than their parent (1 us a span for the rounding
+# of ``/debug/windows``' six decimals).
+_SELF_TOLERANCE_S = 0.020
+_ROUNDING_S = 1e-6
+
+_SPAN_CASES = {
+    # name: (world {pid: start}, the window's pids, steps expected)
+    "every_pid_lives": ({p: 7 for p in range(100, 140)},
+                        list(range(100, 140)), 3),
+    "some_pids_absent": ({p: 7 for p in range(100, 120)},
+                         list(range(100, 140)), 3),
+    "no_pid_lives": ({}, list(range(100, 140)), 3),
+    "a_pid_reused": ({100: 7, 101: 8}, [100, 101], 3),
+    "the_listing_fails": ({p: 7 for p in range(100, 110)},
+                          list(range(100, 112)), 3),
+    # An injected reader is the world: no listing is made, no span of it.
+    "an_injected_reader": (None, list(range(100, 140)), 2),
+}
+
+
+def _window_spans(tracker, pids):
+    """One window through ``observe_window`` inside an open ``identity``
+    span, as ``profiler/cpu.py`` opens it: ({stage: span}, meta)."""
+    rec = FlightRecorder()
+    tr = rec.begin()
+    with tr.span("identity"):
+        reused = tracker.observe_window(np.asarray(pids, np.int32))
+    tr.complete()
+    trace = rec.traces()[0]
+    return {s["stage"]: s for s in trace["spans"]}, trace["meta"], reused
+
+
+@pytest.mark.parametrize("case", sorted(_SPAN_CASES))
+def test_the_checks_steps_are_spans_under_identity(case):
+    world, pids, n_steps = _SPAN_CASES[case]
+    if world is None:
+        t = ProcessIdentityTracker(starttime_of=lambda pid: 7, enabled=True)
+    else:
+        fs = _procfs(world)
+        fs.listdir_raises = case == "the_listing_fails"
+        t = ProcessIdentityTracker(fs=fs, enabled=True)
+    if case == "a_pid_reused":
+        t.observe_window(pids)
+        t._fs = _procfs({100: 7, 101: 9})
+    spans, meta, reused = _window_spans(t, pids)
+    assert reused == ([101] if case == "a_pid_reused" else [])
+    steps = [s for s in ("identity_list", "identity_read",
+                         "identity_settle") if s in spans]
+    assert len(steps) == n_steps and steps[-2:] == [
+        "identity_read", "identity_settle"]
+    parent = spans["identity"]
+    end = parent["start_s"] + parent["duration_s"]
+    at = parent["start_s"]
+    for stage in steps:
+        s = spans[stage]
+        # Nested under ``identity``, one after the other, inside it.
+        assert s["parent"] == parent["id"], stage
+        assert s["start_s"] >= at - _ROUNDING_S, stage
+        at = s["start_s"] + s["duration_s"]
+        assert at <= end + _ROUNDING_S, stage
+    inside = sum(spans[s]["duration_s"] for s in steps)
+    assert inside <= parent["duration_s"] + len(steps) * _ROUNDING_S
+    assert parent["duration_s"] - inside <= _SELF_TOLERANCE_S
+    assert meta["identity_pids"] == len(pids)
+    assert meta["identity_stat_reads"] + meta["identity_absent"] \
+        == len(pids)
+
+
+def test_with_no_window_open_the_steps_record_nowhere():
+    # Library use: no span is open on the thread, the check is the same.
+    t = ProcessIdentityTracker(fs=_procfs({10: 100}), enabled=True)
+    assert t.observe_window([10, 11]) == []
+    m = t.metrics()
+    assert (m["checks_total"], m["absent_total"]) == (1, 1)
+
+
+@pytest.mark.parametrize("n_live", [1, 40, 400])
+def test_every_listed_pid_is_read_in_every_window(n_live):
+    """No watermark, no cache of "checked recently", no sampling of
+    pids: window after window of the same live pids, every one of them
+    is opened once a window, and a pid of the window that ``/proc`` does
+    not list is never opened. Listed pids the window does not hold are
+    not opened either."""
+    live = list(range(1000, 1000 + n_live))
+    unsampled = list(range(5000, 5020))     # listed, not in the window
+    gone = list(range(9000, 9007))          # in the window, not listed
+    fs = _procfs({p: 3 * p for p in live + unsampled})
+    t = ProcessIdentityTracker(fs=fs, enabled=True)
+    col = np.resize(np.asarray(live + gone, np.int32), 4 * (n_live + 7))
+    want = sorted(f"/proc/{p}/stat" for p in live)
+    for window in range(1, 7):
+        fs.opens.clear()
+        _spans, meta, reused = _window_spans(t, col)
+        assert reused == []
+        assert sorted(fs.opens) == want, window
+        assert meta["identity_stat_reads"] == n_live
+        assert meta["identity_absent"] == len(gone)
+        assert meta["identity_pids"] == n_live + len(gone)
+        assert t.metrics()["checks_total"] == window * n_live
+    assert fs.listdirs == ["/proc"] * 6
