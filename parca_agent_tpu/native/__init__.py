@@ -3,10 +3,12 @@
 `sampler.cc` is the perf_event ring drainer (role of the reference's
 bpf/cpu/cpu.bpf.c capture program); capture/live.py compiles it with the
 adjacent Makefile on first use and loads it via ctypes. `vecenc.cc` is
-the varint emission kernel behind pprof/vec.py. Both share the
-build-on-demand policy below; what differs per caller is only what a
+the varint emission kernel behind pprof/vec.py. `procstat.cc` is the
+batch read of `/proc/<pid>/stat` behind process/identity.py. All share
+the build-on-demand policy below; what differs per caller is only what a
 build failure means (the sampler raises SamplerUnavailable, the varint
-kernel falls back to its numpy path).
+kernel falls back to its numpy path, the identity check to its Python
+read loop).
 """
 
 from __future__ import annotations

@@ -36,7 +36,7 @@ bounded stat read. The table and the counters are updated under one
 lock acquisition a window, and ``reused`` comes back in ascending pid
 order. The three steps are three spans under the profiler's
 ``identity`` (``trace.child``, wall clock only): ``identity_list`` (the
-listing and the ``isin``), ``identity_read`` (the read loop),
+listing and the ``isin``), ``identity_read`` (the reads),
 ``identity_settle`` (the table update under the lock and the
 invalidators); what is left of ``identity`` is the ``np.unique``.
 
@@ -51,13 +51,27 @@ read, and found to differ from the remembered generation. The evidence
 stays ``(pid, starttime)`` for every live pid in every window: no
 watermark on ``ns_last_pid``, no cache of "checked recently", no
 sampling of pids — those would be a weaker check, not a faster one.
-What the check costs was measured where it runs: ~75 us a read on the
-chip tool's host (gVisor; PERF.md sections 5 and 7), so ~30 ms of a
-window with 400 live pids, the largest stage between a node window's
-last sample and its pprof bytes. A change that makes it cheaper makes
-the READ cheaper (fewer system calls a read, reads off the GIL); it
-leaves the number of pids read where it is: every listed pid, every
-window (``identity_stat_reads`` on the window's ``meta``). A
+What the check costs was measured where it runs (the chip tool's host,
+gVisor, 400 live pids; PERF.md sections 5 and 6): a read through
+``read_starttime`` is a buffered ``open``, about seven system calls,
+each of which drops and retakes the GIL: ~88 us a pid inside the agent,
+~35 ms of a window, the largest stage between a node window's last
+sample and its pprof bytes. So the reads of a window are ONE call of
+``native/procstat.cc`` (``pa_read_starttimes``, through ctypes, which
+releases the GIL round it): per pid ``open``, ``read``, ``close`` and
+the parse of field 22 in C, ~48 us a pid there (~19 ms of a window). It reads what the loop
+read (every listed pid, every window: ``identity_stat_reads`` on the
+window's ``meta``; ``identity_native_reads`` says how many of them the
+call read), by ``read_starttime``'s rule, and that function stays the
+reference its tests hold it to. A cheaper check is a cheaper READ;
+the number of pids read stays where it is. When the call is made is
+decided by what ``_starttimes`` can observe, not by a switch: the
+reader is the procfs default, the filesystem is the host's (``RealFS``
+itself: a fake or a test's subclass is a world of its own and is asked
+pid by pid), the library loads (a host with no compiler runs the loop,
+with one warning) and there is a pid to read. A call that reports
+failure hands that window to the loop
+(``parca_agent_pid_identity_native_fallbacks_total``). A
 listing that fails falls back to a read per pid (fail-open, same
 result), and an injected ``starttime_of`` is the world: it is asked for
 every distinct pid and no listing is made. (``/proc/<n>/stat`` opens
@@ -71,6 +85,7 @@ misattribution control arm, same idiom as PARCA_NO_CAPTURE_HASH.
 
 from __future__ import annotations
 
+import ctypes
 import functools
 import os
 import threading
@@ -80,6 +95,7 @@ import numpy as np
 
 from parca_agent_tpu.runtime import trace
 from parca_agent_tpu.utils import faults
+from parca_agent_tpu.utils.log import get_logger
 from parca_agent_tpu.utils.poison import read_bounded
 from parca_agent_tpu.utils.vfs import VFS, RealFS
 
@@ -89,6 +105,52 @@ _STAT_CAP = 1 << 16
 # current window are trimmed once the table grows past this (a dead,
 # never-reused pid must not leak memory forever).
 _MAX_TRACKED = 1 << 20
+
+
+# native/procstat.cc, loaded on the first window that has a pid of the
+# host's /proc to read. False: not tried yet; None: cannot be built or
+# loaded here, and every window runs the Python loop.
+_native: ctypes.CDLL | None | bool = False
+
+
+def _load_native() -> ctypes.CDLL | None:
+    global _native
+    if _native is False:
+        _native = None
+        try:
+            from parca_agent_tpu.native import ensure_built
+
+            lib = ctypes.CDLL(
+                ensure_built("libpaprocstat.so", "procstat.cc"))
+            lib.pa_read_starttimes.restype = ctypes.c_int64
+            lib.pa_read_starttimes.argtypes = [
+                ctypes.c_char_p, ctypes.c_void_p, ctypes.c_int64,
+                ctypes.c_int64, ctypes.c_void_p]
+            _native = lib
+        except Exception as e:  # noqa: BLE001 - fallback is the loop
+            # One warning, not silence: the loop costs nearly twice the
+            # native call a live pid (PERF.md section 5), and a host
+            # missing g++ would otherwise regress invisibly.
+            get_logger("process.identity").warn(
+                "native stat reader unavailable; reading each pid's stat "
+                "from Python", error=repr(e))
+    return _native
+
+
+def native_starttimes(lib: ctypes.CDLL, pids: np.ndarray,
+                      root: str = "/proc") -> np.ndarray | None:
+    """One call of native/procstat.cc over ``pids``: per pid the
+    starttime ``read_starttime`` gives for ``<root>/<pid>/stat``, or a
+    negative code where it raises (-1 absent or unreadable, -2 over
+    ``_STAT_CAP`` bytes, -3 garbled). ctypes releases the GIL round the
+    call. None when the call reports that it could not run. ``root`` is
+    a parameter so that tests can hold the call to ``read_starttime``
+    over a tree of files."""
+    pids = np.ascontiguousarray(pids, np.int64)
+    out = np.empty(len(pids), np.int64)
+    rc = lib.pa_read_starttimes(os.fsencode(root), pids.ctypes.data,
+                                len(pids), _STAT_CAP, out.ctypes.data)
+    return out if rc >= 0 else None
 
 
 def read_starttime(fs: VFS, pid: int) -> int:
@@ -140,6 +202,9 @@ class ProcessIdentityTracker:
             "absent_total": 0,
             "trims_total": 0,
         }
+        # guarded-by: _lock — the native reader's counters: pids it read
+        # and parsed, windows it handed back to the loop.
+        self.native_stats = {"reads_total": 0, "fallbacks_total": 0}
         # guarded-by: _lock — last detected reuse, for /healthz.
         self._last_reuse: dict | None = None
 
@@ -173,7 +238,8 @@ class ProcessIdentityTracker:
             distinct = np.unique(arr)
             # Kernel pseudo-pids have no /proc identity.
             distinct = distinct[distinct >= 0]
-            checked, starts, n_reads, n_absent = self._starttimes(distinct)
+            checked, starts, n_reads, n_absent, n_native, fell_back = \
+                self._starttimes(distinct)
             with trace.child("identity_settle"):
                 with self._lock:
                     prevs = list(map(self._gens.get, checked))
@@ -195,12 +261,15 @@ class ProcessIdentityTracker:
                     # entry is what lets us detect it.
                     st["errors_total"] += len(distinct) - len(checked)
                     st["absent_total"] += n_absent
+                    self.native_stats["reads_total"] += n_native
+                    self.native_stats["fallbacks_total"] += fell_back
                     st["reuse_detected_total"] += len(reused)
                     self._trim(distinct)
                     hooks = list(self._invalidators) if reused else ()
                 trace.count(identity_pids=len(distinct),
                             identity_stat_reads=n_reads,
-                            identity_absent=n_absent)
+                            identity_absent=n_absent,
+                            identity_native_reads=n_native)
                 if hooks:
                     self._invalidate(reused, hooks)
         except Exception:
@@ -209,11 +278,14 @@ class ProcessIdentityTracker:
         return reused
 
     def _starttimes(self, distinct: np.ndarray
-                    ) -> tuple[list[int], list[int], int, int]:
+                    ) -> tuple[list[int], list[int], int, int, int, int]:
         """(pids whose starttime was read, their starttimes, reads
-        attempted, pids settled absent by the listing with no read)."""
+        attempted, pids settled absent by the listing with no read, pids
+        the native call read and parsed, 1 if the native call was made
+        and reported failure)."""
         start_of = self._start_of
         n_absent = 0
+        lib = None
         if start_of is None:
             start_of = functools.partial(read_starttime, self._fs)
             with trace.child("identity_list"):
@@ -227,9 +299,23 @@ class ProcessIdentityTracker:
                     distinct = listed
                 except Exception:
                     pass  # no listing: a read per pid, the same result
-        checked: list[int] = []
-        starts: list[int] = []
+            # The native call reads the host's /proc and nothing else: an
+            # injected reader, a fake filesystem or a test's subclass is
+            # the world and is asked pid by pid, below.
+            if type(self._fs) is RealFS and len(distinct):
+                lib = _load_native()
+        fell_back = 0
         with trace.child("identity_read"):
+            if lib is not None:
+                out = native_starttimes(lib, distinct)
+                if out is not None:
+                    ok = out >= 0
+                    checked = distinct[ok].tolist()
+                    return (checked, out[ok].tolist(), len(distinct),
+                            n_absent, len(checked), 0)
+                fell_back = 1  # fail-open: this window by the loop
+            checked: list[int] = []
+            starts: list[int] = []
             for pid in distinct.tolist():
                 try:
                     start = int(start_of(pid))
@@ -237,7 +323,7 @@ class ProcessIdentityTracker:
                     continue
                 checked.append(pid)
                 starts.append(start)
-        return checked, starts, len(distinct), n_absent
+        return checked, starts, len(distinct), n_absent, 0, fell_back
 
     def _invalidate(self, reused: list[int], hooks) -> None:
         fired = failed = 0
@@ -267,6 +353,10 @@ class ProcessIdentityTracker:
         with self._lock:
             return dict(self.stats)
 
+    def native_metrics(self) -> dict:
+        with self._lock:
+            return dict(self.native_stats)
+
     def snapshot(self) -> dict:
         """Observability view for /healthz (never turns readiness red)."""
         with self._lock:
@@ -277,4 +367,5 @@ class ProcessIdentityTracker:
                 "last_reuse": dict(self._last_reuse)
                                if self._last_reuse else None,
                 "stats": dict(self.stats),
+                "native": dict(self.native_stats),
             }

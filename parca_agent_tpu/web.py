@@ -672,6 +672,11 @@ def render_metrics(profilers, batch_client=None, extra: dict | None = None,
              m.get("errors_total", 0))
         emit("parca_agent_pid_identity_absent_total",
              m.get("absent_total", 0))
+        m = identity.native_metrics()
+        emit("parca_agent_pid_identity_native_reads_total",
+             m.get("reads_total", 0))
+        emit("parca_agent_pid_identity_native_fallbacks_total",
+             m.get("fallbacks_total", 0))
     if regression is not None:
         # Regression sentinel (docs/regression.md): verdict counters by
         # kind, the fold/seal/baseline lifecycle counters, judgment

@@ -186,10 +186,11 @@ windows, comms = [], {{}}
 sound_reads = identity.ProcessIdentityTracker._starttimes
 
 def counted_reads(self, distinct):
-    checked, starts, n_reads, n_absent = sound_reads(self, distinct)
+    got = sound_reads(self, distinct)
+    checked, _starts, n_reads, n_absent = got[:4]
     windows.append([sorted(distinct.tolist()), sorted(checked), n_reads,
                     n_absent])
-    return checked, starts, n_reads, n_absent
+    return got
 
 identity.ProcessIdentityTracker._starttimes = counted_reads
 sound_write = writer.RemoteProfileWriter.write
@@ -273,6 +274,8 @@ def test_node_live_rehearses_correct_with_every_pid_read_every_window():
     metrics = line["metrics"]
     assert metrics["identity_checks_per_window"]["value"] == 40.0
     assert metrics["identity_absent_per_window"]["value"] == 0.0
+    # Read by the one native call a window, every one of them.
+    assert metrics["identity_native_reads_per_window"]["value"] == 40.0
     assert metrics["identity_ms.p50"]["value"] \
         >= metrics["identity_read_ms.p50"]["value"] > 0
     assert metrics["identity_list_ms.p50"]["value"] > 0
