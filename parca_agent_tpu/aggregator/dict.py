@@ -259,7 +259,10 @@ def make_feed(cap: int, id_cap: int, n_pad: int, n_blocks: int = 0,
     return feed
 
 
-@functools.lru_cache(maxsize=8)
+# Room for every power of two a run's first feed meets (1,024 up to the
+# 262,144 rows of a firehose window fed in one piece are nine): a program
+# that fell out of this cache would be traced and compiled again.
+@functools.lru_cache(maxsize=16)
 def _feed_program(cap: int, id_cap: int, n_pad: int, n_blocks: int,
                   blk: int):
     import jax
@@ -271,12 +274,14 @@ def _feed_program(cap: int, id_cap: int, n_pad: int, n_blocks: int,
 # The fewest rows a feed is padded to. A feed's program has one shape per
 # power of two of its rows, and a streamed window's later drains ship only
 # the stacks the carry cache has not met: a handful, whose count differs
-# from drain to drain. Up to this floor they share ONE program, compiled
-# in a run's first window (whose drains shrink through it as the cache
-# fills); without it every count from 16 rows up compiled its own, on the
-# feed thread and under its 3 s watchdog, in whichever window first
-# brought it. 1,024 rows are 16 KB of H2D a feed and ~0.3 ms of the probe
-# loop where 16 rows are ~0.04 (PERF.md section 6, PR 37).
+# from drain to drain. Up to this floor they share ONE program; without
+# it every count from 16 rows up compiled its own, on the feed thread and
+# under its 3 s watchdog, in whichever window first brought it. 1,024
+# rows are 16 KB of H2D a feed and ~0.3 ms of the probe loop where 16
+# rows are ~0.04 (PERF.md section 6, PR 37). Above the floor a carrying
+# aggregator's first feed runs every power of two down from its own
+# (feed), so the shapes a run's later feeds fall through are compiled
+# once, under the first feed's long budget.
 _FEED_PAD_MIN = 1 << 10
 
 # What a deferred settle records, by where it runs. A feed's settle runs
@@ -396,6 +401,17 @@ _DELTA_BLOCK = 128
 # Delta fetch must move strictly less than half the full fetch's rows to
 # be worth its second buffer dimension; past this the full close is used.
 _DELTA_MAX_FRAC = 0.5
+# The fewest blocks a delta close fetches, as a share of the full
+# fetch's: 1/64 of it (64 blocks = 4 KB at width 4 beside a full fetch
+# of 256 KB at 524,288 ids), and never under 8. The block buffer is a
+# shape of the close program, a power of two over twice the window
+# before's touched blocks; a streamed window touches only the blocks of
+# the few keys the carry cache leaves to the device (a firehose
+# population's h1 collisions: a handful, one more every few windows),
+# and a buffer sized by that count alone crossed from 8 to 16 to 32
+# inside a run, each a compile of ~0.4 s on the window's path (PERF.md
+# section 6, PR 44). Under the floor the count moves no shape.
+_DELTA_MIN_DIV = 64
 
 
 def make_close_delta(id_cap: int, n_fetch: int, width: int,
@@ -656,7 +672,7 @@ class DictAggregator:
         self._carry_starts = np.zeros(2, np.int64)
         self._carry_open_mass = 0   # mass carried for the open window
         self._carry_disabled = False  # fault: match off until boundary
-        self._floor_met = False     # the floor feed shape has been run
+        self._shapes_met = False    # the first feed has run its shapes
         self._cm_spec = cm_spec or CountMinSpec()
         self._hll_spec = HLLSpec()
         self._cm = None                  # lazy [depth, width] int64
@@ -1147,17 +1163,21 @@ class DictAggregator:
             self._touch = self._new_touch()
         handle = self._feed_dispatch_async(packed, n_pad,
                                            1 if self._needs_reset else 0)
-        if self._carry and not self._floor_met:
-            # With the carry cache every later feed is small: the floor
-            # shape is the one this run will live in. Meet it now, in the
-            # process's first feed (the one the feeder gives its long
-            # budget), not in whichever later drain first falls under the
-            # floor: an all-padding batch counts nothing.
-            self._floor_met = True
-            if n_pad > _FEED_PAD_MIN:
+        if self._carry and not self._shapes_met:
+            # With the carry cache every later feed is smaller: as the
+            # cache fills, a run's drains fall through the powers of two
+            # under this one, as far as its churn leaves them, and which
+            # drain first brings which shape follows the draw. Meet them
+            # all now, in the process's first feed (the one the feeder
+            # gives its long budget), not one compile a drain under the
+            # short one, in whichever window: an all-padding batch counts
+            # nothing.
+            self._shapes_met = True
+            pad = n_pad >> 1
+            while pad >= _FEED_PAD_MIN:
                 self._feed_dispatch_async(
-                    np.zeros((4, _FEED_PAD_MIN), np.uint32),
-                    _FEED_PAD_MIN, 0)
+                    np.zeros((4, pad), np.uint32), pad, 0)
+                pad >>= 1
         self._needs_reset = False
         self._pending.extend(corrections)
         # _fed_total means "mass in the DEVICE accumulator" (the close
@@ -1688,13 +1708,15 @@ class DictAggregator:
     def _delta_plan(self, n_fetch: int) -> int:
         """Blocks to fetch for a delta close, or 0 for a full fetch.
         Sized predictively at 2x the previous window's touched-block
-        population (floor 8 blocks = 1k rows); delta engages only when
-        that moves less than _DELTA_MAX_FRAC of the full fetch's rows."""
+        population (floor 8 blocks = 1k rows, or the full fetch's blocks
+        over _DELTA_MIN_DIV); delta engages only when that moves less
+        than _DELTA_MAX_FRAC of the full fetch's rows."""
         if not self._blk or self._touch is None \
                 or self._prev_touched is None:
             return 0
         nb_prefix = n_fetch // self._blk
-        want = min(nb_prefix, max(8, 2 * self._prev_touched))
+        want = min(nb_prefix, max(8, nb_prefix // _DELTA_MIN_DIV,
+                                  2 * self._prev_touched))
         n_blk_buf = 1 << max(0, (want - 1).bit_length())
         if n_blk_buf * self._blk > _DELTA_MAX_FRAC * n_fetch:
             return 0
@@ -1867,9 +1889,13 @@ class DictAggregator:
             # The carry flush: vectorized (sid, count) corrections from
             # the cross-drain cache, applied exactly once per handle
             # (retries above re-pack the device buffers, never this).
-            sids, cnts = h.pending_vec
-            np.add.at(counts, sids, cnts)
-            h.pending_vec = None
+            with trace.child("close_carry_flush"):
+                sids, cnts = h.pending_vec
+                np.add.at(counts, sids, cnts)
+                h.pending_vec = None
+                trace.count(carry_flush_rows=len(sids))
+                self.stats["carry_flush_rows"] = \
+                    self.stats.get("carry_flush_rows", 0) + len(sids)
         self.stats["windows"] += 1
         out = counts[: h.n_ids]
         self._last_seen[np.flatnonzero(out)] = self.stats["windows"]

@@ -15,6 +15,11 @@ stall the capture loop):
   * Every feed runs under a daemon-thread watchdog with a SHORT timeout
     (the polling thread is stalled while a feed runs; perf rings are
     smaller than a window, so a long stall wraps them and loses samples).
+    A feed inside which XLA was asked for a program (a shape the run
+    had not met: JAX raises the request on the asking thread, runtime/
+    device_telemetry.py compile_requests_here) is compiling, not
+    hanging, and is given what is left of the first feed's long budget;
+    a feed that asked for none is held to the short one, always.
     A failure or hang disables the feeder for a capped-exponential number
     of WINDOWS (2, 4, ... up to 32): mid-window the feeder never retries
     (a wedged device would stall the polling thread again next drain),
@@ -47,7 +52,7 @@ import numpy as np
 
 from parca_agent_tpu.capture.formats import WindowSnapshot
 from parca_agent_tpu.capture.live import columns_to_snapshot
-from parca_agent_tpu.runtime import trace
+from parca_agent_tpu.runtime import device_telemetry, trace
 from parca_agent_tpu.utils import faults
 from parca_agent_tpu.utils.log import get_logger
 
@@ -98,7 +103,10 @@ class StreamingWindowFeeder:
         # dead device costs one long capture-loop stall, not one per
         # cooldown. A timed-out-but-healthy first feed keeps compiling
         # in its abandoned daemon thread, so a later 3 s re-probe still
-        # lands on the warm program cache and succeeds.
+        # lands on the warm program cache and succeeds. A LATER feed is
+        # given the long budget only while it can be seen to compile
+        # (_feed_guarded): the shapes a run's first feed does not meet
+        # (aggregator/dict.py) come with a load that grew.
         self._first_timeout = max(feed_timeout_s, first_feed_timeout_s)
         self._first_attempted = False
         self._fed_total = 0          # mass fed into the open window
@@ -136,6 +144,12 @@ class StreamingWindowFeeder:
         self.fallback_reasons = dict.fromkeys(FALLBACK_REASONS, 0)
         self.stats = {"drains_fed": 0, "windows_streamed": 0,
                       "windows_fallback": 0, "reprobes": 0,
+                      # The watchdog's headroom: feeds that took over
+                      # half the timeout they ran under (0 in a sound
+                      # run), and feeds inside which XLA was asked for
+                      # a program (the first of a run, and any that
+                      # meets a shape the first did not).
+                      "feeds_slow": 0, "feed_compiles": 0,
                       "statics_prebuilt": 0, "last_close_s": 0.0,
                       # Flight-recorder feed/fetch spans (runtime/
                       # trace.py): capture-thread seconds spent in this
@@ -351,17 +365,30 @@ class StreamingWindowFeeder:
         open_span = trace.current()
         clock = time.monotonic
         at = [clock(), 0.0, 0.0]  # the call; the feed's start; its end
+        asked = [0]  # XLA compile requests of the feed, while it runs
 
         def site():
-            with trace.adopt(open_span):
+            with trace.adopt(open_span), \
+                    device_telemetry.compile_requests_here(asked):
                 at[1] = clock()
                 try:
                     return self._agg.feed(mini, hashes=hashes)
                 finally:
                     at[2] = clock()
 
-        status, out, done, _box = bounded_call(site, timeout,
-                                               thread_name="stream-feed")
+        def more():
+            # The short timeout has passed. A feed that asked XLA for a
+            # program gets the rest of the long budget; one that asked
+            # for nothing hangs, and is abandoned now.
+            return self._first_timeout - timeout if asked[0] else 0.0
+
+        status, out, done, _box = bounded_call(
+            site, timeout, thread_name="stream-feed", extend=more)
+        if asked[0]:
+            self.stats["feed_compiles"] += 1
+        budget = self._first_timeout if asked[0] else timeout
+        if clock() - at[0] > budget / 2:
+            self.stats["feeds_slow"] += 1
         if status != "hang":
             trace.note("feed_handoff", at[1] - at[0], start_s=at[0])
             trace.note("feed_return", clock() - at[2], start_s=at[2])
@@ -472,12 +499,17 @@ class StreamingWindowFeeder:
         ``parca_agent_streaming_<stat>`` it has always been."""
         out = {"parca_agent_streaming_disabled": int(self.disabled),
                "parca_agent_streaming_windows_streamed_total":
-                   self.stats["windows_streamed"]}
+                   self.stats["windows_streamed"],
+               "parca_agent_streaming_feeds_slow_total":
+                   self.stats["feeds_slow"],
+               "parca_agent_streaming_feed_compiles_total":
+                   self.stats["feed_compiles"]}
         for reason, n in self.fallback_reasons.items():
             out["parca_agent_streaming_windows_fallback_total"
                 f'{{reason="{reason}"}}'] = n
         for k, v in self.stats.items():
-            if k not in ("windows_streamed", "windows_fallback") \
+            if k not in ("windows_streamed", "windows_fallback",
+                         "feeds_slow", "feed_compiles") \
                     and isinstance(v, (int, float)):
                 out[f"parca_agent_streaming_{k}"] = round(v, 4) \
                     if isinstance(v, float) else v

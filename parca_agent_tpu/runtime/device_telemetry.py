@@ -52,6 +52,7 @@ site — telemetry must never cost a window or change a pprof byte
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from collections import deque
@@ -515,14 +516,37 @@ def tick_window(used_s: float) -> None:
         _active.tick_window(used_s)
 
 
+_XLA_COMPILE_REQUEST = "/jax/compilation_cache/compile_requests_use_cache"
 _XLA_EVENTS = {
-    "/jax/compilation_cache/compile_requests_use_cache":
-        "compile_requests_total",
+    _XLA_COMPILE_REQUEST: "compile_requests_total",
     "/jax/compilation_cache/cache_hits": "cache_hits_total",
     "/jax/compilation_cache/cache_misses": "cache_misses_total",
 }
 _XLA_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _xla_watched = False
+# Threads whose compile requests someone counts (compile_requests_here):
+# thread ident -> [requests so far]. JAX raises the request event on the
+# thread that asks, as the request begins: before the persistent cache is
+# read and before the backend compiles.
+_asking: dict[int, list[int]] = {}
+
+
+@contextlib.contextmanager
+def compile_requests_here(asked: list):
+    """Count into ``asked[0]`` the XLA compile requests the calling
+    thread makes inside the block (what
+    ``parca_agent_xla_compile_requests_total`` counts for the process).
+    The list is the caller's, so another thread may read it while the
+    block runs: the streaming feeder's watchdog tells a feed that is
+    compiling from one that hangs by it. With JAX's compilation cache
+    off no request event is raised and the count stays where it was."""
+    watch_xla_compiles()
+    ident = threading.get_ident()
+    _asking[ident] = asked
+    try:
+        yield
+    finally:
+        _asking.pop(ident, None)
 
 
 def watch_xla_compiles() -> None:
@@ -537,6 +561,10 @@ def watch_xla_compiles() -> None:
     from jax import monitoring
 
     def on_event(event: str, **_kw) -> None:
+        if event == _XLA_COMPILE_REQUEST:
+            asked = _asking.get(threading.get_ident())
+            if asked is not None:
+                asked[0] += 1
         key = _XLA_EVENTS.get(event)
         if key is not None and _active is not None:
             _active.note_xla(key)

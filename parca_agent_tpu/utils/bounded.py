@@ -17,11 +17,15 @@ import threading
 from parca_agent_tpu.runtime.trace import thread_ended
 
 
-def bounded_call(thunk, timeout_s: float, thread_name: str = "bounded-call"):
+def bounded_call(thunk, timeout_s: float, thread_name: str = "bounded-call",
+                 extend=None):
     """Run ``thunk`` on an abandonable daemon thread, bounded by
     ``timeout_s``. A daemon thread, NOT a ThreadPoolExecutor: pool
     workers are non-daemon and joined at interpreter exit, so one wedged
-    call would block process shutdown forever.
+    call would block process shutdown forever. ``extend`` is asked once,
+    when ``timeout_s`` has passed with the call still out: the seconds
+    more to give it (0: abandon it now), for a caller that can see then
+    what the call is doing.
 
     Returns ``(status, value, done, box)``:
 
@@ -53,7 +57,7 @@ def bounded_call(thunk, timeout_s: float, thread_name: str = "bounded-call"):
             thread_ended()
 
     threading.Thread(target=call, name=thread_name, daemon=True).start()
-    if done.wait(timeout_s):
+    if done.wait(timeout_s) or (extend is not None and done.wait(extend())):
         if "err" in box:
             return "err", box["err"], done, box
         return "ok", box["out"], done, box

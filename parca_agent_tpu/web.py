@@ -375,6 +375,15 @@ def render_metrics(profilers, batch_client=None, extra: dict | None = None,
                  agg_stats.get("carry_hits", 0), lab)
             emit("parca_agent_dict_rows_fed_total",
                  agg_stats.get("rows_fed", 0), lab)
+            # What the close applied of the carry cache's fold: the
+            # (stack id, count) rows of its flush (the rows matched
+            # over a window's drains, one row a stack).
+            emit("parca_agent_dict_carry_flush_rows_total",
+                 agg_stats.get("carry_flush_rows", 0), lab)
+            # Rows a full dictionary handed to its count-min sketch
+            # (dict+cm): 0 wherever counts are exact.
+            emit("parca_agent_dict_sketch_rows_total",
+                 agg_stats.get("sketch_rows", 0), lab)
             # Dictionary rows the device probe gathered for them (16 a
             # dispatched lane, padding included, is what a probe that
             # never stops early reads): how often the probe's early

@@ -112,7 +112,7 @@ class PidSink:
 
 
 def streamed_profiler(snaps, drains=10, overflow="sketch", recorder=None,
-                      **feeder_kw):
+                      capacity=1 << 13, **feeder_kw):
     """The DaemonSet's path over fixtures: a replay source that drains,
     the streaming feeder fed from it, a dict aggregator with the carry
     cache and an inline fast encoder. Returns (profiler, feeder,
@@ -123,7 +123,7 @@ def streamed_profiler(snaps, drains=10, overflow="sketch", recorder=None,
     from parca_agent_tpu.profiler.streaming import StreamingWindowFeeder
 
     source = ReplaySource(snaps, drains=drains)
-    agg = DictAggregator(capacity=1 << 13, overflow=overflow, carry=True)
+    agg = DictAggregator(capacity=capacity, overflow=overflow, carry=True)
     feeder = StreamingWindowFeeder(agg, source, **feeder_kw)
     source.on_drain = feeder.on_drain
     sink = PidSink()

@@ -77,17 +77,18 @@ def test_every_metric_lists_cells_that_exist():
         assert m["moves"] in e2e, m["name"]
 
 
-def test_build_node_under_compile_rehearses_correct_on_the_cpu():
-    """The harness's own steps at a size where the 4,096-slot dictionary
-    (2,048 ids) has to give ids back every window: warm-up ends (XLA is
-    asked for nothing new two windows running), every limit of the
-    comparison reads 0, nothing fails, and the traced line carries the
-    miss path's counters."""
+def _rehearse(config: str, traffic: str, pids: int, stacks: int,
+              samples: int, capacity: int, seconds: int):
+    """One traced rehearsal on XLA:CPU in a process of its own, held to
+    what every rehearsal has to show: it ends ``correct: true`` with
+    nothing failed, every number of the comparison 0 and no compile
+    request inside the measured window. Returns its line."""
     out = subprocess.run(
         [sys.executable, os.path.join(BENCH, "rehearse.py"),
-         "--config", "build-node", "--traffic", "compile",
-         "--pids", "40", "--stacks", "400", "--samples", "2400",
-         "--capacity", "4096", "--seconds", "8", "--trace", "1"],
+         "--config", config, "--traffic", traffic,
+         "--pids", str(pids), "--stacks", str(stacks),
+         "--samples", str(samples), "--capacity", str(capacity),
+         "--seconds", str(seconds), "--trace", "1"],
         env={**os.environ, "JAX_PLATFORMS": "cpu"}, capture_output=True,
         text=True, timeout=540, cwd=REPO)
     assert out.returncode == 0, out.stderr[-2000:]
@@ -98,6 +99,17 @@ def test_build_node_under_compile_rehearses_correct_on_the_cpu():
     assert "compared stack_mismatches = 0 (limit 0)" in out.stdout
     assert "in-window parca_agent_xla_compile_requests_total = 0" \
         in out.stdout
+    return line
+
+
+def test_build_node_under_compile_rehearses_correct_on_the_cpu():
+    """The harness's own steps at a size where the 4,096-slot dictionary
+    (2,048 ids) has to give ids back every window: warm-up ends (XLA is
+    asked for nothing new two windows running), every limit of the
+    comparison reads 0, nothing fails, and the traced line carries the
+    miss path's counters."""
+    line = _rehearse("build-node", "compile", pids=40, stacks=400,
+                     samples=2400, capacity=4096, seconds=8)
     metrics = line["metrics"]
     assert 300 <= metrics["misses_per_window"]["value"] <= 400
     assert metrics["dict_reclaimed_ids_per_window"]["value"] > 0
@@ -113,21 +125,8 @@ def test_node_streamed_under_rollout_rehearses_streamed_on_the_cpu():
     carries the feed thread's metrics: every window's new stacks are
     dispatched by the drain that first holds them and settled from the
     feed thread, and the rest of its rows the carry cache folds."""
-    out = subprocess.run(
-        [sys.executable, os.path.join(BENCH, "rehearse.py"),
-         "--config", "node-streamed", "--traffic", "rollout",
-         "--pids", "40", "--stacks", "1024", "--samples", "8000",
-         "--capacity", "16384", "--seconds", "5", "--trace", "1"],
-        env={**os.environ, "JAX_PLATFORMS": "cpu"}, capture_output=True,
-        text=True, timeout=540, cwd=REPO)
-    assert out.returncode == 0, out.stderr[-2000:]
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert line["correct"] is True and line["failed"] == 0
-    assert line["attempted"] >= 2 and line["rehearsal"] is True
-    assert line["device"]["platform"] == "cpu"
-    assert "compared stack_mismatches = 0 (limit 0)" in out.stdout
-    assert "in-window parca_agent_xla_compile_requests_total = 0" \
-        in out.stdout
+    line = _rehearse("node-streamed", "rollout", pids=40, stacks=1024,
+                     samples=8000, capacity=16384, seconds=5)
     metrics = line["metrics"]
     assert metrics["streamed_windows_per_window"]["value"] == 1.0
     assert metrics["stream_rows_fed_per_window"]["value"] > 0
@@ -149,10 +148,50 @@ def test_node_streamed_under_rollout_rehearses_streamed_on_the_cpu():
     assert "feed_probe_roofline" not in metrics     # a device number
 
 
+def test_firehose_streamed_under_rollout_rehearses_streamed_on_the_cpu():
+    """The DaemonSet's flags on the firehose deployment, through the
+    harness at a size whose first drain (8,000 samples) starts three
+    feed shapes above the floor: the run's first feed runs 8,192 down to
+    1,024, every window of the measured window is streamed and none
+    falls back, no feed is slow or compiles, no row reaches the sketch,
+    the comparison reads 0 on every number, and the traced line carries
+    the carry flush (one row a stack the cache folded: nearly all of the
+    window's, so more rows than the new stacks dispatched by far) and
+    the fixture's own stand-in for the sampler's drain."""
+    line = _rehearse("firehose-streamed", "rollout", pids=200, stacks=16384,
+                     samples=80000, capacity=65536, seconds=9)
+    metrics = line["metrics"]
+    assert metrics["streamed_windows_per_window"]["value"] == 1.0
+    assert metrics["stream_fallback_windows_per_window"]["value"] == 0.0
+    assert metrics["stream_feeds_slow_per_window"]["value"] == 0.0
+    assert metrics["sketch_rows_per_window"]["value"] == 0.0
+    fed = metrics["stream_rows_fed_per_window"]["value"]
+    assert 0 < fed == metrics["misses_per_window"]["value"]
+    assert 16384 - fed <= metrics["carry_flush_rows_per_window"]["value"] \
+        <= 16384 + fed
+    assert metrics["carry_matched_rows_per_window"]["value"] \
+        >= metrics["carry_flush_rows_per_window"]["value"]
+    assert metrics["close_ms.p50"]["value"] \
+        >= metrics["close_carry_flush_ms.p50"]["value"] > 0
+    assert metrics["drain_chunk_ms.p50"]["value"] > 0
+    # Every per-layer metric the cell lists that is no device number.
+    listed = {m["name"]: m for m in BENCHMARK["per_layer"]
+              if "firehose-streamed-rollout" in m.get("workloads", [])}
+    assert len(listed) >= 46
+    assert set(listed) >= {m["name"] for m in BENCHMARK["per_layer"]
+                           if "node-streamed-rollout"
+                           in m.get("workloads", [])}
+    for name, m in listed.items():
+        if m["source"] != "device_trace":
+            assert name in metrics, name
+
+
 @pytest.mark.parametrize("config, traffic, seed", [
     ("node-streamed", "rollout", "3700000007"),
     # Over the pool's pids: processes of this machine (node-live, below).
     ("node-live", "steady-live", "4200000011"),
+    # At the configuration's own size: 262,144 stacks, ~1.5 minutes.
+    ("firehose-streamed", "rollout", "4400000007"),
 ])
 def test_the_8_bit_control_is_not_correct(config, traffic, seed):
     """The plain reference in the program's place, its counts carried
@@ -161,7 +200,7 @@ def test_the_8_bit_control_is_not_correct(config, traffic, seed):
     out = subprocess.run(
         [sys.executable, os.path.join(BENCH, "control.py"),
          "--config", config, "--traffic", traffic, "--seeds", seed],
-        capture_output=True, text=True, timeout=240, cwd=REPO)
+        capture_output=True, text=True, timeout=600, cwd=REPO)
     assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-2000:]
     line = json.loads(out.stdout.strip().splitlines()[-1])
     assert line["cell"] == f"{config}-{traffic}" and line["bits"] == 8

@@ -128,6 +128,31 @@ def test_delta_misprediction_grows_then_falls_back():
     assert a.stats.get("delta_guard_trips", 0) == 0
 
 
+@pytest.mark.parametrize("n_fetch, touched, blocks", [
+    # A firehose-sized fetch (4,096 blocks): a streamed window touches a
+    # handful of blocks, one more every few windows; the buffer stays at
+    # the floor, 1/64 of the full fetch, until 2x the count passes it.
+    (1 << 19, 0, 64), (1 << 19, 4, 64), (1 << 19, 5, 64), (1 << 19, 9, 64),
+    (1 << 19, 32, 64), (1 << 19, 33, 128), (1 << 19, 1100, 0),
+    # A node-sized fetch (2,048 blocks) and a test-sized one (8).
+    (1 << 18, 2, 32), (1 << 18, 17, 64), (1 << 11, 1, 8), (1 << 10, 1, 0),
+])
+def test_the_delta_block_buffer_has_a_floor_its_count_does_not_move(
+        n_fetch, touched, blocks):
+    """The block buffer is a shape of the close program: sized by the
+    touched count alone it crossed a power of two whenever the count
+    crept past one (5 blocks: 8 -> 16), a compile on the window's path
+    each time. Under the floor (the full fetch's blocks over 64, at
+    least 8) the count moves no shape; over it the plan is 2x the count
+    as before, and 0 (a full fetch) once that is no saving."""
+    import jax.numpy as jnp
+
+    a = DictAggregator(capacity=1 << 12, overflow="raise", delta_fetch=True)
+    a._touch = jnp.zeros(1, jnp.int32)         # tracking on
+    a._prev_touched = touched
+    assert a._delta_plan(n_fetch) == blocks
+
+
 def test_empty_window_clears_stale_flip_and_delta_timings():
     snap = _snap(seed=31, rows=256, pids=4)
     a = DictAggregator(capacity=1 << 11, overflow="raise")

@@ -374,3 +374,78 @@ def test_feed_carry_fault_falls_back_per_drain_dispatch():
     # Rule exhausted + boundary re-arm: window 3 fully carried.
     assert d.stats["carry_hits"] == len(_snap(seed=47, rows=512, pids=8))
     assert faults.get().stats().get("feed.carry") == 1
+
+
+# -- the carry cache at a population's size -----------------------------------
+
+
+@pytest.mark.parametrize("n_keys, n_rows", [(1_024, 5_000),
+                                           (65_536, 100_000)])
+def test_a_carry_cache_answers_a_drain_as_a_dictionary_of_its_keys_does(
+        n_keys, n_rows):
+    """A carry cache of N keys, admitted over ten settles as a first
+    window's drains bring them, answers a drain of M rows as a Python
+    dictionary of the same keys does: a row is folded exactly when its
+    (h1, h2, h3) is a key of the cache, its weight lands on that key's
+    stack id, and a key that shares its h1 with an earlier one is never
+    admitted (h1 stays unique: such a stack keeps dispatching, exact
+    either way). The sorted arrays, the prefix index rebuilt by every
+    admit and the bucket walk are what a firehose-sized population
+    leans on ten times a window."""
+    rng = np.random.default_rng(n_keys)
+    keys = rng.integers(0, 1 << 32, (n_keys, 3), dtype=np.uint64).astype(
+        np.uint32)
+    # One key in 64 takes the h1 of the key before it.
+    twin = np.arange(1, n_keys, 64)
+    keys[twin, 0] = keys[twin - 1, 0]
+    agg = DictAggregator(capacity=1 << 10, carry=True)
+    ids_of: dict[tuple, int] = {}
+
+    def classify(h1, h2, h3):
+        # Every key offered is live in the host mirror; ids in the
+        # order the keys were first offered.
+        ids = np.array([ids_of.setdefault(k, len(ids_of)) for k in
+                        zip(h1.tolist(), h2.tolist(), h3.tolist())], np.int64)
+        return ids, None, False
+
+    agg._classify_keys_vec = classify
+    want: dict[tuple, int] = {}                # the dictionary: key -> sid
+    seen_h1: set = set()
+    for part in np.array_split(rng.permutation(n_keys), 10):
+        batch = keys[np.concatenate([part, part[:7]])]   # with repeats
+        agg._carry_admit(batch[:, 0], batch[:, 1], batch[:, 2])
+        fresh: dict[int, tuple] = {}           # per h1 its first key
+        for k in map(tuple, batch.tolist()):
+            if k[0] not in seen_h1:
+                fresh.setdefault(k[0], k)
+        for k in fresh.values():
+            want[k] = ids_of[k]
+        seen_h1.update(fresh)
+    assert len(agg._carry_h1) == len(want) == agg.stats["carry_entries"]
+    assert np.all(np.diff(agg._carry_h1.astype(np.int64)) > 0)
+    got = dict(zip(zip(agg._carry_h1.tolist(), agg._carry_h2.tolist(),
+                       agg._carry_h3.tolist()), agg._carry_sid.tolist()))
+    assert got == want
+
+    # The drain: rows of the cache's keys (hot ones many times), of the
+    # twins it refused, and of keys it never met, some on a cached h1.
+    rows = keys[rng.integers(0, n_keys, n_rows)]
+    strangers = rng.integers(0, 1 << 32, (n_rows // 10, 3),
+                             dtype=np.uint64).astype(np.uint32)
+    strangers[::2, 0] = keys[rng.integers(0, n_keys, len(strangers[::2])), 0]
+    rows = rng.permutation(np.concatenate([rows, strangers]))
+    w64 = rng.integers(1, 50, len(rows)).astype(np.int64)
+    keep = agg._carry_match_rows(rows[:, 0], rows[:, 1], rows[:, 2], w64)
+    folded = np.array([tuple(r) in want for r in rows.tolist()])
+    assert 0 < folded.sum() < len(rows)
+    assert np.array_equal(keep, ~folded)
+    by_sid = np.zeros(len(ids_of), np.int64)
+    for r, w in zip(rows[folded].tolist(), w64[folded].tolist()):
+        by_sid[want[tuple(r)]] += w
+    sids, cnts = agg._carry_take()
+    flushed = np.zeros(len(ids_of), np.int64)
+    flushed[sids] = cnts
+    assert np.array_equal(flushed, by_sid)
+    assert len(sids) == len(np.unique(sids)) == int((by_sid > 0).sum())
+    assert agg.stats["carry_hits"] == int(folded.sum())
+    assert agg._carry_open_mass == 0 and not agg._carry_w.any()

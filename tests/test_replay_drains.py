@@ -298,11 +298,33 @@ def test_a_feed_above_the_pad_floor_keeps_its_power_of_two(pads):
     assert pads[0] == (2048, (4, 2048))
 
 
+@pytest.mark.parametrize("carry", [True, False])
+def test_a_carrying_runs_first_feed_runs_every_shape_under_its_own(
+        carry, pads):
+    """With the carry cache every later feed is smaller than a run's
+    first, by how much follows the draw: the first feed (the one the
+    feeder gives its long budget) runs every power of two from its own
+    shape down to the floor, each as an all-padding batch that counts
+    nothing, so no later feed at or under its size compiles. Without
+    the cache (one-shot windows) a feed runs its own shape alone."""
+    agg = DictAggregator(capacity=1 << 15, carry=carry)
+    snap = _snap(seed=5, rows=5000, pids=6)
+    agg.feed(snap)
+    assert [p[0] for p in pads] == ([8192, 4096, 2048, 1024] if carry
+                                   else [8192])
+    assert all(shape == (4, n) for n, shape in pads)
+    n_first = len(pads)
+    agg.feed(snap)                             # a run meets them once
+    assert int(agg.close_window().sum()) == 2 * snap.total_samples()
+    assert len(pads) == (n_first if carry else n_first + 1)
+
+
 def test_a_runs_feeds_use_the_shapes_its_first_window_compiled(pads):
     """Under turnover every window brings stacks the dictionary has not
-    met, a different number in every drain: the first window's drains
-    walk down to the floor as the carry cache fills, and no later feed
-    asks for a shape the first window did not."""
+    met, a different number in every drain: the first window's first
+    feed runs the shapes down to the floor, its drains fall through them
+    as the carry cache fills, and no later feed asks for a shape the
+    first window did not."""
     snaps, _raw = turnover_windows(6, pids=40, stacks=6000, turnover=0.05)
     prof, feeder, agg, sink = streamed_profiler(snaps)
     run_windows(prof, sink, 1)
@@ -314,6 +336,51 @@ def test_a_runs_feeds_use_the_shapes_its_first_window_compiled(pads):
     assert len(later) >= 5                     # every window dispatched
     assert {p[0] for p in later} == {dict_mod._FEED_PAD_MIN}
     assert feeder.stats["windows_streamed"] == 6
+
+
+def _keys(w):
+    """A raw window's rows as (pid, stack) keys."""
+    return [(int(p), s[:int(u) + int(k)].tobytes()) for p, s, u, k
+            in zip(w.pids, w.stacks, w.user_len, w.kernel_len)]
+
+
+def test_every_live_key_is_in_the_carry_cache_by_the_end_of_its_window():
+    """What the carry cache holds and what its flush gives the close,
+    window by window under turnover, against a count made from the
+    windows alone. A stack's first drain dispatches it and the settle
+    admits it before the next drain is matched, so by the end of a
+    window every live (pid, stack) key is in the cache (one entry a
+    stack id); a drain row is matched unless it is a new stack's first,
+    and the flush holds one row for every stack with a matched row: the
+    stacks met before, and the new ones that more than one drain
+    held."""
+    rec = FlightRecorder()
+    trace_mod.install(rec)
+    snaps, raw = turnover_windows(5, pids=40, stacks=6000, turnover=0.05)
+    prof, feeder, agg, sink = streamed_profiler(snaps, recorder=rec,
+                                                capacity=1 << 15)
+    run_windows(prof, sink, 5)
+    met: set = set()
+    flushed = 0
+    for w, snap, t in zip(raw, snaps, rec.traces()):
+        shares = drain_shares(snap.counts, 10) > 0
+        new = np.array([k not in met for k in _keys(w)])
+        met.update(_keys(w))
+        assert t["meta"]["carry_matched_rows"] \
+            == int(shares.sum()) - int(new.sum())
+        want = int((~new).sum()) + int((shares[:, new].sum(axis=0) > 1).sum())
+        assert t["meta"]["carry_flush_rows"] == want
+        flushed += want
+        by_stage = {s["stage"]: s for s in t["spans"]}
+        assert by_stage["close_carry_flush"]["parent"] \
+            == by_stage["close"]["id"]
+    assert new.any() and not new.all()         # the last window churned
+    assert agg.stats["carry_flush_rows"] == flushed
+    assert agg.stats["carry_entries"] == len(agg._carry_h1) \
+        == agg._next_id == len(met)
+    assert agg.stats.get("sketch_rows", 0) == 0
+    assert feeder.stats["windows_streamed"] == 5
+    assert feeder.stats["windows_fallback"] == 0
 
 
 # -- through the profiler: spans, meta, counters ------------------------------
@@ -536,8 +603,21 @@ def test_one_series_a_count_on_metrics():
         == agg.stats["rows_fed"] > 0
     assert sample("parca_agent_dict_carry_matched_rows_total",
                   '{profiler="cpu"}') == agg.stats["carry_hits"] > 0
+    # What the close applied of the cache's fold, the rows handed to the
+    # sketch, and the feed watchdog's two counts.
+    assert sample("parca_agent_dict_carry_flush_rows_total",
+                  '{profiler="cpu"}') == agg.stats["carry_flush_rows"] > 0
+    assert sample("parca_agent_dict_sketch_rows_total", '{profiler="cpu"}') \
+        == agg.stats.get("sketch_rows", 0)
+    assert sample("parca_agent_streaming_feeds_slow_total") == 0
+    assert sample("parca_agent_streaming_feed_compiles_total") \
+        == feeder.stats["feed_compiles"]
     for name in ("parca_agent_dict_rows_fed_total",
                  "parca_agent_dict_carry_matched_rows_total",
+                 "parca_agent_dict_carry_flush_rows_total",
+                 "parca_agent_dict_sketch_rows_total",
+                 "parca_agent_streaming_feeds_slow_total",
+                 "parca_agent_streaming_feed_compiles_total",
                  "parca_agent_streaming_windows_streamed_total"):
         assert names.count(name) == 1
 

@@ -578,3 +578,97 @@ def test_slow_encode_does_not_trip_the_device_watchdog():
     total = sum(sum(v[0] for _, v, _ in parse_pprof(b).samples)
                 for _, b in w.got)
     assert total == snap.total_samples()
+
+
+@pytest.mark.parametrize("compiles", [True, False],
+                         ids=["asks_xla_for_a_program", "asks_for_nothing"])
+def test_a_later_feed_gets_the_long_budget_only_while_it_compiles(compiles):
+    """A feed after a run's first that meets a shape the first did not
+    compiles on the feed thread: XLA is asked for a program inside it
+    (the stub raises the event JAX raises on the asking thread as a
+    compile request begins, and sleeps in the compile's place), so the
+    watchdog gives it the first feed's long budget and streaming stays
+    on. The same slowness with no request is a hang: it trips at the
+    short timeout, as ever."""
+    import time
+
+    from jax import monitoring
+
+    snap = _snap(seed=21, n=60, pids=2)
+    slow = {"on": False}
+
+    class Slow(DictAggregator):
+        def feed(self, *a, **kw):
+            if slow["on"]:
+                if compiles:
+                    monitoring.record_event(
+                        "/jax/compilation_cache/compile_requests_use_cache")
+                time.sleep(0.8)
+            return super().feed(*a, **kw)
+
+    agg = Slow(capacity=1 << 10)
+    feeder = StreamingWindowFeeder(agg, CacheSource(FakeMaps(), FakeObjs()),
+                                   feed_timeout_s=0.2,
+                                   first_feed_timeout_s=20.0)
+    feeder.on_drain(_cols(snap, 0, 30))        # the run's first feed
+    assert not feeder.disabled
+    before = dict(feeder.stats)
+    slow["on"] = True
+    t0 = time.monotonic()
+    feeder.on_drain(_cols(snap, 30, 60))
+    took = time.monotonic() - t0
+    if compiles:
+        assert not feeder.disabled and took >= 0.8
+        assert feeder.stats["drains_fed"] == 2
+        assert feeder.stats["feed_compiles"] == before["feed_compiles"] + 1
+        # 0.8 s of a 20 s budget: the watchdog had room.
+        assert feeder.stats["feeds_slow"] == before["feeds_slow"]
+        counts = feeder.take_window_if_complete(snap)
+        assert int(counts.sum()) == snap.total_samples()
+    else:
+        assert feeder.disabled and took < 0.8
+        assert feeder.device_blocked()
+        assert feeder.stats["feed_compiles"] == before["feed_compiles"]
+        assert feeder.stats["feeds_slow"] == before["feeds_slow"] + 1
+        for _ in range(100):                   # let the abandoned feed end
+            if not feeder.device_blocked():
+                break
+            time.sleep(0.05)
+        assert not feeder.device_blocked()
+    m = feeder.metrics()
+    assert m["parca_agent_streaming_feed_compiles_total"] \
+        == feeder.stats["feed_compiles"]
+    assert m["parca_agent_streaming_feeds_slow_total"] \
+        == feeder.stats["feeds_slow"]
+    assert "parca_agent_streaming_feeds_slow" not in m
+    assert "parca_agent_streaming_feed_compiles" not in m
+
+
+def test_compile_requests_are_counted_for_the_asking_thread_alone():
+    """``compile_requests_here`` counts what its own thread asks XLA
+    for, while the block is open, and nothing another thread asks."""
+    import threading
+
+    from jax import monitoring
+
+    from parca_agent_tpu.runtime import device_telemetry
+
+    event = "/jax/compilation_cache/compile_requests_use_cache"
+    mine, theirs = [0], [0]
+    with device_telemetry.compile_requests_here(mine):
+        monitoring.record_event(event)
+        t = threading.Thread(target=monitoring.record_event, args=(event,))
+        t.start()
+        t.join()
+
+        def other():
+            with device_telemetry.compile_requests_here(theirs):
+                monitoring.record_event(event)
+                monitoring.record_event("/jax/compilation_cache/cache_hits")
+
+        t = threading.Thread(target=other)
+        t.start()
+        t.join()
+        assert (mine, theirs) == ([1], [1])
+    monitoring.record_event(event)             # the block is closed
+    assert mine == [1]
