@@ -331,7 +331,9 @@ class StaticsStore:
         dicts, location lists); CPython's gen-2 collector goes quadratic
         over exactly that shape, so collection is paused for the loop
         (restored in finally) — the profiler's own GC stewardship
-        freezes the adopted state right after startup anyway
+        freezes the adopted state with its first boundary collection
+        anyway: after the first window's ship on the encode worker, or
+        at the end of the first iteration without a pipeline
         (profiler/cpu.py _manage_gc)."""
         import gc
 

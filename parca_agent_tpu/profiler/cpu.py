@@ -16,6 +16,7 @@ None while running.
 from __future__ import annotations
 
 import dataclasses
+import gc
 import threading
 import time
 from typing import Callable, Protocol
@@ -65,6 +66,13 @@ class ProfilerMetrics:
     # The loop's wait between windows, measured: how much later than
     # asked the next window began, summed over the windows of run().
     loop_overshoot_seconds_total: float = 0.0
+    # The boundary collection (manage_gc, _collect_gc): wall time inside
+    # it, how many ran on the encode worker after their window's ship
+    # and how many on the capture loop, and the objects they collected.
+    gc_collect_seconds_total: float = 0.0
+    gc_collections_worker_total: int = 0
+    gc_collections_loop_total: int = 0
+    gc_collected_objects_total: int = 0
     # What the ship's gzip did (agent/writer.py): static pieces spliced
     # from the encoder's cache or built anew, bytes that went through
     # deflate, and splices that fell back to a plain gzip.compress.
@@ -240,7 +248,12 @@ class CPUProfiler:
                 # hook keeps one definition of "safe to read off-thread".
                 sink_capture=(self._rollup_capture
                               if sinks is not None
-                              and sinks.has_secondary else None))
+                              and sinks.has_secondary else None),
+                # A window handed over here is collected where it ends:
+                # on the worker, after its ship and the hooks above
+                # (_manage_gc).
+                after_window=((lambda: self._collect_gc("worker"))
+                              if manage_gc else None))
         else:
             if statics_store is not None:
                 _log.warn("statics snapshotting needs the encode pipeline; "
@@ -344,6 +357,13 @@ class CPUProfiler:
         # collects): only the process owner (the agent CLI) should turn
         # this on; embedders keep CPython's default scheduler.
         self._manage_gc_enabled = manage_gc
+        # The collector's state is written by the capture thread (the
+        # loop arm, the disable, the restore) and by the encode worker
+        # (a pipelined window's collection): one lock, held through a
+        # collection, so a restore never lands inside one.
+        self._gc_mu = threading.Lock()
+        # Did this iteration's window go to the encode pipeline.
+        self._window_piped = False
         # Optional tee of each window's snapshot (the fleet merger feeds
         # on it); failures there must not fail the iteration.
         self._window_sink = window_sink
@@ -534,6 +554,7 @@ class CPUProfiler:
     def run_iteration(self) -> bool:
         """Returns False when the source is exhausted."""
         t_iter0 = time.perf_counter()
+        self._window_piped = False
         tr = (self._recorder.begin() if self._recorder is not None
               else NULL_TRACE)
         if self._loop_waited is not None:
@@ -672,7 +693,7 @@ class CPUProfiler:
         # the window's whole non-idle cost on this thread; off-thread
         # kernel seconds are folded in by the telemetry layer itself.
         dtel.tick_window(time.perf_counter() - t_iter0)
-        self._manage_gc(self.metrics.attempts_total)
+        self._manage_gc()
         if self._on_iteration is not None:
             self._on_iteration(self.metrics.attempts_total)
         return True
@@ -681,49 +702,86 @@ class CPUProfiler:
     # mirror holds millions of long-lived ones (stack-key tuples, per-id
     # location lists), so an automatic pass costs hundreds of ms and can
     # land in the middle of a window close (the Go reference never has
-    # this problem — its GC is concurrent). Policy: after the first
-    # window, freeze the warm state into the permanent generation
-    # (excluded from all collection) and DISABLE the automatic scheduler;
-    # instead collect explicitly here — a window boundary, nothing
-    # latency-sensitive in flight — where the tracked set is only what
-    # this window allocated plus registry growth since the last refreeze.
-    # Every _GC_REFREEZE windows (~1 h), unfreeze + full-collect +
-    # refreeze so garbage that slipped into the frozen set is reclaimed.
+    # this problem — its GC is concurrent). Policy: DISABLE the automatic
+    # scheduler at the end of the first iteration and collect explicitly,
+    # once a window, where that window ends. A full collection is one C
+    # call that keeps the interpreter lock from its first object to its
+    # last, so "where the window ends" is where nothing of it is in
+    # flight any more. For a window handed to the encode pipeline (path
+    # "pipeline") that is the encode worker, after the window's ship and
+    # after-ship hooks (EncodePipeline after_window): at the end of
+    # run_iteration its encode has only just been handed over, and a
+    # collection there is time the prepared window waits for the lock
+    # (the encode_wait span) or its encode stands still for. For every
+    # other window (no pipeline, pipeline disabled, inline, scalar
+    # fallback or backpressure, an iteration error) encode and ship are
+    # over when run_iteration ends, and it is collected there. The run's
+    # first collection also freezes the warm state into the permanent
+    # generation (excluded from all collection): with a pipeline that is
+    # after the first window's cold encode and ship, so the templates,
+    # the per-pid statics, the label sets and the gzip pieces are in it,
+    # and a later collection walks only what its window allocated plus
+    # registry growth since the last refreeze. Every _GC_REFREEZE
+    # collections (~1 h), unfreeze + full-collect + refreeze so garbage
+    # that slipped into the frozen set is reclaimed.
     _GC_REFREEZE = 360
 
-    _gc_modified = False
+    _gc_modified = False    # the collector is disabled and/or frozen by us
+    _gc_closed = False      # run() ended: a late worker leaves it alone
+    _gc_collections = 0     # since this run's first collect-and-freeze
 
     def _restore_gc(self) -> None:
         """Undo the stewardship on shutdown: the process may outlive the
         profiler (embedding tests, supervised restarts) and must get the
-        default collector back."""
-        if not self._gc_modified:
-            return
-        import gc
+        default collector back. After a crash the pipeline's worker may
+        still be on a window: its collection then finds the run closed."""
+        with self._gc_mu:
+            self._gc_closed = True
+            self._gc_collections = 0
+            if not self._gc_modified:
+                return
+            self._gc_modified = False
+            gc.unfreeze()
+            gc.enable()
 
-        self._gc_modified = False
-        gc.unfreeze()
-        gc.enable()
-
-    def _manage_gc(self, window: int) -> None:
+    def _manage_gc(self) -> None:
+        """End of an iteration, capture thread: collect for a window that
+        ended here, and keep the automatic scheduler off."""
         if not self._manage_gc_enabled:
             return
-        import gc
+        if not self._window_piped:
+            self._collect_gc("loop")
+        with self._gc_mu:
+            if not self._gc_closed:
+                # From the end of the first managed iteration of THIS
+                # run (not of the process): a supervised restart
+                # re-enters run() after the crash path restored the
+                # default collector, and re-arms here.
+                gc.disable()
+                self._gc_modified = True
 
-        if not self._gc_modified:
-            # First managed window of THIS run (not of the process): a
-            # supervised restart re-enters run() after the crash path
-            # restored the default collector, and must re-arm here.
-            gc.collect()
-            gc.freeze()
-            gc.disable()
-            self._gc_modified = True
-        elif window % self._GC_REFREEZE == 0:
-            gc.unfreeze()
-            gc.collect()
-            gc.freeze()
-        else:
-            gc.collect()
+    def _collect_gc(self, where: str) -> None:
+        """One boundary collection: on the capture thread ("loop") or on
+        the encode worker after a pipelined window's ship ("worker")."""
+        with self._gc_mu:
+            if self._gc_closed:
+                return
+            t0 = time.perf_counter()
+            refreeze = self._gc_collections % self._GC_REFREEZE == 0
+            if refreeze and self._gc_collections:
+                gc.unfreeze()
+            n = gc.collect()
+            if refreeze:
+                gc.freeze()
+                self._gc_modified = True
+            self._gc_collections += 1
+            m = self.metrics
+            m.gc_collect_seconds_total += time.perf_counter() - t0
+            m.gc_collected_objects_total += n
+            if where == "worker":
+                m.gc_collections_worker_total += 1
+            else:
+                m.gc_collections_loop_total += 1
 
     def _labels_for(self, pid: int) -> dict | None:
         """Label set for a pid; None when relabeling dropped the target."""
@@ -1029,6 +1087,7 @@ class CPUProfiler:
             return None
         if n is not None:
             tr.annotate(path="pipeline")
+            self._window_piped = True
             return n
         # Backpressure: the worker is still encoding the previous window.
         # The encoder's state is its — this window cannot ride it inline,
@@ -1046,6 +1105,7 @@ class CPUProfiler:
                     "encode pipeline busy past its flush bound and no "
                     "fallback aggregator is configured")
             tr.annotate(path="pipeline")
+            self._window_piped = True
             return n
         _log.warn("encode pipeline busy at window close; scalar fallback "
                   "for this window")
@@ -1120,6 +1180,7 @@ class CPUProfiler:
         # restarted by the run group, so a successful re-entry clears the
         # previous crash record.
         self.crashed = None
+        self._gc_closed = False
         try:
             while not self._stop.is_set():
                 t0 = time.monotonic()
@@ -1147,6 +1208,10 @@ class CPUProfiler:
             # ALWAYS restored — the process may outlive a crashed,
             # unsupervised profiler, and must not inherit a disabled
             # collector; a supervised re-entry re-arms it in _manage_gc.
+            # After the pipeline's close has joined the worker, so a
+            # clean exit restores behind the last window's collection;
+            # after a crash the worker may still be on a window, and
+            # finds the run closed (_collect_gc).
             if self.crashed is None and self._pipeline is not None:
                 # Clean shutdown flushes the in-flight window: everything
                 # aggregated gets shipped before the actor exits.

@@ -34,6 +34,20 @@ This pipeline moves encode + ship onto a dedicated worker thread:
     the registry + statics state so a restart adopts instead of
     rebuilding. Worker-thread-only by design — the snapshot reads the
     same encoder state prebuilds do, and must never stall capture.
+  * What a shipped window leaves behind is disposed of here, before the
+    worker counts as idle again (_run's finally): the job's prepared
+    arrays and, through the fallback, the window's snapshot are let go,
+    then after_window runs — the profiler's boundary collection
+    (profiler/cpu.py _manage_gc: one explicit gc.collect() a window, the
+    automatic scheduler being off), which so takes the garbage of the
+    ship, the rollup and the snapshot with it. A full collection keeps
+    the interpreter lock from its first object to its last; at the end
+    of the capture thread's iteration this worker has just been notified
+    of the window and needs that lock to begin, so a collection there
+    falls whole into the window's encode_wait or stops its encode. Here
+    the window is out of the agent, the next close is as far away as it
+    ever is, and _state stays "encode" until the hook returns, so a
+    hand-off never meets a collection halfway.
   * close() flushes the in-flight window before stopping the worker, so
     a draining agent ships everything it aggregated.
 """
@@ -68,7 +82,8 @@ class EncodePipeline:
     def __init__(self, encoder, ship, ship_views: bool = True,
                  name: str = THREAD_NAME, snapshot=None,
                  snapshot_every: int = 0, rollup=None,
-                 rollup_capture=None, sink_capture=None):
+                 rollup_capture=None, sink_capture=None,
+                 after_window=None):
         self._enc = encoder
         self._ship = ship
         self._views = ship_views
@@ -100,6 +115,12 @@ class EncodePipeline:
         # (the agent just stays cold-restartable one interval longer).
         self._snapshot = snapshot
         self._snapshot_every = snapshot_every
+        # The last thing this worker does for a window it was handed,
+        # whichever way the window went (shipped, ship failed, scalar
+        # fallback after a worker death): a zero-arg callable, the
+        # profiler's boundary collection. The worker still counts as
+        # busy while it runs. Errors are counted, never fatal.
+        self._after_window = after_window
         self._cond = threading.Condition()
         self._window = None   # pending (prep, ctx, fallback, trace, t) hand-off
         self._prebuild = None        # latest coalesced (period_ns, budget_s)
@@ -130,6 +151,7 @@ class EncodePipeline:
             "rollup_errors": 0,
             "last_rollup_s": 0.0,
             "sink_capture_errors": 0,
+            "after_window_errors": 0,
         }
 
     # -- profiler-thread API -------------------------------------------------
@@ -332,15 +354,28 @@ class EncodePipeline:
             except Exception as e:  # noqa: BLE001 - surfaced via disable
                 if job[0] == "window":
                     self._fail_window(e, job[1][2], job[1][3])
-                    with self._cond:
-                        self._state = "idle"
-                        self._cond.notify_all()
-                    return  # disabled: the worker's work here is done
+                    return  # disabled: the finally ends the worker's work
                 # A prebuild failure is non-fatal: staleness guards still
                 # trip, the next pass (or encode) retries the build.
                 _log.warn("statics prebuild failed on the encode worker",
                           error=repr(e))
             finally:
+                # Let go of the window while still busy with it: the job
+                # holds its prepared arrays and, through the fallback,
+                # its snapshot (268 MB of mmapped stacks at 262,144
+                # rows). Left in this local they would be freed by the
+                # NEXT window's pick-up above, under the lock, inside
+                # that window's encode_wait: ~25 ms of munmap on a host
+                # where that is dear.
+                after = self._after_window if job[0] == "window" else None
+                job = None
+                if after is not None:
+                    try:
+                        after()
+                    except Exception as e:  # noqa: BLE001 - best-effort
+                        self.stats["after_window_errors"] += 1
+                        _log.warn("after-window hook failed on the encode "
+                                  "worker", error=repr(e))
                 with self._cond:
                     if self._state != "idle":
                         self._state = "idle"

@@ -251,6 +251,18 @@ def render_metrics(profilers, batch_client=None, extra: dict | None = None,
         # summed (a window's own is loop_overshoot_s on its meta).
         emit("parca_agent_profiler_loop_overshoot_seconds_total",
              round(p.metrics.loop_overshoot_seconds_total, 6), lab)
+        # The boundary collection (manage_gc): wall time inside it, which
+        # arm ran it (the encode worker after the window's ship, or the
+        # capture loop for a window that ended there), what it collected.
+        emit("parca_agent_profiler_gc_collect_seconds_total",
+             round(p.metrics.gc_collect_seconds_total, 6), lab)
+        emit("parca_agent_profiler_gc_collections_total",
+             p.metrics.gc_collections_worker_total,
+             {**lab, "where": "worker"})
+        emit("parca_agent_profiler_gc_collections_total",
+             p.metrics.gc_collections_loop_total, {**lab, "where": "loop"})
+        emit("parca_agent_profiler_gc_collected_objects_total",
+             p.metrics.gc_collected_objects_total, lab)
         # The ship's gzip (docs/perf.md "the spliced gzip member"): a
         # steady window reuses one static piece a profile and deflates
         # only what changed; `built` rises when registries grow, and

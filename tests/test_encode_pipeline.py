@@ -592,3 +592,404 @@ def test_streaming_feeder_routes_prebuild_through_pipeline():
     assert feeder.stats["drains_fed"] == 2
     assert len(calls) == 2
     assert enc.statics_backlog(feeder._prebuild_period) > 0
+
+
+# -- what a shipped window leaves behind is disposed of on the worker ---------
+
+
+@pytest.mark.parametrize("how", ["shipped", "ship-failed", "worker-death"])
+def test_after_window_is_the_last_thing_the_worker_does_for_a_window(how):
+    """after_window runs once per window handed over, whichever way the
+    window went, behind everything else the worker does for it and while
+    the worker still counts as busy; a hook that raises is counted and
+    the worker goes on to the next window."""
+    snap = _snap(seed=22)
+    agg = DictAggregator(capacity=1 << 12)
+    counts = np.asarray(agg.window_counts(snap))
+    events = []
+
+    def ship(out, prep):
+        events.append("ship")
+        if how == "ship-failed":
+            raise OSError("store down")
+
+    def after_window():
+        events.append(("after", threading.current_thread().name,
+                       pipe._state))
+        raise RuntimeError("hook bug")
+
+    enc = WindowEncoder(agg)
+    pipe = EncodePipeline(
+        enc, ship=ship, rollup=lambda prep, ctx: events.append("rollup"),
+        snapshot=lambda period_ns: events.append("snapshot"),
+        snapshot_every=1, after_window=after_window)
+    if how == "worker-death":
+        def boom(prep, views=False):
+            raise RuntimeError("encoder bug")
+
+        enc.encode_prepared = boom
+    after = ("after", "encode-pipeline", "encode")
+    want = {"shipped": ["ship", "rollup", "snapshot", after],
+            "ship-failed": ["ship", after],
+            "worker-death": ["fallback", after]}[how]
+    try:
+        for n in (1, 2):
+            del events[:]
+            assert pipe.submit(
+                counts, snap.time_ns, snap.window_ns, snap.period_ns,
+                fallback=lambda: events.append("fallback")) is not None
+            assert pipe.quiesce(30)
+            assert events == want
+            assert pipe.stats["after_window_errors"] == n
+            if how == "worker-death":
+                assert pipe.disabled
+                break
+            assert not pipe.disabled
+    finally:
+        assert pipe.close()
+
+
+def test_worker_lets_go_of_a_window_when_it_is_done_with_it():
+    """What a handed-off window holds (its prepared arrays; through the
+    fallback its snapshot, hundreds of MB at firehose size) is freed by
+    the worker while it still counts as busy with that window and before
+    after_window runs, not by the next window's pick-up inside that
+    window's encode_wait."""
+    import weakref
+
+    class Snapshot:
+        pass
+
+    snap = _snap(seed=23)
+    agg = DictAggregator(capacity=1 << 12)
+    counts = agg.window_counts(snap)
+    events = []
+    dropped = threading.Event()   # the test's own reference is gone
+    pipe = EncodePipeline(WindowEncoder(agg),
+                          ship=lambda out, prep: dropped.wait(30),
+                          after_window=lambda: events.append("after"))
+    held = Snapshot()
+    weakref.finalize(held, lambda: events.append(
+        (threading.current_thread().name, pipe._state)))
+    try:
+        assert pipe.submit(counts, snap.time_ns, snap.window_ns,
+                           snap.period_ns,
+                           fallback=lambda s=held: None) is not None
+        del held
+        dropped.set()
+        assert pipe.quiesce(30)
+        assert events == [("encode-pipeline", "encode"), "after"]
+    finally:
+        assert pipe.close()
+
+
+class _GcWatch:
+    """A manage_gc profiler over a pipeline whose encode blocks until
+    released, with gc.collect, the ship and the after-ship hooks
+    recording (what, thread name) in the order they were entered."""
+
+    def __init__(self, monkeypatch, n_windows=3, **kw):
+        import gc
+
+        self.events = []
+        self.gate = threading.Event()
+        snap = dataclasses.replace(_snap(seed=21), window_ns=50_000_000)
+        kw.setdefault("encode_pipeline", True)
+        self.p = CPUProfiler(
+            source=ReplaySource([snap] * n_windows),
+            aggregator=DictAggregator(capacity=1 << 12),
+            fallback_aggregator=CPUAggregator(), profile_writer=Collect(),
+            fast_encode=True, duration_s=0.01, manage_gc=True, **kw)
+        real_collect = gc.collect
+
+        def collect(*a):
+            self.note("collect")
+            return real_collect(*a)
+
+        monkeypatch.setattr(gc, "collect", collect)
+        real_encode = self.p._encoder.encode_prepared
+
+        def encode_prepared(prep, views=False):
+            self.note("encode")
+            assert self.gate.wait(30)
+            out = real_encode(prep, views=views)
+            self.note("encode_end")
+            return out
+
+        self.p._encoder.encode_prepared = encode_prepared
+        pipe = self.p._pipeline
+        if pipe is not None:
+            real_ship = pipe._ship
+
+            def ship(out, prep):
+                real_ship(out, prep)
+                self.note("ship_end")
+
+            pipe._ship = ship
+            pipe._rollup = lambda prep, ctx: self.note("rollup")
+            pipe._snapshot = lambda period_ns: self.note("snapshot")
+            pipe._snapshot_every = 1
+
+    def note(self, what):
+        self.events.append((what, threading.current_thread().name))
+
+    def whats(self):
+        return [w for w, _ in self.events]
+
+    def close(self):
+        import gc
+
+        self.gate.set()
+        if self.p._pipeline is not None:
+            self.p._pipeline.close()
+        self.p._restore_gc()
+        assert gc.isenabled() and gc.get_freeze_count() == 0
+
+
+def test_gc_collects_on_the_worker_after_the_ship_and_its_hooks(monkeypatch):
+    """No thread enters gc.collect() between a window's hand-off and the
+    end of its encode; the worker enters one after the ship, the rollup
+    and the snapshot, as the last thing it does for the window."""
+    w = _GcWatch(monkeypatch)
+    try:
+        assert threading.current_thread().name != "encode-pipeline"
+        for _ in range(2):           # the cold first window, a warm one
+            del w.events[:]
+            w.gate.clear()
+            assert w.p.run_iteration()  # handed off; the worker blocked
+            t_end = time.monotonic() + 10
+            while "encode" not in w.whats() and time.monotonic() < t_end:
+                time.sleep(0.005)
+            assert w.whats() == ["encode"]   # nobody collected
+            w.gate.set()
+            assert w.p._pipeline.quiesce(30)
+            assert w.events == [(x, "encode-pipeline") for x in (
+                "encode", "encode_end", "ship_end", "rollup", "snapshot",
+                "collect")]
+        m = w.p.metrics
+        assert (m.gc_collections_worker_total,
+                m.gc_collections_loop_total) == (2, 0)
+        assert m.gc_collect_seconds_total > 0
+    finally:
+        w.close()
+
+
+def test_first_freeze_takes_in_what_the_first_windows_ship_built(monkeypatch):
+    """The run's first collect-and-freeze falls after the first window's
+    encode and ship: a tracked object the ship creates is in the
+    permanent generation once that window is over, and nothing was
+    frozen while the window was still on the worker."""
+    import gc
+
+    w = _GcWatch(monkeypatch)
+    made = []
+    real_ship = w.p._pipeline._ship
+
+    def ship(out, prep):
+        real_ship(out, prep)
+        made.append({"static": ["piece"]})     # a tracked container
+
+    w.p._pipeline._ship = ship
+    try:
+        assert gc.get_freeze_count() == 0
+        assert w.p.run_iteration()
+        assert not gc.isenabled()      # the scheduler is off already,
+        assert gc.get_freeze_count() == 0   # the freeze waits for the ship
+        w.gate.set()
+        assert w.p._pipeline.quiesce(30)
+        assert gc.get_freeze_count() > 0
+        # gc.get_objects() leaves the permanent generation out.
+        assert made and not any(o is made[0] for o in gc.get_objects())
+        frozen = gc.get_freeze_count()
+        assert w.p.run_iteration()     # a steady window: collected, and
+        assert w.p._pipeline.quiesce(30)    # nothing more frozen
+        assert gc.get_freeze_count() <= frozen  # (frozen ones may die)
+        assert w.p.metrics.gc_collections_worker_total == 2
+    finally:
+        w.close()
+
+
+@pytest.mark.parametrize("how", ["backpressure", "inline", "worker-death"])
+def test_gc_collects_at_the_end_of_the_iteration_off_the_pipeline(
+        monkeypatch, how):
+    """A window that did not go through the pipeline is collected where
+    it ends: on the capture thread, at the end of run_iteration."""
+    w = _GcWatch(monkeypatch, encode_pipeline=(how != "inline"))
+    me = threading.current_thread().name
+    p, m = w.p, w.p.metrics
+    try:
+        if how == "backpressure":
+            w.gate.set()
+            assert p.run_iteration()       # window 1, piped
+            assert p._pipeline.flush(30)
+            w.gate.clear()
+            assert p.run_iteration()       # window 2 piped, worker blocked
+            del w.events[:]
+            assert p.run_iteration()       # window 3 refused -> scalar
+            assert m.encode_backpressure_total == 1
+            assert ("collect", me) in w.events
+            assert ("collect", "encode-pipeline") not in w.events
+            assert (m.gc_collections_worker_total,
+                    m.gc_collections_loop_total) == (1, 1)
+        elif how == "inline":
+            w.gate.set()
+            assert p.run_iteration() and p.run_iteration()
+            assert [e for e in w.events if e[0] == "collect"] \
+                == [("collect", me)] * 2
+            assert (m.gc_collections_worker_total,
+                    m.gc_collections_loop_total) == (0, 2)
+        else:
+            real = p._encoder.encode_prepared
+            boom = {"on": True}
+
+            def maybe_boom(prep, views=False):
+                if boom["on"]:
+                    raise RuntimeError("encoder bug")
+                return real(prep, views=views)
+
+            p._encoder.encode_prepared = maybe_boom
+            w.gate.set()
+            assert p.run_iteration()       # handed off; the worker dies
+            assert p._pipeline.quiesce(30) and p._pipeline.disabled
+            # ... and collects behind the scalar fallback's ship.
+            assert w.events[-1] == ("collect", "encode-pipeline")
+            boom["on"] = False
+            del w.events[:]
+            assert p.run_iteration()       # disabled: inline, on the loop
+            assert w.events[-1] == ("collect", me)
+            assert (m.gc_collections_worker_total,
+                    m.gc_collections_loop_total) == (1, 1)
+        assert p.last_error is None
+    finally:
+        w.close()
+
+
+@pytest.mark.parametrize("how", ["clean", "crash"])
+def test_run_exit_restores_the_collector_with_a_pipeline(monkeypatch, how):
+    """run()'s exit gives the process its collector back (enabled,
+    nothing frozen) behind a pipeline: after a clean stop, whose close
+    joins the worker first, and after a crash, where a worker still on
+    its window finds the run closed and freezes nothing."""
+    import gc
+
+    w = _GcWatch(monkeypatch, n_windows=2)
+    p = w.p
+    try:
+        if how == "clean":
+            w.gate.set()
+            p.run()                        # exhausts the source, closes
+            assert p.crashed is None
+            assert p.metrics.gc_collections_worker_total == 2
+        else:
+            def bug(_n):
+                raise RuntimeError("loop bug")
+
+            p._on_iteration = bug
+            with pytest.raises(RuntimeError):
+                p.run()                    # window 1 is still on the worker
+            assert p.crashed is not None
+            assert gc.isenabled() and gc.get_freeze_count() == 0
+            w.gate.set()
+            assert p._pipeline.quiesce(30)
+            assert p.metrics.gc_collections_worker_total == 0
+            assert "collect" not in w.whats()
+        assert gc.isenabled() and gc.get_freeze_count() == 0
+        if how == "crash":
+            # A supervised restart re-arms: the loop's first iteration
+            # switches the scheduler off, the worker's first collection
+            # freezes.
+            p._on_iteration = None
+            p.run()
+            assert p.crashed is None
+            assert p.metrics.gc_collections_worker_total == 1
+            assert gc.isenabled() and gc.get_freeze_count() == 0
+    finally:
+        w.close()
+
+
+def test_hourly_refreeze_counts_collections_and_rides_the_hook(monkeypatch):
+    """Every _GC_REFREEZE-th collection of a run unfreezes, collects and
+    freezes again, on whichever arm that collection runs."""
+    import gc
+
+    w = _GcWatch(monkeypatch, n_windows=3)
+    monkeypatch.setattr(CPUProfiler, "_GC_REFREEZE", 2)
+    for name in ("freeze", "unfreeze"):
+        real = getattr(gc, name)
+        monkeypatch.setattr(
+            gc, name, lambda real=real, name=name: (w.note(name), real())[1])
+    w.gate.set()
+    try:
+        for _ in range(3):
+            assert w.p.run_iteration() and w.p._pipeline.quiesce(30)
+        assert [e for e in w.events
+                if e[0] in ("collect", "freeze", "unfreeze")] \
+            == [(x, "encode-pipeline") for x in (
+                "collect", "freeze", "collect",
+                "unfreeze", "collect", "freeze")]
+    finally:
+        w.close()
+
+
+def test_gc_counters_on_metrics(monkeypatch):
+    """The three families of the boundary collection, by arm."""
+    from parca_agent_tpu.web import render_metrics
+
+    w = _GcWatch(monkeypatch)
+    w.gate.set()
+    p = w.p
+    try:
+        text = render_metrics([p])
+        assert 'parca_agent_profiler_gc_collections_total{' \
+            'profiler="cpu",where="worker"} 0' in text
+        assert p.run_iteration() and p._pipeline.quiesce(30)
+        p._pipeline.disabled = True
+        assert p.run_iteration()           # inline: the loop's arm
+        text = render_metrics([p])
+        for where in ("worker", "loop"):
+            assert 'parca_agent_profiler_gc_collections_total{' \
+                f'profiler="cpu",where="{where}"}} 1' in text
+        sec = [ln for ln in text.splitlines() if ln.startswith(
+            "parca_agent_profiler_gc_collect_seconds_total")]
+        assert len(sec) == 1 and float(sec[0].split()[-1]) > 0
+        got = [ln for ln in text.splitlines() if ln.startswith(
+            "parca_agent_profiler_gc_collected_objects_total")]
+        assert len(got) == 1 and float(got[0].split()[-1]) \
+            == p.metrics.gc_collected_objects_total
+    finally:
+        w.close()
+
+
+def test_two_arms_and_a_restore_share_the_collector_without_a_lost_update():
+    """The collector's state is written by the capture thread and the
+    encode worker: under a shortened switch interval every collection of
+    either arm is counted once, and one that meets a closed run leaves
+    the collector alone."""
+    import gc
+    import sys
+
+    p = CPUProfiler(source=ReplaySource([]), aggregator=CPUAggregator(),
+                    manage_gc=True)
+    n, arms = 150, ("worker", "loop", "worker", "loop")
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        ts = [threading.Thread(
+            target=lambda a=a: [p._collect_gc(a) for _ in range(n)])
+            for a in arms]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(60)
+        assert not any(t.is_alive() for t in ts)
+        m = p.metrics
+        assert (m.gc_collections_worker_total, m.gc_collections_loop_total,
+                p._gc_collections) == (2 * n, 2 * n, 4 * n)
+        assert gc.get_freeze_count() > 0
+        p._restore_gc()
+        p._collect_gc("worker")            # a late worker: the run is closed
+        assert m.gc_collections_worker_total == 2 * n
+    finally:
+        sys.setswitchinterval(old)
+        p._restore_gc()
+    assert gc.isenabled() and gc.get_freeze_count() == 0

@@ -119,29 +119,51 @@ def test_profiler_fast_encode_fallback_on_device_failure():
     assert len(w.profiles) == 5  # fallback wrote via the scalar builder
 
 
-def test_profiler_gc_stewardship_opt_in():
+@pytest.mark.parametrize("piped", [False, True],
+                         ids=["loop", "pipeline"])
+def test_profiler_gc_stewardship_opt_in(piped):
     """manage_gc=True (the agent CLI's setting) freezes the warm state and
     disables the automatic scheduler after window 1, collecting explicitly
-    at boundaries instead; the default leaves process GC untouched."""
+    at boundaries instead; the default leaves process GC untouched. With
+    the encode pipeline the collections ride the worker, after each
+    window's ship; the scheduler still goes off at the end of the first
+    iteration, on the capture thread."""
     import gc
 
+    if piped:
+        from parca_agent_tpu.aggregator.dict import DictAggregator
+
+        kw = dict(aggregator=DictAggregator(capacity=1 << 12),
+                  fallback_aggregator=CPUAggregator(), fast_encode=True,
+                  encode_pipeline=True)
+    else:
+        kw = dict(aggregator=CPUAggregator())
     assert gc.isenabled()
     p = CPUProfiler(source=ReplaySource([_snap(), _snap()]),
-                    aggregator=CPUAggregator(), manage_gc=True)
+                    profile_writer=CollectingWriter(), manage_gc=True, **kw)
     try:
         assert p.run_iteration()
         assert not gc.isenabled()  # explicit boundary collects from now on
+        if piped:
+            assert p._pipeline.flush(30)
         assert p.run_iteration()
         assert not gc.isenabled()
+        if piped:
+            assert p._pipeline.close()
+        m = p.metrics
+        assert (m.gc_collections_worker_total,
+                m.gc_collections_loop_total) == ((2, 0) if piped else (0, 2))
+        assert gc.get_freeze_count() > 0
     finally:
-        gc.unfreeze()
-        gc.enable()
+        p._restore_gc()
+    assert gc.isenabled() and gc.get_freeze_count() == 0
 
     # Default: no global side effects.
     q = CPUProfiler(source=ReplaySource([_snap()]),
                     aggregator=CPUAggregator())
     assert q.run_iteration()
-    assert gc.isenabled()
+    assert gc.isenabled() and gc.get_freeze_count() == 0
+    assert q.metrics.gc_collections_loop_total == 0
 
 
 def test_profiler_fallback_on_device_failure():
