@@ -729,6 +729,13 @@ class DictAggregator:
         # the O(pids) staleness scan when nothing could be dirty — the
         # scan used to run on EVERY drain-tick prebuild.
         self._reg_version = 0
+        # The pids behind those bumps, for the one reader that keeps
+        # per-pid state from window to window (take_touched_pids):
+        # noted at every site that creates, grows or drops a registry.
+        # None until a reader has asked once, so an aggregator nobody
+        # reads this from keeps no set of every pid it ever saw.
+        self._touched_pids: set | None = None
+        self._touched_token = 0
         # Device twin (created lazily; None until first window).
         self._dev = None
         # Streaming-window state (feed/close_window protocol). The
@@ -892,6 +899,23 @@ class DictAggregator:
                 + self.stats.get("invalidation_compactions", 0)
                 + self.stats.get("reclaims", 0))
 
+    def take_touched_pids(self, token) -> tuple[int, set | None]:
+        """The pids whose registry was created, grown or dropped since
+        the take that returned `token`, and the token of this take. None
+        for the pids when `token` is not the last take's (the first
+        take, or another reader took in between): the caller then knows
+        nothing of what changed. An id-space compaction is not reported
+        here: it bumps registry_epoch. Call on the thread that owns
+        aggregator mutation."""
+        pids = self._touched_pids if token == self._touched_token else None
+        self._touched_pids = set()
+        self._touched_token += 1
+        return self._touched_token, pids
+
+    def _note_touched(self, pids) -> None:
+        if self._touched_pids is not None:
+            self._touched_pids.update(pids)
+
     def footprint_bytes(self) -> dict:
         """Per-lane host-memory accounting for the endurance sentinel
         (bench_zoo/soak.py) and the /healthz ``endurance`` section:
@@ -972,6 +996,7 @@ class DictAggregator:
                            for m in mappings},
         )
         self._reg_version += 1
+        self._note_touched((pid,))
         return True
 
     # -- streaming window protocol -------------------------------------------
@@ -2060,6 +2085,7 @@ class DictAggregator:
         # Registry content changed even when the pid owned no stack ids
         # yet (an adopted-but-never-fed registry still must not survive).
         self._reg_version += 1
+        self._note_touched(int(p) for p in pids)
         self.stats["pid_invalidations"] = \
             self.stats.get("pid_invalidations", 0) + len(pids)
         if int(keep.sum()) != n:
@@ -2707,6 +2733,7 @@ class DictAggregator:
 
         self._append_id_meta(pids.astype(np.int32), depths, flat_vals)
         self._reg_version += 1
+        self._note_touched(upids.tolist())
         trace.count(registered_pids=len(upids),
                     registered_first_seen=n_first_seen)
 

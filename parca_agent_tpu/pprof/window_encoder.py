@@ -52,6 +52,20 @@ Thread-ownership contract (the encode pipeline, profiler/encode_pipeline.py):
     the template plus the registry rows frozen into the prepared window's
     caps. The pipeline guarantees prepare() never overlaps encoder-thread
     work (it parks the worker first).
+  * What the encoder carries from window to window follows the same
+    split. The caps of the window before (_caps, with the pids they were
+    keyed from and the aggregator's touched-pid token) are prepare()'s
+    alone: it reads them, and a window that changes any of them gets a
+    NEW dictionary, because the one before is still the worker's to read
+    (a prepared window's caps are never mutated after the hand-off; a
+    window that changes nothing is handed the same object). The order
+    arrays are replaced, never written in place, since an all-live
+    window's idx/pids_live ARE those arrays. The template's kept emit
+    state (_Template.kept: the row look-up, the live groups, the time
+    byte positions, the views list) is the encoder thread's alone:
+    encode_prepared() builds, reuses and drops it, and a views list it
+    hands out again is the list of the window before, valid, as that one
+    was, until the next encode.
   * build_statics() may run on the encoder thread concurrently with the
     profiler thread FEEDING the next window. That is safe because the
     aggregator's registries are append-only and published behind a
@@ -374,6 +388,28 @@ class _SpanViews(list):
             yield pid, _SpanBlob(view, spans, g)
 
 
+class _Kept:
+    """What one window's encode leaves with the template for the next:
+    everything a window computes from the layout and from WHICH ids are
+    live, never from their counts. It stands while the layout stands:
+    _build_layout and _append_rows (with the relocations and rewrites
+    under it) drop it, so do a reset and a rotation (the template goes),
+    and a window whose ids are another array than `idx` reuses only what
+    depends on the layout alone."""
+
+    __slots__ = ("time_idx", "dur_idx", "idx", "row", "live_g", "views")
+
+    def __init__(self, tmpl: "_Template", time_w: int):
+        w = np.arange(time_w, dtype=np.int64)
+        # Byte positions of every group's time and duration varints.
+        self.time_idx = tmpl.time_pos[:, None] + 1 + w[None, :]
+        self.dur_idx = self.time_idx + 1 + time_w
+        self.idx = None      # the window's id array (identity is the test)
+        self.row = None      # tmpl.row_of[idx]
+        self.live_g = None   # bool [G]: groups with a live row
+        self.views = None    # _SpanViews over the live groups, if built
+
+
 class _Template:
     """Cached whole-window serialization: every pid's profile bytes laid
     out in one uint8 buffer, one independent blob slice per pid, with the
@@ -405,7 +441,7 @@ class _Template:
                  "val_pos", "pids", "blob_start", "blob_end", "cap_end",
                  "time_pos", "group_of", "g_head_len", "g_tail_len",
                  "g_loc_len", "span_off", "span_len", "span_rev", "pieces",
-                 "alloc_end", "waste", "rotations", "period_ns")
+                 "alloc_end", "waste", "rotations", "period_ns", "kept")
 
     def __init__(self):
         self.buf = None          # np.uint8 big buffer
@@ -437,6 +473,7 @@ class _Template:
         self.waste = 0           # relocation holes, bytes
         self.rotations = -1      # aggregator rotation epoch at build
         self.period_ns = -1      # period the cached statics embed
+        self.kept = None         # _Kept: the last encode's, while it stands
 
 
 class _PreparedWindow:
@@ -482,6 +519,14 @@ def _reg_cap(reg) -> tuple:
     return (reg, len(reg.mappings), n_locs)
 
 
+def _distinct_sorted(a: np.ndarray) -> np.ndarray:
+    """The distinct values of a sorted array: those that differ from
+    the neighbour before them (np.unique would sort them again)."""
+    head = np.ones(len(a), bool)
+    np.not_equal(a[1:], a[:-1], out=head[1:])
+    return a[head]
+
+
 _WTAIL_LEN = 22  # [tag][10B time][tag][10B duration], fixed-width
 
 
@@ -525,8 +570,21 @@ class WindowEncoder:
         # per-sync concatenate would re-copy ~8 MB of offsets per window
         # at 1M ids just to append a trickle of new stacks).
         self._pre_off = np.zeros(1024, np.int64)
+        # Both order arrays are replaced, never written in place: a
+        # window whose every id is live hands them to the worker as its
+        # idx/pids_live (prepare), so they are read-only.
         self._order = None               # ids sorted by pid (int64)
         self._order_pid = None           # pid per sorted slot (int32)
+        # The caps of the window before, carried to the next prepare
+        # (_window_caps): the dictionary that window was handed (never
+        # mutated: a window that changes anything gets a new one), the
+        # pids_live array it was keyed from (identity says "the same
+        # pids" without a pass) with its distinct pids, and the token of
+        # the aggregator's touched-pid report as of that prepare.
+        self._caps: dict | None = None
+        self._caps_src = None
+        self._caps_pids = None
+        self._touch_token = None
         self._static: dict[int, _PidStatic] = {}
         # (registry version, period) after a scan that found NOTHING
         # dirty: while the aggregator reports the same version, the
@@ -567,6 +625,18 @@ class WindowEncoder:
             "statics_adopted_pids": 0,
             "append_fast_groups": 0,
             "append_slow_groups": 0,
+            # The state carried from window to window (docs/perf.md
+            # "what a window costs the encoder"): registry caps read in
+            # prepare (one a pid: 0 a steady window) and windows that
+            # read every live pid's (the first, an epoch change, an
+            # aggregator without a touched-pid report, a short counts
+            # buffer); ids merged into the pid order and full argsorts
+            # of it; encodes that handed out the views of the one before.
+            "caps_refreshed_total": 0,
+            "caps_rebuilds_total": 0,
+            "order_merged_ids_total": 0,
+            "order_rebuilds_total": 0,
+            "views_reused_total": 0,
             # Statics build clock: per-call duration (the gauge) and the
             # monotone accumulator the pipeline worker diffs to span the
             # statics work that ran INSIDE one window's encode. The same
@@ -641,13 +711,15 @@ class WindowEncoder:
             self._static.clear()
             self._statics_clean = None
             self._order = None
+            self._caps = None
         n = getattr(agg, "_published", None)
         if n is None:
             n = agg._next_id
         if n > self._synced:
+            # The pid order lags until _ensure_order merges the new ids
+            # in (its length is its own watermark).
             self._extend_prefixes(self._synced, n)
             self._synced = n
-            self._order = None
 
     def reset(self) -> None:
         """Drop every mirror, cached static, and the template; the next
@@ -662,6 +734,7 @@ class WindowEncoder:
         self._pre_off[0] = 0
         self._order = None
         self._order_pid = None
+        self._caps = None
         self._static.clear()
         self._statics_clean = None
         self._tmpl = _Template()
@@ -673,16 +746,38 @@ class WindowEncoder:
         return 0 if pieces is None else pieces.nbytes
 
     def _ensure_order(self) -> None:
-        """Rebuild the id-by-pid sort order if stale. Lazy and separate
-        from _sync: encode() is the only consumer, and the per-drain
-        statics prebuild syncs on the polling thread every second — an
-        eager argsort there would pay O(n log n) over the full id space
-        per drain during population growth for nothing."""
+        """Bring the id-by-pid sort order up to the synced id space.
+        Lazy and separate from _sync: encode() is the only consumer, and
+        the per-drain statics prebuild syncs on the polling thread every
+        second — paying for the order there during population growth
+        would be for nothing.
+
+        Ids the order has not seen are sorted by pid among themselves
+        and merged in: after every known id of their pid and in id
+        order, which is where the stable argsort of the whole id space
+        puts them. Only a lost order (the first window, an epoch change,
+        a reset) is sorted whole."""
+        n = self._synced
         if self._order is None:
-            n = self._synced
             pids = self._agg._id_pid[:n].astype(np.int32, copy=False)
-            self._order = np.argsort(pids, kind="stable").astype(np.int64)
-            self._order_pid = pids[self._order]
+            order = np.argsort(pids, kind="stable").astype(np.int64)
+            order_pid = pids[order]
+            self.stats["order_rebuilds_total"] += 1
+            window_trace.count(encode_order_rebuilds=1)
+        elif len(self._order) < n:
+            s = len(self._order)
+            pids = self._agg._id_pid[s:n].astype(np.int32, copy=False)
+            by = np.argsort(pids, kind="stable")
+            at = np.searchsorted(self._order_pid, pids[by], side="right")
+            order = np.insert(self._order, at, by + s)
+            order_pid = np.insert(self._order_pid, at, pids[by])
+            self.stats["order_merged_ids_total"] += n - s
+            window_trace.count(encode_order_merged_ids=n - s)
+        else:
+            return
+        order.flags.writeable = False
+        order_pid.flags.writeable = False
+        self._order, self._order_pid = order, order_pid
 
     def _extend_prefixes(self, s: int, n: int) -> None:
         """Encode the fixed Sample prefix (location_id field) for ids
@@ -1209,6 +1304,7 @@ class WindowEncoder:
         Each pid's region is over-allocated with slack so later windows can
         APPEND new stacks' rows instead of relaying out (see _Template)."""
         tmpl = self._tmpl
+        tmpl.kept = None
         bounds = np.flatnonzero(np.diff(pids_live)) + 1
         gstarts = np.concatenate(([0], bounds))
         gends = np.concatenate((bounds, [len(idx)]))
@@ -1353,6 +1449,7 @@ class WindowEncoder:
         churn-encode penalty); only exceptional groups (statics drift,
         slack exhaustion, brand-new pids) take the scalar walk."""
         tmpl = self._tmpl
+        tmpl.kept = None
         # Batch-build dirty statics first (new stacks usually mean new
         # locations for their pids); the per-pid _ensure_static below is
         # then a cache hit — the same reasoning as _build_layout's. Only
@@ -1579,19 +1676,75 @@ class WindowEncoder:
             order, order_pid = self._order[keep], self._order_pid[keep]
         counts_o = np.asarray(counts)[order]
         live = counts_o > 0
-        idx = order[live]
-        vals = counts_o[live].astype(np.uint64)
-        pids_live = order_pid[live]
-        caps: dict[int, tuple] = {}
-        if len(idx):
-            agg = self._agg
-            for pid in np.unique(pids_live).tolist():
-                reg = agg._pids.get(int(pid))
-                if reg is not None:
-                    caps[int(pid)] = _reg_cap(reg)
+        if live.all():
+            # Every covered id is live: the window's ids are the order
+            # itself (the arrays are never written in place), and the
+            # encode knows a window of the same ids by that identity.
+            idx, pids_live = order, order_pid
+            vals = counts_o.astype(np.uint64)
+        else:
+            idx = order[live]
+            vals = counts_o[live].astype(np.uint64)
+            pids_live = order_pid[live]
+        caps = self._window_caps(pids_live, carry=n == self._synced)
         self.timings["encode_sync"] = _time.perf_counter() - t0
         return _PreparedWindow(idx, vals, pids_live, time_ns, duration_ns,
                                period_ns, self._rotations, caps)
+
+    def _window_caps(self, pids_live: np.ndarray, carry: bool) -> dict:
+        """{pid: _reg_cap(registry)} for every live pid of the window,
+        read from the registries only where the window before cannot
+        vouch for it: the pids the aggregator registered stacks or
+        mappings for since the last prepare (take_touched_pids) and the
+        pids that were not live then; pids no longer live are dropped.
+        A window that changes nothing gets the dictionary of the one
+        before; any other a new one (a prepared window's caps are the
+        worker's to read). Without something to go on — no window
+        before, an aggregator that reports no touched pids, a window not
+        carried (`carry`: its counts stop short of the id space) — every
+        live pid's registry is read, as it always was."""
+        agg = self._agg
+        take = getattr(agg, "take_touched_pids", None)
+        touched = None
+        if take is not None:
+            self._touch_token, touched = take(self._touch_token)
+        old, old_pids = self._caps, self._caps_pids
+        if old is not None and pids_live is self._caps_src:
+            upids, same = old_pids, True
+        else:
+            upids = _distinct_sorted(pids_live)
+            same = old is not None and np.array_equal(upids, old_pids)
+        if old is None or touched is None or not carry:
+            caps: dict[int, tuple] = {}
+            refresh = upids
+            self.stats["caps_rebuilds_total"] += 1
+            window_trace.count(caps_rebuilds=1)
+        else:
+            refresh = np.intersect1d(
+                upids, np.fromiter(touched, upids.dtype, len(touched)),
+                assume_unique=True) if touched else upids[:0]
+            if same and not len(refresh):
+                self._caps_src = pids_live
+                window_trace.count(caps_refreshed=0)
+                return old
+            caps = dict(old)
+            if not same:
+                for pid in np.setdiff1d(old_pids, upids,
+                                        assume_unique=True).tolist():
+                    caps.pop(pid, None)
+                refresh = np.union1d(refresh, np.setdiff1d(
+                    upids, old_pids, assume_unique=True))
+        for pid in refresh.tolist():
+            reg = agg._pids.get(pid)
+            if reg is not None:
+                caps[pid] = _reg_cap(reg)
+            else:
+                caps.pop(pid, None)
+        self.stats["caps_refreshed_total"] += len(refresh)
+        window_trace.count(caps_refreshed=len(refresh))
+        self._caps = caps if carry else None
+        self._caps_src, self._caps_pids = pids_live, upids
+        return caps
 
     def encode(self, counts: np.ndarray, time_ns: int, duration_ns: int,
                period_ns: int, views: bool = False) -> list[tuple[int, bytes]]:
@@ -1640,7 +1793,17 @@ class WindowEncoder:
         hit = (tmpl.buf is not None
                and tmpl.period_ns == period_ns
                and tmpl.rotations == self._rotations)
-        if hit:
+        kept = tmpl.kept
+        same_ids = hit and kept is not None and kept.idx is idx
+        if same_ids:
+            # The ids of the window before (an all-live window's idx is
+            # the order array itself) on the layout it left: their rows
+            # are what they were, none is new, and the relayout test
+            # needs no pass over them.
+            row, n_new = kept.row, 0
+            hit = (tmpl.n_rows - len(idx) <= tmpl.n_rows // 2
+                   and tmpl.waste <= tmpl.alloc_end // 3)
+        elif hit:
             # Churn analysis against the template's row set. row_of may
             # lag the id space (population grew since the build).
             row = tmpl.row_of[idx] if int(idx.max()) < len(tmpl.row_of) \
@@ -1664,7 +1827,7 @@ class WindowEncoder:
             self.stats["layouts_built"] += 1
             tmpl.period_ns = period_ns
             row = tmpl.row_of[idx]
-        else:
+        elif not same_ids:
             if row is None or (n_new and len(tmpl.row_of) < self._synced):
                 grown = np.full(max(self._synced, 1), -1, np.int64)
                 grown[: len(tmpl.row_of)] = tmpl.row_of
@@ -1690,12 +1853,12 @@ class WindowEncoder:
         self.stats["dead_rows"] = dead
         self.stats["dead_row_fraction"] = (
             dead / tmpl.n_rows if tmpl.n_rows else 0.0)
-        tp = tmpl.time_pos
-        w10 = np.arange(self._TIME_W, dtype=np.int64)
-        buf[tp[:, None] + 1 + w10[None, :]] = \
-            _padded_bytes(time_ns, self._TIME_W)[None, :]
-        buf[tp[:, None] + 2 + self._TIME_W + w10[None, :]] = \
-            _padded_bytes(duration_ns, self._TIME_W)[None, :]
+        kept = tmpl.kept  # dropped by a layout or an append above
+        if kept is None:
+            kept = tmpl.kept = _Kept(tmpl, self._TIME_W)
+        buf[kept.time_idx] = _padded_bytes(time_ns, self._TIME_W)[None, :]
+        buf[kept.dur_idx] = _padded_bytes(duration_ns,
+                                          self._TIME_W)[None, :]
         self.timings["encode_patch" if hit else "encode_build"] = \
             _time.perf_counter() - t0
 
@@ -1705,28 +1868,40 @@ class WindowEncoder:
         # all-zero profile — the reference never writes a sample-less
         # profile, so skip those groups (their blobs stay for the next
         # window they wake up in).
-        live_g = np.zeros(len(tmpl.pids), bool)
-        live_g[tmpl.row_group[row]] = True
-        pid_list = tmpl.pids.tolist()
+        if kept.idx is idx:
+            live_g = kept.live_g
+        else:
+            live_g = np.zeros(len(tmpl.pids), bool)
+            live_g[tmpl.row_group[row]] = True
+            if kept.live_g is None or not np.array_equal(live_g,
+                                                         kept.live_g):
+                kept.views = None  # other blobs go out than were kept
+            kept.idx, kept.row, kept.live_g = idx, row, live_g
         out: list[tuple[int, bytes]] = []
         if self._compress:
             mv = buf.data
-            for g, pid in enumerate(pid_list):
+            for g, pid in enumerate(tmpl.pids.tolist()):
                 if live_g[g]:
                     out.append((pid, _gzip.compress(
                         bytes(mv[int(bs[g]): int(be[g])]), 1)))
+        elif views and kept.views is not None:
+            # The layout and the live groups of the window before: its
+            # views are this window's, over the bytes just patched (the
+            # list was only ever valid until the next encode).
+            out = kept.views
+            self.stats["views_reused_total"] += 1
         elif views:
             # The views go out with the window's span table, for the
             # ship path's gzip (_SpanViews.span_blobs).
             mv = buf.data
             live = np.flatnonzero(live_g)
-            out = _SpanViews(
+            out = kept.views = _SpanViews(
                 [(pid, mv[a:b]) for pid, a, b in zip(
                     tmpl.pids[live].tolist(), bs[live].tolist(),
                     be[live].tolist())],
                 _SpanTable(tmpl), live.tolist())
         else:
-            for g, pid in enumerate(pid_list):
+            for g, pid in enumerate(tmpl.pids.tolist()):
                 if live_g[g]:
                     out.append((pid, buf[int(bs[g]): int(be[g])].tobytes()))
         self.timings["encode_emit"] = _time.perf_counter() - t0

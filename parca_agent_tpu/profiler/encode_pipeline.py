@@ -417,6 +417,7 @@ class EncodePipeline:
         enc_stats = getattr(self._enc, "stats", {})
         statics0 = enc_stats.get("statics_build_s_total", 0.0)
         layouts0 = enc_stats.get("layouts_built", 0)
+        reused0 = enc_stats.get("views_reused_total", 0)
         out = self._enc.encode_prepared(prep, views=self._views)
         enc_s = time.monotonic() - t0
         self.stats["last_encode_s"] = enc_s
@@ -425,6 +426,10 @@ class EncodePipeline:
         # ("build") or counts patched into the ones that stand.
         trace.annotate(encode="build" if enc_stats.get(
             "layouts_built", 0) > layouts0 else "patch")
+        # Whether it handed out the views of the window before (the
+        # layout and the live groups stood) or built the list again.
+        trace.count(encode_views_reused=enc_stats.get(
+            "views_reused_total", 0) - reused0)
         statics_s = enc_stats.get("statics_build_s_total", 0.0) - statics0
         if statics_s > 0:
             # histogram=False: the encoder already observed each build

@@ -993,3 +993,94 @@ def test_two_arms_and_a_restore_share_the_collector_without_a_lost_update():
         sys.setswitchinterval(old)
         p._restore_gc()
     assert gc.isenabled() and gc.get_freeze_count() == 0
+
+
+# -- the encoder's carried state, seen through the pipeline -------------------
+
+
+def _traced_windows(kind):
+    """Four windows through a pipeline, each with a trace: a cold one,
+    two of the same population and a last one of `kind` ("steady": the
+    same again; "rollout": new stacks and new pids). Returns (the
+    traces' meta, what was shipped, what a sync encoder over a twin
+    aggregator encodes, the pipeline's encoder, ids the last window
+    brought)."""
+    from parca_agent_tpu.runtime.trace import FlightRecorder
+
+    snap = _snap(seed=31)
+    grown = _snap(seed=31, n_pids=9, rows=260)   # more of both
+    agg, twin = (DictAggregator(capacity=1 << 12) for _ in range(2))
+    enc, sync = WindowEncoder(agg), WindowEncoder(twin)
+    shipped, want = [], []
+    pipe = EncodePipeline(enc, ship=lambda out, prep: shipped.append(
+        [(pid, bytes(b)) for pid, b in out]))
+    rec = FlightRecorder(ring=8)
+    new_ids = 0
+    try:
+        for t, s in enumerate((snap, snap, snap,
+                               grown if kind == "rollout" else snap)):
+            s = dataclasses.replace(s, counts=s.counts + t)
+            ids0 = agg._published
+            counts = np.asarray(agg.window_counts(s))
+            new_ids = agg._published - ids0
+            tr = rec.begin(s.time_ns + t)
+            assert pipe.submit(counts, s.time_ns + t, s.window_ns,
+                               s.period_ns, trace=tr) is not None
+            assert pipe.flush(30)
+            want.append([(pid, bytes(b)) for pid, b in sync.encode(
+                np.asarray(twin.window_counts(s)), s.time_ns + t,
+                s.window_ns, s.period_ns, views=True)])
+    finally:
+        assert pipe.close()
+    return [d["meta"] for d in rec.traces()], shipped, want, enc, new_ids
+
+
+@pytest.mark.parametrize("kind", ["steady", "rollout"])
+def test_a_windows_meta_says_what_the_encoder_redid(kind):
+    """The counts a window's trace carries (docs/observability.md): the
+    cold window reads every cap and sorts the order; a steady one reads
+    none, merges nothing and hands out the views it has; a rollout
+    window reads the caps of the pids it touched and merges its ids."""
+    meta, shipped, want, enc, new_ids = _traced_windows(kind)
+    assert shipped == want        # and none of it moves a byte
+    cold, last = meta[0], meta[-1]
+    assert cold["caps_rebuilds"] == 1 and cold["encode_order_rebuilds"] == 1
+    assert cold["caps_refreshed"] == len(shipped[0])
+    assert cold["encode_views_reused"] == 0
+    for m in meta[1:3] + ([last] if kind == "steady" else []):
+        assert m["encode_views_reused"] == 1 and m["caps_refreshed"] == 0
+        assert "caps_rebuilds" not in m
+        assert "encode_order_rebuilds" not in m
+        assert "encode_order_merged_ids" not in m
+    if kind == "rollout":
+        assert new_ids > 0 and last["encode_order_merged_ids"] == new_ids
+        assert 0 < last["caps_refreshed"] <= len(shipped[-1])
+        assert last["encode_views_reused"] == 0
+        assert "caps_rebuilds" not in last
+    assert enc.stats["views_reused_total"] == (3 if kind == "steady" else 2)
+
+
+def test_encoder_carried_state_counters_on_metrics():
+    """The five families of the encoder's carried state."""
+    from parca_agent_tpu.web import render_metrics
+
+    snap = dataclasses.replace(_snap(seed=33), window_ns=50_000_000)
+    p = CPUProfiler(
+        source=ReplaySource([snap] * 3),
+        aggregator=DictAggregator(capacity=1 << 12),
+        fallback_aggregator=CPUAggregator(), profile_writer=Collect(),
+        fast_encode=True, duration_s=0.01, encode_pipeline=True)
+    try:
+        for _ in range(3):
+            assert p.run_iteration() and p._pipeline.quiesce(30)
+        text = render_metrics([p])
+        n = len(np.unique(snap.pids))
+        for family, value in (("caps_refreshed_total", n),
+                              ("caps_rebuilds_total", 1),
+                              ("order_merged_ids_total", 0),
+                              ("order_rebuilds_total", 1),
+                              ("views_reused_total", 2)):
+            assert f'parca_agent_encoder_{family}{{profiler="cpu"}} ' \
+                f'{value}\n' in text, family
+    finally:
+        p._pipeline.close()

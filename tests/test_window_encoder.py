@@ -9,6 +9,8 @@ string table, period/time metadata.
 
 from __future__ import annotations
 
+import dataclasses
+
 import numpy as np
 import pytest
 import span_scenarios
@@ -622,3 +624,309 @@ def test_static_span_revision_follows_every_rewrite(site):
             seen[pid] = (span, rev)
         assert enc.static_piece_bytes() == sum(
             len(s[1]) for s in cache.slots if s is not None)
+
+
+# -- state carried from window to window --------------------------------------
+#
+# One scripted sequence of windows on ONE encoder, each step a way a
+# window can differ from the one before. Beside it the same windows go
+# through a second aggregator and an encoder that is made to forget,
+# before every window, everything the encoder carries (the caps, the
+# order, the template's kept emit state): that is the encoder without
+# the carried state, on the same template history, so the two must ship
+# the same bytes, profile for profile.
+
+# step -> (views kept, caps dictionary kept, full caps loops, order
+# argsorts): what each kind of window may reuse and what it must redo.
+_CARRY_EXPECT = {
+    "cold": (False, False, 1, 1),
+    "steady_1": (True, True, 0, 0),
+    "steady_2": (True, True, 0, 0),
+    "steady_3": (True, True, 0, 0),
+    "new_stacks_known_pids": (False, False, 0, 0),
+    "steady_after_new_stacks": (True, True, 0, 0),
+    "brand_new_pids": (False, False, 0, 0),
+    # The counts cover fewer ids: the full loop, and nothing is carried
+    # out of such a window, so the next one loops in full as well.
+    "short_counts": (False, False, 1, 0),
+    "after_short_counts": (False, False, 1, 0),
+    "steady_after_short": (True, True, 0, 0),
+    # Other groups are live: other blobs go out. The returning pid is
+    # the one cap read.
+    "pid_dies": (False, False, 0, 0),
+    "pid_returns": (False, False, 0, 0),
+    # Other ids, the same pids on the same layout: the list stands.
+    "partly_dead_same_pids": (True, True, 0, 0),
+    # A compaction bumps registry_epoch: every mirror goes.
+    "pid_invalidated_and_reused": (False, False, 1, 1),
+    "steady_after_invalidation": (True, True, 0, 0),
+    "ids_go_cold": (True, True, 0, 0),
+    "rotation": (False, False, 1, 1),
+    "steady_after_rotation": (True, True, 0, 0),
+    "steady_after_rotation_2": (True, True, 0, 0),
+}
+
+
+_CARRY_STEPS = tuple(_CARRY_EXPECT)
+
+
+def _forget(enc) -> None:
+    enc._caps = None
+    enc._order = None
+    enc._tmpl.kept = None
+
+
+def _todays_caps(agg, prep) -> dict:
+    """What the loop prepare() always ran builds for this window."""
+    from parca_agent_tpu.pprof.window_encoder import _reg_cap
+
+    return {int(p): _reg_cap(agg._pids[int(p)])
+            for p in np.unique(prep.pids_live).tolist()
+            if int(p) in agg._pids}
+
+
+def _same_caps(a: dict, b: dict) -> bool:
+    return a.keys() == b.keys() and all(
+        a[p][0] is b[p][0] and a[p][1:] == b[p][1:] for p in a)
+
+
+_COUNTERS = ("caps_refreshed_total", "caps_rebuilds_total",
+             "order_merged_ids_total", "order_rebuilds_total",
+             "views_reused_total", "layouts_built")
+
+
+@pytest.fixture(scope="module")
+def carried():
+    """{step: facts} of the scripted sequence (recorded, not asserted:
+    each case below judges its own step)."""
+    rows_of = span_scenarios.rows_of
+    snap = generate(_spec(seed=77, n_pids=14, rows=700))
+    rng = np.random.default_rng(3)
+    pids = np.unique(snap.pids)
+    late = np.isin(snap.pids, pids[-3:])            # pids that arrive late
+    half = rng.random(len(snap)) < 0.5
+    victim, reused = int(pids[2]), int(pids[4])
+    m_base = ~late & half
+    # A few more stacks, for five of the known pids alone.
+    m_more = m_base | (~late & np.isin(snap.pids, pids[:5])
+                       & (rng.random(len(snap)) < 0.2))
+    m_all = m_more | (late & half)                   # and the late pids
+    m_dead = m_all & (snap.pids != victim)
+    m_cold = m_all & (rng.random(len(snap)) < 0.5)   # half the ids rest
+
+    aggs = [DictAggregator(capacity=1 << 13, rotate_min_age=1)
+            for _ in range(2)]
+    enc, ref = (WindowEncoder(a) for a in aggs)
+    facts: dict[str, dict] = {}
+    frozen: list[tuple[dict, dict]] = []
+    state = {"t": 0, "prev_out": None, "prev_caps": None, "seen": None,
+             "evicted": False}
+
+    def window(step, mask=None, counts=None, thin=None, before=None):
+        """One window through both sides. `mask`: the snapshot's rows
+        that are fed; `counts`: encode these (older) counts instead;
+        `thin`: zero every thin-th id's count after the feed;
+        `before(agg)`: run on each aggregator ahead of the feed."""
+        state["t"] += 1
+        t = state["t"]
+        outs, preps, cs = [], [], []
+        stats0 = {k: enc.stats[k] for k in _COUNTERS}
+        ids0 = enc._synced
+        for agg, e in zip(aggs, (enc, ref)):
+            if before is not None:
+                before(agg)
+            if counts is None:
+                sub = rows_of(snap, mask)
+                sub = dataclasses.replace(sub, counts=sub.counts + t)
+                c = np.asarray(agg.window_counts(sub)).copy()
+                if thin:
+                    c[::thin] = 0
+            else:
+                c = counts
+            if e is ref:
+                _forget(e)
+            prep = e.prepare(c, snap.time_ns + t, snap.window_ns,
+                             snap.period_ns)
+            outs.append(e.encode_prepared(prep, views=True))
+            preps.append(prep)
+            cs.append(c)
+        out, prep = outs[0], preps[0]
+        frozen.append((prep.caps, dict(prep.caps)))
+        try:
+            _assert_same_profiles(
+                aggs[0], dataclasses.replace(snap, time_ns=snap.time_ns + t),
+                cs[0], [(p, bytes(b)) for p, b in out])
+            oracle = None
+        except AssertionError as e:  # judged by the step's own case
+            oracle = str(e) or "differs"
+        new_rows = None
+        if mask is not None and not state["evicted"]:
+            seen = state["seen"]
+            fresh = mask if seen is None else mask & ~seen
+            new_rows = snap.pids[fresh]
+            state["seen"] = mask if seen is None else seen | mask
+        facts[step] = {
+            "same_bytes": [(p, bytes(b)) for p, b in out]
+            == [(p, bytes(b)) for p, b in outs[1]],
+            "n_profiles": len(out),
+            "oracle": oracle,
+            "caps_ok": _same_caps(prep.caps, _todays_caps(aggs[0], prep)),
+            "caps_kept": prep.caps is state["prev_caps"],
+            "order_ok": np.array_equal(enc._order, np.argsort(
+                aggs[0]._id_pid[:enc._synced], kind="stable")),
+            "views_kept": out is state["prev_out"],
+            "all_live": prep.idx is enc._order,
+            "new_ids": enc._synced - ids0,
+            "new_rows_pids": new_rows,
+            "live_pids": len(np.unique(prep.pids_live)),
+            **{k: enc.stats[k] - stats0[k] for k in _COUNTERS},
+        }
+        state["prev_out"], state["prev_caps"] = out, prep.caps
+        return cs[0]
+
+    window("cold", m_base)
+    window("steady_1", m_base)
+    window("steady_2", m_base)
+    c_old = window("steady_3", m_base)
+    window("new_stacks_known_pids", m_more)
+    window("steady_after_new_stacks", m_more)
+    window("brand_new_pids", m_all)
+    # An older window's counts: they stop short of the id space.
+    assert len(c_old) < enc._synced
+    window("short_counts", counts=c_old)
+    window("after_short_counts", m_all)
+    window("steady_after_short", m_all)
+    window("pid_dies", m_dead)
+    window("pid_returns", m_all)
+    window("partly_dead_same_pids", m_all, thin=7)
+    state["evicted"] = True
+    window("pid_invalidated_and_reused", m_all,
+           before=lambda agg: agg.invalidate_pid(reused))
+    window("steady_after_invalidation", m_all)
+    window("ids_go_cold", m_cold)
+
+    def rotate(agg):
+        agg._rotate_pending = True
+
+    window("rotation", m_cold, before=rotate)
+    window("steady_after_rotation", m_cold)
+    window("steady_after_rotation_2", m_cold)
+    facts["_frozen"] = all(now == then for now, then in frozen)
+    facts["_epochs"] = [a.registry_epoch for a in aggs]
+    return facts
+
+
+@pytest.mark.parametrize("step", _CARRY_STEPS)
+def test_carried_state_ships_the_bytes_of_an_encoder_without_it(
+        carried, step):
+    f = carried[step]
+    assert f["n_profiles"] > 0
+    assert f["same_bytes"]
+    assert f["oracle"] is None, f["oracle"]
+    assert f["caps_ok"]       # same keys, same tuples as today's loop
+    assert f["order_ok"]      # the merged order is the stable argsort
+
+
+@pytest.mark.parametrize("step", _CARRY_STEPS)
+def test_a_window_redoes_only_what_it_changed(carried, step):
+    f = carried[step]
+    views_kept, caps_kept, rebuilds, argsorts = _CARRY_EXPECT[step]
+    assert f["views_kept"] is views_kept
+    assert f["views_reused_total"] == int(views_kept)
+    assert f["caps_kept"] is caps_kept
+    assert f["caps_rebuilds_total"] == rebuilds
+    assert f["order_rebuilds_total"] == argsorts
+    if rebuilds:
+        assert f["caps_refreshed_total"] == f["live_pids"]
+    elif caps_kept:
+        assert f["caps_refreshed_total"] == 0
+    if not argsorts:
+        # Every id the window brought was merged into the order.
+        assert f["order_merged_ids_total"] == f["new_ids"]
+    if step.startswith("steady"):
+        # The table of docs/perf.md for a steady window.
+        assert f["all_live"] and f["new_ids"] == 0
+        assert f["layouts_built"] == 0
+
+
+def test_a_rollout_window_reads_the_caps_of_the_pids_it_touched(carried):
+    """New stacks for known pids, then brand-new pids: the caps read are
+    those of the pids that own a new stack (every fed row that was never
+    fed before misses and registers), never the population's; the ids
+    are merged; no list of views is handed out twice."""
+    for step in ("new_stacks_known_pids", "brand_new_pids"):
+        f = carried[step]
+        owners = len(np.unique(f["new_rows_pids"]))
+        assert 0 < owners and f["caps_refreshed_total"] == owners
+        assert f["new_ids"] == len(f["new_rows_pids"]) > 0
+        assert f["order_merged_ids_total"] == f["new_ids"]
+        assert f["caps_rebuilds_total"] == f["order_rebuilds_total"] == 0
+    assert carried["new_stacks_known_pids"]["caps_refreshed_total"] \
+        < carried["new_stacks_known_pids"]["live_pids"]
+    assert carried["pid_returns"]["caps_refreshed_total"] == 1
+    assert carried["pid_dies"]["caps_refreshed_total"] == 0
+
+
+def test_prepared_caps_are_never_mutated_after_the_hand_off(carried):
+    assert carried["_frozen"]
+    assert carried["_epochs"][0] == carried["_epochs"][1] == 2
+
+
+def test_reused_views_read_the_new_counts_and_times():
+    snap, agg, enc, c = _churn_setup(seed=43, n_pids=5, rows=120)
+    out1 = enc.encode(c, snap.time_ns, snap.window_ns, snap.period_ns,
+                      views=True)
+    c2 = c + 11
+    out2 = enc.encode(c2, snap.time_ns + 5, snap.window_ns, snap.period_ns,
+                      views=True)
+    assert out2 is out1 and enc.stats["views_reused_total"] == 1
+    _assert_same_profiles(
+        agg, dataclasses.replace(snap, time_ns=snap.time_ns + 5), c2,
+        [(p, bytes(b)) for p, b in out2])
+    # An append lays rows down: the list is built again, over the layout
+    # as it now is.
+    snap_b = generate(_spec(seed=44, n_pids=5, rows=60))
+    c3 = np.asarray(agg.window_counts(snap_b))
+    out3 = enc.encode(c3, snap_b.time_ns, snap_b.window_ns,
+                      snap_b.period_ns, views=True)
+    assert out3 is not out1 and enc.stats["views_reused_total"] == 1
+    _assert_same_profiles(agg, snap_b, c3,
+                          [(p, bytes(b)) for p, b in out3])
+
+
+def test_an_aggregator_without_the_touched_pid_report_takes_the_full_loop():
+    class Silent(DictAggregator):
+        take_touched_pids = None     # as an aggregator that has none
+
+    snap = generate(_spec(seed=45, n_pids=6, rows=150))
+    agg = Silent(capacity=1 << 12)
+    enc = WindowEncoder(agg)
+    for t in range(3):
+        c = np.asarray(agg.window_counts(snap))
+        prep = enc.prepare(c, snap.time_ns + t, snap.window_ns,
+                           snap.period_ns)
+        assert _same_caps(prep.caps, _todays_caps(agg, prep))
+        _assert_same_profiles(
+            agg, dataclasses.replace(snap, time_ns=snap.time_ns + t), c,
+            enc.encode_prepared(prep))
+    assert enc.stats["caps_rebuilds_total"] == 3
+    assert enc.stats["caps_refreshed_total"] == 3 * len(prep.caps)
+
+
+def test_a_second_reader_of_the_touched_pids_costs_a_full_loop():
+    """Two encoders over one aggregator take each other's report: the
+    token says so, and each falls back to the full loop rather than
+    trust a report with a hole in it."""
+    snap = generate(_spec(seed=46, n_pids=6, rows=150))
+    agg = DictAggregator(capacity=1 << 12)
+    a, b = WindowEncoder(agg), WindowEncoder(agg)
+    c = np.asarray(agg.window_counts(snap))
+    for enc in (a, b, a, b):
+        prep = enc.prepare(c, snap.time_ns, snap.window_ns, snap.period_ns)
+        assert _same_caps(prep.caps, _todays_caps(agg, prep))
+    assert a.stats["caps_rebuilds_total"] == 2
+    assert b.stats["caps_rebuilds_total"] == 2
+    # Left alone, one reader is told of nothing and reads nothing.
+    a.prepare(c, snap.time_ns, snap.window_ns, snap.period_ns)
+    a.prepare(c, snap.time_ns, snap.window_ns, snap.period_ns)
+    assert a.stats["caps_rebuilds_total"] == 3
