@@ -4,7 +4,7 @@
 
 PYTHON ?= python
 
-.PHONY: all native lint test test-live chaos fuzz bench bench-statics bench-close bench-hotspot bench-sinks bench-scale bench-feed bench-regress bench-zoo soak soak-smoke trace-smoke hotspot-smoke regress-smoke fixtures golden clean install
+.PHONY: all native lint test test-live chaos fuzz bench-zoo soak soak-smoke trace-smoke hotspot-smoke regress-smoke fixtures golden clean install
 
 all: native
 
@@ -37,18 +37,17 @@ test-live:
 # preflights it: the chaos-site checker is what keeps this suite's
 # coverage honest (every SITES entry exercised here, and vice versa),
 # so drift fails fast before any test runs.
-chaos: lint bench-zoo soak-smoke
+chaos: lint soak-smoke
 	PARCA_FAULT_SEED=42 $(PYTHON) -m pytest tests/test_chaos.py tests/test_ingest_poison.py tests/test_device_health.py tests/test_statics_store.py tests/test_trace.py tests/test_close_overlap.py tests/test_hotspots_chaos.py tests/test_sinks.py tests/test_admission.py tests/test_regression.py tests/test_feed_coalesce.py tests/test_device_telemetry.py tests/test_identity.py tests/test_zoo.py tests/test_soak.py -q -m chaos
 
-# The workload-zoo matrix (docs/robustness.md "workload zoo"): >= 6
-# seeded hostile-world scenario rows — pid reuse under tenant
-# migration, perf-map churn, fork storms, deep stacks, kernel-heavy
-# mixes, tenant bursts — each driven through the REAL profiler window
-# loop and scored against per-scenario bars, plus the pid-reuse control
-# arm with the generation stamp pinned off (must REPRODUCE the
-# misattribution). Host-bound, reduced scale, one JSON line.
+# The workload zoo at full scale (docs/robustness.md "workload zoo" and
+# "endurance matrix"): the six-scenario sweep, the pid-reuse control
+# arm with the generation stamp off, and the 60-row endurance matrix,
+# each through the real window loop. Prints run_zoo / run_scenario /
+# run_matrix's own results as one JSON line; exits 1 when a bar fails.
+# tests/test_zoo.py runs the same code at test scale on every tier-1 run.
 bench-zoo:
-	JAX_PLATFORMS=cpu PARCA_BENCH_ZOO_CHILD=1 $(PYTHON) bench.py
+	JAX_PLATFORMS=cpu $(PYTHON) -m parca_agent_tpu.bench_zoo
 
 # Wall-clock endurance soak (docs/robustness.md "endurance matrix"):
 # ONE persistent agent (carry aggregator + streaming feeder + the full
@@ -80,26 +79,10 @@ soak-smoke:
 
 # Parser mutation-fuzz gate (docs/robustness.md "ingest containment"):
 # >=500 seeded mutations per ingest parser, nothing may escape the
-# PoisonInput taxonomy. Same harness the bench ingest_poison phase runs.
+# PoisonInput taxonomy.
 fuzz:
 	PARCA_FAULT_SEED=42 PARCA_FUZZ_N=500 $(PYTHON) -m pytest \
 		tests/test_ingest_poison.py -q -m chaos -k fuzz
-
-# The driver-scored benchmark: ONE JSON line on stdout.
-bench:
-	$(PYTHON) bench.py
-
-# The statics-wall drill alone (docs/perf.md): cold vs snapshot-warm
-# statics build + first encode, byte-identity + corrupt-snapshot
-# degradation bars. Host-bound, so it pins the cpu backend.
-bench-statics:
-	JAX_PLATFORMS=cpu PARCA_BENCH_STATICS_CHILD=1 $(PYTHON) bench.py
-
-# The sub-RTT close drill alone (docs/perf.md "sub-RTT close"):
-# double-buffer overlap and delta-fetch byte accounting, gated on pprof
-# byte identity. Host-bound, so it pins the cpu backend.
-bench-close:
-	JAX_PLATFORMS=cpu PARCA_BENCH_CLOSE_CHILD=1 $(PYTHON) bench.py
 
 # Window flight-recorder smoke (docs/observability.md): a short traced
 # session must expose >=3 complete traces with every mandatory span on
@@ -108,54 +91,6 @@ bench-close:
 # zero windows lost. Host-bound, so it pins the cpu backend.
 trace-smoke:
 	JAX_PLATFORMS=cpu $(PYTHON) -m parca_agent_tpu.tools.trace_smoke
-
-# Hotspot rollup acceptance drill (docs/hotspots.md): a multi-hour
-# simulated window stream folded into the rollup hierarchy; top-K vs
-# the exact aggregate >= 99%, query p50/p99 at dashboard rates, and the
-# per-level byte caps held with oldest-eviction engaged. Numpy-only.
-bench-hotspot:
-	JAX_PLATFORMS=cpu PARCA_BENCH_HOTSPOT_CHILD=1 $(PYTHON) bench.py
-
-# Output-backend sink drill (docs/sinks.md): the sha256 pprof-identity
-# bar through the SinkRegistry vs the legacy direct ship, per-sink emit
-# latency, autofdo flush bytes, and the injected-sink-fault zero-loss
-# acceptance check. Host-bound, so it pins the cpu backend.
-bench-sinks:
-	JAX_PLATFORMS=cpu PARCA_BENCH_SINK_CHILD=1 $(PYTHON) bench.py
-
-# Multi-tenant pid-axis sweep (docs/robustness.md "multi-tenant
-# admission"): 50k -> 200k -> 500k pids through one dict aggregator
-# with 32 tenants and ONE tenant 10x over quota at the top tier —
-# close latency + registry RSS per tier, zero windows lost, zero
-# in-quota tenants degraded, mid-tier close within 2x of the low tier.
-# Host-bound, so it pins the cpu backend. PARCA_BENCH_SCALE_TIERS
-# overrides the tier list for quick runs.
-bench-scale:
-	JAX_PLATFORMS=cpu PARCA_BENCH_SCALE_CHILD=1 $(PYTHON) bench.py
-
-# Ingest-wall A/B (docs/perf.md "ingest wall" + "feed endgame"): the
-# scale sweep's pid tiers fed through raw / coalesced / coalesced+
-# native-hash / carry+fold arms over a dup>=2 stationary stream —
-# per-window feed seconds reduced >= 3x at the top tier, coalesced+
-# native saturation < 50% of the window, carry+fold saturation < 1%
-# (steady-state windows dispatch ~nothing: the cross-drain carry cache
-# absorbs repeat stacks host-side and flushes once at close), zero
-# windows lost, counts + pprof identity held across every arm, and the
-# drain-cache hit rate + carry counters land in the artifact.
-# Host-bound, so it pins the cpu backend. PARCA_BENCH_FEED_TIERS
-# overrides for quick runs.
-bench-feed:
-	JAX_PLATFORMS=cpu PARCA_BENCH_FEED_CHILD=1 $(PYTHON) bench.py
-
-# Regression sentinel acceptance drill (docs/regression.md): a
-# synthetic window stream through the REAL encode pipeline with a 2x
-# hotspot shift injected on one build-id mid-run — detected within <= 2
-# rollup intervals, zero false-positive verdicts across the clean
-# control windows, windows_lost == 0 under regression.fold/baseline
-# chaos, pprof sha256 byte-identity unchanged with the sentinel
-# enabled. Host-bound, so it pins the cpu backend.
-bench-regress:
-	JAX_PLATFORMS=cpu PARCA_BENCH_REGRESS_CHILD=1 $(PYTHON) bench.py
 
 # Hotspot end-to-end smoke (docs/hotspots.md): a short real profiler
 # session (dict aggregator, encode pipeline) must serve human-readable

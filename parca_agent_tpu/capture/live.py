@@ -29,7 +29,6 @@ immediately drains again.
 from __future__ import annotations
 
 import ctypes
-import os
 import re
 import struct
 import threading
@@ -299,7 +298,7 @@ def mapping_table_for_pids(maps_cache, objs_cache, pids,
     skipped — its samples stay unmapped and ride the degradation ladder —
     instead of aborting the table build for every pid in the window.
     Without a registry, PoisonInput propagates (the pre-containment
-    drop-on-error behavior the bench's ingest_poison baseline measures).
+    drop-on-error behavior).
     Scalar-level pids skip maps parsing entirely; address-level pids
     keep maps (normalized addresses must travel) but skip ELF opens
     (build_mapping_table's degraded path — the ELF is the suspect)."""
@@ -748,11 +747,10 @@ class PerfEventSampler:
         # drain can stamp each unique record with its h1/h2/h3 triple
         # while the frames are hot in cache. FP mode only (the DWARF
         # walker rewrites user chains after the drain, invalidating any
-        # drain-time hash). PARCA_NO_CAPTURE_HASH=1 pins the hashless
-        # v1d drain — the build-less fallback stays exact either way.
+        # drain-time hash). A sampler that refuses the tables drains
+        # hashless (v1d) and the feeder hashes host-side: exact either way.
         self.hash_carry = False
-        if not capture_stack \
-                and not os.environ.get("PARCA_NO_CAPTURE_HASH"):
+        if not capture_stack:
             try:
                 from parca_agent_tpu.ops.hashing import hash_params
 

@@ -96,14 +96,6 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--trace-incident-interval", type=float, default=300.0,
                    help="minimum seconds between incident captures "
                         "(rate limit; suppressed captures are counted)")
-    p.add_argument("--no-device-telemetry", action="store_true",
-                   help="disable the device flight recorder "
-                        "(docs/observability.md \"device flight "
-                        "recorder\"): per-kernel compile/execute "
-                        "latency histograms, recompile-storm detection, "
-                        "H2D/D2H transfer accounting, and window-SLO "
-                        "budget burn on /metrics + /debug/device. On by "
-                        "default")
     p.add_argument("--telemetry-ring", type=int, default=256,
                    help="kernel events and window-SLO entries kept in "
                         "the device flight recorder's timeline rings "
@@ -238,21 +230,13 @@ def build_parser() -> argparse.ArgumentParser:
                         "vectorized template encoder and ship profiles "
                         "unsymbolized (the server symbolizes, as with the "
                         "reference agent); disables local symbolization")
-    p.add_argument("--no-encode-pipeline", action="store_true",
-                   help="disable the background encode pipeline (with "
-                        "--fast-encode the default hands each closed "
-                        "window to a dedicated encoder thread, so capture "
-                        "of window N+1 overlaps encoding/shipping of "
-                        "window N; if the encoder is still busy at the "
-                        "next close, that window ships via the scalar "
-                        "fallback and a backpressure counter increments)")
     p.add_argument("--encode-deadline", type=float, default=45.0,
                    help="soft deadline (seconds) for one window's inline "
                         "pprof encode: past it the encode is abandoned to "
                         "a daemon thread (it keeps warming the template) "
                         "and the window ships via the scalar fallback; "
-                        "0 disables. Applies when the encode pipeline is "
-                        "off or has self-disabled")
+                        "0 disables. Applies once the encode pipeline "
+                        "has disabled itself")
     p.add_argument("--statics-snapshot-path", default="",
                    help="file for the warm pprof-statics + registry "
                         "snapshot (requires --fast-encode): the encode "
@@ -284,9 +268,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "bounded memory, and served from /hotspots "
                         "('top-K hottest stacks matching this label "
                         "selector over this time range'). Requires "
-                        "--fast-encode with the encode pipeline; with a "
-                        "fleet configured, merge rounds also feed a "
-                        "fleet-wide scope")
+                        "--fast-encode; with a fleet configured, merge "
+                        "rounds also feed a fleet-wide scope")
     p.add_argument("--hotspot-top-k", type=int, default=50,
                    help="default K served per /hotspots query (callers "
                         "may ask for less or up to the candidate bound)")
@@ -401,12 +384,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "+ --fast-encode); window close is then one packed "
                         "fetch. Device trouble self-disables back to the "
                         "one-shot path; exactness is checked per window")
-    p.add_argument("--no-feed-carry", action="store_true",
-                   help="disable the cross-drain carry cache (streaming "
-                        "windows fold repeat stacks host-side and flush "
-                        "their mass once at close; exact either way). "
-                        "PARCA_NO_CAPTURE_HASH=1 separately pins the "
-                        "capture sampler's drain-time hash carry off")
     p.add_argument("--fleet-coordinator", default="",
                    help="host:port of fleet node 0; joining forms the "
                         "cross-host device mesh (jax.distributed) and "
@@ -652,13 +629,11 @@ def run(argv=None) -> int:
     # route through the window recorder's incident machinery below.
     from parca_agent_tpu.runtime import device_telemetry as dtel_mod
 
-    device_telemetry = None
-    if not args.no_device_telemetry:
-        device_telemetry = dtel_mod.DeviceTelemetry(
-            period_s=args.profiling_duration,
-            ring=args.telemetry_ring,
-            incident_interval_s=args.trace_incident_interval)
-        dtel_mod.install(device_telemetry)
+    device_telemetry = dtel_mod.DeviceTelemetry(
+        period_s=args.profiling_duration,
+        ring=args.telemetry_ring,
+        incident_interval_s=args.trace_incident_interval)
+    dtel_mod.install(device_telemetry)
 
     # -- device bring-up (docs/robustness.md "device & fleet health") --------
     # Any config with a device backend gets the demote/promote registry,
@@ -696,8 +671,7 @@ def run(argv=None) -> int:
 
         def claim() -> dict:
             ident = dtel_mod.collect_identity()
-            if device_telemetry is not None:
-                device_telemetry.set_identity(ident)
+            device_telemetry.set_identity(ident)
             return ident
 
         probe = None
@@ -810,7 +784,7 @@ def run(argv=None) -> int:
         aggregator = ShardedDictAggregator(
             capacity=args.aggregator_capacity, overflow="sketch",
             mesh=fleet_mesh(n_shards),
-            carry=args.streaming_window and not args.no_feed_carry)
+            carry=args.streaming_window)
         fallback = CPUAggregator()
     elif args.aggregator in ("dict", "dict+cm"):
         from parca_agent_tpu.aggregator.dict import DictAggregator
@@ -830,7 +804,7 @@ def run(argv=None) -> int:
             # windows; hold wall-clock residency constant across
             # cadences so 1 s windows don't evict 10x faster.
             rotate_min_age=windows_for(6, args.profiling_duration),
-            carry=args.streaming_window and not args.no_feed_carry)
+            carry=args.streaming_window)
         fallback = CPUAggregator()
     else:
         aggregator = CPUAggregator()
@@ -1173,9 +1147,9 @@ def run(argv=None) -> int:
     # the fleet scope through the merger's degrade-safe collectives.
     hotspot_store = None
     if args.hotspots:
-        if not (args.fast_encode and not args.no_encode_pipeline):
-            log.warn("--hotspots needs --fast-encode with the encode "
-                     "pipeline; hotspot rollups disabled")
+        if not args.fast_encode:
+            log.warn("--hotspots needs --fast-encode; hotspot rollups "
+                     "disabled")
         else:
             from parca_agent_tpu.ops.sketch import CountMinSpec
             from parca_agent_tpu.runtime.hotspots import (
@@ -1333,7 +1307,7 @@ def run(argv=None) -> int:
         window_sink=window_sink,
         fast_encode=args.fast_encode,
         streaming_feeder=feeder,
-        encode_pipeline=args.fast_encode and not args.no_encode_pipeline,
+        encode_pipeline=args.fast_encode,
         encode_deadline_s=args.encode_deadline or None,
         quarantine=quarantine,
         admission=admission,
@@ -1410,7 +1384,7 @@ def run(argv=None) -> int:
         if hasattr(source, "hash_carry"):
             # Capture-side hash carry: 1 when the native sampler stamps
             # h1/h2/h3 on each deduped record at drain time (v1h), 0 when
-            # pinned off (PARCA_NO_CAPTURE_HASH) or unavailable.
+            # the sampler refused the tables or predates them.
             out["parca_agent_capture_hash_carry"] = int(source.hash_carry)
         from parca_agent_tpu.web import escape_label_value
 

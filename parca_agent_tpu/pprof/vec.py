@@ -17,7 +17,6 @@ two's-complement uint64, as proto.put_varint does).
 from __future__ import annotations
 
 import ctypes
-import os
 
 import numpy as np
 
@@ -29,8 +28,7 @@ _THRESHOLDS = np.array([1 << (7 * k) for k in range(1, 10)], np.uint64)
 # north-star scale (measured 1.67 s for 25M varints vs 0.15 s for 3.1M —
 # 11x for 8x); one sequential native pass holds ~linear. Loaded lazily,
 # built on demand like the sampler; every helper keeps its numpy path as
-# the build-less fallback (PARCA_NO_NATIVE_VEC=1 forces it, which is how
-# the tests cover both).
+# the build-less fallback.
 _native: ctypes.CDLL | None | bool = False  # False = not yet attempted
 
 
@@ -38,39 +36,37 @@ def _load_native() -> ctypes.CDLL | None:
     global _native
     if _native is False:
         _native = None
-        if not os.environ.get("PARCA_NO_NATIVE_VEC"):
-            try:
-                from parca_agent_tpu.native import ensure_built
+        try:
+            from parca_agent_tpu.native import ensure_built
 
-                lib = ctypes.CDLL(ensure_built("libpavecenc.so",
-                                               "vecenc.cc"))
-                lib.pa_varint_lens.argtypes = [
-                    ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p]
-                lib.pa_put_varints.restype = ctypes.c_int64
-                lib.pa_put_varints.argtypes = [
-                    ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
-                    ctypes.c_void_p, ctypes.c_int64]
-                lib.pa_put_varints_padded.restype = ctypes.c_int64
-                lib.pa_put_varints_padded.argtypes = [
-                    ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
-                    ctypes.c_void_p, ctypes.c_int64, ctypes.c_int32]
-                lib.pa_ragged_copy.restype = ctypes.c_int64
-                lib.pa_ragged_copy.argtypes = [
-                    ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
-                    ctypes.c_int64, ctypes.c_void_p, ctypes.c_void_p,
-                    ctypes.c_void_p, ctypes.c_int64]
-                _native = lib
-            except Exception as e:  # noqa: BLE001 - fallback is numpy
-                _native = None
-                # One warning, not silence: the numpy byte-plane path is
-                # ~1.7x slower per window at north-star scale
-                # (docs/perf.md), and a host missing g++ would otherwise
-                # regress invisibly.
-                from parca_agent_tpu.utils.log import get_logger
+            lib = ctypes.CDLL(ensure_built("libpavecenc.so", "vecenc.cc"))
+            lib.pa_varint_lens.argtypes = [
+                ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p]
+            lib.pa_put_varints.restype = ctypes.c_int64
+            lib.pa_put_varints.argtypes = [
+                ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
+                ctypes.c_void_p, ctypes.c_int64]
+            lib.pa_put_varints_padded.restype = ctypes.c_int64
+            lib.pa_put_varints_padded.argtypes = [
+                ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
+                ctypes.c_void_p, ctypes.c_int64, ctypes.c_int32]
+            lib.pa_ragged_copy.restype = ctypes.c_int64
+            lib.pa_ragged_copy.argtypes = [
+                ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
+                ctypes.c_int64, ctypes.c_void_p, ctypes.c_void_p,
+                ctypes.c_void_p, ctypes.c_int64]
+            _native = lib
+        except Exception as e:  # noqa: BLE001 - fallback is numpy
+            _native = None
+            # One warning, not silence: the numpy byte-plane path is
+            # ~1.7x slower per window at north-star scale
+            # (docs/perf.md), and a host missing g++ would otherwise
+            # regress invisibly.
+            from parca_agent_tpu.utils.log import get_logger
 
-                get_logger("pprof.vec").warn(
-                    "native varint kernel unavailable; falling back to "
-                    "the numpy encode path", error=repr(e))
+            get_logger("pprof.vec").warn(
+                "native varint kernel unavailable; falling back to "
+                "the numpy encode path", error=repr(e))
     return _native
 
 

@@ -80,7 +80,7 @@ it; such a number is settled as absent. The capture's pid column holds
 thread-group ids, listed while any thread lives.)
 
 ``PARCA_NO_PID_GENERATION=1`` pins the hardening off — the bench zoo's
-misattribution control arm, same idiom as PARCA_NO_CAPTURE_HASH.
+misattribution control arm.
 """
 
 from __future__ import annotations

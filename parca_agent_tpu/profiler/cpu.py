@@ -473,7 +473,7 @@ class CPUProfiler:
         """Promotion-gate A/B: does the device result agree with the CPU
         fallback's? Profile lists compare per-pid (mass, unique-stack
         count) digests; the fast path's raw counts compare total window
-        mass (the same invariant bench.py's A/B phases assert)."""
+        mass."""
         def norm(o):
             if isinstance(o, tuple) and len(o) == 2 \
                     and isinstance(o[0], str):

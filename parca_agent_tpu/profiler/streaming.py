@@ -1,10 +1,9 @@
 """Streaming window feeder: ship capture drains to the aggregation device
 DURING the window.
 
-This is the production realization of the boundary the bench measures
-(bench.py "steady-state close"): the reference's BPF map absorbs samples
-in kernel as they happen (bpf/cpu/cpu.bpf.c:110-116), so its window close
-never re-ships the window; here each once-a-second drain is fed to the
+The reference's BPF map absorbs samples in kernel as they happen
+(bpf/cpu/cpu.bpf.c:110-116), so its window close never re-ships the
+window; here each once-a-second drain is fed to the
 dict aggregator's device table as it lands (H2D + the probe/accumulate
 kernel ride the otherwise-idle window), and the profiler's window close
 is just close_window() — one pack kernel, one packed fetch.

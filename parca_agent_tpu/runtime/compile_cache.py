@@ -5,7 +5,7 @@ Every program the dict path runs is keyed on a shape bucket (the feed on
 so a cold process compiles each bucket it meets — seconds apiece on a
 TPU — and a restarted agent would pay them all again. JAX can keep the
 compiled binaries on disk; this module decides where, once, for every
-entry point (the CLI, bench.py, chip_smoke.py's children).
+entry point (the CLI, chip_smoke.py's children).
 
 The directory is part of the cache key, so it must not move between
 runs: it is placed from outside with ``JAX_COMPILATION_CACHE_DIR`` (JAX

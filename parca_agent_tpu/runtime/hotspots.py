@@ -9,8 +9,7 @@ hotspot identification across a large fleet needs hierarchical
 aggregation of compact summaries, not raw profile shipping).
 
 The unit is a :class:`WindowSummary`: a count-min sketch over the whole
-window's (stack-hash, count) stream (ops/sketch.py — the `ab_sketch`
-bench phase holds its error envelope at mean rel. err ~0.002) plus an
+window's (stack-hash, count) stream (ops/sketch.py) plus an
 exact top-candidates table keyed by the 64-bit content hash
 (h1 << 32 | h2, the same identity the fleet merge dedups on), each entry
 carrying enough frame/label context to render a human-readable answer.
@@ -440,7 +439,7 @@ class HotspotStore:
 
     def fold(self, s: WindowSummary) -> None:
         """Fold one node-local window summary into the level hierarchy
-        (public so the bench can drive synthetic streams)."""
+        (public so tests can drive synthetic streams)."""
         with self._lock:
             for k, e in s.entries.items():
                 if e[_FRAMES] is not None:

@@ -33,7 +33,7 @@ a rollup seals it is diffed against the group's BASELINE:
     only when the shift clears BOTH the noise floor (times ``k_sigma``)
     and the sketch error bound, plus an absolute ``min_count`` and a
     relative ``min_ratio`` — four gates, so 30 clean windows produce
-    zero verdicts (the bench bar) while a genuine 2x shift clears all
+    zero verdicts while a genuine 2x shift clears all
     four within two rollup intervals;
   * a group whose normalized distribution distance vs its baseline
     exceeds ``drift_threshold`` (EWMA-smoothed, edge-triggered) emits a
@@ -84,7 +84,7 @@ _U32 = struct.Struct("<I")
 class RegressionSpec:
     """Sizing and sensitivity of the sentinel. The defaults detect a 2x
     shift on a hot binary within two rollup intervals while holding 30+
-    clean windows verdict-free (the bench-regress acceptance bars)."""
+    clean windows verdict-free (tests/test_regression.py holds both)."""
 
     interval_s: float = 60.0        # rollup bucket span
     baseline_rollups: int = 5       # sealed rollups frozen into a baseline

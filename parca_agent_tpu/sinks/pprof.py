@@ -5,8 +5,8 @@ This is the PRIMARY backend: its output is the agent's contract with
 the store, so it is deliberately nothing more than the pre-sink ship
 hook behind a name — the registry invokes the exact same bound callable
 (`CPUProfiler._write_encoded`) the profiler used to call directly, so
-the bytes through the registry are identical by construction (and the
-bench's sink_fanout phase + tests/test_sinks.py enforce the sha256).
+the bytes through the registry are identical by construction (and
+tests/test_sinks.py enforces the sha256).
 
 Unlike secondary sinks, a pprof emit failure is NOT swallowed by the
 registry: it propagates to the encode pipeline's ship guard, which

@@ -119,7 +119,7 @@ production pays one module-attribute read per site.
 Determinism: every probabilistic draw comes from one seeded
 ``random.Random`` and every time window from one injectable clock, so a
 fixed seed + deterministic call order reproduces the same fault schedule
-— the chaos suite and the bench soak phase both rely on this.
+— the chaos suite relies on this.
 
 Rule spec grammar (CLI/env), semicolon-separated::
 

@@ -11,8 +11,8 @@ every mutant to the parser, and flag any escaping non-PoisonInput
 exception.
 
 Deterministic by construction — one ``random.Random(seed)`` drives every
-draw — so `make fuzz`, the chaos suite, and the bench ``ingest_poison``
-phase all reproduce the same mutant stream bit-for-bit.
+draw — so `make fuzz` and the chaos suite reproduce the same mutant
+stream bit-for-bit.
 
 Usage:
 
@@ -239,9 +239,3 @@ def fuzz_parser(name: str, n: int = 500, seed: int = 42) -> dict:
                 escapes.append(f"mutant {i}: {e!r}")
     return {"parser": name, "mutations": n, "benign": benign,
             "contained": contained, "escapes": escapes}
-
-
-def fuzz_all(n: int = 500, seed: int = 42) -> dict:
-    """Every registered parser; the bench ingest_poison phase reports
-    this dict, the chaos suite asserts each escapes list is empty."""
-    return {name: fuzz_parser(name, n=n, seed=seed) for name in PARSERS}

@@ -657,6 +657,57 @@ def test_noisy_tenant_storm_through_real_window_loop():
         assert by_key[(15, str(p))] == ctl_key[(15, str(p))]
 
 
+def test_pid_axis_grows_tenfold_under_32_tenants_one_of_them_noisy():
+    """One dict aggregator rides three tiers of pids (two stacks a pid,
+    32 tenants, the quota twice the fair share at the top tier); at the
+    top tier one tenant sends ten times its share. Every window's counts
+    hold the snapshot's mass, the noisy tenant leaves full fidelity, and
+    none of the 31 in-quota tenants ever does."""
+    from parca_agent_tpu.aggregator.dict import DictAggregator
+
+    tiers, n_tenants = (500, 2_000, 5_000), 32
+
+    class Spread:
+        stats: dict = {}
+
+        def resolve(self, pid):
+            return f"svc:t{int(pid) % n_tenants}"
+
+    def tier_snapshot(n_pids, noisy_mult):
+        n = 2 * n_pids
+        pids = np.repeat(np.arange(1, n_pids + 1, dtype=np.int64), 2)
+        row = np.arange(n, dtype=np.uint64)
+        stacks = np.zeros((n, STACK_SLOTS), np.uint64)
+        stacks[:, 0] = 0x10000 + row * 0x40
+        stacks[:, 1] = 0x900000 + (row % 4096) * 0x10
+        counts = np.ones(n, np.int64)
+        counts[pids % n_tenants == 0] = noisy_mult
+        return WindowSnapshot(
+            pids=pids, tids=pids, counts=counts,
+            user_len=np.full(n, 2, np.int32),
+            kernel_len=np.zeros(n, np.int32),
+            stacks=stacks, mappings=MappingTable.empty())
+
+    top = max(tiers)
+    adm = AdmissionController(
+        Spread(), quota_samples=2 * (2 * top) // n_tenants,
+        burst_windows=1, degrade_after=1, escalate_after=2,
+        recover_windows=2)
+    agg = DictAggregator(capacity=1 << 16, overflow="sketch")
+    for n_pids in tiers:
+        snap = tier_snapshot(n_pids, 10 if n_pids == top else 1)
+        for _ in range(3):
+            adm.account_window(snap.pids, snap.counts)
+            assert int(np.asarray(agg.window_counts(snap)).sum()) \
+                == snap.total_samples(), n_pids
+            adm.tick_window(registry_rows=int(agg._next_id))
+        assert [t for t in range(1, n_tenants)
+                if adm.tenant_level(f"svc:t{t}") != LEVEL_FULL] == []
+        assert (adm.tenant_level("svc:t0") != LEVEL_FULL) \
+            == (n_pids == top)
+    assert agg._next_id == 2 * top  # every tier's new stacks registered
+
+
 def test_profiler_ticks_admission_on_window_clock():
     fs = _fs({1: "/system.slice/a.service"})
     adm = _controller(fs)

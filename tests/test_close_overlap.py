@@ -94,6 +94,56 @@ def test_delta_fetch_engages_and_stays_exact():
     assert "delta_fetch" not in full.timings
 
 
+def test_delta_arm_under_a_split_close_ships_the_full_arms_pprof_bytes():
+    """The two arms a close can take, each through its own encoder, over
+    a steady hot set fed in drain-sized chunks: the delta arm (from its
+    second hot window under the split close, the next window's first
+    drain fed between dispatch and collect) ships every window's pprof
+    bytes as the full-fetch arm does, and its steady close moves under a
+    quarter of the full close's bytes."""
+    from parca_agent_tpu.pprof.window_encoder import WindowEncoder
+
+    rows, chunk = 4096, 1024
+    snap = _snap(seed=77, rows=rows, pids=32)
+    lo, hi = rows // 8, rows // 4
+    arms = {k: DictAggregator(capacity=1 << 14, overflow="raise",
+                              delta_fetch=k == "delta")
+            for k in ("full", "delta")}
+    encs = {k: WindowEncoder(a) for k, a in arms.items()}
+    h = arms["full"].hash_rows(snap)
+
+    def feed(a, a0, a1):
+        for c0 in range(a0, a1, chunk):
+            a.feed(snap, h, c0, min(c0 + chunk, a1))
+
+    def blobs(k, counts, w):
+        return [(pid, bytes(b)) for pid, b in encs[k].encode(
+            counts, 1_000 + w, 10**10, 10**7)]
+
+    shipped = {k: [] for k in arms}
+    for k, a in arms.items():
+        feed(a, 0, rows)  # the population window: every stack a miss
+        c = a.close_window()
+        assert int(c.sum()) == snap.total_samples()
+        shipped[k].append(blobs(k, c, 0))
+    for w in range(1, 5):
+        for k, a in arms.items():
+            feed(a, lo, hi)
+            if k == "delta" and w >= 2:
+                handle = a.close_dispatch()
+                a.feed(snap, h, lo, lo + chunk // 2)  # lands in the twin
+                c = a.close_collect(handle)
+                a.discard_open_window()
+            else:
+                c = a.close_window()
+            assert int(c.sum()) == int(snap.counts[lo:hi].sum()), (k, w)
+            shipped[k].append(blobs(k, c, w))
+    assert shipped["delta"] == shipped["full"]
+    assert arms["delta"].stats.get("delta_closes", 0) >= 2
+    assert 4 * arms["delta"].stats["fetch_bytes_last"] \
+        < arms["full"].stats["fetch_bytes_last"]
+
+
 def test_delta_misprediction_grows_then_falls_back():
     """A window touching far more blocks than predicted must retry (grow
     to the reported population, or full-fetch once delta stops being a

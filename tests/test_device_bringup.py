@@ -63,7 +63,7 @@ def test_default_cache_dir_is_the_same_checkout_path_from_any_process(
     # The one place in the tree that sets it is the helper.
     hits = subprocess.run(
         ["grep", "-rlE", r"jax_compilation_cache_dir|compilation_cache_dir\(",
-         "--include=*.py", "parca_agent_tpu", "bench.py", "chip_smoke.py"],
+         "--include=*.py", "parca_agent_tpu", "chip_smoke.py"],
         capture_output=True, text=True, cwd=REPO).stdout.split()
     assert hits == ["parca_agent_tpu/runtime/compile_cache.py"]
 

@@ -34,9 +34,10 @@ def _no_leaked_injector():
     faults.install(None)
 
 
-def _snap(seed=1):
-    return generate(SyntheticSpec(n_pids=5, n_unique_stacks=40, n_rows=40,
-                                  total_samples=1_000, seed=seed))
+def _snap(seed=1, n_pids=5, rows=40, samples=1_000):
+    return generate(SyntheticSpec(n_pids=n_pids, n_unique_stacks=rows,
+                                  n_rows=rows, total_samples=samples,
+                                  seed=seed))
 
 
 class CollectingWriter:
@@ -463,7 +464,10 @@ def test_device_failure_strikes_demote_then_shadow_recovers():
 # -- the scripted outage acceptance test --------------------------------------
 
 
-def test_scripted_device_outage_zero_windows_lost():
+@pytest.mark.parametrize("n_pids, rows, samples, seed", [
+    (5, 40, 1_000, 1), (8, 64, 2_000, 3)])
+def test_scripted_device_outage_zero_windows_lost(n_pids, rows, samples,
+                                                  seed):
     """THE acceptance bar (ISSUE criteria): chaos injects a 2-window
     device.dispatch hang and one device.probe hang; zero windows may be
     dropped (every demoted window ships via the CPU fallback), demotion
@@ -476,8 +480,7 @@ def test_scripted_device_outage_zero_windows_lost():
                                probe_timeout_s=0.2, probe_deadline_s=2.0,
                                promote_after=1, cooldown_windows=1)
     reg.start()
-    snap = _snap()
-    n_pids = 5
+    snap = _snap(seed, n_pids, rows, samples)
 
     class Source:
         def __init__(self, budget):
@@ -512,6 +515,10 @@ def test_scripted_device_outage_zero_windows_lost():
     assert s["stats"]["hangs_total"] == 2          # both hangs consumed
     assert faults.get().stats()["device.probe"] == 1  # probe hang fired
     assert s["state"] == STATE_HEALTHY             # promoted back
+    # The outage was ridden out on the fallback, and promotion passed
+    # its shadow window.
+    assert s["stats"]["fallback_windows_total"] >= 1
+    assert s["stats"]["shadow_windows_total"] >= 1
     # Promotion within the configured re-probe budget: cooldowns of 1+2
     # windows, one probe round each, plus the shadow window — bounded
     # well under the window budget above.
@@ -748,21 +755,3 @@ def test_shadow_compare_digests():
     b[0].values[0] += 1                  # one count diverges
     assert not shadow_compare(a, b)
     assert not shadow_compare(a, b[:-1])  # a missing pid diverges
-
-
-def test_bench_device_outage_phase_scores_zero_loss():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location("bench", "bench.py")
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    r = bench._device_outage()
-    bench._finalize_result(r, require_full_scale=False, require_device=False)
-    assert r["windows_lost"] == 0
-    assert r["promoted"]
-    assert r["scored"] is True
-    # The satellite's uniformity contract: a violated acceptance bar
-    # reads scored: false through the same stamp, no bespoke strings.
-    bad = {"error": "windows_lost=3"}
-    bench._finalize_result(bad, require_full_scale=False, require_device=False)
-    assert bad["scored"] is False

@@ -202,6 +202,51 @@ def test_byte_cap_evicts_oldest():
     assert recent["windows"] > 0
 
 
+@pytest.mark.parametrize("skew, k", [(0.55, 50), (1.0, 20)],
+                         ids=["every-window-prunes", "long-dormant-tail"])
+def test_two_hours_of_pruned_windows_answer_with_the_exact_top_k(skew, k):
+    """720 windows of a zipf population sixteen times the candidate
+    bound, each with its own Poisson noise: the whole range is served
+    from the hour rollups and names the top K of the exact aggregate
+    (agreement 0.99 or better, counts within 2%), while every ring sits
+    at its byte cap with the oldest windows evicted."""
+    uniques, windows, cap = 1 << 12, 720, 2 << 20
+    spec = HotspotSpec(k=k, candidates=256,
+                       cm=CountMinSpec(depth=4, width=1 << 9))
+    store = _store(spec, level_bytes=cap)
+    rng = np.random.default_rng(0xA77)
+    h1 = rng.integers(0, 1 << 32, uniques, dtype=np.uint64).astype(np.uint32)
+    h2 = np.arange(uniques, dtype=np.uint32)
+    rates = 200.0 / np.arange(1, uniques + 1, dtype=np.float64) ** skew
+    rng.shuffle(rates)  # key order says nothing of hotness
+    exact = np.zeros(uniques, np.int64)
+    for w in range(windows):
+        counts = rng.poisson(rates).astype(np.int64)
+        live = np.flatnonzero(counts)
+        assert len(live) > spec.candidates  # every window prunes
+        exact += counts
+        store.fold(WindowSummary.build(
+            h1[live], h2[live], counts[live],
+            lambda i, live=live: _ctx(int(live[i])), spec,
+            w * 10 * SEC, 10 * SEC))
+
+    ans = store.query(k=k, t0_s=0, t1_s=windows * 10)
+    assert ans["level"] == "1h" and ans["cover"] > 0.99
+    assert not ans["exact"]  # pruned keys' mass is estimated, and said so
+    key64 = (h1.astype(np.uint64) << np.uint64(32)) | h2.astype(np.uint64)
+    want = {f"0x{int(key64[i]):016x}": int(exact[i])
+            for i in np.argsort(exact)[-k:]}
+    got = {e["stack"]: e["count"] for e in ans["entries"]}
+    agreed = set(got) & set(want)
+    assert len(agreed) >= 0.99 * k
+    assert all(abs(got[s] - want[s]) <= 0.02 * want[s] for s in agreed)
+
+    levels = [lv for lv in store.metrics()["levels"]
+              if lv["scope"] == "local"]
+    assert all(lv["bytes"] <= 1.05 * cap for lv in levels)
+    assert sum(lv["evictions"] for lv in levels) > 0
+
+
 def test_label_selector_filters_and_unlabeled_entries_drop():
     store = _store(_spec(k=10, candidates=128))
     _fold_windows(store, 3)

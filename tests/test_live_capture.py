@@ -182,6 +182,71 @@ def test_empty_records():
     assert len(snap) == 0
 
 
+class _ScriptedSamplerLib:
+    """The native library with its sampler calls scripted (no perf
+    events are opened); the decoders are the real ones. ``refuse`` says
+    where the hash carry is turned down: "tables" (pa_sampler_set_hash
+    answers non-zero), "symbol" (a build from before the carry has no
+    such call) or "drain" (the hashed drain fails mid-session)."""
+
+    def __init__(self, refuse: str, record: bytes):
+        self._real, self._refuse, self._record = load_native(), refuse, record
+        self.drains: list[str] = []
+
+    def __getattr__(self, name):
+        if name == "pa_sampler_set_hash":
+            if self._refuse == "symbol":
+                raise AttributeError(name)
+            return lambda *args: -1 if self._refuse == "tables" else 0
+        return getattr(self._real, name)
+
+    def pa_sampler_create2(self, hz, flags, dump_bytes):
+        return 1
+
+    def pa_sampler_drain_dedup2(self, handle, buf, cap):
+        self.drains.append("hashed")
+        return -1
+
+    def pa_sampler_drain_dedup(self, handle, buf, cap):
+        self.drains.append("hashless")
+        ctypes.memmove(buf, self._record, len(self._record))
+        return len(self._record)
+
+    pa_sampler_start = pa_sampler_lost = pa_sampler_truncated = \
+        pa_sampler_dedup_hits = pa_sampler_dedup_overflow = \
+        staticmethod(lambda handle: 0)
+    pa_sampler_n_cpus = staticmethod(lambda handle: 1)
+    pa_sampler_destroy = staticmethod(lambda handle: None)
+
+
+@pytest.mark.parametrize("refuse", ["tables", "symbol", "drain"])
+def test_a_sampler_that_refuses_the_hash_carry_drains_hashless(
+        refuse, monkeypatch):
+    """The hash carry's off-state is entered by what the sampler does,
+    not by a switch: whichever call turns the carry down, the drain is
+    the hashless v1d one (six columns, the feeder hashes host-side), the
+    sampler says so through ``hash_carry`` (the
+    ``parca_agent_capture_hash_carry`` gauge), and it stays so."""
+    from parca_agent_tpu.capture import live
+
+    lib = _ScriptedSamplerLib(
+        refuse, _pack_v1d(7, 8, [0xFFFF800000000010], [0x401000], 5))
+    monkeypatch.setattr(live, "load_native", lambda: lib)
+    sampler = PerfEventSampler(frequency_hz=99, window_s=0.01)
+    try:
+        assert sampler.hash_carry == (refuse == "drain")
+        for _ in range(2):
+            (chunk,) = sampler._drain_columnar()
+            pids, _tids, _ulen, _klen, _stacks, counts = chunk
+            assert (pids.tolist(), counts.tolist()) == ([7], [5])
+        assert not sampler.hash_carry
+        # A refused hashed drain is tried once and never again.
+        assert lib.drains == (["hashed"] if refuse == "drain" else []) \
+            + ["hashless", "hashless"]
+    finally:
+        sampler.close()
+
+
 @pytest.fixture(scope="session")
 def live_sampler():
     try:

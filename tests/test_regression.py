@@ -355,16 +355,21 @@ def test_adopt_missing_file_is_clean_cold_start(tmp_path):
 
 # -- the real window loop (pipeline integration + chaos) ---------------------
 
+def _pipeline_snap():
+    return generate(SyntheticSpec(
+        n_pids=10, n_unique_stacks=256, n_rows=256, total_samples=2500,
+        mean_depth=8, seed=11))
+
+
 def _pipeline_run(n_windows, fault_spec=None, sentinel_spec=None,
-                  shift_after=None):
+                  shift_after=None, path=None):
     """Drive synthetic windows through the REAL encode pipeline with
     the sentinel riding the rollup hook; returns (sentinel, pipeline,
     sha256 of shipped pprof bytes)."""
-    snap = generate(SyntheticSpec(
-        n_pids=10, n_unique_stacks=256, n_rows=256, total_samples=2500,
-        mean_depth=8, seed=11))
+    snap = _pipeline_snap()
     agg = DictAggregator(capacity=1 << 14)
     sent = RegressionSentinel(spec=sentinel_spec or _spec())
+    sent.path = path
     sha = hashlib.sha256()
 
     def ship(out, prep):
@@ -411,9 +416,7 @@ def test_pipeline_attribution_by_synthetic_build_id():
 def test_sentinel_does_not_perturb_pprof_bytes():
     base_sent, _, sha_with = _pipeline_run(6)
     # The same windows with the sentinel disabled (no rollup hook).
-    snap = generate(SyntheticSpec(
-        n_pids=10, n_unique_stacks=256, n_rows=256, total_samples=2500,
-        mean_depth=8, seed=11))
+    snap = _pipeline_snap()
     agg = DictAggregator(capacity=1 << 14)
     sha = hashlib.sha256()
     pipe = EncodePipeline(WindowEncoder(agg),
@@ -430,12 +433,38 @@ def test_sentinel_does_not_perturb_pprof_bytes():
     assert sha.hexdigest() == sha_with
 
 
+def test_pipeline_2x_shift_on_one_build_names_it_within_two_rollups():
+    """Through the real pipeline: ten clean windows give no verdict, then
+    every stack of one shared object doubles and the sentinel names that
+    build `regressed` within two rollup intervals, with no window lost."""
+    shift_after, victim = 10, f"{2:040x}"
+    sent, pipe, _ = _pipeline_run(14, shift_after=shift_after)
+    assert pipe.stats["windows_lost"] == 0
+    v = sent.verdicts(limit=sent.spec.verdict_ring)["verdicts"]
+    shift_at_s = _pipeline_snap().time_ns / 1e9 \
+        + shift_after * sent.spec.interval_s
+    assert [r for r in v if r["t_s"] <= shift_at_s] == []
+    hits = [r for r in v
+            if r["kind"] == "regressed" and r["build"] == victim]
+    assert hits
+    assert min(r["t_s"] for r in hits) - shift_at_s \
+        <= 2 * sent.spec.interval_s
+    assert {r["build"] for r in v if r["kind"] == "regressed"} == {victim}
+
+
 @pytest.mark.chaos
-def test_chaos_fold_error_costs_judgment_never_windows():
+@pytest.mark.parametrize("fault, counted, folded", [
+    ("regression.fold:error:count=3", {"fold_errors": 3}, 5),
+    ("regression.baseline:error:count=2",
+     {"fold_errors": 0, "baseline_save_errors": 2}, 8),
+], ids=["fold", "baseline-save"])
+def test_chaos_fold_error_costs_judgment_never_windows(
+        fault, counted, folded, tmp_path):
     sent, pipe, sha_chaos = _pipeline_run(
-        8, fault_spec="regression.fold:error:count=3")
-    assert sent.stats["fold_errors"] == 3
-    assert sent.stats["windows_folded"] == 5
+        8, fault_spec=fault, sentinel_spec=_spec(save_every=1),
+        path=str(tmp_path / "baselines.bin"))
+    assert {k: sent.metrics()[k] for k in counted} == counted
+    assert sent.stats["windows_folded"] == folded
     assert pipe.stats["windows_lost"] == 0
     assert pipe.stats["rollup_errors"] == 0  # fail-open inside the hook
     _, _, sha_clean = _pipeline_run(8)

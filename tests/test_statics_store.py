@@ -368,28 +368,44 @@ def test_pipeline_snapshot_fault_no_disable_no_double_ship(tmp_path):
     assert store.snapshot_info()["present"]
 
 
-@pytest.mark.chaos
-def test_corrupt_snapshot_degrades_to_cold_zero_windows_lost(tmp_path):
-    """The acceptance drill: a fully corrupt snapshot at startup adopts
-    nothing, and the first window still aggregates, encodes, and ships —
-    zero windows lost, just cold."""
-    snap, store, path = _warm_pair(tmp_path, seed=14, n_pids=5, rows=100)
-    data = bytearray(open(path, "rb").read())
+def _flip_every_seventh(data: bytes) -> bytes:
+    data = bytearray(data)
     for i in range(len(ss._MAGIC), len(data), 7):
         data[i] ^= 0xA5
-    open(path, "wb").write(bytes(data))
+    return bytes(data)
+
+
+@pytest.mark.chaos
+@pytest.mark.parametrize("corrupt", [
+    _flip_every_seventh,
+    lambda data: data[: len(ss._MAGIC) + _FHEAD // 2],
+    lambda data: b"not a snapshot" * 64,
+], ids=["bit-flipped", "torn-in-the-first-frame", "garbage"])
+def test_corrupt_snapshot_degrades_to_cold_zero_windows_lost(tmp_path,
+                                                             corrupt):
+    """The acceptance drill: a snapshot no record of which survives
+    adopts nothing at startup, and the first window still aggregates,
+    encodes, and ships the bytes a cold start ships — zero windows
+    lost, just cold."""
+    snap, store, path = _warm_pair(tmp_path, seed=14, n_pids=5, rows=100)
+    data = open(path, "rb").read()
+    open(path, "wb").write(corrupt(data))
+    cold = DictAggregator(capacity=1 << 12)
+    want = _blobs(WindowEncoder(cold).encode(
+        np.asarray(cold.window_counts(snap)), snap.time_ns, snap.window_ns,
+        snap.period_ns))
     agg = DictAggregator(capacity=1 << 12)
     enc = WindowEncoder(agg)
     out = StaticsStore(path).adopt(agg, enc, snap.period_ns)
     assert out["adopted"] == 0
     shipped = []
-    pipe = EncodePipeline(enc, ship=lambda o, p: shipped.append(len(o)))
+    pipe = EncodePipeline(enc, ship=lambda o, p: shipped.append(_blobs(o)))
     c = np.asarray(agg.window_counts(snap))
     assert int(c.sum()) == snap.total_samples()
     assert pipe.submit(c, snap.time_ns, snap.window_ns,
                        snap.period_ns) is not None
     assert pipe.close()
-    assert shipped == [5]
+    assert shipped == [want]
     assert pipe.stats["windows_lost"] == 0
 
 

@@ -1027,22 +1027,28 @@ class _CountedClock:
 
 
 @pytest.mark.parametrize("work,cpu_share", [
-    (lambda: _burn(0.05), (0.7, 1.1)),
-    (lambda: time.sleep(0.05), (0.0, 0.1)),
+    (lambda: _burn(0.05), None),
+    (lambda: time.sleep(0.05), 0.1),
 ], ids=["burns", "sleeps"])
 def test_a_span_that_asks_records_its_threads_cpu_beside_its_wall(
         work, cpu_share):
-    """A span that works has CPU near its wall; one that waits has wall
-    and next to no CPU."""
+    """A span that works has the CPU its thread's own clock saw go by
+    (within one 10 ms tick; never a share of the wall, which on a loaded
+    machine a busy loop gets any part of); one that waits has wall and
+    next to no CPU."""
     rec = FlightRecorder()
     tr = rec.begin()
     with tr.span("drain", usage=True):
+        c0 = time.thread_time()
         work()
+        burnt = time.thread_time() - c0
     tr.complete()
     drain = _spans_of(rec)["drain"]
-    lo, hi = cpu_share
-    assert lo * drain["duration_s"] <= drain.get("cpu_s", 0.0) \
-        <= hi * drain["duration_s"]
+    cpu = drain.get("cpu_s", 0.0)
+    if cpu_share is None:
+        assert cpu > 0.0 and abs(cpu - burnt) <= 0.010
+    else:
+        assert cpu <= cpu_share * drain["duration_s"]
     assert "cpu_s" not in _spans_of(rec)["total"]
 
 

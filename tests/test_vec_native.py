@@ -58,6 +58,30 @@ def test_native_matches_numpy(native_lib, monkeypatch, maxv):
     np.testing.assert_array_equal(pad_nat, pad_np)
 
 
+@pytest.mark.parametrize("scattered", [False, True],
+                         ids=["packed", "scattered"])
+def test_ragged_gather_native_matches_numpy(native_lib, monkeypatch,
+                                            scattered):
+    rng = np.random.default_rng(5)
+    flat = rng.integers(0, 1 << 62, 5000, dtype=np.uint64)
+    lens = rng.integers(0, 40, 300).astype(np.int64)  # empty runs too
+    starts = rng.integers(0, len(flat) - 40, 300).astype(np.int64)
+    kw = {}
+    if scattered:  # runs land at caller-chosen, gapped, shuffled places
+        slots = rng.permutation(300).astype(np.int64) * 48
+        kw = dict(out_starts=slots)
+
+    def gather():
+        out = np.zeros(300 * 48, np.uint64) if scattered else None
+        return vec.ragged_gather(flat, starts, lens, out=out, **kw)
+
+    out_nat, offs_nat = gather()
+    _numpy_only(monkeypatch)
+    out_np, offs_np = gather()
+    np.testing.assert_array_equal(out_nat, out_np)
+    np.testing.assert_array_equal(offs_nat, offs_np)
+
+
 def test_bounds_check_raises_both_paths(native_lib, monkeypatch):
     """A region leaving the buffer raises IndexError — native checks
     before writing; numpy's fancy indexing raises on its own."""
@@ -167,3 +191,44 @@ def test_native_build_failure_falls_back_with_a_warning(monkeypatch):
         assert vec._load_native() is None        # pinned to the fallback
     finally:
         monkeypatch.setattr(vec, "_native", False)  # don't poison others
+
+
+def test_a_window_encodes_to_the_same_bytes_without_the_library(
+        native_lib, monkeypatch):
+    """The numpy arm is entered by a library that fails to build or
+    load, and by nothing else: a window encoded that way (first layout,
+    then a patched window with new rows) ships the bytes the native arm
+    ships."""
+    from parca_agent_tpu import native as native_mod
+    from parca_agent_tpu.aggregator.dict import DictAggregator
+    from parca_agent_tpu.capture.synthetic import SyntheticSpec, generate
+    from parca_agent_tpu.pprof.window_encoder import WindowEncoder
+
+    snap = generate(SyntheticSpec(n_pids=12, n_unique_stacks=600,
+                                  n_rows=600, total_samples=5000,
+                                  mean_depth=14, kernel_fraction=0.2,
+                                  seed=21))
+
+    def windows():
+        agg = DictAggregator(capacity=1 << 12)
+        enc = WindowEncoder(agg)
+        counts = np.asarray(agg.window_counts(snap)).copy()
+        first = counts.copy()
+        first[::7] = 0  # these stacks appear in the second window
+        return [[(pid, bytes(b)) for pid, b in enc.encode(
+            c, snap.time_ns + w, snap.window_ns, snap.period_ns)]
+            for w, c in enumerate((first, counts))]
+
+    want = windows()
+
+    def boom(*a, **kw):
+        raise OSError("libpavecenc.so: cannot open shared object file")
+
+    monkeypatch.setattr(vec, "_native", False)   # force a fresh load
+    monkeypatch.setattr(native_mod, "ensure_built", boom)
+    try:
+        got = windows()
+        assert vec._native is None               # the load was tried, failed
+    finally:
+        monkeypatch.setattr(vec, "_native", False)  # don't poison others
+    assert got == want

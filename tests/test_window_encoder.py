@@ -392,7 +392,7 @@ def test_encoder_churn_fuzz_multi_window(seed, agg_kind):
 def test_encoder_views_are_invalidated_by_the_next_encode():
     """views=True returns zero-copy memoryviews into the template buffer,
     valid only until the next encode() — which patches counts in place.
-    Consumers must finish within their window (the bench does); this pins
+    Consumers must finish within their window; this pins
     the aliasing so nobody 'optimizes' the default copy path away."""
     snap, agg, enc, c_full = _churn_setup(seed=41, n_pids=4, rows=80)
     out1 = enc.encode(c_full, snap.time_ns, snap.window_ns, snap.period_ns,

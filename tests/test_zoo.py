@@ -3,7 +3,7 @@ rows must clear their bars through the REAL window loop, and the
 ``zoo.scenario`` chaos site must fail open (a poisoned window build
 degrades to an idle filler — the run narrows, it never dies).
 
-The full sweep is `make bench-zoo` (bench.py's workload_zoo phase);
+The full sweep is `make bench-zoo` (`python -m parca_agent_tpu.bench_zoo`);
 this suite pins the contracts cheaply at reduced scale: seeded
 determinism (same seed -> same schedule, same bars, same shipped-bytes
 digest), schedule coverage (every scenario exactly once), one
@@ -60,7 +60,12 @@ def test_seeded_run_is_digest_identical():
     a = run_scenario("deep_stacks", 31, scale=0.25)
     b = run_scenario("deep_stacks", 31, scale=0.25)
     assert a["digest"] == b["digest"]
-    assert a["bars"] == b["bars"]
+    # Every bar but the one that reads a wall clock: `a` is this file's
+    # first run, and in a worker that starts with this file its first
+    # window carries the lazy imports, seconds over the 2 s ceiling.
+    seeded = [k for k in a["bars"] if k != "close_latency_ceiling"]
+    assert [a["bars"][k] for k in seeded] == [b["bars"][k] for k in seeded]
+    assert a["bars"].keys() == b["bars"].keys()
     c = run_scenario("deep_stacks", 32, scale=0.25)
     assert a["digest"] != c["digest"]  # the seed genuinely feeds content
 
