@@ -686,6 +686,9 @@ class DictAggregator:
         # flight; drained at the next window boundary (same safety
         # contract as rotation).
         self._invalidate_pending: set[int] = set()
+        # The last compaction's (registry_epoch it led to, old id -> new
+        # id or -1), for the mirrors that follow it (id_remap).
+        self._remap: tuple[int, np.ndarray] | None = None
         # Per-id window number the id last had samples (eviction clock).
         self._last_seen = np.zeros(self._id_cap, np.int32)
         # Host mirror (source of truth).
@@ -898,6 +901,20 @@ class DictAggregator:
         return (self.stats.get("rotations", 0)
                 + self.stats.get("invalidation_compactions", 0)
                 + self.stats.get("reclaims", 0))
+
+    def id_remap(self, since_epoch: int) -> np.ndarray | None:
+        """Where the compaction that took the id space from epoch
+        `since_epoch` to the current one put every id: an int64 array
+        over the old id space, the new id of a survivor and -1 for an
+        id given away; survivors keep their order. One remap is kept, the
+        last boundary's: a mirror that is further behind than that (it
+        missed a compaction, or was never synced) gets None and
+        rebuilds from nothing. The array is never written again."""
+        remap = self._remap
+        if remap is not None and remap[0] == self.registry_epoch \
+                and since_epoch == remap[0] - 1:
+            return remap[1]
+        return None
 
     def take_touched_pids(self, token) -> tuple[int, set | None]:
         """The pids whose registry was created, grown or dropped since
@@ -2034,13 +2051,20 @@ class DictAggregator:
         if drop <= 0:
             return
         with trace.child("dict_reclaim"):
-            keep = np.ones(n, bool)
-            keep[cold[np.argsort(last[cold], kind="stable")[:drop]]] = False
-            self._compact_ids(keep)
+            with trace.child("reclaim_select"):
+                keep = np.ones(n, bool)
+                keep[cold[np.argsort(last[cold],
+                                     kind="stable")[:drop]]] = False
+            with trace.child("reclaim_compact"):
+                self._compact_ids(keep)
+            # The twin goes up here and not in the feed that follows, so
+            # that the reclaim's span holds all that a reclaim costs.
+            with trace.child("twin_upload"):
+                self._ensure_device()
         self.stats["reclaims"] = self.stats.get("reclaims", 0) + 1
         self.stats["reclaimed_ids"] = \
             self.stats.get("reclaimed_ids", 0) + drop
-        trace.count(reclaimed_ids=drop)
+        trace.count(reclaims=1, reclaimed_ids=drop)
         trace.annotate(ids_after=self._next_id)
 
     def invalidate_pid(self, pid: int) -> bool:
@@ -2120,12 +2144,6 @@ class DictAggregator:
         new_last = np.zeros(self._id_cap, np.int32)
         new_last[: len(kept)] = self._last_seen[kept]
         self._last_seen = new_last
-        # Rebuild the key map and the host probe table for the survivors.
-        new_map: dict[tuple, int] = {}
-        self._occ[:] = False
-        self._ids[:] = -1
-        self._unreachable = {}  # chains change wholesale with the rebuild
-        self._unreach_h1 = None
         # The carry cache maps keys to the OLD id space: drop it
         # wholesale (live keys re-admit at their next dispatch; the
         # accumulated weights are zero at a boundary).
@@ -2136,16 +2154,12 @@ class DictAggregator:
         self._carry_w = np.zeros(0, np.int64)
         self._carry_shift = 32
         self._carry_starts = np.zeros(2, np.int64)
-        for key, sid in self._key_to_id.items():
-            nid = int(old_to_new[sid])
-            if nid < 0:
-                continue
-            new_map[key] = nid
-            slot = self._host_insert_slot(key)
-            self._occ[slot] = True
-            self._h1[slot], self._h2[slot], self._h3[slot] = key
-            self._ids[slot] = nid
-            self._mark_if_unreachable(key, slot, nid)
+        # Rebuild the key map and the host probe table for the survivors.
+        remap = old_to_new.tolist()
+        new_map = {key: nid for key, sid in self._key_to_id.items()
+                   if (nid := remap[sid]) >= 0}
+        if not self._rebuild_table_vec(kept):
+            self._rebuild_table_scalar(new_map)
         self._key_to_id = new_map
         self._next_id = len(kept)
         self._published = self._next_id
@@ -2153,18 +2167,93 @@ class DictAggregator:
         live_pids = set(self._id_pid[: self._next_id].tolist())
         self._pids = {p: r for p, r in self._pids.items() if p in live_pids}
         # Device twin is rebuilt lazily from the host mirror; the open
-        # accumulator is empty at a boundary; width prediction resets.
-        # BOTH double buffers go (the spare indexes the old id space too),
-        # as do the touch flags and the delta history.
+        # accumulator is empty at a boundary. BOTH double buffers go (the
+        # spare indexes the old id space too), as do the touch flags and
+        # the delta history. The width and sideband predictions follow
+        # the survivors: they only predict (a close that they misjudge
+        # is retried wider, never lossy), and without them the close
+        # after a compaction would pack at the cold start's width, a
+        # program that a process which has grown since never compiled.
         self._dev = None
         self._acc = None
         self._acc_spare = None
         self._touch = None
         self._touch_spare = None
         self._prev_touched = None
-        self._prev_counts = None
-        self._prev_n_over = 0  # sideband prediction resets with it
+        prev = self._prev_counts
+        self._prev_counts = prev[kept] \
+            if prev is not None and len(prev) == n else None
+        if self._prev_counts is None:
+            self._prev_n_over = 0
         self._reg_version += 1
+        # Every caller bumps its own epoch stat next, by one.
+        old_to_new.flags.writeable = False
+        self._remap = (self.registry_epoch + 1, old_to_new)
+
+    def _rebuild_table_vec(self, kept: np.ndarray) -> bool:
+        """The host probe table for the surviving ids, as array
+        operations: the keys are read off the table as it stands (every
+        id sits in exactly one occupied slot), the table is emptied, and
+        the survivors are placed in new-id order by the miss plan's slot
+        arbitration (_place_new_keys_vec), every key from its home slot.
+        That is a valid linear-probe layout over the slots a one-by-one
+        insert would fill, though not key for key the one-by-one
+        layout (a contested slot goes to the lowest id among the keys
+        that reached it in the same round); _unreachable is the keys 16 or
+        more steps from home in THIS layout. False when the table does
+        not hold one slot an id or the arbitration overran: the scalar
+        rebuild, which reads the key map, then takes it."""
+        n = self._next_id
+        slots_old = np.flatnonzero(self._occ)
+        sid_old = self._ids[slots_old].astype(np.int64)
+        at = np.full(n, -1, np.int64)   # old id -> its slot
+        if len(slots_old) == n and n and 0 <= int(sid_old.min()) \
+                and int(sid_old.max()) < n:
+            at[sid_old] = slots_old
+        if n and int(at.min()) < 0:
+            return False                # an id in no slot
+        at = at[kept]
+        h1k, h2k, h3k = self._h1[at], self._h2[at], self._h3[at]
+        self._occ[:] = False
+        base, start, _mask = self._probe_geometry_vec(h1k, h2k)
+        slots = self._place_new_keys_vec(h1k, h2k, base + start)
+        if slots is None:
+            return False
+        self._ids[:] = -1
+        self._unreachable = {}  # chains change wholesale with the rebuild
+        self._write_slots_vec(slots, h1k, h2k, h3k,
+                              np.arange(len(kept), dtype=np.int64))
+        return True
+
+    def _write_slots_vec(self, slots, h1, h2, h3, sids) -> None:
+        """A batch of placed keys into the host probe table, and into
+        _unreachable those that sit beyond the device probe's reach."""
+        self._occ[slots] = True
+        self._h1[slots] = h1
+        self._h2[slots] = h2
+        self._h3[slots] = h3
+        self._ids[slots] = sids
+        base, start, mask = self._probe_geometry_vec(h1, h2)
+        for j in np.flatnonzero(
+                ((slots - base - start) & mask) >= _PROBES).tolist():
+            self._unreachable[(int(h1[j]), int(h2[j]), int(h3[j]))] = \
+                int(sids[j])
+            self._unreach_h1 = None
+
+    def _rebuild_table_scalar(self, new_map: dict) -> None:
+        """The host probe table for `new_map`'s keys, inserted one by
+        one in the map's order: the vectorised rebuild's fall-back and
+        the tests' reference."""
+        self._occ[:] = False
+        self._ids[:] = -1
+        self._unreachable = {}  # chains change wholesale with the rebuild
+        self._unreach_h1 = None
+        for key, nid in new_map.items():
+            slot = self._host_insert_slot(key)
+            self._occ[slot] = True
+            self._h1[slot], self._h2[slot], self._h3[slot] = key
+            self._ids[slot] = nid
+            self._mark_if_unreachable(key, slot, nid)
 
     # -- internals ----------------------------------------------------------
 
@@ -2487,18 +2576,10 @@ class DictAggregator:
             base_sid = self._next_id
             sids = np.arange(base_sid, base_sid + n_new, dtype=np.int64)
             self._next_id = base_sid + n_new
-            keys = list(zip(h1n.tolist(), h2n.tolist(), h3n.tolist()))
-            self._key_to_id.update(zip(keys, sids.tolist()))
-            self._occ[slots] = True
-            self._h1[slots] = h1n
-            self._h2[slots] = h2n
-            self._h3[slots] = h3n
-            self._ids[slots] = sids
-            gbase, gstart, gmask = self._probe_geometry_vec(h1n, h2n)
-            dist = (slots - gbase - gstart) & gmask
-            for j in np.flatnonzero(dist >= _PROBES):
-                self._unreachable[keys[int(j)]] = int(sids[j])
-                self._unreach_h1 = None
+            self._key_to_id.update(zip(
+                zip(h1n.tolist(), h2n.tolist(), h3n.tolist()),
+                sids.tolist()))
+            self._write_slots_vec(slots, h1n, h2n, h3n, sids)
             self._last_seen[sids] = self.stats["windows"] + 1
             self.stats["inserts"] += n_new
             self.stats["miss_vec_inserts"] = \

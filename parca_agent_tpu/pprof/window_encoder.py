@@ -299,8 +299,10 @@ class _PieceCache:
     slot holds (revision, piece); the revision is the one the span's
     bytes were written under (_Template.span_rev), so a piece made from
     bytes since rewritten is never handed out. The cache is an attribute
-    of the template's layout: a relayout, a reset or a rotation replaces
-    it whole, and nothing else ever evicts."""
+    of the template's layout: a relayout replaces it, taking over the
+    pieces of the spans it lays down again byte for byte
+    (WindowEncoder._held_pieces); a reset starts an empty one, and
+    nothing else ever evicts."""
 
     __slots__ = ("slots", "nbytes")
 
@@ -440,8 +442,9 @@ class _Template:
     __slots__ = ("buf", "n_rows", "row_of", "row_id", "row_group",
                  "val_pos", "pids", "blob_start", "blob_end", "cap_end",
                  "time_pos", "group_of", "g_head_len", "g_tail_len",
-                 "g_loc_len", "span_off", "span_len", "span_rev", "pieces",
-                 "alloc_end", "waste", "rotations", "period_ns", "kept")
+                 "g_loc_len", "g_static", "span_off", "span_len", "span_rev",
+                 "pieces", "alloc_end", "waste", "rotations", "period_ns",
+                 "kept")
 
     def __init__(self):
         self.buf = None          # np.uint8 big buffer
@@ -459,6 +462,8 @@ class _Template:
         self.g_head_len = None   # int64 [G] static head bytes in blob
         self.g_tail_len = None   # int64 [G] static tail bytes in blob
         self.g_loc_len = None    # int64 [G] location bytes in blob
+        self.g_static = None     # list [G]: the _PidStatic each group's
+        #                          span was last written from
         # The static span: the contiguous run [head][locations][tail] as
         # it was LAID DOWN, blob-relative (a relocation moves a blob, not
         # the span inside it). Not g_head_len + g_loc_len + g_tail_len:
@@ -637,6 +642,13 @@ class WindowEncoder:
             "order_merged_ids_total": 0,
             "order_rebuilds_total": 0,
             "views_reused_total": 0,
+            # Changes of the aggregator's registry_epoch this encoder
+            # met with mirrors to lose, and the per-pid statics it kept
+            # (it followed the compaction's remap and the pid's registry
+            # is the object it held) or dropped across them.
+            "epoch_changes_total": 0,
+            "epoch_statics_kept_total": 0,
+            "epoch_statics_dropped_total": 0,
             # Statics build clock: per-call duration (the gauge) and the
             # monotone accumulator the pipeline worker diffs to span the
             # statics work that ran INSIDE one window's encode. The same
@@ -690,28 +702,12 @@ class WindowEncoder:
         # reclaim compacted it (aggregators without one never remap).
         rot = getattr(agg, "registry_epoch", 0)
         if rot != self._rotations:
-            # Rotation remapped ids wholesale: drop every mirror. But
-            # first rescue the location blobs into the content cache —
-            # rotation never edits a surviving pid's registry content, so
-            # the blobs are still exact and the imminent rebuild can be
-            # lookups instead of re-encodes. (Head/tail pairs were cached
-            # at build time; delta-extended loc blobs were not.)
-            if self._rotations >= 0:
-                for pid, st in self._static.items():
-                    reg = agg._pids.get(pid)
-                    if (reg is None or reg is not st.reg
-                            or st.n_locs == 0
-                            or len(reg.loc_mapping_id) < st.n_locs):
-                        continue
-                    self._cache_put(_loc_key(reg, st.n_locs),
-                                    bytes(st.loc_bytes), len(st.loc_bytes))
+            if self._rotations < 0:
+                self._drop_mirrors()    # never synced: nothing to lose
+            else:
+                with window_trace.child("epoch_remap"):
+                    self._change_epoch()
             self._rotations = rot
-            self._synced = 0
-            self._pre_off[0] = 0
-            self._static.clear()
-            self._statics_clean = None
-            self._order = None
-            self._caps = None
         n = getattr(agg, "_published", None)
         if n is None:
             n = agg._next_id
@@ -721,6 +717,70 @@ class WindowEncoder:
             self._extend_prefixes(self._synced, n)
             self._synced = n
 
+    def _change_epoch(self) -> None:
+        """The id space was compacted under synced mirrors. Where the
+        aggregator says where the ids went (id_remap: one compaction
+        back, over exactly the ids synced) the mirrors follow: a
+        compaction never edits a surviving pid's registry, so its
+        sample prefixes, its place in the pid order, its static
+        sections and its caps are what they were, under new ids. The
+        template is laid out again by the window's encode (its rows
+        are keyed by id), from these kept parts. Otherwise every mirror
+        goes, as it always did."""
+        agg = self._agg
+        take = getattr(agg, "id_remap", None)
+        remap = take(self._rotations) if take is not None else None
+        self.stats["epoch_changes_total"] += 1
+        if remap is None or len(remap) != self._synced:
+            # Rescue the location blobs into the content cache first:
+            # the blobs are still exact and the imminent rebuild can be
+            # lookups instead of re-encodes. (Head/tail pairs were
+            # cached at build time; delta-extended loc blobs were not.)
+            for pid, st in self._static.items():
+                reg = agg._pids.get(pid)
+                if (reg is None or reg is not st.reg
+                        or st.n_locs == 0
+                        or len(reg.loc_mapping_id) < st.n_locs):
+                    continue
+                self._cache_put(_loc_key(reg, st.n_locs),
+                                bytes(st.loc_bytes), len(st.loc_bytes))
+            self.stats["epoch_statics_dropped_total"] += len(self._static)
+            self._drop_mirrors()
+            return
+        kept = np.flatnonzero(remap >= 0)
+        off = self._pre_off
+        flat, new_off = ragged_gather(self._pre_flat, off[kept],
+                                      off[kept + 1] - off[kept])
+        self._pre_flat = flat
+        self._pre_off = np.empty(max(len(off), len(new_off)), np.int64)
+        self._pre_off[: len(new_off)] = new_off
+        self._synced = len(kept)
+        if self._order is not None:
+            # Survivors keep their order, so the order by pid stands.
+            order = remap[self._order]
+            live = order >= 0
+            order, order_pid = order[live], self._order_pid[live]
+            order.flags.writeable = False
+            order_pid.flags.writeable = False
+            self._order, self._order_pid = order, order_pid
+        n_before = len(self._static)
+        pids = agg._pids
+        self._static = {pid: st for pid, st in self._static.items()
+                        if pids.get(pid) is st.reg}
+        self.stats["epoch_statics_kept_total"] += len(self._static)
+        self.stats["epoch_statics_dropped_total"] += \
+            n_before - len(self._static)
+        self._statics_clean = None
+
+    def _drop_mirrors(self) -> None:
+        self._synced = 0
+        self._pre_off[0] = 0
+        self._static.clear()
+        self._statics_clean = None
+        self._order = None
+        self._order_pid = None
+        self._caps = None
+
     def reset(self) -> None:
         """Drop every mirror, cached static, and the template; the next
         encode rebuilds from the aggregator's registry. For recovery after
@@ -729,14 +789,8 @@ class WindowEncoder:
         survives: its values are immutable bytes keyed by input digests —
         an aborted encode cannot have corrupted them, and they are what
         makes the post-reset rebuild cheap."""
-        self._synced = 0
+        self._drop_mirrors()
         self._rotations = -1
-        self._pre_off[0] = 0
-        self._order = None
-        self._order_pid = None
-        self._caps = None
-        self._static.clear()
-        self._statics_clean = None
         self._tmpl = _Template()
         self.last_prep = None
 
@@ -1297,6 +1351,24 @@ class WindowEncoder:
 
     # -- encode --------------------------------------------------------------
 
+    def _held_pieces(self, period_ns: int) -> dict:
+        """{pid: (static, head length, tail length, span length, piece)}
+        of the standing template's groups whose static span has its
+        compressed piece held, for the layout that replaces it."""
+        tmpl = self._tmpl
+        if tmpl.pieces is None or not tmpl.pieces.nbytes \
+                or tmpl.period_ns != period_ns:
+            return {}
+        held = {}
+        for pid, st, hl, tl, sl, rev, slot in zip(
+                tmpl.pids.tolist(), tmpl.g_static,
+                tmpl.g_head_len.tolist(), tmpl.g_tail_len.tolist(),
+                tmpl.span_len.tolist(), tmpl.span_rev.tolist(),
+                tmpl.pieces.slots):
+            if slot is not None and slot[0] == rev:
+                held[pid] = (st, hl, tl, sl, slot[1])
+        return held
+
     def _build_layout(self, idx: np.ndarray, pids_live: np.ndarray,
                       period_ns: int, caps: dict | None = None) -> None:
         """Serialize the full window layout (everything except the count and
@@ -1305,6 +1377,7 @@ class WindowEncoder:
         APPEND new stacks' rows instead of relaying out (see _Template)."""
         tmpl = self._tmpl
         tmpl.kept = None
+        held = self._held_pieces(period_ns)
         bounds = np.flatnonzero(np.diff(pids_live)) + 1
         gstarts = np.concatenate(([0], bounds))
         gends = np.concatenate((bounds, [len(idx)]))
@@ -1391,11 +1464,24 @@ class WindowEncoder:
         tmpl.g_tail_len = np.array([len(s.tail) for s in statics], np.int64)
         tmpl.g_loc_len = np.array(
             [len(s.loc_bytes) for s in statics], np.int64)
+        tmpl.g_static = statics
         tmpl.span_off = samples_per_g
         tmpl.span_len = static_lens
         self._span_rev += 1
         tmpl.span_rev = np.full(len(pids), self._span_rev, np.int64)
-        tmpl.pieces = _PieceCache(len(pids))
+        tmpl.pieces = pieces = _PieceCache(len(pids))
+        if held:
+            # A span laid down again from the static it was laid from,
+            # each of its three sections as long as it was, holds the
+            # bytes it held (a pid's sections only ever grow by
+            # appending): its compressed piece stands.
+            for g, (pid, st, span) in enumerate(zip(
+                    pids.tolist(), statics, static_lens.tolist())):
+                got = held.get(pid)
+                if got is not None and got[0] is st \
+                        and got[1:4] == (len(st.head), len(st.tail), span):
+                    pieces.slots[g] = (self._span_rev, got[4])
+                    pieces.nbytes += len(got[4])
         tmpl.alloc_end = total
         tmpl.waste = 0
         tmpl.rotations = self._rotations
@@ -1565,6 +1651,7 @@ class WindowEncoder:
                                   "span_rev"), cols[1:]):
                 setattr(tmpl, slot, np.concatenate(
                     (getattr(tmpl, slot), np.array(col, np.int64))))
+            tmpl.g_static.extend(cols[11])
             tmpl.pieces.slots.extend([None] * len(pend))
         # Register the new rows (one concatenate per array per window).
         tmpl.row_id = np.concatenate((tmpl.row_id[:n0], new_ids))
@@ -1631,7 +1718,7 @@ class WindowEncoder:
             g = next_g
             pend.append((pid, base, base + blob_len, base + cap, tpos,
                          len(st.head), len(st.tail), len(st.loc_bytes),
-                         int(s_off[-1]), static_len, self._span_rev))
+                         int(s_off[-1]), static_len, self._span_rev, st))
             tmpl.group_of[pid] = g
         else:
             tmpl.waste += int(tmpl.cap_end[g]) - int(tmpl.blob_start[g])
@@ -1642,6 +1729,7 @@ class WindowEncoder:
             tmpl.g_head_len[g] = len(st.head)
             tmpl.g_tail_len[g] = len(st.tail)
             tmpl.g_loc_len[g] = len(st.loc_bytes)
+            tmpl.g_static[g] = st
             tmpl.span_off[g] = int(s_off[-1])
             tmpl.span_len[g] = static_len
             tmpl.span_rev[g] = self._span_rev

@@ -9,7 +9,9 @@ for the flight recorder's span tree of a streamed window
 `run(site)` yields one step per encoded window:
 (encoder, views=True output (its `span_blobs()` are what the writer is
 given), pids whose span was rewritten by that window or None for "all
-of them", note)."""
+of them", note). RELAID is the note of the one step that lays every
+span down again with the bytes it held: it rewrites none, and the new
+layout's piece cache takes the pieces over."""
 
 from __future__ import annotations
 
@@ -31,6 +33,7 @@ SHAPES = {
 
 SITES = ("counts", "slack_append", "new_pid", "new_locations",
          "relocated", "head_tail", "relayout", "reset", "rotation")
+RELAID = "full relayout"
 
 
 def spec(seed=7, n_pids=12, rows=400, depth=10):
@@ -245,7 +248,7 @@ def run(site: str):
         c2[np.arange(len(c2)) % 3 != 0] = 0
         out = _enc(snap, c2, 1, enc)
         assert "encode_build" in enc.timings
-        yield enc, out, None, "full relayout"
+        yield enc, out, set(), RELAID
     elif site == "reset":
         enc.reset()
         c2 = c1

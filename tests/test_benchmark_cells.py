@@ -117,6 +117,53 @@ def test_build_node_under_compile_rehearses_correct_on_the_cpu():
     assert "miss_scatter_roofline" not in metrics   # a device number
 
 
+def test_firehose_churn_under_redeploy_rehearses_a_reclaim_on_the_cpu():
+    """The tier that fills its dictionary, at a size whose id space
+    (4,096 ids for 2,400 stacks and ~200 new a window) fills once inside
+    the rehearsal, at the first feed of the ninth window: the run holds
+    its reclaim inside the measured window, the window behind it goes
+    through the fast encoder like any other (nothing failed), the last
+    measured window, which lies after the reclaim, reads 0 on every
+    number of the comparison, and the traced line carries the reclaim's
+    spans and what the encoder kept across the epoch: the static
+    sections of every pid that stayed, so that the ship builds a gzip
+    piece for the ten new pids of a window and for no other."""
+    line = _rehearse("firehose-churn", "redeploy", pids=120, stacks=2400,
+                     samples=12000, capacity=8192, seconds=26)
+    metrics = line["metrics"]
+    windows = line["attempted"]
+    assert metrics["dict_reclaims_per_window"]["value"] * windows \
+        == pytest.approx(1.0)
+    assert metrics["dict_reclaimed_ids_per_window"]["value"] > 0
+    assert metrics["dict_reclaim_ms.p50"]["value"] \
+        >= metrics["reclaim_compact_ms.p50"]["value"] > 0
+    assert metrics["epoch_remap_ms.p50"]["value"] > 0
+    # Every pid the encoder knew but the ten the reclaim's window lost.
+    assert 100 <= metrics["encoder_epoch_statics_kept_per_window"]["value"] \
+        * windows <= 120
+    assert metrics["ship_static_built_per_window"]["value"] \
+        == pytest.approx(10.0)
+    assert metrics["ship_static_reused_per_window"]["value"] \
+        == pytest.approx(110.0)
+    assert metrics["encode_order_rebuilds_per_window"]["value"] == 0.0
+    for name in ("prepare_ms.p99", "encode_ms.p99", "handoff_wait_ms.p99"):
+        assert metrics[name]["value"] > 0
+    # Every per-layer metric the cell lists that is no device number.
+    listed = {m["name"]: m for m in BENCHMARK["per_layer"]
+              if "firehose-churn-redeploy" in m.get("workloads", [])}
+    assert len(listed) >= 60
+    assert set(listed) >= {m["name"] for m in BENCHMARK["per_layer"]
+                           if "firehose-rollout" in m.get("workloads", [])}
+    # (The row hash goes over several threads from 32,768 rows a batch:
+    # its two metrics read at the population's size alone.)
+    at_size = {"feed_hash_parallel_batches_per_window",
+               "cpu_row_hash_workers_ms_per_window"}
+    for name, m in listed.items():
+        if m["source"] != "device_trace" and name not in at_size:
+            assert name in metrics, name
+    assert "miss_scatter_roofline" not in metrics   # a device number
+
+
 def test_node_streamed_under_rollout_rehearses_streamed_on_the_cpu():
     """The DaemonSet's flags through the harness at a tiny size: every
     window of the measured window is streamed (ten drains, fed while it

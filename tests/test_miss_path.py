@@ -284,6 +284,167 @@ def test_the_sharded_twin_scatters_in_one_shape_and_reclaims():
     assert in_miss_path[1] >= 1 and not any(in_miss_path[2:])
 
 
+# -- a compaction says where the ids went, and rebuilds its table in bulk ------
+
+
+def _seeded_dictionary(agg, n: int, seed: int, cluster: int = 0):
+    """``n`` seeded keys in ``agg``'s host mirror, ids in key order, as
+    the one-by-one insert lays them; ``cluster`` of them share one home
+    slot, so that some sit 16 or more steps out (beyond the device
+    probe: the unreachable ones)."""
+    rng = np.random.default_rng(seed)
+    h = rng.integers(0, 1 << 32, size=(n, 3), dtype=np.uint64)
+    if cluster:
+        h[:cluster, 0] = (h[:cluster, 0] & ~np.uint64(agg._cap - 1)) | 7
+        if hasattr(agg, "_n_shards"):
+            h[:cluster, 1] -= h[:cluster, 1] % agg._n_shards
+    keys = [tuple(int(v) & 0xFFFFFFFF for v in row) for row in h]
+    assert len(set(keys)) == n
+    agg._rebuild_table_scalar({key: i for i, key in enumerate(keys)})
+    agg._key_to_id = {key: i for i, key in enumerate(keys)}
+    agg._next_id = agg._published = n
+    agg._id_pid = (np.arange(n) % 37).astype(np.int32)
+    agg._id_h1 = np.array([k[0] for k in keys], np.uint32)
+    agg._id_h2 = np.array([k[1] for k in keys], np.uint32)
+    agg._loc_off = np.arange(n + 1, dtype=np.int64) * 3
+    agg._loc_flat = np.arange(3 * n, dtype=np.int32)
+    agg._last_seen[:n] = 1
+    return keys
+
+
+def _table_facts(agg) -> dict:
+    """What a look-up can see of the host mirror: every key's id, found
+    by walking its own chain from its home slot over occupied slots
+    alone; the set of occupied slots; and the keys 16 or more steps
+    out, which have to be exactly ``_unreachable``."""
+    from parca_agent_tpu.aggregator.dict import _PROBES
+
+    found, far = {}, {}
+    for key, sid in agg._key_to_id.items():
+        h1 = np.array([key[0]], np.uint32)
+        h2 = np.array([key[1]], np.uint32)
+        base, start, mask = agg._probe_geometry_vec(h1, h2)
+        base, start = int(base[0]), int(start[0])
+        for k in range(mask + 1):
+            slot = base + ((start + k) & mask)
+            assert agg._occ[slot], (key, "an empty slot before the key")
+            if (int(agg._h1[slot]), int(agg._h2[slot]),
+                    int(agg._h3[slot])) == key:
+                break
+        found[key] = int(agg._ids[slot])
+        assert agg._chain_dist(key, slot) == k
+        if k >= _PROBES:
+            far[key] = sid
+    assert far == agg._unreachable
+    assert int(agg._occ.sum()) == len(found)
+    assert set(agg._ids[agg._occ].tolist()) == set(found.values())
+    return {"ids": found, "occupied": np.flatnonzero(agg._occ).tolist(),
+            "n_far": len(far), "map": dict(agg._key_to_id)}
+
+
+def _watch_the_scalar_rebuild(agg) -> list[int]:
+    """The list gains, per one-by-one rebuild of ``agg``'s table, the
+    number of keys it inserted."""
+    seen: list[int] = []
+    real = agg._rebuild_table_scalar
+    agg._rebuild_table_scalar = lambda m: (seen.append(len(m)), real(m))
+    return seen
+
+
+def _make(kind: str, capacity: int):
+    if kind == "sharded":
+        from parca_agent_tpu.aggregator.sharded import ShardedDictAggregator
+        from parca_agent_tpu.parallel.mesh import fleet_mesh
+
+        return ShardedDictAggregator(capacity=capacity, mesh=fleet_mesh(8),
+                                     overflow="raise")
+    return DictAggregator(capacity=capacity, overflow="raise")
+
+
+@pytest.mark.parametrize("kind, n, capacity, cluster, seed", [
+    ("dict", 900, 1 << 11, 0, 11),        # load 0.44: long chains by chance
+    ("dict", 400, 1 << 12, 40, 12),       # 40 keys on one home slot
+    ("dict", 2000, 1 << 13, 64, 2147483659),
+    pytest.param("sharded", 1200, 1 << 14, 40, 13,
+                 marks=requires_shard_map),
+])
+def test_the_bulk_rebuild_of_the_key_map_and_probe_table_equals_the_scalar_one(
+        kind, n, capacity, cluster, seed):
+    """A compaction's rebuild as array operations against the
+    one-by-one insert it replaced, on seeded keys, unreachable chains
+    included: the same keys under the same ids, the same slots
+    occupied, every key on its own chain with no gap before it, and
+    ``_unreachable`` exactly the keys beyond the device probe's reach
+    in the layout it describes. (Which key of a contested run sits in
+    which slot is the arbitration's to say: the facts are those a
+    look-up can see.)"""
+    rng = np.random.default_rng(seed)
+    keep = rng.random(n) < 0.6
+    keep[:cluster] = True     # the long chain survives
+    facts = {}
+    for how in ("bulk", "scalar"):
+        agg = _make(kind, capacity)
+        keys = _seeded_dictionary(agg, n, seed, cluster)
+        one_by_one = _watch_the_scalar_rebuild(agg)
+        if how == "scalar":
+            agg._rebuild_table_vec = lambda *a: False
+        epoch = agg.registry_epoch
+        agg._compact_ids(keep)
+        assert one_by_one == ([] if how == "bulk" else [int(keep.sum())])
+        agg.stats["reclaims"] = agg.stats.get("reclaims", 0) + 1
+        facts[how] = _table_facts(agg)
+        # Where the ids went: survivors in order, -1 for the rest.
+        remap = agg.id_remap(epoch)
+        want = np.full(n, -1, np.int64)
+        want[keep] = np.arange(int(keep.sum()))
+        assert np.array_equal(remap, want) and not remap.flags.writeable
+        assert facts[how]["map"] == {
+            key: int(want[i]) for i, key in enumerate(keys) if keep[i]}
+    assert facts["bulk"]["ids"] == facts["scalar"]["ids"]
+    assert facts["bulk"]["occupied"] == facts["scalar"]["occupied"]
+    if cluster:
+        assert facts["bulk"]["n_far"] >= cluster - 16 > 0
+        assert facts["scalar"]["n_far"] >= cluster - 16
+
+
+def test_a_table_that_does_not_hold_every_id_is_rebuilt_one_by_one():
+    """The bulk rebuild reads the keys off the table; where the table
+    and the key map disagree it gives up and the one-by-one rebuild,
+    which reads the map, takes over."""
+    agg = DictAggregator(capacity=1 << 10, overflow="raise")
+    _seeded_dictionary(agg, 200, 5)
+    slot = int(np.flatnonzero(agg._occ)[0])
+    agg._occ[slot] = False                      # an id in no slot
+    keep = np.ones(200, bool)
+    keep[::3] = False
+    one_by_one = _watch_the_scalar_rebuild(agg)
+    agg._compact_ids(keep)
+    assert one_by_one == [int(keep.sum())]
+    assert len(_table_facts(agg)["ids"]) == int(keep.sum())
+
+
+def test_one_remap_is_kept_and_a_mirror_further_behind_gets_none():
+    windows = _turnover_windows(10, 40, 400)
+    agg = DictAggregator(capacity=1 << 12, overflow="raise")
+    assert agg.id_remap(-1) is None and agg.id_remap(0) is None
+    seen = []
+    for snap in windows:
+        before, n_before = agg.registry_epoch, agg._next_id
+        agg.window_counts(snap)
+        if agg.registry_epoch != before:
+            remap = agg.id_remap(before)
+            assert len(remap) == n_before
+            kept = remap[remap >= 0]
+            assert np.array_equal(kept, np.arange(len(kept)))
+            assert len(kept) == n_before - (
+                agg.stats["reclaimed_ids"] - sum(seen))
+            seen.append(n_before - len(kept))
+            # Not for the epoch itself, nor for one further back.
+            assert agg.id_remap(agg.registry_epoch) is None
+            assert agg.id_remap(before - 1) is None
+    assert len(seen) == agg.stats["reclaims"] >= 2
+
+
 # -- the register step: one pass over a batch, held to the loop it replaced ---
 
 

@@ -415,11 +415,15 @@ def test_encoder_views_are_invalidated_by_the_next_encode():
 # -- content-addressed statics ------------------------------------------------
 
 
-def test_rotation_rebuild_is_served_from_the_content_cache():
-    """A registry rotation wipes the per-pid statics map; the content
-    cache (keyed by build inputs, not pids) must serve the rebuild —
-    bytes identical to a fresh cold encoder, with zero re-encoding for
-    the surviving content."""
+@pytest.mark.parametrize("remap", ["followed", "missed"])
+def test_rotation_rebuild_is_served_from_what_was_built(remap):
+    """A registry rotation remaps every id. An encoder that follows the
+    aggregator's remap keeps the statics of the pids that stay, and one
+    that missed it (the aggregator keeps one remap, the last
+    boundary's) loses the per-pid statics map and is served by the
+    content cache (keyed by build inputs, not pids): either way bytes
+    identical to a fresh cold encoder, with zero re-encoding for the
+    surviving content."""
     snap1 = generate(_spec(seed=51))
     snap2 = generate(_spec(seed=52))
     agg = DictAggregator(capacity=1 << 13, rotate_min_age=1)
@@ -433,11 +437,25 @@ def test_rotation_rebuild_is_served_from_the_content_cache():
     agg._rotate_pending = True
     c2 = agg.window_counts(snap2)
     assert agg.stats.get("rotations", 0) == 1
+    assert agg.id_remap(0) is not None and agg.id_remap(1) is None
+    if remap == "missed":
+        agg._remap = None
     built_before = enc.stats["statics_bytes_built"]
+    hits_before = enc.stats["statics_cache_hits"]
     out = enc.encode(c2, snap2.time_ns, snap2.window_ns, snap2.period_ns)
-    assert enc.stats["statics_cache_hits"] > 0
-    # Surviving pids' sections were not re-encoded, only looked up.
-    assert enc.stats["statics_bytes_reused"] > 0
+    assert enc.stats["epoch_changes_total"] == 1
+    if remap == "followed":
+        assert enc.stats["epoch_statics_kept_total"] == len(enc._static) > 0
+        assert enc.stats["epoch_statics_dropped_total"] == 0
+        assert enc.stats["statics_cache_hits"] == hits_before
+        assert enc.stats["order_rebuilds_total"] == 1   # the cold one
+    else:
+        assert enc.stats["epoch_statics_kept_total"] == 0
+        assert enc.stats["epoch_statics_dropped_total"] > 0
+        assert enc.stats["statics_cache_hits"] > hits_before
+        # Surviving pids' sections were not re-encoded, only looked up.
+        assert enc.stats["statics_bytes_reused"] > 0
+        assert enc.stats["order_rebuilds_total"] == 2
     ref = WindowEncoder(agg).encode(c2, snap2.time_ns, snap2.window_ns,
                                     snap2.period_ns)
     assert [(p, bytes(b)) for p, b in out] \
@@ -595,17 +613,23 @@ def test_span_blob_is_a_bytes_like_of_the_same_bytes(shape):
 @pytest.mark.parametrize("site", span_scenarios.SITES)
 def test_static_span_revision_follows_every_rewrite(site):
     """A span's revision changes when, and only when, the bytes inside it
-    are rewritten; a move keeps it, and so keeps the group's compressed
-    piece; a relayout, a reset and a rotation start a new piece cache."""
-    seen: dict[int, tuple[bytes, int]] = {}   # pid -> (span bytes, rev)
+    are written; a move keeps it, and so keeps the group's compressed
+    piece; a reset and a rotation that grew every static start an empty
+    piece cache, and a relayout a new one that takes over the piece of
+    every span it lays down again with the bytes it held."""
+    seen: dict[int, tuple[bytes, int, bytes]] = {}  # pid -> span, rev, piece
     cache = None
     for enc, out, rewritten, note in span_scenarios.run(site):
         _assert_span_is_the_static_block(enc, out)
         tmpl = enc._tmpl
+        relaid = note == span_scenarios.RELAID
         if rewritten is None:
             assert tmpl.pieces is not cache, note
             assert tmpl.pieces.nbytes == 0
             seen.clear()
+        elif relaid:
+            assert tmpl.pieces is not cache, note
+            assert tmpl.pieces.nbytes == cache.nbytes > 0
         else:
             assert tmpl.pieces is cache, note
         cache = tmpl.pieces
@@ -614,14 +638,18 @@ def test_static_span_revision_follows_every_rewrite(site):
             rev = int(tmpl.span_rev[tmpl.group_of[pid]])
             span = _span_bytes(blob)
             if pid in seen and pid not in (rewritten or ()):
-                assert (span, rev) == seen[pid], (note, pid)
-                assert blob.static_piece() == b"piece-%d" % rev
+                was, old_rev, piece = seen[pid]
+                assert span == was, (note, pid)
+                assert (rev != old_rev) == relaid, (note, pid)
+                # The piece made from these very bytes.
+                assert blob.static_piece() == piece, (note, pid)
             else:
                 assert pid not in seen or rev != seen[pid][1], (note, pid)
                 # No piece made from other bytes is handed out.
                 assert blob.static_piece() is None, (note, pid)
-                blob.keep_static_piece(b"piece-%d" % rev)
-            seen[pid] = (span, rev)
+                piece = b"piece-%d" % rev
+                blob.keep_static_piece(piece)
+            seen[pid] = (span, rev, piece)
         assert enc.static_piece_bytes() == sum(
             len(s[1]) for s in cache.slots if s is not None)
 
@@ -657,11 +685,14 @@ _CARRY_EXPECT = {
     "pid_returns": (False, False, 0, 0),
     # Other ids, the same pids on the same layout: the list stands.
     "partly_dead_same_pids": (True, True, 0, 0),
-    # A compaction bumps registry_epoch: every mirror goes.
-    "pid_invalidated_and_reused": (False, False, 1, 1),
+    # A compaction bumps registry_epoch: the mirrors follow its remap
+    # (the order and the caps of the pids that stay stand), the template
+    # is laid out again.
+    "pid_invalidated_and_reused": (False, False, 0, 0),
     "steady_after_invalidation": (True, True, 0, 0),
     "ids_go_cold": (True, True, 0, 0),
-    "rotation": (False, False, 1, 1),
+    # The same pids with no registry touched: their caps stand whole.
+    "rotation": (False, True, 0, 0),
     "steady_after_rotation": (True, True, 0, 0),
     "steady_after_rotation_2": (True, True, 0, 0),
 }
@@ -731,7 +762,7 @@ def carried():
         t = state["t"]
         outs, preps, cs = [], [], []
         stats0 = {k: enc.stats[k] for k in _COUNTERS}
-        ids0 = enc._synced
+        ids0, epoch0 = enc._synced, aggs[0].registry_epoch
         for agg, e in zip(aggs, (enc, ref)):
             if before is not None:
                 before(agg)
@@ -751,6 +782,9 @@ def carried():
             preps.append(prep)
             cs.append(c)
         out, prep = outs[0], preps[0]
+        if aggs[0].registry_epoch != epoch0:
+            # The ids the compaction left are those the window began on.
+            ids0 = int((aggs[0].id_remap(epoch0) >= 0).sum())
         frozen.append((prep.caps, dict(prep.caps)))
         try:
             _assert_same_profiles(
@@ -930,3 +964,120 @@ def test_a_second_reader_of_the_touched_pids_costs_a_full_loop():
     a.prepare(c, snap.time_ns, snap.window_ns, snap.period_ns)
     a.prepare(c, snap.time_ns, snap.window_ns, snap.period_ns)
     assert a.stats["caps_rebuilds_total"] == 3
+
+
+# -- across a compaction of the id space ---------------------------------------
+#
+# The aggregator says where a compaction put every id (id_remap) and the
+# encoder follows: the prefixes, the pid order, the statics and the caps
+# of the pids that stay are kept under their new ids, and the template is
+# laid out again from them. Beside it the same windows go through a
+# second aggregator whose remap is taken away before the encoder sees
+# it: the encoder that drops every mirror, as every encoder did.
+
+
+def _bytes_of(out) -> list[tuple[int, bytes]]:
+    return [(p, bytes(b)) for p, b in out]
+
+
+@pytest.mark.parametrize("pids, stacks, capacity, turnover, n", [
+    (40, 800, 1 << 12, 0.5, 10),     # a reclaim every other window
+    (120, 1200, 1 << 13, 0.25, 14),  # one, with nine pids in ten staying
+])
+def test_after_a_reclaim_every_window_ships_a_fresh_encoders_bytes(
+        pids, stacks, capacity, turnover, n):
+    windows, _ = span_scenarios.turnover_windows(
+        n, pids=pids, stacks=stacks, turnover=turnover)
+    aggs = [DictAggregator(capacity=capacity, overflow="raise")
+            for _ in range(2)]
+    enc, ref = (WindowEncoder(a) for a in aggs)
+    reclaims = 0
+    for snap in windows:
+        at = (snap.time_ns, snap.window_ns, snap.period_ns)
+        outs = []
+        for agg, e in zip(aggs, (enc, ref)):
+            epoch, known = agg.registry_epoch, len(e._static)
+            c = agg.window_counts(snap)
+            changed = agg.registry_epoch != epoch
+            if e is ref and changed:
+                agg._remap = None         # the mirror that missed it
+            kept0 = e.stats["epoch_statics_kept_total"]
+            dropped0 = e.stats["epoch_statics_dropped_total"]
+            built0 = e.stats["layouts_built"]
+            outs.append(_bytes_of(e.encode(c, *at, views=True)))
+            kept = e.stats["epoch_statics_kept_total"] - kept0
+            dropped = e.stats["epoch_statics_dropped_total"] - dropped0
+            if changed:
+                # The template is keyed by id: laid out again either way.
+                assert e.stats["layouts_built"] == built0 + 1
+                assert kept + dropped == known
+                if e is enc:
+                    # k of n pids stay: theirs are kept, the rest dropped.
+                    stay = set(agg._pids) - set(
+                        np.unique(snap.pids).tolist())
+                    assert kept >= len(stay) and 0 < kept < known
+                else:
+                    assert kept == 0
+                    # A cold encoder on the same aggregator: these bytes.
+                    assert _bytes_of(WindowEncoder(agg).encode(c, *at)) \
+                        == outs[-1]
+            else:
+                assert kept == dropped == 0
+        reclaims += changed
+        assert outs[0] == outs[1]
+        _assert_same_profiles(aggs[0], snap, c, outs[0])
+    assert reclaims >= 1 and aggs[0].stats["reclaims"] == reclaims
+    assert enc.stats["epoch_changes_total"] == reclaims
+    # Following the remap, the order is never sorted whole again.
+    assert enc.stats["order_rebuilds_total"] == 1
+    assert ref.stats["order_rebuilds_total"] == 1 + reclaims
+
+
+def test_a_pid_that_loses_some_stacks_to_a_reclaim_keeps_its_static():
+    """Built by hand (the turnover generator never makes one): a pid
+    shows half its stacks from the second window on, the other half
+    goes cold and a reclaim gives those ids away. The encoder keeps the
+    pid's static sections (the very object) and the compressed piece of
+    its span, sheds its dead rows (the bytes are a cold encoder's) and
+    sorts nothing again."""
+    rows_of = span_scenarios.rows_of
+    snap = generate(_spec(seed=88, n_pids=10, rows=1300))
+    victim = int(np.bincount(snap.pids.astype(np.int64)).argmax())
+    mask = np.ones(len(snap), bool)
+    rows_v = np.flatnonzero(snap.pids == victim)
+    mask[rows_v[::2]] = False
+    part = rows_of(snap, mask)
+    agg = DictAggregator(capacity=1 << 12, overflow="raise")   # 2,048 ids
+    enc = WindowEncoder(agg)
+
+    def window(s, t):
+        c = agg.window_counts(s)
+        out = enc.encode(c, s.time_ns + t, s.window_ns, s.period_ns,
+                         views=True)
+        for _pid, blob in out.span_blobs():
+            if blob.static_piece() is None:
+                blob.keep_static_piece(b"piece of %d" % _pid)
+        return c, out
+
+    window(snap, 0)
+    window(part, 1)
+    static, n_rows = enc._static[victim], enc._tmpl.n_rows
+    assert n_rows == len(snap) and agg.stats.get("reclaims", 0) == 0
+    # The window just closed is made to look like one that brought 400
+    # new stacks: 1,300 ids and twice that churn are over the id space.
+    agg._inserts_mark -= 400
+    c, out = window(part, 2)
+    assert agg.stats["reclaims"] == 1
+    assert agg.stats["reclaimed_ids"] == len(rows_v[::2])
+    assert enc.stats["epoch_statics_kept_total"] == 10
+    assert enc.stats["epoch_statics_dropped_total"] == 0
+    assert enc._static[victim] is static
+    assert enc._tmpl.n_rows == len(part) < n_rows          # dead rows shed
+    assert enc.stats["order_rebuilds_total"] == 1
+    got = dict(out.span_blobs())
+    assert all(got[p].static_piece() == b"piece of %d" % p for p in got)
+    assert _bytes_of(out) == _bytes_of(WindowEncoder(agg).encode(
+        c, part.time_ns + 2, part.window_ns, part.period_ns))
+    _assert_same_profiles(
+        agg, dataclasses.replace(part, time_ns=part.time_ns + 2), c,
+        _bytes_of(out))
