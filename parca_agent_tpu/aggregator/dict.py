@@ -33,7 +33,6 @@ superset of the window's — valid pprof, same samples.
 
 from __future__ import annotations
 
-import dataclasses
 import functools
 import os
 
@@ -581,9 +580,39 @@ def registry_content_digest(mappings, loc_address, loc_normalized,
     return h.digest()
 
 
-@dataclasses.dataclass
+def _frozen(view: np.ndarray) -> np.ndarray:
+    view.flags.writeable = False
+    return view
+
+
+def _grown(buf: np.ndarray, n: int, room: int) -> np.ndarray:
+    """A buffer of ``room`` rows whose first ``n`` are ``buf``'s."""
+    grown = np.empty(room, buf.dtype)
+    grown[:n] = buf[:n]
+    return grown
+
+
 class _PidRegistry:
     """Per-pid incremental location registry (grows, never shrinks).
+
+    The location table is four numpy columns of ONE published length,
+    ``n_locs``: ``loc_address`` (uint64), ``loc_normalized`` (uint64),
+    ``loc_mapping_id`` (int32), ``loc_is_kernel`` (bool). Each reads as
+    a view of the published rows (``len()``, ``col[i]`` and ``col[:n]``
+    as a list would, a slice without a copy; nobody but ``append_locs``
+    writes). Rows below a published length never change;
+    ``append_locs`` writes the rows first and the length last, and a
+    growth publishes buffers whose prefix is equal before it publishes
+    the length, so a reader on another thread that froze ``n_locs`` (the
+    encoder's caps, the statics store) may read ``col[:n]`` while the
+    owner registers the next window.
+
+    No address look-up is kept for a pid that is never asked:
+    ``index()`` builds one from the address column the first time a
+    known pid brings addresses to test (a dictionary, address -> 1-based
+    location id: the tests are a few addresses each of many pids, which
+    a sorted order searched a pid at a time loses to), and
+    ``append_locs`` extends it from there.
 
     Mappings are append-only with registry-stable 1-based ids: when a
     later window brings a changed mapping table (dlopen, remap), new
@@ -591,13 +620,93 @@ class _PidRegistry:
     this registry's list rather than dangling into the new window's table.
     """
 
-    addr_to_loc: dict  # int addr -> 1-based loc id
-    loc_address: list
-    loc_normalized: list
-    loc_mapping_id: list
-    loc_is_kernel: list
-    mappings: list     # ProfileMapping with registry-stable ids
-    mapping_index: dict  # (start, end, offset) -> 1-based registry id
+    __slots__ = ("n_locs", "_address", "_normalized", "_mapping_id",
+                 "_is_kernel", "_index", "mappings", "mapping_index")
+
+    def __init__(self, loc_address: np.ndarray, loc_normalized: np.ndarray,
+                 loc_mapping_id: np.ndarray, loc_is_kernel: np.ndarray,
+                 mappings: list, mapping_index: dict):
+        # The four arrays become the registry's own: the caller hands
+        # over copies (a run cut from a group's arrays would pin them
+        # for as long as the pid lives; a restored record's buffer is
+        # read-only).
+        assert (loc_address.dtype, loc_normalized.dtype,
+                loc_mapping_id.dtype, loc_is_kernel.dtype) == (
+            np.uint64, np.uint64, np.int32, np.bool_)
+        self._address = loc_address
+        self._normalized = loc_normalized
+        self._mapping_id = loc_mapping_id
+        self._is_kernel = loc_is_kernel
+        self.n_locs = len(loc_address)
+        self._index = None  # int address -> location id, once asked
+        self.mappings = mappings  # ProfileMapping, registry-stable ids
+        self.mapping_index = mapping_index  # (start, end, offset) -> id
+
+    # The length is read BEFORE the buffer: a buffer read after a length
+    # holds at least that many finished rows.
+    @property
+    def loc_address(self) -> np.ndarray:
+        n = self.n_locs
+        return self._address[:n]
+
+    @property
+    def loc_normalized(self) -> np.ndarray:
+        n = self.n_locs
+        return self._normalized[:n]
+
+    @property
+    def loc_mapping_id(self) -> np.ndarray:
+        n = self.n_locs
+        return self._mapping_id[:n]
+
+    @property
+    def loc_is_kernel(self) -> np.ndarray:
+        n = self.n_locs
+        return self._is_kernel[:n]
+
+    @property
+    def nbytes(self) -> int:
+        """What the columns hold, spare rows included, and an estimate
+        of the look-up where one was built (~96 B an entry: its slot,
+        a boxed address, a boxed id)."""
+        n = (self._address.nbytes + self._normalized.nbytes
+             + self._mapping_id.nbytes + self._is_kernel.nbytes)
+        if self._index is not None:
+            n += 96 * len(self._index)
+        return n
+
+    def append_locs(self, address, normalized, mapping_id,
+                    is_kernel) -> None:
+        """Append a run of locations (``address`` ascending and in none
+        of the rows before). Owner thread only."""
+        n0 = self.n_locs
+        n1 = n0 + len(address)
+        a, b, c, d = (self._address, self._normalized, self._mapping_id,
+                      self._is_kernel)
+        if n1 > len(a):
+            # Room for as much again: a pid that grows once tends to
+            # grow again (a streamed window's new pids, in each of its
+            # later drains).
+            a, b, c, d = (_grown(buf, n0, 2 * n1) for buf in (a, b, c, d))
+        a[n0:n1] = address
+        b[n0:n1] = normalized
+        c[n0:n1] = mapping_id
+        d[n0:n1] = is_kernel
+        self._address, self._normalized = a, b
+        self._mapping_id, self._is_kernel = c, d
+        if self._index is not None:
+            self._index.update(zip(address.tolist(), range(n0 + 1, n1 + 1)))
+        self.n_locs = n1          # published last
+
+    def index(self) -> tuple[dict, bool]:
+        """The address look-up (plain ints: an np.uint64 key would miss
+        every look-up), and whether this call had to build it."""
+        built = self._index is None
+        if built:
+            n = self.n_locs
+            self._index = dict(zip(self._address[:n].tolist(),
+                                   range(1, n + 1)))
+        return self._index, built
 
 
 class DictAggregator:
@@ -938,8 +1047,9 @@ class DictAggregator:
         (bench_zoo/soak.py) and the /healthz ``endurance`` section:
         in a stationary workload every lane must go flat (or sit at its
         construction-time cap) once warm — a lane that keeps climbing is
-        the leak the soak verdict fails on. Lanes holding Python lists
-        (the per-pid location registries) are counted at a fixed
+        the leak the soak verdict fails on. The per-pid registries'
+        location columns are arrays and counted as such; their mapping
+        objects (and the interned key tuples) are counted at a fixed
         per-entry estimate; the soak bars care about GROWTH, not about
         allocator-exact totals."""
         carry = int(self._carry_h1.nbytes + self._carry_h2.nbytes
@@ -951,13 +1061,13 @@ class DictAggregator:
         id_meta = int(self._id_pid.nbytes + self._loc_off.nbytes
                       + self._loc_flat.nbytes + self._id_h1.nbytes
                       + self._id_h2.nbytes)
-        # ~56 B per interned key tuple entry; ~48 B per location list
-        # row across the four parallel lists; ~120 B per mapping row.
+        # ~56 B per interned key tuple entry; ~120 B per mapping row; a
+        # registry's location columns (and its address look-up, where
+        # one was built) by their arrays' bytes.
         keys = 56 * len(self._key_to_id)
         regs = 0
         for reg in self._pids.values():
-            regs += 48 * len(reg.loc_address) + 120 * len(reg.mappings) \
-                + 56 * len(reg.addr_to_loc)
+            regs += reg.nbytes + 120 * len(reg.mappings)
         return {
             "carry_bytes": carry,
             "table_bytes": table,
@@ -977,9 +1087,8 @@ class DictAggregator:
         reg = self._pids.get(pid)
         if reg is None:
             return None
+        nl = reg.n_locs           # locations first: see _reg_cap
         nm = len(reg.mappings) if n_mappings is None else n_mappings
-        nl = min(len(reg.loc_address), len(reg.loc_normalized),
-                 len(reg.loc_mapping_id), len(reg.loc_is_kernel))
         if n_locs is not None:
             nl = min(nl, n_locs)
         return registry_content_digest(
@@ -999,19 +1108,12 @@ class DictAggregator:
         which is exactly what keeps the restored statics blobs valid."""
         if pid in self._pids:
             return False
-        # One C-level pass to plain ints (dict keys must be exact ints;
-        # a np.uint64 key would silently miss every later lookup).
-        addrs = np.asarray(loc_address, np.uint64).tolist()
         self._pids[pid] = _PidRegistry(
-            addr_to_loc=dict(zip(addrs, range(1, len(addrs) + 1))),
-            loc_address=addrs,
-            loc_normalized=np.asarray(loc_normalized, np.uint64).tolist(),
-            loc_mapping_id=np.asarray(loc_mapping_id, np.int32).tolist(),
-            loc_is_kernel=np.asarray(loc_is_kernel, bool).tolist(),
-            mappings=list(mappings),
-            mapping_index={(m.start, m.end, m.offset): m.id
-                           for m in mappings},
-        )
+            np.array(loc_address, np.uint64),
+            np.array(loc_normalized, np.uint64),
+            np.array(loc_mapping_id, np.int32),
+            np.array(loc_is_kernel, bool), list(mappings),
+            {(m.start, m.end, m.offset): m.id for m in mappings})
         self._reg_version += 1
         self._note_touched((pid,))
         return True
@@ -2848,13 +2950,17 @@ class DictAggregator:
         upi = pidx[head].astype(np.int64)
 
         # A first-seen pid's addresses are all fresh; a known pid's are
-        # tested against its registry, the one dictionary look-up per
-        # address that is still due.
+        # tested against its registry, which builds its look-up the
+        # first time it is asked.
         loc = np.zeros(len(ua), np.int64)
         if known.any():
+            index, built = zip(*(r.index() if r is not None
+                                 else (None, False) for r in regs))
             kp = np.flatnonzero(known[upi])
-            loc[kp] = [regs[k].addr_to_loc.get(a, 0)
+            loc[kp] = [index[k].get(a, 0)
                        for k, a in zip(upi[kp].tolist(), ua[kp].tolist())]
+            self.stats["registry_index_builds"] = \
+                self.stats.get("registry_index_builds", 0) + sum(built)
         fresh = loc == 0
         fa = ua[fresh]
         fpi = upi[fresh]
@@ -2909,13 +3015,14 @@ class DictAggregator:
 
         # Location ids: a pid's fresh addresses, ascending, after the
         # locations it has.
-        base = np.array([len(r.loc_address) if r is not None else 0
+        base = np.array([r.n_locs if r is not None else 0
                          for r in regs], np.int64)
         loc[fresh] = (base - fresh_off[:-1])[fpi] \
             + np.arange(1, len(fa) + 1, dtype=np.int64)
 
-        # What is left per pid is what has to be Python objects, built
-        # from slices of one tolist() a column: no numpy call below.
+        # What is left per pid: a first-seen pid gets a registry that
+        # owns a copy of its run of the four columns (and its mapping
+        # objects); a known pid's run is appended to its columns.
         first_seen = np.flatnonzero(~known[rpi])
         new_maps, new_keys = _table_mappings(
             table, trows[first_seen], within[first_seen] + 1)
@@ -2923,26 +3030,19 @@ class DictAggregator:
         # those of known pids; a known pid's entry is not read.)
         map_off = (row_off[:-1] - np.cumsum(n_rows * known)).tolist()
         map_len = n_rows.tolist()
-        f_addr, f_norm = fa.tolist(), norm.tolist()
-        f_map, f_kern = map_id.tolist(), is_kernel.tolist()
         offs = fresh_off.tolist()
         for k, (pid, reg) in enumerate(zip(pid_list, regs)):
             f0, f1 = offs[k], offs[k + 1]
-            a = f_addr[f0:f1]
             if reg is None:
                 m0, m1 = map_off[k], map_off[k] + map_len[k]
                 self._pids[pid] = _PidRegistry(
-                    dict(zip(a, range(1, len(a) + 1))), a, f_norm[f0:f1],
-                    f_map[f0:f1], f_kern[f0:f1], new_maps[m0:m1],
+                    fa[f0:f1].copy(), norm[f0:f1].copy(),
+                    map_id[f0:f1].copy(), is_kernel[f0:f1].copy(),
+                    new_maps[m0:m1],
                     dict(zip(new_keys[m0:m1], range(1, m1 - m0 + 1))))
-            elif a:
-                n0 = len(reg.loc_address)
-                reg.addr_to_loc.update(zip(a, range(n0 + 1,
-                                                    n0 + len(a) + 1)))
-                reg.loc_address.extend(a)
-                reg.loc_normalized.extend(f_norm[f0:f1])
-                reg.loc_mapping_id.extend(f_map[f0:f1])
-                reg.loc_is_kernel.extend(f_kern[f0:f1])
+            elif f1 > f0:
+                reg.append_locs(fa[f0:f1], norm[f0:f1], map_id[f0:f1],
+                                is_kernel[f0:f1])
         return loc[pair_of_frame].astype(np.int32), \
             n_pids - int(known.sum())
 
@@ -2999,10 +3099,12 @@ class DictAggregator:
                 stack_loc_ids=loc_rows,
                 stack_depths=depths.copy(),
                 values=vals[lo:hi].copy(),
-                loc_address=np.array(reg.loc_address, np.uint64),
-                loc_normalized=np.array(reg.loc_normalized, np.uint64),
-                loc_mapping_id=np.array(reg.loc_mapping_id, np.int32),
-                loc_is_kernel=np.array(reg.loc_is_kernel, bool),
+                # Views of the registry's columns, read-only: a profile
+                # leaves the aggregator, its registry's rows never change.
+                loc_address=_frozen(reg.loc_address),
+                loc_normalized=_frozen(reg.loc_normalized),
+                loc_mapping_id=_frozen(reg.loc_mapping_id),
+                loc_is_kernel=_frozen(reg.loc_is_kernel),
                 mappings=reg.mappings,
                 period_ns=snapshot.period_ns,
                 time_ns=snapshot.time_ns,

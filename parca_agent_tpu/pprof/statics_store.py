@@ -160,8 +160,6 @@ class StaticsStore:
         runs as an EncodePipeline snapshot hook, and an exception from
         the skip-check's stat() would otherwise read as an encoder death
         and disable the pipeline over a disk hiccup."""
-        import numpy as np
-
         try:
             t0 = time.perf_counter()
             # Clean skip: nothing mutated any registry since the last
@@ -218,15 +216,14 @@ class StaticsStore:
             }).encode())
             n_records = dropped = 0
             for pid, reg in list(agg._pids.items()):
-                # Location lengths FIRST, mapping count second — the
+                # Location length FIRST, mapping count second — the
                 # same read order _reg_cap documents: registries append
                 # mappings BEFORE the location rows that reference them,
                 # so nl-then-nm guarantees every persisted location's
                 # mapping id resolves inside the persisted mapping rows
                 # even while a feed is appending concurrently (extra
                 # unreferenced mappings are legal; dangling ids are not).
-                nl = min(len(reg.loc_address), len(reg.loc_normalized),
-                         len(reg.loc_mapping_id), len(reg.loc_is_kernel))
+                nl = reg.n_locs
                 nm = len(reg.mappings)
                 st = encoder._static.get(pid) if encoder is not None \
                     else None
@@ -280,14 +277,10 @@ class StaticsStore:
                                       int(st_period) & (2**64 - 1),
                                       nm, nl, digest)
                 rec += map_block
-                rec += np.asarray(reg.loc_address[:nl],
-                                  np.uint64).tobytes()
-                rec += np.asarray(reg.loc_normalized[:nl],
-                                  np.uint64).tobytes()
-                rec += np.asarray(reg.loc_mapping_id[:nl],
-                                  np.int32).tobytes()
-                rec += np.asarray(reg.loc_is_kernel[:nl],
-                                  np.uint8).tobytes()
+                rec += reg.loc_address[:nl].tobytes()
+                rec += reg.loc_normalized[:nl].tobytes()
+                rec += reg.loc_mapping_id[:nl].tobytes()
+                rec += reg.loc_is_kernel[:nl].tobytes()
                 rec += _U32.pack(1 if has_statics else 0)
                 if has_statics:
                     rec += _U32.pack(st_nm)
@@ -501,13 +494,10 @@ class StaticsStore:
             if st_nm > nm or st_nl > nl:
                 raise ValueError("statics extend past the registry")
             statics = (head, tail, loc_bytes, st_nm, st_nl)
-        # .tolist() (C-level) — per-element Python conversion made
-        # adoption cost more than the cold build it replaces.
-        if not agg.adopt_registry(int(pid), mappings,
-                                  loc_address.tolist(),
-                                  loc_normalized.tolist(),
-                                  loc_mapping_id.tolist(),
-                                  loc_is_kernel.tolist()):
+        # The registry copies the columns out of the record's buffer.
+        if not agg.adopt_registry(int(pid), mappings, loc_address,
+                                  loc_normalized, loc_mapping_id,
+                                  loc_is_kernel):
             out["stale"] += 1  # pid already live: adoption is cold-start only
             return
         if encoder is not None and statics is not None:

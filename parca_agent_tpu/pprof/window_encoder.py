@@ -69,8 +69,10 @@ Thread-ownership contract (the encode pipeline, profiler/encode_pipeline.py):
   * build_statics() may run on the encoder thread concurrently with the
     profiler thread FEEDING the next window. That is safe because the
     aggregator's registries are append-only and published behind a
-    watermark (_published): list reads are bounded by lengths observed
-    under the GIL, id-mirror reads by the watermark, and a rotation
+    watermark (_published): a registry's location columns are read below
+    a length observed under the GIL (rows under a published length never
+    change, and a column that grew keeps an equal prefix), its mapping
+    list likewise, id-mirror reads by the watermark, and a rotation
     observed mid-read at worst caches state that the next prepare() (which
     always sees the bumped rotation epoch, being sequenced after it)
     throws away wholesale.
@@ -248,8 +250,8 @@ def _loc_key(reg, n_locs: int) -> bytes:
     dense 1-based numbering, so they are implied by n_locs."""
     h = _hashlib.blake2b(digest_size=16)
     h.update(n_locs.to_bytes(8, "little"))
-    h.update(np.asarray(reg.loc_mapping_id[:n_locs], np.uint64).tobytes())
-    h.update(np.asarray(reg.loc_normalized[:n_locs], np.uint64).tobytes())
+    h.update(reg.loc_mapping_id[:n_locs].astype(np.uint64))
+    h.update(reg.loc_normalized[:n_locs])
     return b"L" + h.digest()
 
 
@@ -513,14 +515,13 @@ class _PreparedWindow:
 
 def _reg_cap(reg) -> tuple:
     """(registry, safe mapping count, safe location count) for concurrent
-    readers: the loc lists are extended address-first, so the minimum of
-    the three lengths is complete in all of them, and mappings are
-    appended BEFORE any location row references them — which is only a
-    guarantee if the LOCATION lengths are read first (reading the
-    mapping count first could miss a mapping that location rows read a
-    moment later already reference)."""
-    n_locs = min(len(reg.loc_address), len(reg.loc_normalized),
-                 len(reg.loc_mapping_id))
+    readers: the location columns have one published length, set after
+    the rows it covers are written, and mappings are appended BEFORE any
+    location row references them — which is only a guarantee if the
+    LOCATION length is read first (reading the mapping count first could
+    miss a mapping that location rows read a moment later already
+    reference)."""
+    n_locs = reg.n_locs
     return (reg, len(reg.mappings), n_locs)
 
 
@@ -740,7 +741,7 @@ class WindowEncoder:
                 reg = agg._pids.get(pid)
                 if (reg is None or reg is not st.reg
                         or st.n_locs == 0
-                        or len(reg.loc_mapping_id) < st.n_locs):
+                        or reg.n_locs < st.n_locs):
                     continue
                 self._cache_put(_loc_key(reg, st.n_locs),
                                 bytes(st.loc_bytes), len(st.loc_bytes))
@@ -952,10 +953,8 @@ class WindowEncoder:
                     self._count_statics_bytes(reused=len(got))
                     return st
             ids = np.arange(st.n_locs + 1, n_locs + 1, dtype=np.uint64)
-            mids = np.asarray(reg.loc_mapping_id[st.n_locs:n_locs],
-                              np.uint64)
-            addrs = np.asarray(reg.loc_normalized[st.n_locs:n_locs],
-                               np.uint64)
+            mids = reg.loc_mapping_id[st.n_locs:n_locs].astype(np.uint64)
+            addrs = reg.loc_normalized[st.n_locs:n_locs]
             buf, _ = _encode_location_stream(ids, mids, addrs)
             data = buf.tobytes()
             self._count_statics_bytes(built=len(data))
@@ -1122,8 +1121,6 @@ class WindowEncoder:
         restart-adoption shape) are content-addressed: a cache hit skips
         the varint encode entirely and aliases the shared bytes; only
         misses and true deltas ride the batch encode below."""
-        from itertools import chain
-
         rest: list[tuple] = []  # (st, reg, n, full_blob_key_or_None)
         dups: dict[bytes, list] = {}  # within-batch identical blobs
         for st, reg, n in dirty:
@@ -1155,14 +1152,11 @@ class WindowEncoder:
         ids = np.repeat(first, lens) + (
             np.arange(total, dtype=np.uint64)
             - np.repeat(bounds[:-1], lens).astype(np.uint64))
-        mids = np.fromiter(
-            chain.from_iterable(reg.loc_mapping_id[st.n_locs:n]
-                                for st, reg, n, _ in rest),
-            np.uint64, total)
-        addrs = np.fromiter(
-            chain.from_iterable(reg.loc_normalized[st.n_locs:n]
-                                for st, reg, n, _ in rest),
-            np.uint64, total)
+        mids = np.concatenate(
+            [reg.loc_mapping_id[st.n_locs:n] for st, reg, n, _ in rest]
+        ).astype(np.uint64)
+        addrs = np.concatenate(
+            [reg.loc_normalized[st.n_locs:n] for st, reg, n, _ in rest])
         buf, offs = _encode_location_stream(ids, mids, addrs)
         mv = buf.data
         for k, (st, reg, n, key) in enumerate(rest):
@@ -1387,7 +1381,8 @@ class WindowEncoder:
         # pid, ruinous for a cold 50k-pid first window (the production
         # profiler lands here without ever calling build_statics itself).
         # After this, _ensure_static is a pure cache hit per pid.
-        self.build_statics(period_ns, caps=caps)
+        with window_trace.child("encode_statics"):
+            self.build_statics(period_ns, caps=caps)
         statics = [self._ensure_static(int(p), period_ns,
                                        cap=None if caps is None
                                        else caps.get(int(p)))
@@ -1547,7 +1542,8 @@ class WindowEncoder:
                    if p in self._agg._pids}
         else:
             sub = {p: caps[p] for p in pids_u if p in caps}
-        self.build_statics(period_ns, caps=sub)
+        with window_trace.child("encode_statics"):
+            self.build_statics(period_ns, caps=sub)
         stream, s_off, vp_rel = self._serialize_rows(new_ids)
         bounds = np.flatnonzero(np.diff(new_pids)) + 1
         gstarts = np.concatenate(([0], bounds))

@@ -57,6 +57,7 @@ from __future__ import annotations
 import threading
 import time
 
+from parca_agent_tpu.runtime import trace as window_trace
 from parca_agent_tpu.runtime.trace import NULL_TRACE
 from parca_agent_tpu.utils import faults
 from parca_agent_tpu.utils.log import get_logger
@@ -418,7 +419,11 @@ class EncodePipeline:
         statics0 = enc_stats.get("statics_build_s_total", 0.0)
         layouts0 = enc_stats.get("layouts_built", 0)
         reused0 = enc_stats.get("views_reused_total", 0)
-        out = self._enc.encode_prepared(prep, views=self._views)
+        # What the encoder records inside the encode (encode_statics)
+        # is a child of the `encode` span recorded below.
+        sp_enc = window_trace.pending(trace, "encode")
+        with window_trace.adopt(sp_enc):
+            out = self._enc.encode_prepared(prep, views=self._views)
         enc_s = time.monotonic() - t0
         self.stats["last_encode_s"] = enc_s
         self.stats["overlap_s_total"] += enc_s
@@ -438,7 +443,7 @@ class EncodePipeline:
             # seconds would distort the distribution).
             trace.add_span("statics", statics_s, histogram=False,
                            start_s=t0, accumulated=True)
-        trace.add_span("encode", enc_s, start_s=t0)
+        trace.add_span("encode", enc_s, start_s=t0, span_id=sp_enc.id)
         try:
             # A context span: what the ship is made of is recorded under
             # it (profiler/cpu.py _write_all), and a failed ship's span

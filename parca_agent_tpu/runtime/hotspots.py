@@ -296,9 +296,10 @@ def render_frames(agg, sid: int, max_frames: int) -> tuple:
     pid = int(agg._id_pid[sid])
     reg = agg._pids.get(pid)
     frames = []
+    n_locs = 0 if reg is None else len(reg.loc_address)
     for lid in loc_ids.tolist():
         i = int(lid) - 1
-        if reg is None or not (0 <= i < len(reg.loc_address)):
+        if not (0 <= i < n_locs):
             frames.append("?")
             continue
         addr = int(reg.loc_address[i])

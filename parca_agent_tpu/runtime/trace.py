@@ -1160,6 +1160,18 @@ def child(stage: str, histogram: bool = False,
                     usage=usage)
 
 
+def pending(tr, stage: str) -> _SpanCtx:
+    """A span of ``tr`` that its caller records at its end itself,
+    through ``add_span(..., span_id=ctx.id)`` (the encode worker's
+    ``encode``: recorded where it always was, and not at all when the
+    encode raises). ``adopt`` it for the length of the work, and what
+    begins on the thread meanwhile names it as parent."""
+    ctx = _SpanCtx(tr, stage)
+    if tr is not NULL_TRACE:
+        ctx.id = tr.new_id()
+    return ctx
+
+
 def note(stage: str, duration_s: float, start_s: float | None = None,
          accumulated: bool = False, histogram: bool = False) -> None:
     """Record an interval measured elsewhere as a child of the innermost

@@ -407,6 +407,11 @@ def render_metrics(profilers, batch_client=None, extra: dict | None = None,
                  agg_stats.get("reclaims", 0), lab)
             emit("parca_agent_dict_reclaimed_ids_total",
                  agg_stats.get("reclaimed_ids", 0), lab)
+            # Known pids whose address look-up was built because they
+            # brought addresses to test (a pid that is never asked
+            # keeps none: docs/perf.md "What a registered pid costs").
+            emit("parca_agent_dict_registry_index_builds_total",
+                 agg_stats.get("registry_index_builds", 0), lab)
         feeder = getattr(p, "_feeder", None)
         if feeder is not None and getattr(feeder, "stats", None):
             # The ingest ceiling as a first-class number: the fraction

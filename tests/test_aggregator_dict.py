@@ -398,3 +398,122 @@ def test_prefix_sum_equals_cumsum_at_every_branch():
         got = np.asarray(jax.jit(prefix_sum)(jnp.asarray(x)))
         assert got.dtype == np.int32
         assert np.array_equal(got, np.cumsum(x, dtype=np.int32)), n
+
+
+# -- the per-pid registry's columns ------------------------------------------
+
+
+def _registry(n: int):
+    from parca_agent_tpu.aggregator.dict import _PidRegistry
+
+    a = np.arange(1, n + 1, dtype=np.uint64) * 16
+    return _PidRegistry(a.copy(), a + 1, np.full(n, 3, np.int32),
+                        a % 32 == 0, [], {})
+
+
+def _run(lo: int, hi: int):
+    a = np.arange(lo + 1, hi + 1, dtype=np.uint64) * 16
+    return a, a + 1, np.full(hi - lo, 3, np.int32), a % 32 == 0
+
+
+def test_registry_columns_read_like_the_lists_they_were():
+    reg = _registry(5)
+    assert reg.n_locs == len(reg.loc_address) == 5
+    assert int(reg.loc_address[1]) == 32 and bool(reg.loc_is_kernel[1])
+    assert reg.loc_normalized[1:3].tolist() == [33, 49]
+    part = reg.loc_mapping_id[:4]
+    assert part.base is not None and part.dtype == np.int32   # a view
+    # Appends with room and past it: the length grows, the rows below
+    # a length read before stay what they were, in whichever buffer.
+    held = reg.loc_address[:5]
+    want = held.tolist()
+    for lo, hi in ((5, 6), (6, 40), (40, 41), (41, 200)):
+        reg.append_locs(*_run(lo, hi))
+        assert reg.n_locs == len(reg.loc_is_kernel) == hi
+        assert held.tolist() == reg.loc_address[:5].tolist() == want
+    assert reg.loc_address.tolist() == (
+        np.arange(1, 201, dtype=np.uint64) * 16).tolist()
+    assert reg.loc_normalized.tolist() == [a + 1 for a in
+                                           reg.loc_address.tolist()]
+    # Amortised: 195 rows arrived in four appends and three growths.
+    assert len(reg._address) >= 200 and reg.nbytes == 21 * len(reg._address)
+
+
+def test_registry_look_up_is_built_when_asked_and_follows_appends():
+    reg = _registry(0)
+    reg.append_locs(*_run(0, 0))      # nothing, and no growth
+    assert reg._index is None and reg.nbytes == 0
+    index, built = reg.index()
+    assert built and index == {}
+    # Runs arrive ascending within themselves, not among each other.
+    hi, lo = _run(100, 103), _run(0, 2)
+    reg.append_locs(*hi)
+    reg.append_locs(*lo)
+    again, built = reg.index()
+    assert again is index and not built
+    assert index == {1616: 1, 1632: 2, 1648: 3, 16: 4, 32: 5}
+    assert all(type(a) is int for a in index)     # never an np.uint64
+    assert [int(reg.loc_address[i - 1]) for i in index.values()] \
+        == list(index)
+    assert reg.nbytes == 21 * len(reg._address) + 96 * 5
+
+
+def test_a_reader_thread_never_sees_a_length_ahead_of_its_rows():
+    """The encode worker's side of the contract: while the owner thread
+    appends (through growths), a reader that takes the length and then a
+    column always finds that many finished rows, equal to the rows any
+    earlier read gave."""
+    import threading
+
+    reg = _registry(1)
+    stop, bad = threading.Event(), []
+
+    def reader():
+        while not stop.is_set():
+            n = reg.n_locs
+            addr, norm = reg.loc_address[:n], reg.loc_normalized[:n]
+            kern = reg.loc_is_kernel[:n]
+            if not (len(addr) == len(norm) == len(kern) == n
+                    and int(addr[-1]) == 16 * n
+                    and int(norm[n // 2]) == 16 * (n // 2 + 1) + 1
+                    and bool(kern[-1]) == (n % 2 == 0)):
+                bad.append(n)
+                return
+
+    import sys
+
+    readers = [threading.Thread(target=reader) for _ in range(3)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)     # hand the interpreter over often
+    try:
+        for t in readers:
+            t.start()
+        n = 1
+        for k in range(3000):
+            step = 1 + k % 7
+            reg.append_locs(*_run(n, n + step))
+            n += step
+    finally:
+        stop.set()
+        for t in readers:
+            t.join(30)
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in readers)
+    assert not bad and reg.n_locs == n
+
+
+def test_a_profiles_location_columns_are_read_only_views():
+    """_build_profiles hands out views of the registry's columns (no
+    copy a pid a window); a consumer cannot write through them."""
+    snap = generate(SyntheticSpec(n_pids=4, n_unique_stacks=40,
+                                  total_samples=400, seed=9))
+    d = DictAggregator(capacity=1 << 10)
+    prof = d.aggregate(snap)[0]
+    reg = d._pids[prof.pid]
+    for col, own in ((prof.loc_address, reg.loc_address),
+                     (prof.loc_normalized, reg.loc_normalized),
+                     (prof.loc_mapping_id, reg.loc_mapping_id),
+                     (prof.loc_is_kernel, reg.loc_is_kernel)):
+        assert np.shares_memory(col, own) and not col.flags.writeable
+        with np.testing.assert_raises(ValueError):
+            col[:1] = 0

@@ -146,6 +146,12 @@ def test_firehose_churn_under_redeploy_rehearses_a_reclaim_on_the_cpu():
     assert metrics["ship_static_reused_per_window"]["value"] \
         == pytest.approx(110.0)
     assert metrics["encode_order_rebuilds_per_window"]["value"] == 0.0
+    # The statics build of the window's new pids is a span under encode;
+    # the mix gives new stacks to new pids alone, so no known pid is
+    # asked for an address and no look-up is built.
+    assert 0 < metrics["encode_statics_ms.p50"]["value"] \
+        <= metrics["encode_ms.p99"]["value"]
+    assert metrics["registry_index_builds_per_window"]["value"] == 0.0
     for name in ("prepare_ms.p99", "encode_ms.p99", "handoff_wait_ms.p99"):
         assert metrics[name]["value"] > 0
     # Every per-layer metric the cell lists that is no device number.
@@ -179,6 +185,10 @@ def test_node_streamed_under_rollout_rehearses_streamed_on_the_cpu():
     assert metrics["stream_rows_fed_per_window"]["value"] > 0
     assert metrics["carry_matched_rows_per_window"]["value"] > 0
     assert metrics["misses_per_window"]["value"] > 0
+    # A window's new pids are registered by the drain that first holds
+    # them and come back, known, in its later drains: each is asked for
+    # its addresses then, and builds its look-up once.
+    assert metrics["registry_index_builds_per_window"]["value"] > 0
     # Every per-layer metric the cell lists that is no device number
     # reads one here, and the feed thread's self time is no deficit.
     cell = next(w for w in BENCHMARK["workloads"]

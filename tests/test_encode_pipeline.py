@@ -1060,6 +1060,38 @@ def test_a_windows_meta_says_what_the_encoder_redid(kind):
     assert enc.stats["views_reused_total"] == (3 if kind == "steady" else 2)
 
 
+def test_a_pipelined_windows_statics_build_is_a_span_under_its_encode():
+    """The worker records `encode` at its end, as it always did; what
+    the encoder records inside it, the statics build of a window that
+    lays out or appends, names it as parent. A window that only patches
+    counts builds no statics and has no such span."""
+    from parca_agent_tpu.runtime.trace import FlightRecorder
+
+    snap = _snap(seed=31)
+    grown = _snap(seed=31, n_pids=9, rows=260)
+    agg = DictAggregator(capacity=1 << 12)
+    pipe = EncodePipeline(WindowEncoder(agg), ship=lambda out, prep: None)
+    rec = FlightRecorder(ring=8)
+    try:
+        for t, s in enumerate((snap, snap, grown)):
+            counts = np.asarray(agg.window_counts(s))
+            tr = rec.begin(s.time_ns + t)
+            assert pipe.submit(counts, s.time_ns + t, s.window_ns,
+                               s.period_ns, trace=tr) is not None
+            assert pipe.flush(30)
+    finally:
+        assert pipe.close()
+    cold, steady, rollout = (
+        {sp["stage"]: sp for sp in d["spans"]} for d in rec.traces())
+    for spans in (cold, rollout):
+        enc_sp, st_sp = spans["encode"], spans["encode_statics"]
+        assert st_sp["parent"] == enc_sp["id"] and enc_sp["parent"] is None
+        assert 0 < st_sp["duration_s"] <= enc_sp["duration_s"]
+        assert enc_sp["start_s"] <= st_sp["start_s"]
+        assert st_sp["thread"] == enc_sp["thread"] == "encode-pipeline"
+    assert "encode" in steady and "encode_statics" not in steady
+
+
 def test_encoder_carried_state_counters_on_metrics():
     """The five families of the encoder's carried state."""
     from parca_agent_tpu.web import render_metrics
@@ -1082,5 +1114,9 @@ def test_encoder_carried_state_counters_on_metrics():
                               ("views_reused_total", 2)):
             assert f'parca_agent_encoder_{family}{{profiler="cpu"}} ' \
                 f'{value}\n' in text, family
+        # Beside them, the aggregator's: no known pid was asked for an
+        # address (the same stacks three times), so no look-up was built.
+        assert 'parca_agent_dict_registry_index_builds_total' \
+            '{profiler="cpu"} 0\n' in text
     finally:
         p._pipeline.close()

@@ -452,7 +452,9 @@ def _register_loop(agg, snapshot, rows: np.ndarray) -> None:
     """The plain reference: ``_register_stacks_bulk`` as it stood until
     PR 33, a numpy pass per pid. It left the package with that PR and
     lives on here, as what the one pass has to leave behind, field for
-    field."""
+    field. It keeps what the list registry kept, a dictionary from
+    address to location id made from the address column, and hands the
+    registry each pid's run through ``append_locs``."""
     from parca_agent_tpu.aggregator.base import ProfileMapping
     from parca_agent_tpu.aggregator.cpu import _pid_mappings
     from parca_agent_tpu.aggregator.dict import _PidRegistry
@@ -475,10 +477,13 @@ def _register_loop(agg, snapshot, rows: np.ndarray) -> None:
         if reg is None:
             mappings = _pid_mappings(table, int(pid))
             reg = _PidRegistry(
-                {}, [], [], [], [], mappings,
+                np.zeros(0, np.uint64), np.zeros(0, np.uint64),
+                np.zeros(0, np.int32), np.zeros(0, bool), mappings,
                 {(m.start, m.end, m.offset): m.id for m in mappings},
             )
             agg._pids[int(pid)] = reg
+        addr_to_loc = {a: k + 1
+                       for k, a in enumerate(reg.loc_address.tolist())}
 
         prows = rows[sel]
         pdepths = depths[sel]
@@ -486,7 +491,7 @@ def _register_loop(agg, snapshot, rows: np.ndarray) -> None:
         live = np.arange(STACK_SLOTS)[None, :] < pdepths[:, None]
         addrs = stacks[live]
         uniq = np.unique(addrs)
-        known = np.array([int(a) in reg.addr_to_loc for a in uniq], bool)
+        known = np.array([int(a) in addr_to_loc for a in uniq], bool)
         fresh = uniq[~known] if len(uniq) else uniq
         if len(fresh):
             is_kernel = fresh >= np.uint64(KERNEL_ADDR_START)
@@ -526,14 +531,11 @@ def _register_loop(agg, snapshot, rows: np.ndarray) -> None:
                     row_to_reg[r] = rid
                 map_id = np.where(hit, row_to_reg[safe], 0)
             base = len(reg.loc_address)
-            reg.loc_address.extend(fresh.tolist())
-            reg.loc_normalized.extend(norm.tolist())
-            reg.loc_mapping_id.extend(map_id.tolist())
-            reg.loc_is_kernel.extend(is_kernel.tolist())
+            reg.append_locs(fresh, norm, map_id, is_kernel)
             for k, a in enumerate(fresh.tolist()):
-                reg.addr_to_loc[a] = base + k + 1
+                addr_to_loc[a] = base + k + 1
 
-        lut = np.array([reg.addr_to_loc[int(a)] for a in uniq], np.int32)
+        lut = np.array([addr_to_loc[int(a)] for a in uniq], np.int32)
         frame_ids = lut[np.searchsorted(uniq, stacks[live])]
         pd64 = pdepths.astype(np.int64)
         src_starts = np.zeros(len(sel), np.int64)
@@ -553,14 +555,29 @@ def _with_the_loop(agg):
     return agg
 
 
+def _registry_facts(reg) -> dict:
+    """A registry, column for column: each location column's dtype,
+    length and values, the one published length, the mappings and their
+    index in insertion order."""
+    cols = {name: getattr(reg, name) for name in (
+        "loc_address", "loc_normalized", "loc_mapping_id", "loc_is_kernel")}
+    return {
+        "n_locs": reg.n_locs,
+        "columns": {name: (col.dtype.str, col.tolist())
+                    for name, col in cols.items()},
+        "mappings": repr(reg.mappings),
+        "mapping_index": list(reg.mapping_index.items()),
+    }
+
+
 def _registered_state(agg) -> dict:
-    """Everything the register step writes. A registry is compared by
-    its ``repr``: every field, dictionaries in insertion order, a bool
-    told from an int."""
+    """Everything the register step writes, a registry by
+    ``_registry_facts``."""
     n = agg._next_id
     return {
         "pids": list(agg._pids),
-        "registries": {pid: repr(reg) for pid, reg in agg._pids.items()},
+        "registries": {pid: _registry_facts(reg)
+                       for pid, reg in agg._pids.items()},
         "id_pid": agg._id_pid[:n].tolist(),
         "loc_off": agg._loc_off[:n + 1].tolist(),
         "loc_flat": agg._loc_flat[:int(agg._loc_off[n])].tolist(),
@@ -774,7 +791,9 @@ def test_the_register_step_makes_as_many_numpy_calls_for_1800_pids_as_for_18(
         monkeypatch):
     """The loop per pid cannot come back unnoticed: one call of the
     register step makes a number of numpy calls that does not grow with
-    the number of pids in the batch."""
+    the number of pids in the batch. (What a first-seen pid does cost
+    is the four ``.copy()`` that cut its run of the columns out of the
+    group's arrays: meant to be there, methods, not counted.)"""
     calls = {}
     for n_pids in (18, 1800):
         (snap, rows), = _batches_synthetic(n_pids, 5, seed=n_pids)
@@ -827,3 +846,139 @@ def test_a_windows_meta_counts_the_pids_it_registered(pids, stacks):
     # the counts are there and say so.
     assert (meta["misses"], meta["registered_pids"],
             meta["registered_first_seen"]) == (0, 0, 0)
+
+
+# -- the registry's columns: arrays, written once ----------------------------
+
+
+_COLUMNS = ("loc_address", "loc_normalized", "loc_mapping_id",
+            "loc_is_kernel")
+
+
+@pytest.mark.parametrize("pids, stacks", [
+    (40, 400),        # the scalar settle (under 512 misses a window)
+    (160, 1600)])     # the vectorised one
+def test_a_registered_and_an_adopted_registry_are_equal_column_for_column(
+        pids, stacks):
+    """The two constructors of a registry, the register step (reached
+    from both miss settles) and ``adopt_registry`` (fed, as the statics
+    store feeds it, arrays over a record's read-only bytes), leave the
+    same type with the same columns: dtype, length, values."""
+    agg = DictAggregator(capacity=1 << 14, overflow="raise")
+    for snap in _turnover_windows(3, pids, stacks, seed=12, turnover=0.75):
+        agg.window_counts(snap)
+    twin = DictAggregator(capacity=1 << 14, overflow="raise")
+    for pid, reg in agg._pids.items():
+        cols = [np.frombuffer(getattr(reg, c).tobytes(),
+                              getattr(reg, c).dtype) for c in _COLUMNS]
+        assert not cols[0].flags.writeable
+        assert twin.adopt_registry(pid, reg.mappings, *cols)
+        got = twin._pids[pid]
+        assert type(got) is type(reg)
+        assert _registry_facts(got) == _registry_facts(reg), pid
+        assert [getattr(got, c).dtype.str for c in _COLUMNS] == [
+            "<u8", "<u8", "<i4", "|b1"]
+        assert twin.registry_digest(pid) == agg.registry_digest(pid)
+        # Adopted columns are the registry's own, and writable: the
+        # pid's next window appends to them.
+        assert all(_owns_its_memory(got, c) for c in _COLUMNS)
+    assert len(twin._pids) == len(agg._pids) > pids
+
+
+def _owns_its_memory(reg, column: str) -> bool:
+    """The column is a view of a buffer that is nobody's view and holds
+    no more than the registry asked for (its rows, or the doubled room
+    of a growth): a registry pins no array of the group it came in."""
+    col = getattr(reg, column)
+    buf = col.base if col.base is not None else col
+    return buf.base is None and buf.flags.owndata \
+        and len(buf) <= max(2 * len(col), 1)
+
+
+def test_a_first_seen_pids_columns_own_their_memory():
+    """A first-seen pid is handed a copy of its run of the group's four
+    arrays: were it a slice, every pid of a group would keep the whole
+    group's arrays alive for as long as it lives."""
+    (snap, rows), = _batches_synthetic(60, 5, seed=5)
+    agg = DictAggregator(capacity=1 << 14, overflow="raise")
+    agg._next_id += len(rows)
+    agg._register_stacks_bulk(snap, rows)
+    regs = list(agg._pids.values())
+    assert len(regs) > 50
+    for reg in regs:
+        assert all(_owns_its_memory(reg, c) for c in _COLUMNS)
+    a, b = regs[0], regs[1]
+    assert not any(np.shares_memory(getattr(a, c), getattr(b, c))
+                   for c in _COLUMNS)
+
+
+def test_a_known_pid_that_grows_gets_the_list_registrys_ids_and_one_look_up():
+    """A pid the registry knows brings new stacks in later windows, some
+    of their addresses registered and some fresh: the fresh ones take
+    the location ids the list registry gave (ascending, after the
+    locations the pid has), the profiles are the plain reference's, the
+    address look-up is built the first time the pid is asked and kept
+    from there (the counter rises by one, once), and a first-seen pid
+    beside it is never asked."""
+    maps = [(7, 0x1000, 0x2000, 0x0, 0), (7, 0x4000, 0x5000, 0x100, 1),
+            (9, 0x1000, 0x2000, 0x0, 0)]
+    windows = [
+        _snap([(7, [0x1010, 0x4020], []), (7, [0x1030, 0x1010], [_K + 1])],
+              maps),
+        # Known addresses (0x1010, 0x4020, _K + 1) among fresh ones, the
+        # fresh ones on both sides of what the pid has; pid 9 first seen.
+        _snap([(7, [0x1010, 0x4020], []), (7, [0x1030, 0x1010], [_K + 1]),
+               (7, [0x1005, 0x4020, 0x4fff, 0x9999], [_K + 1, _K]),
+               (9, [0x1010, 0x1020], [])], maps),
+        # And again, twice over: a growth past the doubled room.
+        _snap([(7, [0x1010, 0x4020], []),
+               (7, [0x1005, 0x4020, 0x4fff, 0x9999], [_K + 1, _K]),
+               (7, [0x1000 + 8 * k for k in range(40)], [_K + 7]),
+               (9, [0x1010, 0x1020], [])], maps),
+        _snap([(7, [0x1000 + 8 * k for k in range(40)], [_K + 7]),
+               (7, [0x4000 + 4 * k for k in range(60)] + [0x1005], []),
+               (9, [0x1020, 0x1fff], [])], maps),
+    ]
+    new = DictAggregator(capacity=1 << 10, overflow="raise")
+    old = _with_the_loop(DictAggregator(capacity=1 << 10, overflow="raise"))
+    builds = []
+    for snap in windows:
+        counts = np.asarray(new.window_counts(snap))
+        np.asarray(old.window_counts(snap))
+        assert _registered_state(new) == _registered_state(old)
+        have = {p.pid: parse_pprof(build_pprof(p, compress=False))
+                for p in new._build_profiles(snap, counts)}
+        want = _reference(snap)
+        assert set(have) == set(want)
+        for pid, ref in want.items():
+            assert have[pid].stacks_by_address() == ref.stacks_by_address()
+        builds.append(new.stats.get("registry_index_builds", 0))
+    # Window 2 asks pid 7 for the first time; window 3 asks it again and
+    # window 4 asks pid 9, each look-up built once.
+    assert builds == [0, 1, 1, 2]
+    reg = new._pids[7]
+    assert reg._index == {a: k + 1 for k, a in
+                          enumerate(reg.loc_address.tolist())}
+    assert new._pids[9]._index is not None
+    # The ids: each window's fresh addresses ascending, after the rest.
+    first = [0x1010, 0x1030, 0x4020, _K + 1]
+    second = [0x1005, 0x4fff, 0x9999, _K]
+    assert reg.loc_address[:8].tolist() == first + second
+    # (Window 3's forty hold 0x1010 and 0x1030, window 4's sixty 0x4020.)
+    assert reg.n_locs == 8 + (38 + 1) + 59
+    assert all(_owns_its_memory(reg, c) for c in _COLUMNS)
+    assert old.stats.get("registry_index_builds", 0) == 0
+
+
+def test_the_footprint_counts_a_registrys_arrays():
+    (snap, rows), = _batches_synthetic(20, 5, seed=3)
+    agg = DictAggregator(capacity=1 << 12, overflow="raise")
+    before = agg.footprint_bytes()["pid_registry_bytes"]
+    agg.window_counts(snap)
+    regs = agg._pids.values()
+    want = sum(r.nbytes + 120 * len(r.mappings) for r in regs)
+    assert agg.footprint_bytes()["pid_registry_bytes"] == want > before == 0
+    # 21 bytes a location row (8 + 8 + 4 + 1); no pid was asked for an
+    # address, so none has a look-up.
+    assert all(r._index is None for r in regs)
+    assert sum(r.nbytes for r in regs) == 21 * sum(r.n_locs for r in regs)

@@ -48,13 +48,15 @@ def _no_leaked_injector():
 # -- a hand-rolled (view, prep) pair: precise control over builds,
 # -- tenants, and counts, without a full aggregator run ----------------------
 
-class _Reg:
-    def __init__(self, mappings, n_locs, kernel=()):
-        self.mappings = mappings
-        self.loc_is_kernel = [i in kernel for i in range(n_locs)]
-        self.loc_mapping_id = [1 + (i % len(mappings))
-                               for i in range(n_locs)]
-        self.loc_normalized = [0x100 * (i + 1) for i in range(n_locs)]
+def _Reg(mappings, n_locs, kernel=()):
+    """A registry as the aggregator keeps one: array columns."""
+    from parca_agent_tpu.aggregator.dict import _PidRegistry
+
+    normalized = 0x100 * np.arange(1, n_locs + 1, dtype=np.uint64)
+    return _PidRegistry(
+        normalized.copy(), normalized,
+        (1 + np.arange(n_locs) % len(mappings)).astype(np.int32),
+        np.isin(np.arange(n_locs), list(kernel)), mappings, {})
 
 
 class _View:

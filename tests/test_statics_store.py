@@ -544,3 +544,90 @@ def test_corrupt_length_field_resyncs_to_next_record(tmp_path):
                                    snap.period_ns)
     assert out["adopted"] == n - 1
     assert out["corrupt"] >= 1
+
+
+# -- a snapshot written when the registry's columns were Python lists ---------
+
+# `StaticsStore.save` of the tree before PR 51 (columns as lists, each
+# dumped through np.asarray(list, dtype).tobytes()): three pids of one
+# synthetic window (seed 51), their registries and built statics;
+# zlib-compressed, base64. Period 10,000,000 ns.
+_LIST_LAYOUT_SNAPSHOT = (
+    "eNrtlm1sU1UYx2/f1q7r2s6xcYcipfihJHPtvXup48vuAhESNUzKmyZkbF2RKbRLu/ESddzN"
+    "oQTnRgDJEnVWTSbGsJCJBgwv/aAocc6ZEFREsxghOlG3JYouYn3OeZ5rt7olm1/gw05y+v+f"
+    "c27Pufeec3/PU1UZWFu5NiBVBdYs3ygIwqfu7v4n3TtC0Vh9JOxe5pIKXe5gNFTTGKqrrmms"
+    "bgrX72K9/nJJuq+stFgqksqlMslfCpc1hKL1kbrqcIxd4MMC3aGGSHArdPmeZmv0mARhhfec"
+    "5weDIKhHugRWjFBzBCy5Jfbvb3S5Pmsb/WBj4R+Z3+hYZ1W0VljHNchVK9TvAOutaWjw1taH"
+    "vTsj0SdCUQ90+WZWJL023R7+q+PqpL6RZDLZLAj5bImmWNS7rb6W1djWmmiozlcUi8xiJdmA"
+    "czpppRyuLlppKOlg7alXkma3UrER53TRSou5+milweSi6VeSZ7dSiXLsKb4JiqUZVSSNq6hl"
+    "+GaVr22o67NRrzpQ/Tr+PpQTJtQ/M1Bf0/G7V66QurLxaZoK4OQkk6rbxVUwLuaqbLLyh1NO"
+    "ZqF+TFplR70fH175yYa6JRv1NwfqBh3qRRNqgRn1c+q36Wk+/N9096HjRyhV9WnVMKEaJ9Tp"
+    "iw5K2lcSgWo1WnRO/YJ8+FXjFw49G88TQV8C9Rh8xgUFFr1TZWVokQi/+0Bdqstj8mXAkIEP"
+    "tdBQCxtSPGafBYaMfKiVhlrZ0GZPps/qg3uQBdkcq9nesC0Uk03BSFO4UXakfXiyZ6afnZw/"
+    "9Qc14xnkqWaQZjND8VQzyLOZoUQ2BBua5KxwTTgSCwUj4brYo0ZLltO2WT32nfEUbZfbApuk"
+    "ExPvLwWnZ26kCJyBuQNecEbm4uXgTE69mGjXgctg7lIxODNz/aXgLMwNV4DLdBrERK8JnJW5"
+    "o/PBZTHXdxc4m9MoJl43g8tmrssCzs5cvNSdbXGI6vBVdjLgtELTKaofqi3/NnNEdV8bNRmx"
+    "/4KDV1bwQu+PacS20cPZokc2PXPi2/fWF1Q1e959O5t/LoPLRzmpB5ePTSI29c8R+1YS++Yq"
+    "vgnKFw+i+h9GjRqQyJvMqNeyUFcTuXv1SOYeIvSIFXWdHQn9lgP1XiKoORP1MSJtK75NZTuR"
+    "9XcidDUReYAIez4D1Unje4ngXzlou2dH10ksTTu8/wFp5/5ODtLOg51zIL1tQLorDaSX52kg"
+    "7bhHA+lAKQcpALLNroH08kINpNe9GkjPShykgMqXszSQtt+pgTReyEEKqNy/TANpXwXj4CE4"
+    "le9sqTw1nMZBke5t/MyeitrR+hU7Bh5f80mGdIlz8PjKXzn/jq8cmcRB6p/j4K3kYGQV3wTl"
+    "Aqn/AdQA6dKHUFesRt0ZQH1zA+oBPXLxFdIAcfNnynBjNuTjI3bU0/hUyhILarWNq+pwYCZZ"
+    "R9qKKmTmoV5zc01Yl2Cme8WLeo4y2gLKUJ8jvr5Bups4fJh4+xHx9AZlvHHi7THSIF13k3h9"
+    "iLi8m/h7ndYLWFA7cfz/3n96pjzTzHlmWfM0ifSEZFqcJgYMdY93sBgw1N06FwNunxiQq5sc"
+    "A/7WazGgz6jFgA67lkyfxxgAbiyPxwBwR+/mMYBd5+UxANwvmExDXEhYeQxgUcPKYwC48wt5"
+    "DAA37ufJNESIHpkn0+DGWMLuYBHiSwM4J3M9i8HlMNctQzJ9h6i+ejqVaueK6oUzqeY8Ud17"
+    "NtWEM3fwYqqZL6r9z6fy8vli4sX2VFMUEyfPUfMfCwHROg==")
+_LIST_LAYOUT_PERIOD_NS = 10_000_000
+# registry_digest / _loc_key of each pid, as that tree computed them.
+_LIST_LAYOUT_DIGESTS = {
+    1000: ("13340ee4f79820cf84f1c4582cf809dd", 17,
+           "4c2ce89d4363ca55568aaea264a3115494"),
+    1001: ("0c72965d83b4deb65619507e28b5ab0d", 12,
+           "4c96b871de0f1f4eec35ef7c40f2bb2ecb"),
+    1002: ("fabd7f3f62f1694476ce6a52cb0631d6", 24,
+           "4c4353ee5cf72350468b71249e81d4c2f7"),
+}
+
+
+def test_a_snapshot_of_the_list_layout_restores_as_array_columns(tmp_path):
+    """An agent that kept its registries as lists wrote this file; the
+    agent that keeps them as arrays adopts every record of it (the
+    record's digest is recomputed from the arrays' bytes), ends with
+    equal content digests and equal location-blob keys, and writes the
+    same records back."""
+    import base64
+
+    from parca_agent_tpu.aggregator.dict import _PidRegistry
+    from parca_agent_tpu.pprof.window_encoder import _loc_key
+
+    data = zlib.decompress(base64.b64decode(_LIST_LAYOUT_SNAPSHOT))
+    path = str(tmp_path / "old.snap")
+    open(path, "wb").write(data)
+    agg = DictAggregator(capacity=1 << 8)
+    enc = WindowEncoder(agg)
+    out = StaticsStore(path, max_age_s=None).adopt(
+        agg, enc, _LIST_LAYOUT_PERIOD_NS)
+    assert (out["adopted"], out["corrupt"], out["stale"]) == (3, 0, 0)
+    assert list(agg._pids) == list(_LIST_LAYOUT_DIGESTS)
+    for pid, (digest, n_locs, loc_key) in _LIST_LAYOUT_DIGESTS.items():
+        reg = agg._pids[pid]
+        assert type(reg) is _PidRegistry and reg.n_locs == n_locs
+        assert [c.dtype.str for c in (
+            reg.loc_address, reg.loc_normalized, reg.loc_mapping_id,
+            reg.loc_is_kernel)] == ["<u8", "<u8", "<i4", "|b1"]
+        assert reg.loc_address.flags.writeable   # its own, not the file's
+        assert agg.registry_digest(pid).hex() == digest
+        assert _loc_key(reg, n_locs).hex() == loc_key
+        assert enc._static[pid].n_locs == n_locs
+    # And back: the records (every frame after the json header) are the
+    # old file's, byte for byte.
+    back = str(tmp_path / "new.snap")
+    assert StaticsStore(back).save(agg, enc, _LIST_LAYOUT_PERIOD_NS)
+    new = open(back, "rb").read()
+    old_frames, new_frames = _frames(data), _frames(new)
+    assert len(old_frames) == len(new_frames) >= 1 + 3
+    for (o_off, o_len), (n_off, n_len) in zip(old_frames[1:],
+                                              new_frames[1:]):
+        assert data[o_off: o_off + _FHEAD + o_len] \
+            == new[n_off: n_off + _FHEAD + n_len]

@@ -813,6 +813,38 @@ def test_child_with_nothing_open_measures_and_records_nowhere():
     assert trace_mod.current() is None
 
 
+def test_a_pending_span_parents_what_begins_before_it_is_recorded():
+    """A span its caller records at its end through add_span (the encode
+    worker's `encode`): adopted meanwhile, it is the parent of children
+    begun on the thread; a raise inside records the children and no
+    span of the stage; with tracing off nothing is recorded at all."""
+    rec = FlightRecorder()
+    tr = rec.begin()
+    t0 = time.monotonic()
+    sp = trace_mod.pending(tr, "encode")
+    with trace_mod.adopt(sp):
+        assert trace_mod.current() is sp
+        with trace_mod.child("encode_statics"):
+            time.sleep(0.001)
+    assert trace_mod.current() is None and len(tr.spans) == 1
+    tr.add_span("encode", time.monotonic() - t0, start_s=t0, span_id=sp.id)
+    child, parent = tr.spans
+    assert (child["stage"], parent["stage"]) == ("encode_statics", "encode")
+    assert child["parent"] == parent["id"] == sp.id
+    assert parent["parent"] is None
+    with pytest.raises(RuntimeError):
+        with trace_mod.adopt(trace_mod.pending(tr, "ship")):
+            raise RuntimeError("boom")
+    assert trace_mod.current() is None
+    assert [s["stage"] for s in tr.spans] == ["encode_statics", "encode"]
+    off = trace_mod.pending(NULL_TRACE, "encode")
+    with trace_mod.adopt(off):
+        with trace_mod.child("encode_statics") as c:
+            pass
+    assert off.id is None and c.duration_s >= 0
+    assert trace_mod.current() is None and rec.stats["record_errors"] == 0
+
+
 @pytest.mark.chaos
 def test_child_is_fail_open_under_the_trace_record_chaos_site():
     rec = FlightRecorder()

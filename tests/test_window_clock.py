@@ -145,13 +145,15 @@ def test_quarantine_cooldown_holds_wall_time(window_s):
 T0_NS = 1_700_000_000_000_000_000
 
 
-class _Reg:
-    def __init__(self, mappings, n_locs):
-        self.mappings = mappings
-        self.loc_is_kernel = [False] * n_locs
-        self.loc_mapping_id = [1 + (i % len(mappings))
-                               for i in range(n_locs)]
-        self.loc_normalized = [0x100 * (i + 1) for i in range(n_locs)]
+def _Reg(mappings, n_locs):
+    """A registry as the aggregator keeps one: array columns."""
+    from parca_agent_tpu.aggregator.dict import _PidRegistry
+
+    normalized = 0x100 * np.arange(1, n_locs + 1, dtype=np.uint64)
+    return _PidRegistry(
+        normalized.copy(), normalized,
+        (1 + np.arange(n_locs) % len(mappings)).astype(np.int32),
+        np.zeros(n_locs, bool), mappings, {})
 
 
 class _View:

@@ -152,6 +152,80 @@ def test_encoder_matches_builder_single_window():
         assert p1 == p2 and b1 == b2
 
 
+@pytest.mark.parametrize("second", ["first_seen_pids", "known_pids_grow"])
+def test_the_batch_build_ships_the_stragglers_bytes(second):
+    """A window that brings first-seen pids, and one in which known pids
+    grow new locations: the encoder whose statics come from the batch
+    build (_build_locs_batch: one concatenate of the registries' column
+    views) ships the bytes of one whose build_statics does nothing, so
+    that every static is built, and later extended by its delta, by the
+    scalar _ensure_static."""
+    snap = generate(_spec(seed=77, n_pids=14, rows=700))
+    rng = np.random.default_rng(3)
+    late = np.isin(snap.pids, np.unique(snap.pids)[-3:])
+    half = rng.random(len(snap)) < 0.5
+    first = ~late & half
+    masks = [first, first | (late if second == "first_seen_pids"
+                             else ~late)]
+    aggs = [DictAggregator(capacity=1 << 13) for _ in range(2)]
+    batch, scalar = (WindowEncoder(a) for a in aggs)
+    scalar.build_statics = lambda *a, **kw: 0
+    for t, mask in enumerate(masks):
+        sub = span_scenarios.rows_of(snap, mask)
+        outs = []
+        for agg, enc in zip(aggs, (batch, scalar)):
+            had = {p: r.n_locs for p, r in agg._pids.items()}
+            c = np.asarray(agg.window_counts(sub)).copy()
+            outs.append(_bytes_of(enc.encode(
+                c, snap.time_ns + t, snap.window_ns, snap.period_ns)))
+        assert outs[0] == outs[1]
+        _assert_same_profiles(aggs[0], dataclasses.replace(
+            sub, time_ns=snap.time_ns + t), c, outs[0])
+    now = {p: r.n_locs for p, r in aggs[0]._pids.items()}
+    if second == "first_seen_pids":
+        assert len(now) == len(had) + 3
+        assert all(now[p] == n for p, n in had.items())
+    else:
+        assert now.keys() == had.keys()
+        assert sum(now[p] > n for p, n in had.items()) >= 5
+    assert batch.stats["statics_build_s_total"] > 0
+    assert scalar.stats["statics_build_s_total"] == 0
+    assert scalar.stats["statics_bytes_built"] \
+        == batch.stats["statics_bytes_built"] > 0
+
+
+def test_the_location_key_of_array_columns_is_the_list_registrys():
+    """_loc_key digests a registry's (mapping id, normalised address)
+    rows as little-endian uint64, which is what np.asarray(list,
+    np.uint64).tobytes() gave when the columns were lists: content-cache
+    keys, and statics an older agent persisted, stay valid. The
+    constants are the list registry's keys for these values (from the
+    tree before PR 51)."""
+    import hashlib
+
+    from parca_agent_tpu.aggregator.dict import _PidRegistry
+    from parca_agent_tpu.pprof.window_encoder import _loc_key
+
+    k = 0xFFFF_8000_0000_0000
+    addr = [0x1010, 0x4020, k + 1, 0x7FFF_FFFF_F000, 0xFFFF_FFFF_FFFF_FFFF]
+    norm = [0x10, 0x120, k + 1, 0x7FFF_FFFF_F000, 0xFFFF_FFFF_FFFF_FFFF]
+    mid = [1, 2, 0, 0, 0]
+    reg = _PidRegistry(
+        np.array(addr, np.uint64), np.array(norm, np.uint64),
+        np.array(mid, np.int32),
+        np.array([False, False, True, False, True]), [], {})
+    pinned = {5: "4c38fec5f38d945b13fb252a2a9f400ed8",
+              3: "4cbd4b1aec71ab309525cf0e14386ac131",
+              0: "4cc804ce198ec337e3dc762bdd1a09aece"}
+    for n, want in pinned.items():
+        assert _loc_key(reg, n).hex() == want
+        h = hashlib.blake2b(digest_size=16)
+        h.update(n.to_bytes(8, "little"))
+        h.update(np.asarray(mid[:n], np.uint64).tobytes())
+        h.update(np.asarray(norm[:n], np.uint64).tobytes())
+        assert (b"L" + h.digest()).hex() == want
+
+
 def test_encoder_incremental_new_stacks_and_pids():
     snap1 = generate(_spec(seed=1))
     snap2 = generate(_spec(seed=2, n_pids=20, rows=600))
@@ -716,6 +790,18 @@ def _todays_caps(agg, prep) -> dict:
             if int(p) in agg._pids}
 
 
+def _cap_rows(cap) -> tuple:
+    """The bytes a (registry, n_mappings, n_locs) cap lets a reader on
+    another thread read: every location column below n_locs, and the
+    mappings below n_mappings."""
+    reg, n_mappings, n_locs = cap
+    return (reg.loc_address[:n_locs].tobytes(),
+            reg.loc_normalized[:n_locs].tobytes(),
+            reg.loc_mapping_id[:n_locs].tobytes(),
+            reg.loc_is_kernel[:n_locs].tobytes(),
+            repr(reg.mappings[:n_mappings]))
+
+
 def _same_caps(a: dict, b: dict) -> bool:
     return a.keys() == b.keys() and all(
         a[p][0] is b[p][0] and a[p][1:] == b[p][1:] for p in a)
@@ -750,6 +836,7 @@ def carried():
     enc, ref = (WindowEncoder(a) for a in aggs)
     facts: dict[str, dict] = {}
     frozen: list[tuple[dict, dict]] = []
+    rows_then: list[list[tuple]] = []   # each window's caps, as read then
     state = {"t": 0, "prev_out": None, "prev_caps": None, "seen": None,
              "evicted": False}
 
@@ -786,6 +873,8 @@ def carried():
             # The ids the compaction left are those the window began on.
             ids0 = int((aggs[0].id_remap(epoch0) >= 0).sum())
         frozen.append((prep.caps, dict(prep.caps)))
+        rows_then.append([(cap, cap[0]._address, _cap_rows(cap))
+                          for cap in prep.caps.values()])
         try:
             _assert_same_profiles(
                 aggs[0], dataclasses.replace(snap, time_ns=snap.time_ns + t),
@@ -846,6 +935,15 @@ def carried():
     window("steady_after_rotation", m_cold)
     window("steady_after_rotation_2", m_cold)
     facts["_frozen"] = all(now == then for now, then in frozen)
+    # What a cap frozen in prepare() reads below its lengths, read again
+    # now that later windows appended to its registry; and how many of
+    # those registries meanwhile moved to a grown buffer.
+    caps_then = [c for window_caps in rows_then for c in window_caps]
+    facts["_rows_frozen"] = all(
+        _cap_rows(cap) == rows for cap, _buf, rows in caps_then)
+    facts["_caps_outgrown"] = sum(
+        cap[0]._address is not buf and cap[0].n_locs > cap[2]
+        for cap, buf, _rows in caps_then)
     facts["_epochs"] = [a.registry_epoch for a in aggs]
     return facts
 
@@ -904,6 +1002,12 @@ def test_a_rollout_window_reads_the_caps_of_the_pids_it_touched(carried):
 def test_prepared_caps_are_never_mutated_after_the_hand_off(carried):
     assert carried["_frozen"]
     assert carried["_epochs"][0] == carried["_epochs"][1] == 2
+    # Nor are the rows a cap covers: `[:n]` of every column reads what it
+    # read at the hand-off, also where the capture thread has since
+    # appended past a growth (the registry's columns are another buffer
+    # by now, with an equal prefix).
+    assert carried["_rows_frozen"]
+    assert carried["_caps_outgrown"] > 0
 
 
 def test_reused_views_read_the_new_counts_and_times():
